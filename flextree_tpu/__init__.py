@@ -10,7 +10,6 @@ benchmark harness — re-architected for TPU: schedules lower to
 planner factors the device count along physical torus axes.
 """
 
-from .utils import compat as _compat  # noqa: F401  installs jax API shims
 from .schedule import (
     BlockLayout,
     Operation,

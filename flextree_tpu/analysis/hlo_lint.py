@@ -17,7 +17,7 @@ program may contain —
   f32 upcast doubles wire bytes and is exactly the kind of regression a
   refactor introduces without failing any numeric test;
 - **donation**: entrypoints jitted with donated buffers actually lower
-  with ``jax.buffer_donor`` so XLA may alias (a dropped donation doubles
+  with ``tf.aliasing_output`` / ``jax.buffer_donor`` so XLA may alias (a dropped donation doubles
   peak memory, again numerically invisible).
 
 Everything works on ``jax.jit(...).lower().as_text()`` — tracing plus
@@ -317,14 +317,18 @@ def lint_ir(name: str, ir: str, budget: HloBudget) -> list[Violation]:
                     "replicated again and the sharded wire savings gone",
                 )
             )
-    if budget.require_donation and "jax.buffer_donor" not in ir:
+    if budget.require_donation and not any(
+        # a donation matched to an output at lowering, or left to XLA
+        attr in ir for attr in ("tf.aliasing_output", "jax.buffer_donor")
+    ):
         out.append(
             Violation(
                 "hlo",
                 "donation",
                 name,
-                "no jax.buffer_donor attribute survived lowering: the "
-                "donated input is being copied, doubling peak memory",
+                "neither tf.aliasing_output nor jax.buffer_donor survived "
+                "lowering: the donated input is being copied, doubling "
+                "peak memory",
             )
         )
     return out
@@ -347,7 +351,7 @@ def _require_devices(n: int = 8) -> None:
 def _lower_allreduce(topo, op="sum", dtype=None, chunks=1, donate=False) -> str:
     import jax
     import jax.numpy as jnp
-    from jax.sharding import PartitionSpec as P
+    from jax.sharding import NamedSharding, PartitionSpec as P
 
     from ..parallel import tree_allreduce
     from ..parallel.mesh import flat_mesh
@@ -361,7 +365,12 @@ def _lower_allreduce(topo, op="sum", dtype=None, chunks=1, donate=False) -> str:
 
     fn = jax.shard_map(f, mesh=mesh, in_specs=P("ft"), out_specs=P("ft"))
     jitted = jax.jit(fn, donate_argnums=(0,) if donate else ())
-    return jitted.lower(jnp.zeros((8, 64), dtype)).as_text()
+    # the input arrives row-sharded, as every caller's does: JAX matches a
+    # donation to an output only when the shardings agree
+    x = jax.ShapeDtypeStruct(
+        (8, 64), dtype, sharding=NamedSharding(mesh, P("ft"))
+    )
+    return jitted.lower(x).as_text()
 
 
 def _lower_compressed_allreduce(topo, codec, size: int = 2048, upcast: bool = False) -> str:

@@ -2,8 +2,8 @@
 --comm-type flextree --topo 4,2``.
 
 Flag set mirrors the reference harness (``benchmark.cpp:67-116``), with
-``--devices`` / ``--cpu N`` replacing ``mpirun -np N`` (virtual CPU devices
-stand in for ranks when real multi-chip hardware isn't attached) and
+``--devices`` / ``--cpu N`` replacing ``mpirun -np N`` (``--cpu N`` asks for
+N virtual CPU devices by name; without it the run needs an accelerator) and
 ``--comm-type xla`` as the library-baseline A/B (``--comm-type mpi`` there).
 ``--version`` prints the package version like the reference's git-stamped
 ``--version`` (``benchmark.cpp:109-115``).
@@ -61,7 +61,7 @@ def main(argv=None) -> int:
         "--attn-variant", choices=["loop", "pipelined", "kvgrid"],
         default="loop",
         help="flash forward k-walk structure (ablation knob for the "
-        "MXU/VPU-overlap win; loop = the carry-serialized r03 kernel)",
+        "MXU/VPU-overlap question; loop = the carry-serialized kernel)",
     )
     ap.add_argument(
         "--attn-mode", choices=["fwd", "grad"], default="fwd",
@@ -93,15 +93,15 @@ def main(argv=None) -> int:
         print(version_string())
         return 0
 
+    import jax
+
+    from ..utils.backend import announce_devices, enable_compile_cache
+
     if args.cpu:
-        import jax
-
-        from flextree_tpu.utils.compat import request_cpu_devices
-
         jax.config.update("jax_platforms", "cpu")
-        # this jax pin has no jax_num_cpu_devices option — the compat
-        # shim falls back to XLA_FLAGS (same fix as trainer --cpu)
-        request_cpu_devices(args.cpu)
+        jax.config.update("jax_num_cpu_devices", args.cpu)
+    enable_compile_cache()
+    announce_devices("flextree_tpu.bench")
 
     if args.bench == "attention":
         from .harness import (

@@ -169,7 +169,9 @@ def prefill_suffix(params, tokens, prefix_kv, cfg: TransformerConfig,
     with a different accumulation order than the multi-row prefill —
     numerically fine, but not bitwise against the full prefill.  Callers
     that need the bitwise guarantee must pass at least two suffix
-    tokens.
+    tokens.  XLA:CPU (jaxlib 0.9.0) has a second class boundary in the
+    same P·V contraction, between 16 and 17 query rows: across it the
+    identity holds to ~1e-7, not to the bit.
     """
     b, t = tokens.shape
     ks = prefix_kv["k"]

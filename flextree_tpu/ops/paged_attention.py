@@ -24,10 +24,10 @@ Two implementations, one contract:
   the knob exists because the trade flips on hardware where fewer,
   larger contractions beat tighter masking.
 - ``impl="pallas"`` — a Pallas kernel, one grid step per slot, same
-  accumulation order; ``interpret=None`` auto-selects the interpreter
-  off-TPU exactly like ``flash_attention`` does.  On CPU it validates the
-  kernel's numerics (the interpreter emulates, so its *timings* are a
-  floor, not the TPU win).
+  accumulation order.  It runs under the Pallas interpreter on the CPU
+  only, where it validates the kernel's numerics.  It CANNOT lower for a
+  TPU as written (see :func:`require_runnable`), so on a TPU it is
+  refused with one sentence; rewriting it is ROADMAP S6.
 
 ``paged_attention_gather`` is the retained gather-materialize oracle —
 the exact computation the historical decode step ran, and the thing
@@ -56,10 +56,13 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
+from ..utils.backend import pallas_interpret
+
 __all__ = [
     "FUSED_DECODE_ATOL",
     "paged_attention",
     "paged_attention_gather",
+    "require_runnable",
 ]
 
 _NEG_INF = -1e30
@@ -225,13 +228,32 @@ def _paged_kernel(q_ref, kn_ref, vn_ref, tab_ref, len_ref, kp_ref, vp_ref,
     o_ref[0] = (acc / l).astype(o_ref.dtype)
 
 
+def require_runnable(impl: str, interpret: bool | None = None) -> None:
+    """Raise unless ``impl`` can run where this process runs.
+
+    Mosaic refuses the ``"pallas"`` kernel at lowering, whatever the
+    shape: the ``(1, P)`` block on the ``(S, P)`` int32 table breaks the
+    (8, 128) tiling rule, the whole K/V pool is one VMEM block, and the
+    table and lengths are read as scalars out of VMEM instead of SMEM.
+    Saying so here, once, beats a lowering dump from inside the first
+    decode round; nothing swaps in the ``jnp`` path on the caller's
+    behalf.
+    """
+    if impl not in ("jnp", "pallas"):
+        raise ValueError(f"unknown paged-attention impl {impl!r}")
+    if impl == "pallas" and not pallas_interpret(interpret):
+        raise NotImplementedError(
+            "paged attention impl='pallas' cannot lower for TPU (its block "
+            "shapes break Mosaic's (8, 128) rule and it holds the whole K/V "
+            "pool in VMEM); use impl='jnp' on a TPU."
+        )
+
+
 def _stream_pallas(q, k_new, v_new, k_pool, v_pool, tables, lengths, scale,
                    interpret):
     s, h, d = q.shape
     n, bs = k_pool.shape[:2]
     p = tables.shape[1]
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     return pl.pallas_call(
         functools.partial(_paged_kernel, bs=bs, scale=scale),
         out_shape=jax.ShapeDtypeStruct((s, h, d), q.dtype),
@@ -280,10 +302,11 @@ def paged_attention(
     :data:`FUSED_DECODE_ATOL` (summation order is the only difference).
 
     ``impl="jnp"`` is the batched block-streaming path (``block_chunk``
-    table columns per loop step); ``impl="pallas"`` runs the kernel
-    (interpreted off-TPU, like ``flash_attention``'s ``interpret=``
-    plumbing).
+    table columns per loop step); ``impl="pallas"`` runs the kernel under
+    the interpreter on the CPU and is refused on a TPU
+    (:func:`require_runnable`).
     """
+    require_runnable(impl, interpret)
     _check_shapes(q, k_new, v_new, k_pool, v_pool, tables, lengths)
     if scale is None:
         scale = 1.0 / (q.shape[-1] ** 0.5)
@@ -292,7 +315,5 @@ def paged_attention(
     if impl == "jnp":
         return _stream_jnp(q, k_new, v_new, k_pool, v_pool, tables, lengths,
                            float(scale), block_chunk)
-    if impl == "pallas":
-        return _stream_pallas(q, k_new, v_new, k_pool, v_pool, tables,
-                              lengths, float(scale), interpret)
-    raise ValueError(f"unknown paged-attention impl {impl!r}")
+    return _stream_pallas(q, k_new, v_new, k_pool, v_pool, tables, lengths,
+                          float(scale), pallas_interpret(interpret))

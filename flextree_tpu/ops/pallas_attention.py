@@ -27,11 +27,15 @@ delta rows).
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from ..utils.backend import pallas_interpret
 
 __all__ = ["flash_attention", "attention_with_offsets"]
 
@@ -40,18 +44,55 @@ _LANE = 128  # lse is lane-replicated to satisfy Mosaic's (8, 128) block rule
 _LOG2E = 1.4426950408889634
 
 # forward k-loop unroll factor (env-overridable for tuning experiments);
-# measured neutral-to-slightly-negative on v5e at the benchmark shape, so
 # the default stays 1 — the knob exists for other chips/shapes
 import os as _os
 
 _FWD_UNROLL = int(_os.environ.get("FLEXTREE_FLASH_UNROLL", "1"))
 
-# Default forward k-walk schedule.  "loop" is the r03 kernel with measured
-# TPU numbers (93.3 TFLOP/s, BENCH_ATTENTION.json); "pipelined"/"kvgrid"
-# are CPU-parity-pinned but flip to default only once the on-chip variant
-# ablation (tools/run_tpu_artifacts.sh) shows one of them winning.
-# Env-overridable so the bench can sweep without editing call sites.
+# Default forward k-walk schedule.  "loop" is the only variant with a chip
+# number behind it (BENCH_ATTENTION.json, 2026-07-30); "pipelined"/"kvgrid"
+# are CPU-parity-pinned and not measured on today's code (ROADMAP S2 times
+# all three and keeps one).  Env-overridable so a bench can sweep without
+# editing call sites.
 DEFAULT_FWD_VARIANT = _os.environ.get("FLEXTREE_FLASH_VARIANT", "loop")
+
+# Mosaic's default scoped-VMEM budget on v5e: 16 MiB unless a kernel raises
+# vmem_limit_bytes, which these do not.
+_SCOPED_VMEM_BYTES = 16 * 1024 * 1024
+
+
+def _check_vmem(kernel: str, blocks, q) -> None:
+    """Refuse at trace time what Mosaic refuses at compile time.
+
+    ``blocks``: the (block_shape, dtype) of every BlockSpec'd operand and
+    result of one ``pallas_call``.  The pipeline double-buffers each, and
+    ``2 * sum(bytes)`` reproduced Mosaic's own "scoped allocation" figure
+    to the byte at every shape it refused (AOT compiles for v5e, B4 H16
+    D128, T 2048..16384, bf16 and f32, default block sizes, jax 0.9.0 /
+    libtpu 0.0.34).  It is a floor, not the whole need: Mosaic adds its
+    own scratch for the score tiles, which grows with block_q x block_k
+    (the pipelined forward at block_q=1024 passed this rule and was
+    refused by Mosaic on the chip), so a shape this passes can still be
+    refused by the compiler.  With fewer than 8 batch*head rows Mosaic
+    single-buffers blocks that never move and accepts more than this rule
+    would; the compiler decides there.
+    """
+    b, _, h, _ = q.shape
+    if b * h < 8:
+        return
+    need = 2 * sum(
+        math.prod(shape) * jnp.dtype(dtype).itemsize for shape, dtype in blocks
+    )
+    if need > _SCOPED_VMEM_BYTES:
+        raise ValueError(
+            f"flash attention {kernel} kernel does not fit VMEM for "
+            f"q{tuple(q.shape)} {q.dtype.name}: its double-buffered blocks "
+            f"need {need} bytes ({need / 2**20:.2f} MiB) against the "
+            f"{_SCOPED_VMEM_BYTES}-byte (16 MiB) scoped-VMEM limit. The "
+            f"kernel keeps whole-sequence operands resident, so the need "
+            f"grows with T and the element size: shard the sequence, use "
+            f"bfloat16, or use attn_impl='reference'."
+        )
 
 
 def attention_with_offsets(
@@ -97,8 +138,8 @@ def _flash_kernel(
     # fold scale*log2(e) into q once (bq x D) instead of scaling each
     # (bq x bk) score tile, and run the online softmax in the exp2 domain —
     # softmax is base-invariant when max/normalizer use the same base.
-    # Together with the full/masked loop split below this lifted the v5e
-    # benchmark shape from 83 to ~95 TFLOP/s (see PROFILE_ATTENTION.md).
+    # (The speed-up of this and the full/masked loop split below is not
+    # measured on today's code.)
     q = q_ref[0] * (scale * _LOG2E)  # native dtype — bf16 q/k feed the MXU
     d = q.shape[-1]
     n_kb = t_kv // block_k
@@ -161,9 +202,7 @@ def _flash_kernel(
         # tile j-1's already-computed scores into the online softmax (VPU +
         # the p@v MXU op).  Inside one loop body the two are explicitly
         # independent, so Mosaic can overlap them — the cross-iteration
-        # scheduling a carry-serialized ``fori_loop`` body denies it
-        # (PROFILE_ATTENTION.md §2: the ~52% ceiling assumed no MXU/VPU
-        # overlap; this is the lever that escapes it).
+        # scheduling a carry-serialized ``fori_loop`` body denies it.
         s0, vb0 = tile(0)  # safe: t_kv >= block_k always (padded geometry)
 
         def step_pipe(j, carry):
@@ -189,7 +228,7 @@ def _flash_kernel(
                 0, kb_full, step_full, (m0, l0, acc0), unroll=unroll
             )
         except ValueError:
-            # older JAX rejects unroll with the dynamic (causal) bound;
+            # JAX rejects unroll with the dynamic (causal) bound;
             # unroll is a tuning knob, never a semantics change — fall back
             carry = lax.fori_loop(0, kb_full, step_full, (m0, l0, acc0))
     m, l, acc = lax.fori_loop(kb_full, kb_hi, step_masked, carry)
@@ -235,7 +274,7 @@ def _flash_kernel_kvgrid(
     per step, double-buffers the k/v fetches across steps, and can overlap
     tile t+1's DMA/matmul with tile t's softmax.  This is the structure
     the stock Pallas TPU flash kernel uses; the ``loop`` variant's dynamic
-    trip count denies Mosaic all of it (PROFILE_ATTENTION.md §2/§4).
+    trip count denies Mosaic all of it.
     Causally-invisible (i, j) grid steps skip compute under ``pl.when``
     (their k/v DMA still happens — same total traffic as the loop
     variant's whole-k/v residency).
@@ -415,8 +454,7 @@ def _flash_fwd_impl(
         raise ValueError(f"unknown flash variant {variant!r}")
     b, tq, h, d = q.shape
     tk = k.shape[1]
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = pallas_interpret(interpret)
     bq, bk, tq_pad, tk_pad = _blocks(q, k, block_q, block_k)
     q3, k3, v3 = _to_bhd(q, tq_pad), _to_bhd(k, tk_pad), _to_bhd(v, tk_pad)
 
@@ -430,16 +468,9 @@ def _flash_fwd_impl(
             out_specs.append(
                 pl.BlockSpec((1, bq, _LANE), lambda bh, i, j: (bh, i, 0))
             )
-        from jax.experimental.pallas import tpu as pltpu
-
-        try:
-            compiler_params = pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel", "arbitrary")
-            )
-        except AttributeError:  # pragma: no cover - older naming
-            compiler_params = pltpu.TPUCompilerParams(
-                dimension_semantics=("parallel", "parallel", "arbitrary")
-            )
+        compiler_params = pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")
+        )
         # k/v-major DMA granule: up to 4 minor tiles (<= 2048 rows) per
         # grid step, statically unrolled in the kernel — bigger transfers
         # for the pipeline to double-buffer, with per-minor-tile compute
@@ -488,6 +519,15 @@ def _flash_fwd_impl(
             )
             out_specs.append(
                 pl.BlockSpec((1, bq, _LANE), lambda bh, i: (bh, i, 0))
+            )
+        if not interpret:
+            # k and v are resident whole: the need grows with Tk
+            _check_vmem(
+                f"forward ({variant})",
+                [((bq, d), q.dtype), ((tk_pad, d), k.dtype),
+                 ((tk_pad, d), v.dtype), ((bq, d), q.dtype)]
+                + ([((bq, _LANE), jnp.float32)] if emit_lse else []),
+                q,
             )
         res = pl.pallas_call(
             functools.partial(
@@ -701,9 +741,25 @@ def _flash_bwd_impl(
     the lse output ((B*H, Tq_pad) or None when lse was not consumed)."""
     b, tq, h, d = q.shape
     tk = k.shape[1]
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = pallas_interpret(interpret)
     bq, bk, tq_pad, tk_pad = _blocks(q, k, block_q, block_k)
+    if not interpret:
+        n_lse = 2 if g_lse is not None else 1
+        # dq walks whole k/v per q tile; dk/dv keeps whole-T q, do, o and
+        # the lane-replicated f32 lse (and its cotangent) resident per k
+        # tile — the operand set that outgrows VMEM first
+        _check_vmem(
+            "backward dq",
+            [((bq, d), q.dtype)] * 4 + [((tk_pad, d), k.dtype)] * 2
+            + [((bq, _LANE), jnp.float32)] * n_lse,
+            q,
+        )
+        _check_vmem(
+            "backward dk/dv",
+            [((tq_pad, d), q.dtype)] * 3 + [((bk, d), k.dtype)] * 4
+            + [((tq_pad, _LANE), jnp.float32)] * n_lse,
+            q,
+        )
     q3, k3, v3 = _to_bhd(q, tq_pad), _to_bhd(k, tk_pad), _to_bhd(v, tk_pad)
     do3 = _to_bhd(g, tq_pad)
     o3 = _to_bhd(out, tq_pad)
@@ -901,8 +957,20 @@ def flash_attention(
 
     Same contract as ``attention_reference`` (output for the local queries
     in ``q``'s dtype) plus global ``q_offset``/``k_offset`` positions for
-    causal masking of shifted blocks.  ``interpret=None`` auto-selects the
-    Pallas interpreter off-TPU so tests run on CPU.
+    causal masking of shifted blocks.  ``interpret=None`` runs the Mosaic
+    kernel on a TPU and the Pallas interpreter on the CPU (so tests run
+    there); any other backend is an error
+    (``utils.backend.pallas_interpret``).
+
+    VMEM limits, found by AOT compile for v5e at B4 H16 D128 and refused
+    here with a ``ValueError`` at trace time (``_check_vmem``) instead of
+    Mosaic's stack dump from inside a train step: the ``loop``/``pipelined``
+    forward keeps whole k and v resident and stops fitting at f32 T >= 8192
+    (bf16 T >= 16384); the dK/dV backward keeps whole-T q, do, o and the
+    lane-replicated lse resident and stops fitting at bf16 T >= 8192 and
+    f32 T >= 4096.  ``kvgrid`` tiles k/v and has no such forward limit.
+    T is the LOCAL length: under ring/zigzag sequence parallelism each hop
+    sees T/sp.
 
     With ``return_lse=True`` also returns the per-row logsumexp of the
     masked scores, shape (B, Tq, H) float32 (fully-masked rows: -1e30) —
@@ -910,8 +978,8 @@ def flash_attention(
     attention) merge partial attentions exactly.
 
     ``variant`` selects the forward k-walk structure — identical numerics:
-    "loop" (carry-serialized fori_loop, the r03 kernel; the default via
-    ``DEFAULT_FWD_VARIANT`` until the on-chip ablation crowns a winner),
+    "loop" (carry-serialized fori_loop; the default via
+    ``DEFAULT_FWD_VARIANT`` until a chip measurement picks one),
     "pipelined" (software-pipelined fori_loop: tile j's MXU score matmul
     issued alongside tile j-1's VPU softmax), "kvgrid" (k/v tiles as a
     grid axis with VMEM scratch carry and BlockSpec-DMA'd k/v — Mosaic

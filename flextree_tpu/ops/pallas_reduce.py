@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..utils.backend import pallas_interpret
 from .reduce import get_op
 
 __all__ = ["reduce_stacked", "reduce_stacked_reference"]
@@ -84,8 +85,8 @@ def reduce_stacked(
 
     ``L`` is padded internally to a multiple of ``rows_tile * 128`` with the
     op identity (like the schedule layer pads to ``data_size_aligned``,
-    ``mpi_mod.hpp:232``).  ``interpret=None`` auto-selects the Pallas
-    interpreter off-TPU so tests run on CPU.
+    ``mpi_mod.hpp:232``).  ``interpret=None`` runs the kernel on a TPU
+    and the Pallas interpreter on the CPU (``utils.backend.pallas_interpret``).
 
     ``sources_tile`` folds that many sources per grid step (a 3D input
     block) — a DMA-granularity/step-count tuning knob with identical
@@ -102,8 +103,7 @@ def reduce_stacked(
     w, length = x.shape
     if w == 1:
         return x[0]
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = pallas_interpret(interpret)
     st = np.gcd(int(sources_tile), w) if sources_tile else 1
 
     chunk = rows_tile * _LANE

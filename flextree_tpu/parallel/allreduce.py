@@ -467,7 +467,7 @@ def ring_allreduce(x: jax.Array, axis_name, op="sum") -> jax.Array:
     the allgather phase repeats the walk forwarding fully-reduced blocks
     (``:1149-1159``).  Steps run under ``lax.fori_loop`` so the compiled
     program is O(1) in N, not an unrolled 2(N-1)-deep graph.
-    ``unroll=True`` was measured (VERDICT r2 item 4): 30% SLOWER on the
+    ``unroll=True`` was measured: 30% SLOWER on the
     virtual-CPU mesh (6.5 -> 8.5 ms at N=4, 31 -> 43 ms at N=8, 1 MB) —
     the dispatch per ppermute is unchanged and the unrolled graph only
     bloats compilation, so the rolled loop stays.

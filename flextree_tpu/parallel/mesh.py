@@ -67,7 +67,12 @@ def allreduce_over_mesh(
     the reference's ``MPI_IN_PLACE`` path (``mpi_mod.hpp:1193-1215``; the
     reference benchmark always runs in-place, ``benchmark.cpp:153``).  The
     caller's array is consumed; XLA reuses its buffer for the output, which
-    removes the output allocation + copy from the hot path.
+    removes the output allocation + copy from the hot path — provided
+    ``stacked`` already lives row-per-device on ``mesh``: JAX matches a
+    donation to an output only when their shardings agree, and drops it
+    (with a "donated buffers were not usable" warning) for an unsharded
+    input.  Compiled for a v5e 2x2 the row-sharded input is aliased whole
+    (``alias_size`` == the input's bytes) for flat, tree and ring.
     """
     axis = axis_name or mesh.axis_names[0]
     n = mesh.shape[axis]

@@ -15,6 +15,13 @@ admission modes stay drivable::
     python -m flextree_tpu.serving --admission ondemand --preempt swap \\
         --blocks 33 --requests 24
 
+    # the flagship width on the chip JAX finds (no --cpu: landing on the
+    # CPU unasked is an error)
+    python -m flextree_tpu.serving --d-model 2048 --n-heads 16 --n-layers 4 \\
+        --d-ff 8192 --vocab 32768 --dtype bfloat16 --slots 16 \\
+        --block-size 16 --blocks-per-seq 128 --blocks 2049 --requests 8 \\
+        --prompt-len 512 --max-new 32
+
 Prints a JSON report: completions, throughput, TTFT percentiles, and the
 cache-pressure accounting (free/active blocks, occupancy histogram,
 preempt/resume counters) from the engine's metrics registry.
@@ -24,10 +31,10 @@ from __future__ import annotations
 
 import argparse
 import json
-import sys
+import time
 
 
-def main(argv=None) -> int:
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser(prog="flextree_tpu.serving")
     ap.add_argument("--requests", type=int, default=12)
     ap.add_argument("--slots", type=int, default=4)
@@ -43,6 +50,11 @@ def main(argv=None) -> int:
     ap.add_argument("--n-heads", type=int, default=8)
     ap.add_argument("--n-layers", type=int, default=2)
     ap.add_argument("--d-ff", type=int, default=512)
+    ap.add_argument(
+        "--dtype", choices=["float32", "bfloat16"], default="float32",
+        help="compute and KV-pool dtype (TransformerConfig.dtype); "
+        "parameters and logits stay float32",
+    )
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument(
         "--fused-decode", action=argparse.BooleanOptionalAction, default=True,
@@ -56,8 +68,8 @@ def main(argv=None) -> int:
     ap.add_argument(
         "--decode-impl", choices=["jnp", "pallas"], default="jnp",
         help="fused-path implementation: the batched block-streaming jnp "
-        "twin (default; fastest on CPU) or the Pallas kernel "
-        "(interpreted off-TPU)",
+        "twin (default) or the Pallas kernel, which runs interpreted on "
+        "the CPU only and is refused on a TPU (it cannot lower there)",
     )
     ap.add_argument(
         "--admission", choices=["reserve", "ondemand"], default="reserve",
@@ -77,12 +89,22 @@ def main(argv=None) -> int:
                     help="also write the JSON report to this path")
     ap.add_argument("--cpu", action="store_true",
                     help="pin the CPU backend (generation is single-device)")
-    args = ap.parse_args(argv)
+    return ap.parse_args(argv)
 
+
+def serve(args: argparse.Namespace):
+    """Build the engine, answer the synthetic workload, and return
+    ``(engine, requests, report)`` — everything ``main`` does short of
+    printing."""
     import jax
+
+    from ..utils.backend import announce_devices, enable_compile_cache
 
     if args.cpu:
         jax.config.update("jax_platforms", "cpu")
+    enable_compile_cache()
+    announce_devices("flextree_tpu.serving")
+    import jax.numpy as jnp
     import numpy as np
 
     from ..models.transformer import TransformerConfig, init_params
@@ -91,6 +113,7 @@ def main(argv=None) -> int:
     cfg = TransformerConfig(
         vocab_size=args.vocab, d_model=args.d_model, n_heads=args.n_heads,
         n_layers=args.n_layers, d_ff=args.d_ff,
+        dtype=getattr(jnp, args.dtype),
     )
     params = init_params(jax.random.PRNGKey(args.seed), cfg)
     pcfg = PagedCacheConfig(
@@ -119,8 +142,6 @@ def main(argv=None) -> int:
         sorted({r.prompt_len for r in reqs}),
         {pcfg.blocks_for(r.prompt_len + r.max_new_tokens) for r in reqs},
     )
-    import time
-
     t0 = time.monotonic()
     submitted = sum(1 for r in reqs if eng.submit(r))
     eng.run_until_idle()
@@ -143,12 +164,18 @@ def main(argv=None) -> int:
         "throughput_tok_s": round(tokens / makespan, 2) if makespan else 0.0,
         **eng.report(),
     }
+    return eng, reqs, report
+
+
+def main(argv=None) -> int:
+    args = parse_args(argv)
+    _, _, report = serve(args)
     text = json.dumps(report, indent=1)
     print(text)
     if args.report:
         with open(args.report, "w") as f:
             f.write(text + "\n")
-    return 0 if len(eng.completed) == submitted else 1
+    return 0 if report["completed"] == report["submitted"] else 1
 
 
 if __name__ == "__main__":

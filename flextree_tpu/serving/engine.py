@@ -166,8 +166,10 @@ class ServingEngine:
         self.pools = init_pools(cfg, pcfg)
         # donation keeps steady-state decode allocation-free: the pool
         # scatter aliases in place instead of copying the whole pool every
-        # round (measured ~35% of the paged round's cost on the CPU
-        # backend, which — on this pin — implements donation warning-free)
+        # round.  XLA:TPU aliases every donated pool buffer (AOT compile
+        # of the flagship decode for v5e: alias_size == the pools' 1.0 GiB)
+        # and so does XLA:CPU, warning-free — pools enter and leave on one
+        # device with one shape, the case JAX's donation matching needs
         self._decode = make_paged_decode_fn(
             cfg, donate=True, fused=self.fused, impl=decode_impl
         )

@@ -57,7 +57,11 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..models.generate import _qkv
-from ..ops.paged_attention import paged_attention, paged_attention_gather
+from ..ops.paged_attention import (
+    paged_attention,
+    paged_attention_gather,
+    require_runnable,
+)
 from ..models.transformer import (
     TransformerConfig,
     apply_rope,
@@ -403,7 +407,10 @@ def make_paged_decode_fn(cfg: TransformerConfig, donate: bool = True,
     """Jit ``paged_decode_step`` with the pool buffers donated (the old
     pool is dead the moment the new one exists — donation keeps steady-
     state decode allocation-free).  ``fused=``/``impl=`` select the
-    attention path (see :func:`paged_decode_step`)."""
+    attention path (see :func:`paged_decode_step`); an ``impl`` that
+    cannot run on this backend is refused here, at construction."""
+    if fused:
+        require_runnable(impl)
     return jax.jit(
         partial(paged_decode_step, cfg=cfg, fused=fused, impl=impl),
         donate_argnums=(1,) if donate else (),

@@ -13,14 +13,34 @@ with the FlexTree gradient sync, checkpoint and resume.  Examples::
     # mixture-of-experts over (1, 2, 2, 2) dp/ep/sp/tp with a 2-stage
     # hierarchical gradient-sync topology
     python -m flextree_tpu.trainer --cpu 8 --model moe --mesh 1,2,2,2 --grad-topo 2,2
+
+    # the flagship width on the chip(s) JAX finds (no --cpu: landing on the
+    # CPU unasked is an error)
+    python -m flextree_tpu.trainer --d-model 2048 --n-heads 16 --n-layers 4 \
+        --d-ff 8192 --vocab 32768 --dtype bfloat16 --attn-impl flash \
+        --batch 4 --seq-len 2048 --steps 6
 """
 
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import os
 import shutil
 import tempfile
+from typing import Any
+
+
+@dataclasses.dataclass
+class TrainRun:
+    """What :func:`train` hands back: the fit result plus the objects it
+    ran with, so a caller (``chip_smoke.py``) can inspect the compiled
+    step without rebuilding it."""
+
+    result: Any  # parallel.loop.FitResult
+    step_fn: Any  # the jitted step as built (a feedback replan may swap it)
+    mesh: Any
+    dataset: Any
 
 
 def build(args, init_state=True):
@@ -34,6 +54,8 @@ def build(args, init_state=True):
     optimizer state beside the live one doubles peak memory at exactly
     the replan moment."""
     import jax
+
+    import jax.numpy as jnp
 
     from .models.transformer import TransformerConfig
     from .parallel.train import TrainConfig
@@ -64,7 +86,9 @@ def build(args, init_state=True):
         d_ff=args.d_ff,
         sp_impl=args.sp_impl,
         attn_impl=args.attn_impl,
+        dtype=getattr(jnp, args.dtype),
     )
+
     def sharded_hooks(mesh, pspecs, params_shapes, axis_names, sspecs, tc):
         """(state_specs_for_restore, pack, unpack) for the run: sharded
         runs checkpoint CONSOLIDATED (world-size-independent), so the
@@ -202,7 +226,7 @@ def build(args, init_state=True):
     raise ValueError(f"unknown model {args.model!r}")
 
 
-def main(argv=None) -> int:
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser(prog="flextree_tpu.trainer")
     ap.add_argument("--model", choices=["dense", "pipeline", "moe"],
                     default="dense")
@@ -223,6 +247,11 @@ def main(argv=None) -> int:
     )
     ap.add_argument("--attn-impl", choices=["reference", "flash"],
                     default="reference")
+    ap.add_argument(
+        "--dtype", choices=["float32", "bfloat16"], default="float32",
+        help="compute dtype (TransformerConfig.dtype); parameters, "
+        "optimizer state, softmax and the loss stay float32",
+    )
     ap.add_argument("--lr", type=float, default=1e-3)
     ap.add_argument(
         "--grad-clip", type=float, default=0.0,
@@ -350,15 +379,28 @@ def main(argv=None) -> int:
         "({--ckpt-dir}/obs, or ./ft_obs without a checkpoint dir); "
         "equivalent to --obs-dir with that path",
     )
-    args = ap.parse_args(argv)
+    return ap.parse_args(argv)
+
+
+def train(args: argparse.Namespace) -> TrainRun:
+    """Everything ``main`` does short of printing the summary line."""
+    import jax
+
+    from .utils.backend import announce_devices, enable_compile_cache
 
     if args.cpu:
-        import jax
-
-        from .utils.compat import request_cpu_devices
-
         jax.config.update("jax_platforms", "cpu")
-        request_cpu_devices(args.cpu)  # both config spellings (compat shim)
+        jax.config.update("jax_num_cpu_devices", args.cpu)
+    enable_compile_cache()
+    announce_devices("flextree_tpu.trainer")
+    # the bucket planner prices collectives with these constants; nothing
+    # has calibrated them on ICI yet, so say which ones a run used
+    print(
+        "planner constants: "
+        + (os.environ.get("FLEXTREE_CALIBRATION")
+           or "built-in defaults (not calibrated on this fabric)"),
+        flush=True,
+    )
 
     from .data import LMDataset, synthetic_tokens
     from .parallel.loop import FitConfig, Supervision, fit
@@ -420,8 +462,6 @@ def main(argv=None) -> int:
             # the largest mesh axis (the dominant sync wire); a drift-
             # triggered replan rebuilds the step so the refreshed
             # calibration re-derives bucket sizes/topology at trace time
-            import jax
-
             from .planner.feedback import FeedbackConfig, FeedbackController
 
             param_bytes = sum(
@@ -527,6 +567,13 @@ def main(argv=None) -> int:
                     os.environ.pop("FLEXTREE_CALIBRATION", None)
                 else:
                     os.environ["FLEXTREE_CALIBRATION"] = fb_prev_cal
+    return TrainRun(result, step_fn, mesh, dataset)
+
+
+def main(argv=None) -> int:
+    args = parse_args(argv)
+    run = train(args)
+    result, mesh = run.result, run.mesh
     first = result.losses[0][1] if result.losses else float("nan")
     last = result.losses[-1][1] if result.losses else float("nan")
     print(

@@ -12,9 +12,10 @@ from __future__ import annotations
 
 __all__ = ["TPU_GENERATIONS", "tpu_generation"]
 
-#: device_kind substring -> canonical generation name.  Order matters:
-#: most-specific first ("v5 lite" before bare "v5", which is how v5p can
-#: report itself).
+#: device_kind substring -> canonical generation name, most specific
+#: first.  A bare "v5" is NOT taken for a v5p: a kind this table does not
+#: name is an error, never a guess — a wrong peak makes every utilisation
+#: figure wrong without a word.
 TPU_GENERATIONS = (
     ("v5 lite", "v5e"),
     ("v5litepod", "v5e"),
@@ -22,18 +23,21 @@ TPU_GENERATIONS = (
     ("v6 lite", "v6e"),
     ("v6e", "v6e"),
     ("v5p", "v5p"),
-    ("v5", "v5p"),
     ("v4", "v4"),
     ("v3", "v3"),
     ("v2", "v2"),
 )
 
 
-def tpu_generation(device_kind: str) -> str | None:
+def tpu_generation(device_kind: str) -> str:
     """Canonical generation name ("v5e", "v5p", ...) for a device_kind
-    string, or None when unrecognized."""
+    string; ``ValueError`` for a kind the table does not name."""
     kind = device_kind.lower()
     for sub, gen in TPU_GENERATIONS:
         if sub in kind:
             return gen
-    return None
+    raise ValueError(
+        f"unknown TPU device_kind {device_kind!r}: add it to "
+        f"flextree_tpu.utils.device.TPU_GENERATIONS (and its peaks to the "
+        f"tables keyed by generation) before measuring on it"
+    )

@@ -298,7 +298,7 @@ def test_single_leaf_bucket_compiles_identically():
     comm_span scopes) — so any measured fused-vs-per-leaf delta in that
     regime (BENCH_BUCKETING.json sync_single_large) is host noise, not a
     fusion cost."""
-    import re
+    from conftest import strip_hlo_debug
 
     mesh = flat_mesh(8, "dp")
     topos = resolve_axis_topos(mesh, ("dp",), None)
@@ -320,9 +320,8 @@ def test_single_leaf_bucket_compiles_identically():
             )
         )
 
-    strip = lambda s: re.sub(r'(metadata=\{[^}]*\}|op_name="[^"]*")', "", s)
-    per_leaf = strip(make(0).lower(tree).compile().as_text())
-    fused = strip(make(None).lower(tree).compile().as_text())
+    per_leaf = strip_hlo_debug(make(0).lower(tree).compile().as_text())
+    fused = strip_hlo_debug(make(None).lower(tree).compile().as_text())
     assert per_leaf == fused
 
 

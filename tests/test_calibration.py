@@ -3,7 +3,7 @@ orderings* — the property the reference's hand-calibrated constants
 implicitly had (``cost_model/CostModel.h:1-30``) and round 1's invented
 defaults did not.
 
-Validation per VERDICT r1 item 2: Spearman rank correlation >= 0.8 between
+Validation: Spearman rank correlation >= 0.8 between
 predicted and measured times over 5 shapes x 2 sizes on the 8-vdev mesh,
 and the planner's argmin must be the measured winner or within noise of it.
 """
@@ -32,7 +32,7 @@ SIZES = [1 << 16, 1 << 18, 1 << 20]  # 256 KB, 1 MB, 4 MB float32
 @pytest.fixture(scope="module")
 def fitted():
     # median-of-10 per point: min-of-3 on a timeshared single-core host is
-    # noise-bound and produced the unreproducible fit of VERDICT r2 weak #2
+    # noise-bound and produced an unreproducible fit
     points = measure_points(TOPOS, SIZES, repeat=10, devices=8, stat="median")
     params = fit_cost_params(points)
     return points, params
@@ -52,8 +52,8 @@ def test_fitted_model_rank_correlates(fitted):
     )
     # Non-degeneracy first: the fit must actually discriminate shapes at
     # each size, by more than the measurement noise — otherwise the rank
-    # assertion below would be judging tie-broken noise (VERDICT r2 weak #2:
-    # the round-2 fit predicted a 1.17x spread where measurements spread
+    # assertion below would be judging tie-broken noise (an earlier
+    # fit predicted a 1.17x spread where measurements spread
     # 1.9x, i.e. the shape features had been zeroed out).
     for nb in sorted({p.nbytes for p in points}):
         idx = [i for i, p in enumerate(points) if p.nbytes == nb]
@@ -116,7 +116,7 @@ def test_fit_recovers_synthetic_constants():
 
 def test_fit_quality_under_noise_deterministic():
     """Fit on noise-corrupted model data must still rank shapes correctly
-    (VERDICT r4 item 6: the live rank tests are opt-in ``perf``; this pins
+    (the live rank tests are opt-in ``perf``; this pins
     fit *quality* in every default run, deterministically).
 
     Seeded +-15% multiplicative noise on every point — comparable to the

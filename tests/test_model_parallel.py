@@ -250,7 +250,7 @@ def test_graft_entry_contract(monkeypatch):
 
 @pytest.mark.slow
 def test_dryrun_non_power_of_two_world():
-    """The driver-facing extra worlds (VERDICT r3 item 7): one child dryrun
+    """The driver-facing extra worlds: one child dryrun
     at n=12 running the grad-sync oracles (tree topologies, lonely shape,
     planner-picked multi-slice sync vs psum) exactly as dryrun_multichip(8)
     spawns it — but scenario-subset so the test stays minutes, not tens."""

@@ -3,8 +3,7 @@
 The reference ran its cluster path (``Makefile:8-24`` scp-deploy +
 ``mpirun --hostfile``); this is the analog actually executing — production
 ``init_distributed`` + ``hybrid_mesh`` with a genuine process-granule DCN
-axis, FlexTree tree + ring allreduce across the process boundary (VERDICT
-r3 missing #2).  The committed artifact is ``MULTIPROC_BRINGUP.json``
+axis, FlexTree tree + ring allreduce across the process boundary.  The committed artifact is ``MULTIPROC_BRINGUP.json``
 (regenerate with ``python tools/multiproc_bringup.py``).
 """
 
@@ -35,7 +34,7 @@ def test_two_process_bringup_allreduce():
 
 def test_committed_bringup_artifact_carries_timings():
     """The committed MULTIPROC_BRINGUP.json must carry the measured
-    hierarchy A/B across the real process boundary (VERDICT r4 item 3):
+    hierarchy A/B across the real process boundary:
     per-config min/avg timings, the planner's pick, and — since this
     1-core fabric lacks the link asymmetry the hierarchy exploits — the
     honest analysis of why flat wins here (hierarchy_win recorded either

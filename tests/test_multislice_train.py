@@ -1,7 +1,7 @@
 """Multi-slice integration: launch -> planner -> production train step.
 
-Composes three individually-tested subsystems end to end (VERDICT r2 item
-6): ``parallel/launch.py``'s hybrid DCN x ICI mesh, the DCN-aware planner
+Composes three individually-tested subsystems end to end:
+``parallel/launch.py``'s hybrid DCN x ICI mesh, the DCN-aware planner
 (``plan_for_mesh``), and ``parallel/train.py``'s full train step.  A
 2-slice x 4-chip virtual system trains data-parallel over all 8 devices;
 the planner picks the gradient-sync topology from the mesh's physical

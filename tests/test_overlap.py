@@ -448,8 +448,10 @@ def test_overlap_false_compiles_the_historical_program():
         jax.random.PRNGKey(0),
     )
     tok = jax.ShapeDtypeStruct((4, 32), jnp.int32)
-    a = STRIP.sub("", production.lower(state_sds, tok, tok).compile().as_text())
-    b = STRIP.sub("", replica.lower(state_sds, tok, tok).compile().as_text())
+    from conftest import strip_hlo_debug
+
+    a = strip_hlo_debug(production.lower(state_sds, tok, tok).compile().as_text())
+    b = strip_hlo_debug(replica.lower(state_sds, tok, tok).compile().as_text())
     assert a == b
 
 

@@ -6,7 +6,7 @@ fabric (``cost_model/CostModel.h:82-119``, ``cost_model/README.md:5-71`` —
 the two-level 16-host Ethernet cluster).  The TPU analog of that fabric is
 multi-slice: fast ICI inside a slice, slow DCN between slices.  A 1-core
 CPU host cannot show the win empirically (no real links), so this test pins
-the analytical + structural case end to end (VERDICT r2 next-round item 3):
+the analytical + structural case end to end:
 
 1. the planner, given the multi-slice mesh, picks a multi-stage ICI-first
    shape — NOT flat, NOT ring;

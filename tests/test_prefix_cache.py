@@ -15,7 +15,8 @@ The decisive properties, in dependency order:
   same requests build identical key paths);
 - **suffix-only prefill is bitwise**: ``prefill_suffix`` over a cached
   prefix reproduces the full prefill's last-token logits AND its suffix
-  cache rows exactly — no tolerance;
+  cache rows exactly — wherever XLA sums in one order for both (see
+  ``_pv_row_class``; a stated tolerance only across that boundary);
 - **the warm engine is the cold engine**: with the prefix cache on,
   every completed request's tokens are bitwise-identical to a cold
   engine and to contiguous ``generate`` — through COW divergence
@@ -310,11 +311,28 @@ def test_index_clear_releases_everything():
 # ----------------------------------------------------- suffix-only prefill
 
 
+def _pv_row_class(rows: int) -> int:
+    """XLA:CPU (jaxlib 0.9.0) lowers ``cached_attention``'s P·V
+    contraction — ``einsum("bhqk,bkhd->bqhd")``, a batched dot over the
+    ``max_len`` cached positions — with one of three accumulation orders,
+    chosen by the number of query rows: 1, 2..16, or 17 and up (probed by
+    slicing the rows of one einsum: the same row's result changes exactly
+    at those two boundaries and nowhere else up to 40)."""
+    return 0 if rows == 1 else 1 if rows <= 16 else 2
+
+
 @pytest.mark.parametrize("c,s", [(8, 5), (16, 8), (24, 2)])
-def test_prefill_suffix_bitwise_matches_full_prefill(model, c, s):
-    """The tentpole's bitwise core, at the kernel level: suffix prefill
-    over a cached prefix reproduces the full prefill's last-token logits
-    AND every suffix cache row exactly."""
+def test_prefill_suffix_matches_full_prefill(model, c, s):
+    """The tentpole's core, at the kernel level: suffix prefill over a
+    cached prefix reproduces the full prefill's last-token logits AND
+    every suffix cache row — bitwise when the full prefill (c + s rows)
+    and the suffix (s rows) fall in the same P·V row class, which is the
+    claim the cache was built on.  Across a class boundary the two sum
+    the same 48 products per output in a different order, so the claim
+    is re-pinned to a tolerance THERE ONLY: 1e-5 is ~100 f32 ulps at the
+    logits' magnitude (observed 6e-7) — room for two layers of
+    reassociated sums and the vocab projection, and five orders below
+    what a wrong RoPE offset or mask does to a logit (O(1))."""
     cfg, params = model
     rng = np.random.default_rng(7)
     toks = _prompt(rng, c + s)
@@ -326,18 +344,19 @@ def test_prefill_suffix_bitwise_matches_full_prefill(model, c, s):
     got_logits, got_cache = prefill_suffix(
         params, toks[None, c:], prefix, cfg, max_len=48
     )
-    np.testing.assert_array_equal(
-        np.asarray(got_logits), np.asarray(want_logits)
-    )
+    if _pv_row_class(c + s) == _pv_row_class(s):
+        check = np.testing.assert_array_equal
+    else:
+        check = lambda a, b: np.testing.assert_allclose(  # noqa: E731
+            a, b, rtol=1e-5, atol=1e-5
+        )
+    check(np.asarray(got_logits), np.asarray(want_logits))
     for l in range(cfg.n_layers):
-        np.testing.assert_array_equal(
-            np.asarray(got_cache["k"][l][:, : c + s]),
-            np.asarray(want_cache["k"][l][:, : c + s]),
-        )
-        np.testing.assert_array_equal(
-            np.asarray(got_cache["v"][l][:, : c + s]),
-            np.asarray(want_cache["v"][l][:, : c + s]),
-        )
+        for kind in ("k", "v"):
+            check(
+                np.asarray(got_cache[kind][l][:, : c + s]),
+                np.asarray(want_cache[kind][l][:, : c + s]),
+            )
 
 
 def test_prefill_suffix_rejects_empty_suffix_and_overflow(model):

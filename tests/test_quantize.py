@@ -155,7 +155,7 @@ class TestIdentityCodec:
         the compressed entrypoint compiles to the SAME program as the
         plain allreduce (modulo op-name metadata) — the codec layer adds
         literally nothing to the uncompressed path."""
-        import re
+        from conftest import strip_hlo_debug
 
         mesh = flat_mesh(N, "ft")
 
@@ -169,9 +169,8 @@ class TestIdentityCodec:
             )
             return jf.lower(jnp.zeros((N, 4096), jnp.float32)).compile().as_text()
 
-        strip = lambda s: re.sub(r'(metadata=\{[^}]*\}|op_name="[^"]*")', "", s)
-        plain = strip(lower(lambda v: allreduce(v, "ft", topo="4,2")))
-        compressed = strip(
+        plain = strip_hlo_debug(lower(lambda v: allreduce(v, "ft", topo="4,2")))
+        compressed = strip_hlo_debug(
             lower(lambda v: compressed_allreduce(v, "ft", topo="4,2", codec="f32"))
         )
         assert plain == compressed

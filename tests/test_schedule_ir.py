@@ -26,12 +26,12 @@ Four contracts, in order of importance:
 from __future__ import annotations
 
 import dataclasses
-import re
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from conftest import strip_hlo_debug
 from jax.sharding import PartitionSpec as P
 
 from flextree_tpu.analysis.schedule_check import (
@@ -66,7 +66,8 @@ needs_8_devices = pytest.mark.skipif(
     len(jax.devices()) < 8, reason="needs 8 (virtual) devices"
 )
 
-_STRIP = re.compile(r'(metadata=\{[^}]*\}|op_name="[^"]*")')
+def _compiled(fn, x) -> str:
+    return strip_hlo_debug(fn.lower(x).compile().as_text())
 
 
 def _jit_collective(f, n):
@@ -123,9 +124,7 @@ class TestGoldenEquivalence:
             lambda v: tree_allreduce(v, "ft", topo, chunks=chunks), 8
         )
         assert _bitwise_equal(ir_fn(x), legacy(x))
-        assert _STRIP.sub("", ir_fn.lower(x).compile().as_text()) == _STRIP.sub(
-            "", legacy.lower(x).compile().as_text()
-        )
+        assert _compiled(ir_fn, x) == _compiled(legacy, x)
 
     @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
     @pytest.mark.parametrize("count", [64, 67, 5])
@@ -137,9 +136,7 @@ class TestGoldenEquivalence:
         ir_fn = _jit_collective(lambda v: allreduce(v, "ft", "1"), 8)
         legacy = _jit_collective(lambda v: ring_allreduce(v, "ft"), 8)
         assert _bitwise_equal(ir_fn(x), legacy(x))
-        assert _STRIP.sub("", ir_fn.lower(x).compile().as_text()) == _STRIP.sub(
-            "", legacy.lower(x).compile().as_text()
-        )
+        assert _compiled(ir_fn, x) == _compiled(legacy, x)
 
     @pytest.mark.parametrize("topo", ["3,2+2", "7+1"])
     @pytest.mark.parametrize("count", [66, 63, 100])
@@ -151,9 +148,7 @@ class TestGoldenEquivalence:
         ir_fn = _jit_collective(lambda v: allreduce(v, "ft", topo), 8)
         legacy = _jit_collective(lambda v: lonely_allreduce(v, "ft", topo), 8)
         assert _bitwise_equal(ir_fn(x), legacy(x))
-        assert _STRIP.sub("", ir_fn.lower(x).compile().as_text()) == _STRIP.sub(
-            "", legacy.lower(x).compile().as_text()
-        )
+        assert _compiled(ir_fn, x) == _compiled(legacy, x)
 
     def test_non_sum_op_routes_through_ir_identically(self):
         x = jnp.asarray(

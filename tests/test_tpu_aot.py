@@ -1,0 +1,213 @@
+"""Compile for a TPU v5e from abstract shapes, on a machine with no chip.
+
+libtpu ships the real Mosaic and XLA:TPU compilers, and
+``jax.experimental.topologies`` hands out abstract v5e devices, so
+``jit(f).trace(avals).lower(lowering_platforms=("tpu",)).compile()`` says
+what the chip's compiler will say — shapes that do not fit VMEM, block
+specs Mosaic refuses, programs that do not partition — before any chip
+time is spent.  It is not a chip run: it gives no time and cannot say the
+result is right.  This is the cheap gate a kernel PR runs first.
+
+Kernel-level tests are unmarked (a second or two each); the step-level
+ones compile the flagship width (``flagship-d2048``: vocab 32768, d_model
+2048, 16 heads of 128, 4 layers, d_ff 8192, bf16) and are ``slow``.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+from flextree_tpu.models.transformer import TransformerConfig, init_params
+from flextree_tpu.ops.paged_attention import paged_attention
+from flextree_tpu.ops.pallas_attention import flash_attention
+from flextree_tpu.utils import backend
+
+FLAGSHIP = TransformerConfig(
+    vocab_size=32768, d_model=2048, n_heads=16, n_layers=4, d_ff=8192,
+    dtype=jnp.bfloat16, attn_impl="flash",
+)
+# chip_smoke.py's attention shape: the one-chip train batch's q/k/v
+B, T, H, D = 4, 2048, 16, 128
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """Four abstract v5e devices (a 2x2 host), or skip with the reason."""
+    try:
+        from jax.experimental import topologies
+
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        ).devices
+    except Exception as e:  # noqa: BLE001 — no libtpu, or one that cannot
+        pytest.skip(f"no TPU compiler to ask: {type(e).__name__}: {e}")
+
+
+@pytest.fixture
+def tpu_lowering(monkeypatch):
+    """Lower kernels as on the chip (Mosaic, never the interpreter), with
+    x64 off: the suite's x64 sends the Pallas TPU lowering into a
+    RecursionError, and the chip path never runs under it."""
+    monkeypatch.setattr(backend, "kernel_platform", lambda: "tpu")
+    with jax.enable_x64(False):
+        yield
+
+
+def _mesh(devices, shape):
+    n = int(np.prod(shape))
+    return Mesh(np.array(devices[:n]).reshape(shape), ("dp", "sp", "tp"))
+
+
+def _on(tree, sharding):
+    """``tree``'s shapes as avals placed by ``sharding`` (one sharding, or
+    a matching tree of them)."""
+    if not isinstance(sharding, (dict, list, tuple)):
+        sharding = jax.tree.map(lambda _: sharding, tree)
+    return jax.tree.map(
+        lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
+        tree, sharding,
+    )
+
+
+def _compile(fn, *avals):
+    jitted = fn if hasattr(fn, "trace") else jax.jit(fn)
+    return jitted.trace(*avals).lower(lowering_platforms=("tpu",)).compile()
+
+
+def _qkv(dev, dtype, t=T):
+    one = NamedSharding(_mesh([dev], (1, 1, 1)), P())
+    return [jax.ShapeDtypeStruct((B, t, H, D), dtype, sharding=one)] * 3
+
+
+def _loss(q, k, v):
+    return flash_attention(q, k, v).astype(jnp.float32).sum()
+
+
+# ------------------------------------------------------------ kernel level
+
+
+def test_flash_forward_is_a_mosaic_kernel(v5e, tpu_lowering):
+    hlo = _compile(flash_attention, *_qkv(v5e[0], jnp.bfloat16)).as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 1
+
+
+def test_flash_backward_is_two_more_mosaic_kernels(v5e, tpu_lowering):
+    grad = jax.grad(_loss, argnums=(0, 1, 2))
+    hlo = _compile(grad, *_qkv(v5e[0], jnp.bfloat16)).as_text()
+    # forward-with-lse, dq, dk/dv
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 3
+
+
+def test_flash_backward_that_cannot_fit_vmem_is_refused_at_trace(
+    v5e, tpu_lowering
+):
+    """f32 T=4096: Mosaic's own figure is 18.00M against 16.00M.  The
+    refusal must be ours (a ValueError while tracing, naming shape, dtype,
+    bytes and the limit), not a JaxRuntimeError from inside a compile."""
+    grad = jax.jit(jax.grad(_loss, argnums=(0, 1, 2)))
+    with pytest.raises(ValueError, match="scoped-VMEM") as e:
+        grad.trace(*_qkv(v5e[0], jnp.float32, t=4096))
+    msg = str(e.value)
+    assert "backward dk/dv" in msg and "float32" in msg
+    assert "(4, 4096, 16, 128)" in msg
+    assert str(18 * 2**20) in msg and str(16 * 2**20) in msg
+    # the same shape in bf16 fits, and so does the f32 forward alone
+    grad.trace(*_qkv(v5e[0], jnp.bfloat16, t=4096))
+    jax.jit(flash_attention).trace(*_qkv(v5e[0], jnp.float32, t=4096))
+
+
+def test_pallas_paged_attention_is_refused_on_tpu_in_one_sentence(
+    v5e, tpu_lowering
+):
+    from flextree_tpu.serving.kv_cache import make_paged_decode_fn
+
+    q = jnp.zeros((2, H, D), jnp.bfloat16)
+    pool = jnp.zeros((5, 16, H, D), jnp.bfloat16)
+    args = (q, q, q, pool, pool, jnp.zeros((2, 3), jnp.int32),
+            jnp.zeros((2,), jnp.int32))
+    with pytest.raises(NotImplementedError, match="cannot lower for TPU"):
+        paged_attention(*args, impl="pallas")
+    # ... and at construction, before any decode round exists
+    with pytest.raises(NotImplementedError, match="use impl='jnp'"):
+        make_paged_decode_fn(FLAGSHIP, fused=True, impl="pallas")
+    # nothing swapped anything in: the jnp path is its own request
+    jax.jit(lambda *a: paged_attention(*a, impl="jnp")).trace(*args)
+
+
+# -------------------------------------------------------------- step level
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize(
+    "shape,batch,seq", [((1, 1, 1), 4, 2048), ((2, 2, 1), 8, 4096)]
+)
+def test_flagship_train_step_compiles(v5e, tpu_lowering, shape, batch, seq):
+    from flextree_tpu.parallel.train import (
+        TrainConfig,
+        init_train_state,
+        make_train_step,
+        state_specs,
+    )
+
+    mesh = _mesh(v5e, shape)
+    tc = TrainConfig()
+    step = make_train_step(mesh, FLAGSHIP, tc)
+    shardings = jax.tree.map(
+        lambda spec: NamedSharding(mesh, spec),
+        state_specs(FLAGSHIP, train_cfg=tc, mesh=mesh),
+        is_leaf=lambda x: isinstance(x, P),
+    )
+    state = _on(
+        jax.eval_shape(
+            lambda k: init_train_state(k, FLAGSHIP, tc), jax.random.PRNGKey(0)
+        ),
+        shardings,
+    )
+    tok = jax.ShapeDtypeStruct(
+        (batch, seq), jnp.int32, sharding=NamedSharding(mesh, P("dp", "sp"))
+    )
+    compiled = _compile(step, state, tok, tok)
+    assert 'custom_call_target="tpu_custom_call"' in compiled.as_text()
+    mem = compiled.memory_analysis()
+    per_chip = (
+        mem.argument_size_in_bytes + mem.output_size_in_bytes
+        + mem.temp_size_in_bytes - mem.alias_size_in_bytes
+    )
+    assert per_chip < 16 * 2**30, f"{per_chip / 2**30:.1f} GiB a chip"
+
+
+@pytest.mark.slow
+def test_flagship_paged_decode_and_prefill_compile(v5e, tpu_lowering):
+    from flextree_tpu.models.generate import prefill
+    from flextree_tpu.serving.kv_cache import (
+        PagedCacheConfig,
+        init_pools,
+        make_paged_decode_fn,
+    )
+
+    one = NamedSharding(_mesh(v5e, (1, 1, 1)), P())
+    cfg = FLAGSHIP
+    pcfg = PagedCacheConfig(num_blocks=2049, block_size=16, blocks_per_seq=128)
+    params = _on(
+        jax.eval_shape(lambda k: init_params(k, cfg), jax.random.PRNGKey(0)),
+        one,
+    )
+    pools = _on(jax.eval_shape(lambda: init_pools(cfg, pcfg)), one)
+    slots = 16
+    tables = jax.ShapeDtypeStruct((slots, 128), jnp.int32, sharding=one)
+    row = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one)
+    decode = _compile(
+        make_paged_decode_fn(cfg, donate=True, fused=True, impl="jnp"),
+        params, pools, tables, row, row,
+    )
+    # every donated pool buffer is aliased to an output, not copied
+    pool_bytes = sum(
+        x.size * x.dtype.itemsize for x in jax.tree.leaves(pools)
+    )
+    assert decode.memory_analysis().alias_size_in_bytes == pool_bytes
+    prompt = jax.ShapeDtypeStruct((1, 512), jnp.int32, sharding=one)
+    _compile(
+        lambda p, t: prefill(p, t, cfg, max_len=pcfg.max_len), params, prompt
+    )

@@ -260,7 +260,7 @@ def test_zigzag_rejects_bad_args():
 
 
 def test_zigzag_critical_path_closed_form():
-    """The README's throughput claim, as accounting (VERDICT r4 item 7):
+    """The README's throughput claim, as accounting:
     per-hop critical path (max over devices of visible work, since the
     hop's ppermute is a lockstep barrier) summed over hops gives
     plain/zigzag = 2 - 1/n exactly, with total executed work identical —

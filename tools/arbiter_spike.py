@@ -65,9 +65,7 @@ import jax  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
 
-from flextree_tpu.utils.compat import request_cpu_devices  # noqa: E402
-
-request_cpu_devices(4)
+jax.config.update("jax_num_cpu_devices", 4)
 
 import numpy as np  # noqa: E402
 

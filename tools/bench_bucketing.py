@@ -34,9 +34,7 @@ def main() -> int:
     import jax
 
     jax.config.update("jax_platforms", "cpu")
-    from flextree_tpu.utils.compat import request_cpu_devices
-
-    request_cpu_devices(8)
+    jax.config.update("jax_num_cpu_devices", 8)
 
     from flextree_tpu.bench.harness import (
         GradSyncBenchConfig,

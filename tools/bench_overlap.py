@@ -121,9 +121,7 @@ def child_main(rounds: int, n_blocks: int) -> int:
     import jax
 
     jax.config.update("jax_platforms", "cpu")
-    from flextree_tpu.utils.compat import request_cpu_devices
-
-    request_cpu_devices(1)
+    jax.config.update("jax_num_cpu_devices", 1)
     jax.config.update("jax_cpu_collectives_implementation", "gloo")
 
     import random
@@ -408,9 +406,7 @@ def run_in_process(quick: bool) -> dict:
     import jax
 
     jax.config.update("jax_platforms", "cpu")
-    from flextree_tpu.utils.compat import request_cpu_devices
-
-    request_cpu_devices(8)
+    jax.config.update("jax_num_cpu_devices", 8)
     from flextree_tpu.bench.harness import (
         TrainStepBenchConfig,
         run_train_step_bench,

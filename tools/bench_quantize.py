@@ -54,9 +54,7 @@ def child_main(sizes, repeat) -> int:
     import jax
 
     jax.config.update("jax_platforms", "cpu")
-    from flextree_tpu.utils.compat import request_cpu_devices
-
-    request_cpu_devices(1)
+    jax.config.update("jax_num_cpu_devices", 1)
     jax.config.update("jax_cpu_collectives_implementation", "gloo")
 
     import random
@@ -223,9 +221,7 @@ def run_in_process(quick: bool) -> dict:
     import jax
 
     jax.config.update("jax_platforms", "cpu")
-    from flextree_tpu.utils.compat import request_cpu_devices
-
-    request_cpu_devices(8)
+    jax.config.update("jax_num_cpu_devices", 8)
     from flextree_tpu.bench.harness import GradSyncBenchConfig, run_grad_sync_bench
 
     cfg = GradSyncBenchConfig(

@@ -85,9 +85,7 @@ def child_main(sync_sizes, repeat, steps_n) -> int:
     import jax
 
     jax.config.update("jax_platforms", "cpu")
-    from flextree_tpu.utils.compat import request_cpu_devices
-
-    request_cpu_devices(1)
+    jax.config.update("jax_num_cpu_devices", 1)
     jax.config.update("jax_cpu_collectives_implementation", "gloo")
 
     import random

@@ -193,9 +193,7 @@ def child_train_sharded() -> int:
     import jax
 
     jax.config.update("jax_platforms", "cpu")
-    from flextree_tpu.utils.compat import request_cpu_devices
-
-    request_cpu_devices(4)
+    jax.config.update("jax_num_cpu_devices", 4)
     import numpy as np
 
     from flextree_tpu.models.transformer import (

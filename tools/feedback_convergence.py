@@ -111,9 +111,7 @@ def main(argv=None) -> int:
     import jax
 
     jax.config.update("jax_platforms", "cpu")
-    from flextree_tpu.utils.compat import request_cpu_devices
-
-    request_cpu_devices(8)
+    jax.config.update("jax_num_cpu_devices", 8)
     import numpy as np
 
     import tempfile

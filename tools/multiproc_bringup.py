@@ -5,7 +5,7 @@ The reference's cluster path actually *ran*: ``make sync`` deployed the
 binary to 16 hosts and ``mpirun --hostfile mpi_config_file`` spawned ranks
 across them (``allreduce_over_mpi/Makefile:8-24``, ``mpi_config_file:1-16``).
 Until now our analog (``flextree_tpu.parallel.launch``) was unit-tested but
-never executed across a real process boundary (VERDICT r3 missing #2).
+never executed across a real process boundary.
 
 This tool is the executed bring-up: the parent spawns two child processes,
 each pins 4 virtual CPU devices and calls the production
@@ -54,9 +54,7 @@ def child_main() -> int:
     # CPU pinning must precede any backend touch; gloo is the CPU
     # cross-process collective transport (the MPI-of-this-world)
     jax.config.update("jax_platforms", "cpu")
-    from flextree_tpu.utils.compat import request_cpu_devices
-
-    request_cpu_devices(LOCAL_DEVICES)
+    jax.config.update("jax_num_cpu_devices", LOCAL_DEVICES)
     jax.config.update("jax_cpu_collectives_implementation", "gloo")
 
     import numpy as np
@@ -143,8 +141,8 @@ def child_main() -> int:
     if not all(results.values()):
         return 1
 
-    # --- the measured hierarchy A/B across the real slow link (VERDICT r4
-    # item 3).  The gloo fabric is a genuine two-level hierarchy: intra-
+    # --- the measured hierarchy A/B across the real slow link.
+    # The gloo fabric is a genuine two-level hierarchy: intra-
     # process device "transfers" are shared-memory, cross-process ones
     # serialize through loopback TCP — a DCN/ICI analog.  Time flat vs
     # two-level vs ring vs psum on a bandwidth-sized buffer.  Caveat

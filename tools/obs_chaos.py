@@ -76,9 +76,7 @@ def child_train() -> int:
     import jax
 
     jax.config.update("jax_platforms", "cpu")
-    from flextree_tpu.utils.compat import request_cpu_devices
-
-    request_cpu_devices(2)
+    jax.config.update("jax_num_cpu_devices", 2)
     import numpy as np
 
     from flextree_tpu.models.transformer import TransformerConfig
@@ -345,9 +343,7 @@ def run_overhead_bench(repeat: int) -> dict:
     import jax
 
     jax.config.update("jax_platforms", "cpu")
-    from flextree_tpu.utils.compat import request_cpu_devices
-
-    request_cpu_devices(8)
+    jax.config.update("jax_num_cpu_devices", 8)
     from flextree_tpu.bench.harness import (
         TrainStepBenchConfig,
         run_train_step_bench,

@@ -122,7 +122,7 @@ def child_main(cfg: dict) -> None:
     n = int(cfg["ranks"])
     # calibrate the cost model on THIS host before asking the planner —
     # the r02 sweep ranked with the invented v5e defaults, so its "planner"
-    # row predicted ICI behavior on a 1-core host (VERDICT r2 weak #4/#5);
+    # row predicted ICI behavior on a 1-core host;
     # bench.py already follows this calibrate-then-trust protocol
     cal_params = None
     if "planner" in cfg["topos"]:
