@@ -23,6 +23,13 @@ is MXU-friendly and mesh-native:
   as an ordinary single-device function (no axes bound) and as a
   collective-context function inside ``shard_map``.
 
+Every phase of the block traces under one ``jax.named_scope`` of its own
+(``ft_embed``, ``ft_norm``, ``ft_attn``, ``ft_mlp``, ``ft_head``,
+``ft_loss``), never one inside another, so that the first ``ft_`` name on
+an operation's path, forward or ``transpose(jvp(...))``, is its phase: the
+step's device time is read by phase from a profile (PERF.md §3).  Scopes
+are metadata; they change no operation.
+
 All matmuls keep a (tokens, features) trailing structure with static shapes
 so XLA tiles them onto the MXU; compute dtype is configurable (bfloat16 for
 TPU), accumulation and softmax stay float32.
@@ -149,6 +156,7 @@ def param_specs(cfg: TransformerConfig, tp_axis: str | None = "tp") -> dict:
     }
 
 
+@jax.named_scope("ft_norm")
 def rms_norm(x, scale, eps: float = 1e-6):
     x32 = x.astype(jnp.float32)
     rms = jnp.sqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
@@ -205,41 +213,42 @@ def attention_block(
             f"got {cfg.attn_impl!r}"
         )
     h = rms_norm(x, layer["ln1"])
-    q = (h @ layer["wq"].astype(cfg.dtype)).reshape(b, t_local, -1, head_dim)
-    k = (h @ layer["wk"].astype(cfg.dtype)).reshape(b, t_local, -1, head_dim)
-    v = (h @ layer["wv"].astype(cfg.dtype)).reshape(b, t_local, -1, head_dim)
-    q = apply_rope(q, positions, cfg.rope_theta)
-    k = apply_rope(k, positions, cfg.rope_theta)
-    if sp_axis is None:
-        attn = local_attention(
-            q, k, v, causal=True, impl=cfg.attn_impl, **attn_opts
-        )
-    elif cfg.sp_impl == "ulysses":
-        # Ulysses' inner attention is also full-sequence-local flash —
-        # the tuned opts apply there too (ADVICE r5)
-        attn = ulysses_attention(
-            q, k, v, sp_axis, causal=True, impl=cfg.attn_impl, **attn_opts
-        )
-    elif attn_opts:
-        # ring/zigzag hop kernels run library defaults; a tuned config
-        # that cannot be honored must fail, not silently degrade
-        raise ValueError(
-            f"attn_opts {sorted(attn_opts)} are not supported by "
-            f"sp_impl={cfg.sp_impl!r} (only the full-sequence-local and "
-            f"ulysses paths take flash kwargs)"
-        )
-    elif cfg.sp_impl == "ring":
-        attn = ring_attention(q, k, v, sp_axis, causal=True, impl=cfg.attn_impl)
-    elif cfg.sp_impl == "zigzag":
-        # contiguous layout at the model boundary: RoPE positions above are
-        # contiguous-shard positions, so convert around the attention only
-        attn = zigzag_ring_attention(
-            q, k, v, sp_axis, layout="contiguous", impl=cfg.attn_impl
-        )
-    else:
-        raise ValueError(f"unknown sp_impl {cfg.sp_impl!r}")
-    o = attn.reshape(b, t_local, -1) @ layer["wo"].astype(cfg.dtype)
-    return x + _tp_combine(o, tp_axis, cfg)
+    with jax.named_scope("ft_attn"):
+        q = (h @ layer["wq"].astype(cfg.dtype)).reshape(b, t_local, -1, head_dim)
+        k = (h @ layer["wk"].astype(cfg.dtype)).reshape(b, t_local, -1, head_dim)
+        v = (h @ layer["wv"].astype(cfg.dtype)).reshape(b, t_local, -1, head_dim)
+        q = apply_rope(q, positions, cfg.rope_theta)
+        k = apply_rope(k, positions, cfg.rope_theta)
+        if sp_axis is None:
+            attn = local_attention(
+                q, k, v, causal=True, impl=cfg.attn_impl, **attn_opts
+            )
+        elif cfg.sp_impl == "ulysses":
+            # Ulysses' inner attention is also full-sequence-local flash —
+            # the tuned opts apply there too (ADVICE r5)
+            attn = ulysses_attention(
+                q, k, v, sp_axis, causal=True, impl=cfg.attn_impl, **attn_opts
+            )
+        elif attn_opts:
+            # ring/zigzag hop kernels run library defaults; a tuned config
+            # that cannot be honored must fail, not silently degrade
+            raise ValueError(
+                f"attn_opts {sorted(attn_opts)} are not supported by "
+                f"sp_impl={cfg.sp_impl!r} (only the full-sequence-local and "
+                f"ulysses paths take flash kwargs)"
+            )
+        elif cfg.sp_impl == "ring":
+            attn = ring_attention(q, k, v, sp_axis, causal=True, impl=cfg.attn_impl)
+        elif cfg.sp_impl == "zigzag":
+            # contiguous layout at the model boundary: RoPE positions above are
+            # contiguous-shard positions, so convert around the attention only
+            attn = zigzag_ring_attention(
+                q, k, v, sp_axis, layout="contiguous", impl=cfg.attn_impl
+            )
+        else:
+            raise ValueError(f"unknown sp_impl {cfg.sp_impl!r}")
+        o = attn.reshape(b, t_local, -1) @ layer["wo"].astype(cfg.dtype)
+        return x + _tp_combine(o, tp_axis, cfg)
 
 
 def layer_forward(
@@ -267,9 +276,10 @@ def layer_forward(
 def mlp_block(layer, x, cfg: TransformerConfig, *, tp_axis: str | None = None):
     """Pre-norm GELU MLP residual half (column/row-parallel over tp)."""
     h = rms_norm(x, layer["ln2"])
-    u = jax.nn.gelu(h @ layer["w1"].astype(cfg.dtype))
-    y = u @ layer["w2"].astype(cfg.dtype)
-    return x + _tp_combine(y, tp_axis, cfg)
+    with jax.named_scope("ft_mlp"):
+        u = jax.nn.gelu(h @ layer["w1"].astype(cfg.dtype))
+        y = u @ layer["w2"].astype(cfg.dtype)
+        return x + _tp_combine(y, tp_axis, cfg)
 
 
 def final_logits(embed, ln_f, h):
@@ -279,7 +289,8 @@ def final_logits(embed, ln_f, h):
     (``parallel.overlap``) — the overlap path's bitwise contract depends
     on these never drifting apart."""
     x = rms_norm(h, ln_f)
-    return x.astype(jnp.float32) @ embed.T.astype(jnp.float32)
+    with jax.named_scope("ft_head"):
+        return x.astype(jnp.float32) @ embed.T.astype(jnp.float32)
 
 
 def global_positions(t_local: int, sp_axis: str | None):
@@ -304,8 +315,9 @@ def forward(
     pre-sliced by ``param_specs``).  Returns (B, T_local, vocab) logits in
     float32, replicated over ``tp_axis``.
     """
-    positions = global_positions(tokens.shape[1], sp_axis)
-    x = params["embed"][tokens].astype(cfg.dtype)
+    with jax.named_scope("ft_embed"):
+        positions = global_positions(tokens.shape[1], sp_axis)
+        x = params["embed"][tokens].astype(cfg.dtype)
     for layer in params["layers"]:
         x = layer_forward(
             layer, x, positions, cfg, tp_axis=tp_axis, sp_axis=sp_axis
@@ -313,6 +325,7 @@ def forward(
     return final_logits(params["embed"], params["ln_f"], x)
 
 
+@jax.named_scope("ft_loss")
 def cross_entropy_loss(logits, targets):
     """Per-token cross entropy, summed — (loss_sum, token_count).
 

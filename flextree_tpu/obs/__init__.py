@@ -18,7 +18,11 @@ artifacts.  This package is the single place they meet:
   (ranks as tracks, requests and buckets as flows, every comm event
   carrying its plan provenance and predicted cost).
 
-Instrumentation sites call :func:`record_event` — a module-global read
+Instrumentation sites call :func:`record_event` for a point and
+:func:`span` for a stretch of time (a ``jax.profiler.TraceAnnotation``
+always, so a running profile shows it beside the device operations, plus
+one ``span`` event with its start, end and parent when a recorder is
+installed).  ``record_event`` is a module-global read
 plus a ``None`` check when no recorder is installed, so the library pays
 nothing until a run opts in (``with flight_recorder(dir, rank):`` or the
 trainer's ``--obs-dir``/``--flight-recorder`` flags).  See
@@ -46,6 +50,7 @@ from .recorder import (
     get_registry,
     install_signal_dump,
     record_event,
+    span,
 )
 from .stepclock import StepPlan, StepSample, StepSpanClock, plan_from_capture
 from .timeline import (
@@ -81,6 +86,7 @@ __all__ = [
     "flight_recorder",
     "current_recorder",
     "record_event",
+    "span",
     "dump_current",
     "get_registry",
     "install_signal_dump",

@@ -52,6 +52,7 @@ __all__ = [
     "flight_recorder",
     "current_recorder",
     "record_event",
+    "span",
     "dump_current",
     "get_registry",
     "install_signal_dump",
@@ -282,6 +283,67 @@ def record_event(kind: str, **fields) -> None:
     rec = _CURRENT
     if rec is not None:
         rec.record(kind, **fields)
+
+
+# Spans nest per thread: the enclosing span's name is the ``parent`` of
+# the one being opened (the heartbeat daemon's spans never adopt the step
+# loop's).  Touched only while a recorder is installed.
+_SPAN_STACKS = threading.local()
+_TraceAnnotation = None  # jax.profiler.TraceAnnotation, imported on first use
+
+
+class span:
+    """``with span("ft.engine.sample", round=7): ...`` — one named span
+    with a start, an end and a parent, in two places at once:
+
+    - always a ``jax.profiler.TraceAnnotation(name, **ids)``, so that a
+      running profile shows the span beside the device operations, on the
+      profiler's clock, nested under the spans that enclose it, with the
+      ids as its stats (when no profile runs the annotation is the
+      profiler's own flag check);
+    - when a recorder is installed, one ``span`` event on exit: ``name``,
+      ``start`` and ``end`` (one ``_wall`` read each), the enclosing
+      span's name as ``parent`` (None at the top) and the ids.
+      ``obs/timeline.py`` draws it as a duration.
+
+    Names are ``ft.<layer>.<phase>``.  An annotation takes its ids when it
+    opens: a span that must carry a count opens once the count is known.
+    The profiler's clock is the wall clock less the profile's start, so
+    one constant joins a profile's spans to the recorder's (PERF.md §3).
+    """
+
+    __slots__ = ("name", "ids", "_ann", "_rec", "_t0", "_parent")
+
+    def __init__(self, name: str, **ids):
+        global _TraceAnnotation
+        if _TraceAnnotation is None:
+            from jax.profiler import TraceAnnotation as _TraceAnnotation
+        self.name = name
+        self.ids = ids
+        self._ann = _TraceAnnotation(name, **ids)
+        self._rec = None
+
+    def __enter__(self) -> "span":
+        rec = _CURRENT
+        if rec is not None:
+            stack = _SPAN_STACKS.__dict__.setdefault("stack", [])
+            self._parent = stack[-1] if stack else None
+            stack.append(self.name)
+            self._rec = rec
+            self._t0 = _wall()
+        self._ann.__enter__()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self._ann.__exit__(*exc)
+        rec = self._rec
+        if rec is not None:
+            end = _wall()
+            _SPAN_STACKS.stack.pop()
+            rec.record(
+                "span", name=self.name, start=self._t0, end=end,
+                parent=self._parent, **self.ids,
+            )
 
 
 def dump_current(reason: str, **fields) -> str | None:

@@ -19,6 +19,10 @@ Perfetto (https://ui.perfetto.dev) or ``chrome://tracing``:
 - serving request lifecycles (``serve_admit`` → ``serve_retire``)
   become **flow arrows** keyed by request id — a re-routed request's
   arrow visibly jumps tracks;
+- ``span`` events (``obs.span``: the program's own ``ft.<layer>.<phase>``
+  spans, each with its measured ``start`` and ``end`` and its ``parent``)
+  become complete events of that duration on the main lane, nested as
+  they ran;
 - arbiter decisions (``slo_breach``, ``lease_preempt``/``lease_grant``/
   ``lease_return``, the trainer's ``lease_resize``) render on a
   dedicated **arbiter lane** with the SLO reading in their ``args``, so
@@ -104,6 +108,10 @@ _MEASURED_KINDS = frozenset({"bucket_measured", "serve_round_measured"})
 #: host wall time, args carry the comm/floor split and the plan signature
 _STEP_MEASURED_KINDS = frozenset({"step_measured"})
 
+#: the program's own spans (obs.recorder.span): one event per span, whose
+#: duration is its own ``start`` → ``end``
+_SPAN_KINDS = frozenset({"span"})
+
 _META_KEYS = frozenset({"ts", "rank", "src", "seq", "kind"})
 
 
@@ -171,7 +179,12 @@ def merge_events(events, dumps: dict[int, dict] | None = None) -> dict:
         seen.add(key)
         deduped.append(ev)
     events = sorted(deduped, key=lambda e: (e["ts"], e.get("seq", 0)))
-    t0 = events[0]["ts"] if events else 0.0
+    # a span is recorded when it closes: the trace starts where the first
+    # of them opened
+    t0 = min(
+        (e["start"] if e["kind"] in _SPAN_KINDS else e["ts"] for e in events),
+        default=0.0,
+    )
 
     def us(ts: float) -> float:
         return round((ts - t0) * 1e6, 1)
@@ -221,6 +234,22 @@ def merge_events(events, dumps: dict[int, dict] | None = None) -> dict:
             trace.append(
                 {"name": kind, "cat": base, "ph": "i", "s": "t", **common,
                  "args": _args(ev)}
+            )
+            continue
+
+        if kind in _SPAN_KINDS:
+            args = _args(ev)
+            start, end = float(args.pop("start")), float(args.pop("end"))
+            trace.append(
+                {
+                    "name": str(args.pop("name")),
+                    "cat": "span",
+                    "ph": "X",
+                    **common,
+                    "ts": us(start),
+                    "dur": max(round((end - start) * 1e6, 1), 0.1),
+                    "args": args,
+                }
             )
             continue
 

@@ -53,7 +53,7 @@ from typing import Any, Callable
 import jax
 import numpy as np
 
-from ..obs import dump_current, get_registry, record_event
+from ..obs import dump_current, get_registry, record_event, span
 from ..utils.checkpoint import (
     latest_checkpoint,
     restore_train_state,
@@ -814,251 +814,265 @@ def fit(
     )
     try:
         while step < cfg.num_steps:
-            if sup is not None:
-                if sup.preemption is not None and sup.preemption.preempted:
-                    # the checkpoint-now fast path: at most one step lost
-                    if cfg.ckpt_dir and _drained_saves():
-                        # drain timed out -> the in-flight background save
-                        # IS a recent checkpoint; racing its rotation with
-                        # a second writer would be worse than one lost step
-                        save_train_state(
-                            cfg.ckpt_dir, _packed(state),
-                            max_to_keep=cfg.max_to_keep,
-                        )
-                    report.preempted_at = step
-                    record_event("preempt", step=step)
-                    dump_current("preempted", step=step)
-                    log.warning(
-                        "preemption: checkpointed at step %d, exiting", step
+            with span("ft.loop.step", step=step):
+                if sup is not None or arbiter is not None:
+                    # supervisor, coordination and lease ticks
+                    with span("ft.loop.bookkeeping"):
+                        if sup is not None:
+                            if sup.preemption is not None and sup.preemption.preempted:
+                                # the checkpoint-now fast path: at most one step lost
+                                if cfg.ckpt_dir and _drained_saves():
+                                    # drain timed out -> the in-flight background save
+                                    # IS a recent checkpoint; racing its rotation with
+                                    # a second writer would be worse than one lost step
+                                    save_train_state(
+                                        cfg.ckpt_dir, _packed(state),
+                                        max_to_keep=cfg.max_to_keep,
+                                    )
+                                report.preempted_at = step
+                                record_event("preempt", step=step)
+                                dump_current("preempted", step=step)
+                                log.warning(
+                                    "preemption: checkpointed at step %d, exiting", step
+                                )
+                                break
+                            if (
+                                sup.membership is not None
+                                and step % max(1, sup.check_every) == 0
+                                and _membership_tick(step) == "shrunk"
+                            ):
+                                continue
+                            if coordn is not None and _coordination_gate(step):
+                                # a committed group decision just applied (shrink /
+                                # replan / resize): re-enter the loop on the new world
+                                continue
+                        if arbiter is not None:
+                            # the arbiter moved chips: apply the grant before the next
+                            # step (checkpoint → rebuild → restore → ack), then loop —
+                            # the resized world re-reads its batch stream from `step`
+                            directive = arbiter.poll(step)
+                            if directive is not None:
+                                _lease_resize(step, directive)
+                                continue
+                with span("ft.loop.data_wait"):
+                    tokens, targets = (
+                        next(batches) if batches is not None else dataset.batch_at(step)
                     )
-                    break
-                if (
-                    sup.membership is not None
-                    and step % max(1, sup.check_every) == 0
-                    and _membership_tick(step) == "shrunk"
-                ):
-                    continue
-                if coordn is not None and _coordination_gate(step):
-                    # a committed group decision just applied (shrink /
-                    # replan / resize): re-enter the loop on the new world
-                    continue
-            if arbiter is not None:
-                # the arbiter moved chips: apply the grant before the next
-                # step (checkpoint → rebuild → restore → ack), then loop —
-                # the resized world re-reads its batch stream from `step`
-                directive = arbiter.poll(step)
-                if directive is not None:
-                    _lease_resize(step, directive)
-                    continue
-            tokens, targets = (
-                next(batches) if batches is not None else dataset.batch_at(step)
-            )
-            record_event("step_start", step=step)
-            if sup is None:
-                new_state, metrics = cur_step_fn(state, tokens, targets)
-            else:
-                # probe-free feedback (docs/FEEDBACK.md): when the
-                # controller wants per-step spans (probe_free=True with
-                # the recorder on — recorder off costs one None check),
-                # capture the compile-time bucket plan while a fresh step
-                # traces, MATERIALIZE the step (async dispatch would time
-                # the enqueue, not the execution), and feed the host-timed
-                # duration to the span clock below.
-                fb = sup.feedback
-                fb_spans = (
-                    fb is not None
-                    and not feedback_dead
-                    and hasattr(fb, "wants_step_spans")
-                    and fb.wants_step_spans()
-                )
-                fb_cap = None
-                t_step0 = time.perf_counter()
-                try:
-                    with contextlib.ExitStack() as _stack:
-                        _stack.enter_context(
-                            step_scope(on_duration=_feed_supervisor)
+                record_event("step_start", step=step)
+                if sup is None:
+                    with span("ft.loop.dispatch"):
+                        new_state, metrics = cur_step_fn(
+                            state, tokens, targets
                         )
-                        if fb_spans:
-                            fb_cap = _stack.enter_context(plan_capture())
-                        new_state, metrics = (
-                            watchdog.run(
-                                _materialized_step, state, tokens, targets,
-                                timeout_s=step_timeout, step=step,
-                            )
-                            if watchdog is not None
-                            else (
-                                _materialized_step(state, tokens, targets)
-                                if fb_spans
-                                else cur_step_fn(state, tokens, targets)
-                            )
-                        )
-                except StepTimeout as e:
-                    report.step_timeouts += 1
-                    log.warning("%s", e)
-                    # the watchdog recorded the timeout event; the dump is
-                    # fit's to guarantee — this is a failure path even when
-                    # the retry below saves the run
-                    dump_current("watchdog_timeout", step=step)
-                    batches = _batches(step)  # reseek: the batch was consumed
-                    if _membership_tick(step) == "shrunk":
-                        timeout_retries = 0
-                        continue
-                    if timeout_retries < sup.max_step_retries:
-                        timeout_retries += 1
-                        report.step_retries += 1
-                        log.warning(
-                            "retrying step %d after timeout (%d/%d)",
-                            step, timeout_retries, sup.max_step_retries,
-                        )
-                        continue
-                    raise
-                timeout_retries = 0
-                if fb_spans:
-                    try:
-                        if fb_cap:
-                            fb.set_step_plan(fb_cap)
-                        fb.observe_step(
-                            step, time.perf_counter() - t_step0
-                        )
-                    except Exception as e:  # noqa: BLE001 — obs contract
-                        # span bookkeeping must never kill the run: same
-                        # disarm semantics as a raising tick below
-                        feedback_dead = True
-                        record_event(
-                            "feedback_error", step=step,
-                            reason=f"{type(e).__name__}: {e}"[:300],
-                        )
-                        log.exception(
-                            "per-step span clock failed at step %d; "
-                            "planner feedback disarmed for the run", step,
-                        )
-            record_event("step_end", step=step)
-            if cfg.nan_guard and not _metrics_finite(metrics):
-                report.anomalies += 1
-                report.skipped_steps.append(step)
-                bad_streak += 1
-                record_event("nan_skip", step=step, streak=bad_streak)
-                log.warning(
-                    "step %d: non-finite loss/grad (%d consecutive) — update skipped",
-                    step, bad_streak,
-                )
-                if bad_streak >= cfg.max_bad_steps:
-                    if not (cfg.ckpt_dir and latest_checkpoint(cfg.ckpt_dir)):
-                        raise TrainingDiverged(
-                            f"{bad_streak} consecutive non-finite steps at step "
-                            f"{step} and no checkpoint to rewind to"
-                        )
-                    if report.rewinds >= cfg.max_rewinds:
-                        raise TrainingDiverged(
-                            f"still diverging after {report.rewinds} rewinds "
-                            f"(step {step})"
-                        )
-                    if sup is not None:
-                        # never race an in-flight background save's rotation
-                        # with the restore (the saver forbids two writers)
-                        _drained_saves(timeout=None)
-                    dump_current("nan_rewind", step=step)  # pre-rewind context
-                    state = _restore()
-                    report.rewinds += 1
-                    bad_streak = 0
-                    step = int(np.asarray(jax.device_get(state["step"])))
-                    record_event("nan_rewind", step=step)
-                    log.warning("rewound to checkpointed step %d", step)
-                    batches = _batches(step)
-                    continue
-                # skip: discard the poisoned update, advance past the batch
-                step += 1
-                state = _stamp_step(state, step)
-                continue
-            state = new_state
-            bad_streak = 0
-            step += 1
-            if (sup is not None and sup.feedback is not None
-                    and not feedback_dead and step < cfg.num_steps):
-                # closed-loop planner feedback (docs/FEEDBACK.md): with no
-                # recorder installed maybe_tick is ONE None check — the
-                # same check record_event makes — so telemetry-off runs
-                # pay nothing; on the every_k cadence it probes the wire,
-                # and past the drift band hands back a refitted replan.
-                # Gated on step < num_steps: a tick after the FINAL step
-                # would spend a probe round (and possibly a refit + full
-                # step rebuild) on a plan no step will ever run.
-                try:
-                    decision = sup.feedback.maybe_tick(step)
-                    if decision is not None and getattr(
-                        decision, "rotation", False
-                    ):
-                        # a probe-free plan-rotation swap: a bucket-size
-                        # variant of the same plan (bitwise-invariant),
-                        # applied through the replan swap path but NOT a
-                        # refit — the controller recorded feedback_rotate
-                        if decision.rebuilt is not None:
-                            (cur_step_fn, cur_mesh, cur_specs,
-                             cur_pack, cur_unpack) = _apply_rebuild(
-                                 decision.rebuilt, cur_pack, cur_unpack)
-                    elif decision is not None:
-                        report.feedback_refits += 1
-                        if decision.rebuilt is not None:
-                            # the same swap the shrink path runs, minus the
-                            # restore: the world didn't change, only the plan
-                            (cur_step_fn, cur_mesh, cur_specs,
-                             cur_pack, cur_unpack) = _apply_rebuild(
-                                 decision.rebuilt, cur_pack, cur_unpack)
-                            report.feedback_replans += 1
-                        record_event(
-                            "feedback_replan",
-                            step=step,
-                            topo=decision.plan.to_ft_topo(),
-                            invalidated=decision.invalidated,
-                            swapped=decision.rebuilt is not None,
-                        )
-                        log.warning(
-                            "feedback replan at step %d: topo %s, %d cache "
-                            "entr%s invalidated%s",
-                            step, decision.plan.to_ft_topo(),
-                            decision.invalidated,
-                            "y" if decision.invalidated == 1 else "ies",
-                            "" if decision.rebuilt is not None
-                            else " (no rebuild hook: plan recorded only)",
-                        )
-                except Exception as e:
-                    # telemetry never kills the run (the obs contract:
-                    # spill errors drop, predicted_error spans skip) — an
-                    # unwritable calibration path, a failed probe compile,
-                    # or a broken rebuild hook disarms feedback for the
-                    # rest of the run and training continues on the
-                    # current plan.  A half-applied swap is impossible:
-                    # _apply_rebuild returns before any of the five
-                    # loop-state names is reassigned.
-                    feedback_dead = True
-                    # the reason must land in the FLIGHT record, not only
-                    # the process log: a later SIGKILL takes the log with
-                    # it while the spilled record survives (the same
-                    # post-mortem parity feedback_refused already has)
-                    record_event(
-                        "feedback_error", step=step,
-                        reason=f"{type(e).__name__}: {e}"[:300],
-                    )
-                    log.exception(
-                        "feedback tick failed at step %d; planner feedback "
-                        "disarmed for the rest of the run", step,
-                    )
-            if cfg.log_every and (step % cfg.log_every == 0 or step == cfg.num_steps):
-                loss = float(metrics["loss"])
-                losses.append((step, loss))
-                rate = (step - start) / (time.perf_counter() - t0)
-                log.info("step %d loss %.4f (%.1f steps/s)", step, loss, rate)
-            if cfg.ckpt_dir and cfg.ckpt_every and step % cfg.ckpt_every == 0:
-                if sup is not None and sup.background_saver is not None:
-                    # off-step-path save: the step loop never blocks on
-                    # serialization + fsync, so ckpt_every can be small
-                    # (the pack conversion, when set, runs on-path — it
-                    # is the consolidation collective, not the fsync)
-                    sup.background_saver.submit(_packed(state))
                 else:
-                    save_train_state(
-                        cfg.ckpt_dir, _packed(state), max_to_keep=cfg.max_to_keep
+                    # probe-free feedback (docs/FEEDBACK.md): when the
+                    # controller wants per-step spans (probe_free=True with
+                    # the recorder on — recorder off costs one None check),
+                    # capture the compile-time bucket plan while a fresh step
+                    # traces, MATERIALIZE the step (async dispatch would time
+                    # the enqueue, not the execution), and feed the host-timed
+                    # duration to the span clock below.
+                    fb = sup.feedback
+                    fb_spans = (
+                        fb is not None
+                        and not feedback_dead
+                        and hasattr(fb, "wants_step_spans")
+                        and fb.wants_step_spans()
                     )
+                    fb_cap = None
+                    t_step0 = time.perf_counter()
+                    try:
+                        with contextlib.ExitStack() as _stack:
+                            _stack.enter_context(
+                                step_scope(on_duration=_feed_supervisor)
+                            )
+                            _stack.enter_context(span("ft.loop.dispatch"))
+                            if fb_spans:
+                                fb_cap = _stack.enter_context(plan_capture())
+                            new_state, metrics = (
+                                watchdog.run(
+                                    _materialized_step, state, tokens, targets,
+                                    timeout_s=step_timeout, step=step,
+                                )
+                                if watchdog is not None
+                                else (
+                                    _materialized_step(state, tokens, targets)
+                                    if fb_spans
+                                    else cur_step_fn(state, tokens, targets)
+                                )
+                            )
+                    except StepTimeout as e:
+                        report.step_timeouts += 1
+                        log.warning("%s", e)
+                        # the watchdog recorded the timeout event; the dump is
+                        # fit's to guarantee — this is a failure path even when
+                        # the retry below saves the run
+                        dump_current("watchdog_timeout", step=step)
+                        batches = _batches(step)  # reseek: the batch was consumed
+                        if _membership_tick(step) == "shrunk":
+                            timeout_retries = 0
+                            continue
+                        if timeout_retries < sup.max_step_retries:
+                            timeout_retries += 1
+                            report.step_retries += 1
+                            log.warning(
+                                "retrying step %d after timeout (%d/%d)",
+                                step, timeout_retries, sup.max_step_retries,
+                            )
+                            continue
+                        raise
+                    timeout_retries = 0
+                    if fb_spans:
+                        try:
+                            if fb_cap:
+                                fb.set_step_plan(fb_cap)
+                            fb.observe_step(
+                                step, time.perf_counter() - t_step0
+                            )
+                        except Exception as e:  # noqa: BLE001 — obs contract
+                            # span bookkeeping must never kill the run: same
+                            # disarm semantics as a raising tick below
+                            feedback_dead = True
+                            record_event(
+                                "feedback_error", step=step,
+                                reason=f"{type(e).__name__}: {e}"[:300],
+                            )
+                            log.exception(
+                                "per-step span clock failed at step %d; "
+                                "planner feedback disarmed for the run", step,
+                            )
+                record_event("step_end", step=step)
+                # where the host waits for the device: the guard fetches
+                # the step's loss
+                with span("ft.loop.guard_fetch"):
+                    finite = not cfg.nan_guard or _metrics_finite(metrics)
+                if not finite:
+                    report.anomalies += 1
+                    report.skipped_steps.append(step)
+                    bad_streak += 1
+                    record_event("nan_skip", step=step, streak=bad_streak)
+                    log.warning(
+                        "step %d: non-finite loss/grad (%d consecutive) — update skipped",
+                        step, bad_streak,
+                    )
+                    if bad_streak >= cfg.max_bad_steps:
+                        if not (cfg.ckpt_dir and latest_checkpoint(cfg.ckpt_dir)):
+                            raise TrainingDiverged(
+                                f"{bad_streak} consecutive non-finite steps at step "
+                                f"{step} and no checkpoint to rewind to"
+                            )
+                        if report.rewinds >= cfg.max_rewinds:
+                            raise TrainingDiverged(
+                                f"still diverging after {report.rewinds} rewinds "
+                                f"(step {step})"
+                            )
+                        if sup is not None:
+                            # never race an in-flight background save's rotation
+                            # with the restore (the saver forbids two writers)
+                            _drained_saves(timeout=None)
+                        dump_current("nan_rewind", step=step)  # pre-rewind context
+                        state = _restore()
+                        report.rewinds += 1
+                        bad_streak = 0
+                        step = int(np.asarray(jax.device_get(state["step"])))
+                        record_event("nan_rewind", step=step)
+                        log.warning("rewound to checkpointed step %d", step)
+                        batches = _batches(step)
+                        continue
+                    # skip: discard the poisoned update, advance past the batch
+                    step += 1
+                    state = _stamp_step(state, step)
+                    continue
+                with span("ft.loop.bookkeeping"):
+                    state = new_state
+                    bad_streak = 0
+                    step += 1
+                    if (sup is not None and sup.feedback is not None
+                            and not feedback_dead and step < cfg.num_steps):
+                        # closed-loop planner feedback (docs/FEEDBACK.md): with no
+                        # recorder installed maybe_tick is ONE None check — the
+                        # same check record_event makes — so telemetry-off runs
+                        # pay nothing; on the every_k cadence it probes the wire,
+                        # and past the drift band hands back a refitted replan.
+                        # Gated on step < num_steps: a tick after the FINAL step
+                        # would spend a probe round (and possibly a refit + full
+                        # step rebuild) on a plan no step will ever run.
+                        try:
+                            decision = sup.feedback.maybe_tick(step)
+                            if decision is not None and getattr(
+                                decision, "rotation", False
+                            ):
+                                # a probe-free plan-rotation swap: a bucket-size
+                                # variant of the same plan (bitwise-invariant),
+                                # applied through the replan swap path but NOT a
+                                # refit — the controller recorded feedback_rotate
+                                if decision.rebuilt is not None:
+                                    (cur_step_fn, cur_mesh, cur_specs,
+                                     cur_pack, cur_unpack) = _apply_rebuild(
+                                         decision.rebuilt, cur_pack, cur_unpack)
+                            elif decision is not None:
+                                report.feedback_refits += 1
+                                if decision.rebuilt is not None:
+                                    # the same swap the shrink path runs, minus the
+                                    # restore: the world didn't change, only the plan
+                                    (cur_step_fn, cur_mesh, cur_specs,
+                                     cur_pack, cur_unpack) = _apply_rebuild(
+                                         decision.rebuilt, cur_pack, cur_unpack)
+                                    report.feedback_replans += 1
+                                record_event(
+                                    "feedback_replan",
+                                    step=step,
+                                    topo=decision.plan.to_ft_topo(),
+                                    invalidated=decision.invalidated,
+                                    swapped=decision.rebuilt is not None,
+                                )
+                                log.warning(
+                                    "feedback replan at step %d: topo %s, %d cache "
+                                    "entr%s invalidated%s",
+                                    step, decision.plan.to_ft_topo(),
+                                    decision.invalidated,
+                                    "y" if decision.invalidated == 1 else "ies",
+                                    "" if decision.rebuilt is not None
+                                    else " (no rebuild hook: plan recorded only)",
+                                )
+                        except Exception as e:
+                            # telemetry never kills the run (the obs contract:
+                            # spill errors drop, predicted_error spans skip) — an
+                            # unwritable calibration path, a failed probe compile,
+                            # or a broken rebuild hook disarms feedback for the
+                            # rest of the run and training continues on the
+                            # current plan.  A half-applied swap is impossible:
+                            # _apply_rebuild returns before any of the five
+                            # loop-state names is reassigned.
+                            feedback_dead = True
+                            # the reason must land in the FLIGHT record, not only
+                            # the process log: a later SIGKILL takes the log with
+                            # it while the spilled record survives (the same
+                            # post-mortem parity feedback_refused already has)
+                            record_event(
+                                "feedback_error", step=step,
+                                reason=f"{type(e).__name__}: {e}"[:300],
+                            )
+                            log.exception(
+                                "feedback tick failed at step %d; planner feedback "
+                                "disarmed for the rest of the run", step,
+                            )
+                    if cfg.log_every and (step % cfg.log_every == 0 or step == cfg.num_steps):
+                        loss = float(metrics["loss"])
+                        losses.append((step, loss))
+                        rate = (step - start) / (time.perf_counter() - t0)
+                        log.info("step %d loss %.4f (%.1f steps/s)", step, loss, rate)
+                    if cfg.ckpt_dir and cfg.ckpt_every and step % cfg.ckpt_every == 0:
+                        if sup is not None and sup.background_saver is not None:
+                            # off-step-path save: the step loop never blocks on
+                            # serialization + fsync, so ckpt_every can be small
+                            # (the pack conversion, when set, runs on-path — it
+                            # is the consolidation collective, not the fsync)
+                            sup.background_saver.submit(_packed(state))
+                        else:
+                            save_train_state(
+                                cfg.ckpt_dir, _packed(state), max_to_keep=cfg.max_to_keep
+                            )
         # the preemption fast path already saved this exact state — a second
         # serialize+fsync would double the cost inside the grace window
         if cfg.ckpt_dir and step > start and report.preempted_at is None:

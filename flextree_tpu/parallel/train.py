@@ -374,6 +374,7 @@ def resolve_axis_topos(mesh: Mesh, mesh_axes, grad_topo) -> dict:
     return {ax: axis_topo(ax) for ax in mesh_axes}
 
 
+@jax.named_scope("ft_grad_sync")
 def sync_grads(
     grads,
     pspecs,
@@ -594,6 +595,7 @@ def clip_by_global_norm(grads, norm, clip: float):
     return jax.tree.map(lambda g: g * scale.astype(g.dtype), grads)
 
 
+@jax.named_scope("ft_grad_clip")
 def maybe_clip_grads(grads, pspecs, train_cfg: "TrainConfig", metrics: dict):
     """Shared clip-and-record step for every train-step builder: when
     ``grad_clip_norm`` is set (must be positive), clips ``grads`` to it
@@ -618,6 +620,7 @@ def metric_specs(train_cfg: "TrainConfig", base: dict) -> dict:
     return out
 
 
+@jax.named_scope("ft_optimizer")
 def adamw_apply(state: dict, grads, train_cfg: "TrainConfig") -> dict:
     """One AdamW update on (sharded) state; moments shard like the params."""
     step = state["step"] + 1

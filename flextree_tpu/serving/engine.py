@@ -52,7 +52,7 @@ import numpy as np
 
 from ..models.generate import prefill, prefill_suffix, sample_token
 from ..models.transformer import TransformerConfig
-from ..obs import MetricsRegistry, record_event
+from ..obs import MetricsRegistry, current_recorder, record_event, span
 from .batcher import BatcherConfig, ContinuousBatcher, Request, SeqState
 from .kv_cache import (
     CacheExhausted,
@@ -255,60 +255,86 @@ class ServingEngine:
         newest resident sequence (swap-out or recompute per
         ``BatcherConfig.preempt``) until the rest fit."""
         t0 = _now()
-        resumed = self.batcher.try_resume(t0)
-        for slot, state, kv in resumed:
-            self._resume_slot(slot, state, kv)
-        admitted = self.batcher.try_admit(t0)
-        if self.batcher.admit_blocked is not None:
-            rid, want, free = self.batcher.admit_blocked
-            self.metrics.counter("serve.admit_blocked").inc()
-            record_event("serve_admit_blocked", rid=rid, want=want, free=free)
-        for slot, state in admitted:
-            record_event(
-                "serve_admit", rid=state.rid, slot=slot,
-                prompt_len=state.request.prompt_len,
-                blocks=len(state.block_ids),
-            )
-            self._prefill_slot(slot, state)
-        preempted = self._grow_with_preemption()
-        active = self.batcher.active_slots()
-        if active:
-            tables, lengths, tokens, _ = self.batcher.batch_arrays()
-            t_dec = _now()
-            logits, self.pools = self._decode(
-                self.params, self.pools, tables, lengths, tokens
-            )
-            logits = np.asarray(logits)  # host fetch = the step boundary
-            decode_s = _now() - t_dec
-            now = _now()
-            for slot in active:
-                tok = self._pick(slot, logits[slot])
-                self.batcher.record_decode_token(slot, tok, now)
-            self.decode_steps += 1
-            self.metrics.counter("serve.decode_tokens").inc(len(active))
-            record_event("serve_decode", n_active=len(active))
-            self._round_feedback(
-                len(active), int(np.asarray(lengths).max()), decode_s
-            )
-        finished = self.batcher.retire_ready()
-        for slot, state in finished:
-            self._keys.pop(slot, None)
-            self._complete(state)
-        self.steps += 1
-        m = self.metrics
-        m.counter("serve.rounds").inc()
-        m.counter("serve.admitted").inc(len(admitted))
-        m.counter("serve.finished").inc(len(finished))
-        m.gauge("serve.active_slots").set(self.batcher.num_active)
-        free = self.batcher.allocator.num_free
-        total = self.pcfg.num_blocks - 1
-        m.gauge("serve.free_blocks").set(free)
-        m.gauge("serve.active_blocks").set(total - free)
-        m.gauge("serve.preempted_seqs").set(len(self.batcher.preempted))
-        m.histogram(
-            "serve.cache_occupancy", buckets=_OCCUPANCY_BUCKETS
-        ).observe((total - free) / total)
-        m.histogram("serve.round_ms").observe((_now() - t0) * 1e3)
+        with span("ft.engine.round", round=self.steps):
+            with span("ft.engine.resume"):
+                resumed = self.batcher.try_resume(t0)
+                for slot, state, kv in resumed:
+                    self._resume_slot(slot, state, kv)
+            with span("ft.batcher.try_admit"):
+                admitted = self.batcher.try_admit(t0)
+            if self.batcher.admit_blocked is not None:
+                rid, want, free = self.batcher.admit_blocked
+                self.metrics.counter("serve.admit_blocked").inc()
+                record_event(
+                    "serve_admit_blocked", rid=rid, want=want, free=free
+                )
+            for slot, state in admitted:
+                record_event(
+                    "serve_admit", rid=state.rid, slot=slot,
+                    prompt_len=state.request.prompt_len,
+                    blocks=len(state.block_ids),
+                )
+                with span(
+                    "ft.engine.prefill", rid=state.rid,
+                    prompt_len=state.request.prompt_len,
+                    cached_tokens=state.cached_tokens,
+                ):
+                    self._prefill_slot(slot, state)
+            with span("ft.engine.grow"):
+                preempted = self._grow_with_preemption()
+            active = self.batcher.active_slots()
+            if active:
+                with span("ft.batcher.batch_arrays"):
+                    tables, lengths, tokens, _ = self.batcher.batch_arrays()
+                t_dec = _now()
+                with span("ft.engine.decode_dispatch"):
+                    logits, self.pools = self._decode(
+                        self.params, self.pools, tables, lengths, tokens
+                    )
+                with span("ft.engine.decode_fetch"):
+                    # host fetch = the step boundary
+                    logits = np.asarray(logits)
+                decode_s = _now() - t_dec
+                now = _now()
+                with span("ft.engine.sample"):
+                    for slot in active:
+                        tok = self._pick(slot, logits[slot])
+                        self.batcher.record_decode_token(slot, tok, now)
+                self.decode_steps += 1
+                self.metrics.counter("serve.decode_tokens").inc(len(active))
+                record_event("serve_decode", n_active=len(active))
+                self._round_feedback(
+                    len(active), int(np.asarray(lengths).max()), decode_s
+                )
+            with span("ft.engine.retire"):
+                finished = self.batcher.retire_ready()
+                for slot, state in finished:
+                    self._keys.pop(slot, None)
+                    self._complete(state)
+            free = self.batcher.allocator.num_free
+            total = self.pcfg.num_blocks - 1
+            # an annotation takes its values when it opens: the last span
+            # of the round, opened once its counts are known, carries them
+            with span(
+                "ft.engine.bookkeeping", round=self.steps,
+                decoded=len(active), admitted=len(admitted),
+                finished=len(finished), blocks_in_use=total - free,
+                blocks_total=total,
+            ):
+                self.steps += 1
+                m = self.metrics
+                m.counter("serve.rounds").inc()
+                m.counter("serve.admitted").inc(len(admitted))
+                m.counter("serve.finished").inc(len(finished))
+                m.gauge("serve.active_slots").set(self.batcher.num_active)
+                m.gauge("serve.free_blocks").set(free)
+                m.gauge("serve.active_blocks").set(total - free)
+                m.gauge("serve.preempted_seqs").set(
+                    len(self.batcher.preempted)
+                )
+                m.histogram(
+                    "serve.cache_occupancy", buckets=_OCCUPANCY_BUCKETS
+                ).observe((total - free) / total)
         return {
             "admitted": len(admitted),
             "resumed": len(resumed),
@@ -442,16 +468,17 @@ class ServingEngine:
         self.metrics.histogram(
             "serve.round_residual", buckets=_RESIDUAL_BUCKETS
         ).observe(rel)
-        record_event(
-            "serve_round_measured",
-            round=self.decode_steps,
-            n_active=int(n_active),
-            max_len=int(max_len),
-            measured_us=round(measured_us, 3),
-            predicted_us=round(predicted_us, 3),
-            compute_us=round(pred["compute_us"], 3),
-            bytes_us=round(pred["bytes_us"], 3),
-        )
+        if current_recorder() is not None:
+            record_event(
+                "serve_round_measured",
+                round=self.decode_steps,
+                n_active=int(n_active),
+                max_len=int(max_len),
+                measured_us=round(measured_us, 3),
+                predicted_us=round(predicted_us, 3),
+                compute_us=round(pred["compute_us"], 3),
+                bytes_us=round(pred["bytes_us"], 3),
+            )
 
     def _cost_params(self):
         params = getattr(self, "_cost_params_cache", None)
@@ -548,19 +575,7 @@ class ServingEngine:
         self.metrics.histogram("serve.ttft_ms").observe(
             (now - req.arrival_s) * 1e3
         )
-        from .costs import predict_prefill_us
-
-        record_event(
-            "serve_prefill", rid=req.rid, slot=-1,
-            prompt_len=req.prompt_len, cached_tokens=0,
-            measured_us=round((now - t0) * 1e6, 3),
-            predicted_us=round(
-                predict_prefill_us(
-                    self.cfg, req.prompt_len, self._cost_params()
-                ),
-                3,
-            ),
-        )
+        self._record_prefill(req, -1, 0, now - t0)
         return {
             "first_token": first_token,
             "meta": meta,
@@ -780,11 +795,64 @@ class ServingEngine:
         record_event("serve_handoff_prewarm", **stats)
         return stats
 
+    def _record_prefill(self, req: Request, slot: int, cached: int,
+                        measured_s: float) -> None:
+        """The ``serve_prefill`` event: a prefill's measured time beside
+        the cost model's prediction.  The prediction is the event's alone,
+        so nothing of it is computed while no recorder is installed."""
+        if current_recorder() is None:
+            return
+        from .costs import predict_prefill_us
+
+        record_event(
+            "serve_prefill", rid=req.rid, slot=slot,
+            prompt_len=req.prompt_len, cached_tokens=cached,
+            measured_us=round(measured_s * 1e6, 3),
+            predicted_us=round(
+                predict_prefill_us(
+                    self.cfg, req.prompt_len, self._cost_params(),
+                    cached_tokens=cached,
+                ),
+                3,
+            ),
+        )
+
     def _prefill_slot(self, slot: int, state: SeqState) -> None:
         t0 = _now()
         req = state.request
         prompt = np.asarray(req.prompt, np.int32)
         c = state.cached_tokens
+        with span("ft.engine.prefill_dispatch"):
+            logits = self._dispatch_prefill(state, prompt, c)
+        if self.batcher.prefix_index is not None:
+            self._note_prefix_admission(c > 0, t0)
+        if self.chaos_prefill_sleep_s > 0:
+            # per COMPUTED token: a prefix-cache hit only pays its suffix
+            time.sleep(self.chaos_prefill_sleep_s * (req.prompt_len - c))
+        if req.temperature > 0:
+            if req.seed is None:  # unreachable via submit(); guard direct use
+                raise ValueError(
+                    f"request {req.rid}: temperature > 0 requires seed="
+                )
+            # the SAME presplit schedule generate() uses, so a sampled
+            # request reproduces generate(key=PRNGKey(seed)) exactly
+            self._keys[slot] = jax.random.split(
+                jax.random.PRNGKey(req.seed), req.max_new_tokens
+            )
+        with span("ft.engine.prefill_fetch_sample"):
+            tok = self._pick(slot, np.asarray(logits[0]))
+        now = _now()
+        self.batcher.record_first_token(slot, tok, now)
+        self.metrics.histogram("serve.ttft_ms").observe(
+            (now - req.arrival_s) * 1e3
+        )
+        self._record_prefill(req, slot, c, now - t0)
+
+    def _dispatch_prefill(self, state: SeqState, prompt, c: int):
+        """Dispatch the prompt's prefill (the suffix alone on a
+        prefix-cache hit) and the scatter of its K/V into the sequence's
+        blocks; returns the logits, still on the device."""
+        req = state.request
         if c > 0:
             bs = self.pcfg.block_size
             # the prefix K/V lives in the shared blocks — plus, for a
@@ -829,41 +897,7 @@ class ServingEngine:
             )
             if self.batcher.prefix_index is not None:
                 self.metrics.counter("serve.prefix_misses").inc()
-        if self.batcher.prefix_index is not None:
-            self._note_prefix_admission(c > 0, t0)
-        if self.chaos_prefill_sleep_s > 0:
-            # per COMPUTED token: a prefix-cache hit only pays its suffix
-            time.sleep(self.chaos_prefill_sleep_s * (req.prompt_len - c))
-        if req.temperature > 0:
-            if req.seed is None:  # unreachable via submit(); guard direct use
-                raise ValueError(
-                    f"request {req.rid}: temperature > 0 requires seed="
-                )
-            # the SAME presplit schedule generate() uses, so a sampled
-            # request reproduces generate(key=PRNGKey(seed)) exactly
-            self._keys[slot] = jax.random.split(
-                jax.random.PRNGKey(req.seed), req.max_new_tokens
-            )
-        tok = self._pick(slot, np.asarray(logits[0]))
-        now = _now()
-        self.batcher.record_first_token(slot, tok, now)
-        self.metrics.histogram("serve.ttft_ms").observe(
-            (now - req.arrival_s) * 1e3
-        )
-        from .costs import predict_prefill_us
-
-        record_event(
-            "serve_prefill", rid=req.rid, slot=slot,
-            prompt_len=req.prompt_len, cached_tokens=c,
-            measured_us=round((now - t0) * 1e6, 3),
-            predicted_us=round(
-                predict_prefill_us(
-                    self.cfg, req.prompt_len, self._cost_params(),
-                    cached_tokens=c,
-                ),
-                3,
-            ),
-        )
+        return logits
 
     def _pick(self, slot: int, logits_row: np.ndarray) -> int:
         state = self.batcher.slots[slot]
@@ -899,7 +933,7 @@ class ServingEngine:
 
     def report(self) -> dict:
         """The replica's accounting: a VIEW over its metrics registry
-        (one snapshot — counters, gauges, TTFT/round-time histograms)
+        (one snapshot — counters, gauges, the TTFT and occupancy histograms)
         plus the loop counters the pool reads directly."""
         return {
             "steps": self.steps,

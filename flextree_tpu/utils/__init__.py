@@ -12,7 +12,7 @@ from .checkpoint import (
     verify_checkpoint,
 )
 from .logging import get_logger, result_file_name, write_result_file
-from .profiling import PhaseTimer, debug_dump_schedule, debug_enabled, phase_timer, trace
+from .profiling import debug_dump_schedule, debug_enabled
 from .timing import BenchResult, Timer, time_jax_fn
 
 __all__ = [
@@ -33,9 +33,6 @@ __all__ = [
     "BenchResult",
     "Timer",
     "time_jax_fn",
-    "PhaseTimer",
-    "phase_timer",
-    "trace",
     "debug_dump_schedule",
     "debug_enabled",
 ]

@@ -1,18 +1,20 @@
-"""Profiling / tracing: named spans, phase timers, span ledgers.
+"""Profiling / tracing: named comm spans, span ledgers, step timing.
 
-Three layers, host-side unless noted:
+Host-side unless noted:
 
-- :func:`trace` wraps ``jax.profiler`` so a benchmark run produces a
-  TensorBoard-loadable trace; the per-stage ``jax.named_scope`` annotations
-  inside :mod:`flextree_tpu.parallel.allreduce` (``ft_rs_stage*`` /
-  ``ft_ag_stage*``) make the hierarchical phases visible in it.
-- :func:`phase_timer` is the in-process fallback when a full profiler
-  trace is overkill: named checkpoints with deltas, rank-0 gated logging.
-- :func:`comm_span` names each bucket's collectives; at trace time it
-  feeds every active :class:`SpanLedger` *and* the ambient flight
-  recorder (:mod:`flextree_tpu.obs`), carrying plan provenance when the
-  caller supplies it — the always-on telemetry layer's view of the comm
-  plan.
+- :func:`comm_span` names each bucket's collectives (a
+  ``jax.named_scope``, like the per-stage ``ft_rs_stage*`` /
+  ``ft_ag_stage*`` scopes inside :mod:`flextree_tpu.parallel.allreduce`);
+  at trace time it feeds every active :class:`SpanLedger` *and* the
+  ambient flight recorder (:mod:`flextree_tpu.obs`), carrying plan
+  provenance when the caller supplies it — the always-on telemetry
+  layer's view of the comm plan.
+- :func:`step_scope` times one host-level training step for the
+  straggler classifier.
+
+Host spans with a start, an end and a parent are
+:func:`flextree_tpu.obs.span`; a profile is ``jax.profiler.start_trace``
+(the benchmark's ``--trace 1``).
 
 (The reference-lineage note — how the C++ ``SHOW_TIME`` / ``FT_DEBUG``
 compile-time knobs map onto these runtime facilities — lives in
@@ -29,9 +31,6 @@ import time
 from .logging import get_logger
 
 __all__ = [
-    "trace",
-    "phase_timer",
-    "PhaseTimer",
     "comm_span",
     "span_bytes",
     "SpanLedger",
@@ -200,24 +199,16 @@ def exposed_split(step_ms: float, nosync_step_ms: float, comm_total_ms: float):
 
 
 @contextlib.contextmanager
-def comm_span(
-    name: str,
-    timer: "PhaseTimer | None" = None,
-    provenance: dict | None = None,
-):
-    """Named communication span: a ``jax.named_scope`` (so the span shows up
+def comm_span(name: str, provenance: dict | None = None):
+    """Named communication span: a ``jax.named_scope``, so the span shows up
     as a named range over its collectives in profiler traces, exactly like
-    the per-stage ``ft_rs_stage*`` scopes) plus an optional host-side
-    :class:`PhaseTimer` checkpoint on exit.
+    the per-stage ``ft_rs_stage*`` scopes.
 
     This is the per-*bucket* observability layer the fused gradient sync
     uses (``parallel.bucketing``): each bucket's collectives trace under an
     ``ft_bucket{i}_{axis}_{k}leaves_{bytes}B`` range, so a profile (or a
     run_report built from one) can attribute comm time per bucket and
-    separate comm from compute per step.  Under ``jit`` the body runs at
-    trace time, so the *timer* measures tracing, not execution — pass a
-    timer only in eager/host-level phases; inside jitted code the named
-    scope is the useful half.
+    separate comm from compute per step.
 
     Every span also feeds the active :class:`SpanLedger`\\ s and the
     ambient flight recorder (:func:`flextree_tpu.obs.record_event`, a
@@ -240,83 +231,6 @@ def comm_span(
         record_event("collective", name=name, bytes=span_bytes(name))
     with jax.named_scope(name):
         yield
-    if timer is not None:
-        timer.checkpoint(name)
-
-
-@contextlib.contextmanager
-def trace(log_dir: str, create_perfetto_link: bool = False):
-    """Profile the enclosed block to ``log_dir`` (TensorBoard/XPlane format).
-
-    Usage::
-
-        with trace("/tmp/ft_trace"):
-            jax.block_until_ready(allreduce_over_mesh(x, mesh, topo="4,2"))
-
-    The stage scopes (``ft_rs_stage0_w4`` etc.) appear as named ranges over
-    the XLA collective ops they wrap.
-    """
-    import jax
-
-    jax.profiler.start_trace(log_dir, create_perfetto_link=create_perfetto_link)
-    try:
-        yield
-    finally:
-        jax.profiler.stop_trace()
-
-
-class PhaseTimer:
-    """Named phase checkpoints with wall-clock deltas — the ``TIME_RESET`` /
-    ``TIME_LOG_IF`` pattern (``mpi_mod.hpp:34-38``) as an object.
-
-    ``log=True`` emits each checkpoint via the framework logger (rank-0
-    gating is the caller's concern, as in the reference's
-    ``LOG_IF(INFO, rank == 0)``).
-    """
-
-    def __init__(self, log: bool = False, logger_name: str = "flextree.phase"):
-        self._log = log
-        self._logger = get_logger(logger_name)
-        self.reset()
-
-    def reset(self) -> None:
-        self._t0 = time.perf_counter()
-        self._last = self._t0
-        self.phases: list[tuple[str, float]] = []
-
-    def checkpoint(self, name: str) -> float:
-        """Record time since the previous checkpoint under ``name``."""
-        now = time.perf_counter()
-        dt = now - self._last
-        self._last = now
-        self.phases.append((name, dt))
-        if self._log:
-            self._logger.info("phase %-24s %8.3f ms", name, dt * 1e3)
-        return dt
-
-    @property
-    def total_s(self) -> float:
-        return self._last - self._t0
-
-    def summary(self) -> str:
-        lines = [f"{n:<24} {dt * 1e3:8.3f} ms" for n, dt in self.phases]
-        lines.append(f"{'total':<24} {self.total_s * 1e3:8.3f} ms")
-        return "\n".join(lines)
-
-
-@contextlib.contextmanager
-def phase_timer(log: bool = True):
-    """``with phase_timer() as pt: pt.checkpoint("reduce-scatter"); ...``
-
-    On exit the phase summary table is logged (the per-phase deltas plus the
-    total), so the scope has a visible end — the ``SHOW_TIME`` run footer.
-    """
-    pt = PhaseTimer(log=log)
-    try:
-        yield pt
-    finally:
-        if log and pt.phases:
-            pt._logger.info("phase summary:\n%s", pt.summary())
 
 
 def debug_enabled() -> bool:

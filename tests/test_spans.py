@@ -1,0 +1,607 @@
+"""``obs.span``: the program's own spans, in the flight recorder and in the
+profiler's trace at once; where the serving round and the train loop open
+them; the named scopes inside the train step; and the benchmark's readers
+of both (``benchmarks/readers/spans.py``) on hand-made traces whose
+answers are worked by hand.
+"""
+
+import glob
+import threading
+import types
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmarks.lib import xplane as X
+from benchmarks.lib.harness import ReaderContext, Run
+from benchmarks.readers import spans as S
+from flextree_tpu.models.transformer import TransformerConfig, init_params
+from flextree_tpu.obs import (
+    flight_recorder,
+    merge_events,
+    record_event,
+    span,
+    validate_trace,
+)
+from flextree_tpu.obs import recorder as recorder_mod
+
+ROUND_SPANS = [
+    "ft.engine.round", "ft.engine.resume", "ft.batcher.try_admit",
+    "ft.engine.prefill", "ft.engine.prefill_dispatch",
+    "ft.engine.prefill_fetch_sample", "ft.engine.grow",
+    "ft.batcher.batch_arrays", "ft.engine.decode_dispatch",
+    "ft.engine.decode_fetch", "ft.engine.sample", "ft.engine.retire",
+    "ft.engine.bookkeeping",
+]
+STEP_SPANS = [
+    "ft.loop.step", "ft.loop.data_wait", "ft.loop.dispatch",
+    "ft.loop.guard_fetch", "ft.loop.bookkeeping",
+]
+PHASE_SCOPES = [
+    "ft_embed", "ft_norm", "ft_attn", "ft_mlp", "ft_head", "ft_loss",
+    "ft_grad_sync", "ft_grad_clip", "ft_optimizer",
+]
+
+
+def _spans(rec):
+    return [e for e in rec.events if e["kind"] == "span"]
+
+
+# ---------------------------------------------------------------- obs.span
+
+
+def test_span_records_start_end_ids_and_names_its_parent():
+    with flight_recorder(None) as rec:
+        with span("ft.t.outer", round=7):
+            with span("ft.t.inner", rid=3):
+                pass
+            with span("ft.t.second"):
+                pass
+    inner, second, outer = _spans(rec)  # recorded as each closes
+    assert (inner["name"], inner["parent"], inner["rid"]) == (
+        "ft.t.inner", "ft.t.outer", 3)
+    assert (second["name"], second["parent"]) == ("ft.t.second", "ft.t.outer")
+    assert (outer["name"], outer["parent"], outer["round"]) == (
+        "ft.t.outer", None, 7)
+    assert outer["start"] <= inner["start"] <= inner["end"] <= outer["end"]
+    assert inner["end"] <= second["start"]
+
+
+def test_span_parent_is_per_thread():
+    inside = threading.Event()
+    done = threading.Event()
+
+    def other():
+        inside.wait(5)
+        with span("ft.t.other_thread"):
+            pass
+        done.set()
+
+    t = threading.Thread(target=other)
+    with flight_recorder(None) as rec:
+        t.start()
+        with span("ft.t.main"):
+            inside.set()
+            assert done.wait(5)
+        t.join(5)
+    assert not t.is_alive()
+    by_name = {e["name"]: e for e in _spans(rec)}
+    # opened while ft.t.main was open, on another thread: not its child
+    assert by_name["ft.t.other_thread"]["parent"] is None
+    assert by_name["ft.t.main"]["start"] < by_name["ft.t.other_thread"]["start"]
+
+
+def test_span_without_a_recorder_records_nothing_and_keeps_no_stack():
+    assert recorder_mod.current_recorder() is None
+    recorder_mod._SPAN_STACKS.__dict__.pop("stack", None)
+    with span("ft.t.off", round=1) as s:
+        with span("ft.t.off_inner"):
+            pass
+    # no recorder: no clock read, no per-thread stack, no event
+    assert s._rec is None and not hasattr(s, "_t0")
+    assert "stack" not in recorder_mod._SPAN_STACKS.__dict__
+
+
+def test_span_opened_before_the_recorder_closes_quietly():
+    s = span("ft.t.early")
+    s.__enter__()
+    with flight_recorder(None) as rec:
+        s.__exit__(None, None, None)
+        with span("ft.t.late"):
+            pass
+    assert [e["name"] for e in _spans(rec)] == ["ft.t.late"]
+    assert _spans(rec)[0]["parent"] is None
+
+
+def test_span_closes_when_its_body_raises():
+    with flight_recorder(None) as rec:
+        with pytest.raises(ValueError):
+            with span("ft.t.raises"):
+                raise ValueError("boom")
+        with span("ft.t.after"):
+            pass
+    names = [(e["name"], e["parent"]) for e in _spans(rec)]
+    assert names == [("ft.t.raises", None), ("ft.t.after", None)]
+
+
+def test_timeline_draws_spans_as_durations():
+    with flight_recorder(None) as rec:
+        record_event("step_start", step=0)
+        with span("ft.t.outer", round=2):
+            with span("ft.t.inner"):
+                pass
+    doc = merge_events(list(rec.events))
+    assert validate_trace(doc) == []
+    drawn = {e["name"]: e for e in doc["traceEvents"] if e.get("cat") == "span"}
+    assert set(drawn) == {"ft.t.outer", "ft.t.inner"}
+    outer, inner = drawn["ft.t.outer"], drawn["ft.t.inner"]
+    assert outer["ph"] == inner["ph"] == "X"
+    assert outer["args"] == {"parent": None, "round": 2}
+    assert inner["args"] == {"parent": "ft.t.outer"}
+    assert outer["ts"] <= inner["ts"]
+    assert inner["ts"] + inner["dur"] <= outer["ts"] + outer["dur"] + 0.2
+    assert min(e["ts"] for e in doc["traceEvents"] if "ts" in e) >= 0
+
+
+@pytest.mark.filterwarnings("ignore:builtin type:DeprecationWarning")
+def test_span_in_the_profile_and_in_the_recorder_agree_in_time(tmp_path):
+    """The profiler's clock is the wall clock less the profile's start
+    (the ``profile_start_time`` stat of the ``Task Environment`` plane),
+    so one constant joins an xplane span to its recorder event."""
+    import time
+
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 1
+    with flight_recorder(None) as rec:
+        jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+        try:
+            for i in range(4):
+                with span("ft.t.timed", i=i):
+                    time.sleep(0.003 * (i + 1))
+        finally:
+            jax.profiler.stop_trace()
+    [path] = glob.glob(str(tmp_path / "plugins" / "profile" / "*" / "*.xplane.pb"))
+    in_profile = sorted(S._spans_of_file(path), key=lambda e: e.stats["i"])
+    in_recorder = sorted(_spans(rec), key=lambda e: e["i"])
+    assert [e.name for e in in_profile] == ["ft.t.timed"] * 4
+    offsets = []
+    for prof, ev in zip(in_profile, in_recorder):
+        assert prof.stats["i"] == ev["i"]
+        assert prof.dur_ns / 1e6 == pytest.approx(
+            (ev["end"] - ev["start"]) * 1e3, abs=1.0)
+        offsets.append(ev["start"] * 1e3 - prof.start_ns / 1e6)  # ms
+    assert max(offsets) - min(offsets) < 1.0
+    starts = [
+        v for p in jax.profiler.ProfileData.from_file(path).planes
+        for k, v in p.stats if k == "profile_start_time"
+    ]
+    if starts:  # wall-clock nanoseconds
+        assert offsets[0] == pytest.approx(starts[0] / 1e6, abs=1.0)
+
+
+# ------------------------------------------------------- the serving round
+
+
+def _engine():
+    from flextree_tpu.serving import (
+        BatcherConfig, PagedCacheConfig, ServingEngine,
+    )
+
+    cfg = TransformerConfig(
+        vocab_size=64, d_model=32, n_heads=4, n_layers=2, d_ff=64)
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    pcfg = PagedCacheConfig(num_blocks=32, block_size=8, blocks_per_seq=6)
+    return ServingEngine(params, cfg, pcfg, BatcherConfig(slots=2))
+
+
+def _request(rid, prompt_len, max_new):
+    from flextree_tpu.serving import Request
+
+    prompt = np.random.default_rng(rid).integers(0, 64, (prompt_len,))
+    return Request(rid=rid, prompt=prompt.astype(np.int32),
+                   max_new_tokens=max_new)
+
+
+def test_one_engine_round_emits_exactly_its_spans():
+    eng = _engine()
+    assert eng.submit(_request(11, 6, 4))
+    with flight_recorder(None) as rec:
+        out = eng.step()
+    assert out["admitted"] == 1 and out["decoded"] == 1
+    spans = _spans(rec)
+    assert sorted(e["name"] for e in spans) == sorted(ROUND_SPANS)
+    by_name = {e["name"]: e for e in spans}
+    children = set(ROUND_SPANS) - {
+        "ft.engine.round", "ft.engine.prefill_dispatch",
+        "ft.engine.prefill_fetch_sample"}
+    assert all(by_name[n]["parent"] == "ft.engine.round" for n in children)
+    assert by_name["ft.engine.prefill_dispatch"]["parent"] == "ft.engine.prefill"
+    assert by_name["ft.engine.prefill_fetch_sample"]["parent"] == "ft.engine.prefill"
+    assert by_name["ft.engine.round"]["round"] == 0
+    prefill = by_name["ft.engine.prefill"]
+    assert (prefill["rid"], prefill["prompt_len"], prefill["cached_tokens"]) == (11, 6, 0)
+    book = by_name["ft.engine.bookkeeping"]
+    total = eng.pcfg.num_blocks - 1
+    assert book["blocks_total"] == total
+    assert book["blocks_in_use"] == total - eng.batcher.allocator.num_free > 0
+    assert (book["round"], book["decoded"], book["admitted"], book["finished"]) == (0, 1, 1, 0)
+    # the children tile the round in the order the work happens
+    order = [e["name"] for e in sorted(spans, key=lambda e: e["start"])
+             if e["parent"] == "ft.engine.round"]
+    assert order == [
+        "ft.engine.resume", "ft.batcher.try_admit", "ft.engine.prefill",
+        "ft.engine.grow", "ft.batcher.batch_arrays",
+        "ft.engine.decode_dispatch", "ft.engine.decode_fetch",
+        "ft.engine.sample", "ft.engine.retire", "ft.engine.bookkeeping",
+    ]
+
+
+def test_a_round_samples_under_one_span_and_later_rounds_count_on():
+    eng = _engine()
+    for rid in (1, 2):
+        assert eng.submit(_request(rid, 5, 3))
+    with flight_recorder(None) as rec:
+        eng.run_until_idle()
+    spans = _spans(rec)
+    rounds = [e for e in spans if e["name"] == "ft.engine.round"]
+    assert [e["round"] for e in rounds] == list(range(len(rounds)))
+    samples = [e for e in spans if e["name"] == "ft.engine.sample"]
+    decoding = [e for e in spans if e["name"] == "ft.engine.bookkeeping"
+                and e["decoded"] > 0]
+    assert len(samples) == len(decoding)  # ONE a round, whatever the slots
+    assert any(e["decoded"] == 2 for e in decoding)
+    assert sum(e["finished"] for e in spans
+               if e["name"] == "ft.engine.bookkeeping") == 2
+    # the round span took the round-time histogram's place
+    assert not any(k.endswith("round_ms") for k in eng.report()["histograms"])
+
+
+def test_prefill_prediction_is_built_only_for_a_recorder(monkeypatch):
+    """Tracing off costs no tracing work: the cost model's prediction is
+    an argument of the ``serve_prefill`` event and of nothing else."""
+    from flextree_tpu.serving import costs
+
+    calls = []
+    real = costs.predict_prefill_us
+    monkeypatch.setattr(
+        costs, "predict_prefill_us",
+        lambda *a, **k: calls.append(1) or real(*a, **k))
+    eng = _engine()
+    assert eng.submit(_request(1, 6, 2))
+    eng.step()
+    assert calls == []
+    assert eng.submit(_request(2, 6, 2))
+    with flight_recorder(None) as rec:
+        eng.step()
+    assert calls == [1]
+    [ev] = [e for e in rec.events if e["kind"] == "serve_prefill"]
+    assert ev["rid"] == 2 and ev["predicted_us"] > 0 and ev["measured_us"] > 0
+    assert [e for e in rec.events if e["kind"] == "serve_round_measured"]
+
+
+# ---------------------------------------------------------- the train loop
+
+
+def _toy_fit(num_steps, **kw):
+    from flextree_tpu.parallel.loop import FitConfig, fit
+
+    class Data:
+        def batch_at(self, step):
+            t = np.full((2, 4), float(step + 1))
+            return t, t
+
+    def step_fn(state, tokens, targets):
+        s = int(np.asarray(state["step"]))
+        return ({"step": np.int64(s + 1), "w": np.asarray(state["w"]) - 1.0},
+                {"loss": 0.5})
+
+    return fit(
+        {"step": np.int64(0), "w": np.zeros(2)}, step_fn, Data(),
+        FitConfig(num_steps=num_steps, log_every=0, prefetch=0), **kw)
+
+
+def test_three_fit_steps_emit_the_loop_spans():
+    with flight_recorder(None) as rec:
+        result = _toy_fit(3)
+    assert result.steps_run == 3
+    spans = _spans(rec)
+    assert sorted(e["name"] for e in spans) == sorted(STEP_SPANS * 3)
+    steps = [e for e in spans if e["name"] == "ft.loop.step"]
+    assert [e["step"] for e in steps] == [0, 1, 2]
+    assert all(e["parent"] is None for e in steps)
+    assert all(e["parent"] == "ft.loop.step" for e in spans
+               if e["name"] != "ft.loop.step")
+    first = [e["name"] for e in sorted(spans, key=lambda e: e["start"])][:5]
+    assert first == STEP_SPANS
+    # the events the benchmark reads are still there, one a step
+    kinds = [e["kind"] for e in rec.events]
+    assert kinds.count("step_start") == kinds.count("step_end") == 3
+    assert kinds[0] == "fit_start" and kinds[-1] == "fit_end"
+
+
+def test_supervised_fit_ticks_under_bookkeeping_and_dispatches_once_a_step():
+    from flextree_tpu.parallel.loop import Supervision
+
+    with flight_recorder(None) as rec:
+        _toy_fit(2, supervision=Supervision(
+            membership=lambda: {0: "healthy"}, configured_world=1))
+    names = [e["name"] for e in _spans(rec)]
+    assert names.count("ft.loop.dispatch") == 2
+    # the supervisor's ticks before the step and the bookkeeping after it
+    assert names.count("ft.loop.bookkeeping") == 4
+    assert names.count("ft.loop.step") == 2
+
+
+# ------------------------------------------------ scopes inside the step
+
+
+@pytest.fixture(scope="module")
+def lowered_step_paths():
+    """The ``op_name`` path of every operation of a lowered (2,2,2) train
+    step with clipping on."""
+    import re
+
+    from flextree_tpu.parallel.train import (
+        TrainConfig, init_train_state, make_mesh_nd, make_train_step,
+    )
+
+    cfg = TransformerConfig(
+        vocab_size=64, d_model=32, n_heads=4, n_layers=2, d_ff=64)
+    mesh = make_mesh_nd(8, (2, 2, 2), ("dp", "sp", "tp"))
+    tc = TrainConfig(grad_clip_norm=1.0)
+    state = init_train_state(jax.random.PRNGKey(0), cfg, tc, mesh=mesh)
+    tok = jnp.zeros((4, 16), jnp.int32)
+    text = make_train_step(mesh, cfg, tc).lower(state, tok, tok).as_text(
+        debug_info=True)
+    return sorted(set(re.findall(r'loc\("([^"]*ft_[^"]*)"', text)))
+
+
+@pytest.mark.parametrize("scope", PHASE_SCOPES)
+def test_lowered_train_step_holds_the_scope(lowered_step_paths, scope):
+    import re
+
+    token = re.compile(rf"\b{scope}\b")
+    assert any(token.search(p) for p in lowered_step_paths), scope
+
+
+@pytest.mark.parametrize(
+    "scope", ["ft_embed", "ft_norm", "ft_attn", "ft_mlp", "ft_head", "ft_loss"])
+def test_scope_survives_into_the_backward_pass(lowered_step_paths, scope):
+    assert any(f"transpose(jvp({scope}))" in p for p in lowered_step_paths)
+
+
+def test_phase_scopes_never_nest(lowered_step_paths):
+    """One phase a path: the shares by scope then add up, and the scopes
+    the collectives bring (``ft_bucket*``, ``ft_rs_stage*``) sit INSIDE a
+    phase."""
+    import re
+
+    phases = re.compile(r"\b(" + "|".join(PHASE_SCOPES) + r")\b")
+    for path in lowered_step_paths:
+        assert len(set(phases.findall(path))) == 1, path
+    assert any("ft_grad_sync" in p and "ft_bucket" in p
+               for p in lowered_step_paths)
+
+
+# ------------------------------------------------------------ the readers
+
+E = X.Event
+
+
+def _ctx(host_events, ops, window=(0.0, 1000.0), trace_dir=None):
+    planes = [X.Plane("/host:CPU", [X.Line("python3", host_events)])]
+    if ops is not None:
+        planes.append(X.Plane("/device:TPU:0", [X.Line("XLA Ops", ops)]))
+    run = Run(True, 0, 0, {}, {}, 0.0, trace_dir)
+    cell = types.SimpleNamespace(name="toy")
+    return ReaderContext(cell, run, {}, X.Trace(planes), window)
+
+
+def _round_trace():
+    """Two rounds of 400 ns inside a 1000 ns window.  Chip 0 is busy
+    [50,150] [230,300] [450,560] [640,700] [900,1000]: idle 650."""
+    host = [
+        E("bench_window", 0, 1000),
+        E("ft.engine.round", 0, 400, {"round": 0}),
+        E("ft.engine.decode_fetch", 100, 100),  # 100..200
+        E("ft.engine.sample", 200, 100),  # 200..300
+        E("ft.engine.bookkeeping", 300, 100, {"blocks_in_use": 12, "blocks_total": 32}),
+        E("ft.engine.round", 420, 400, {"round": 1}),
+        E("ft.engine.prefill", 430, 20),
+        E("ft.engine.decode_fetch", 500, 100),  # 500..600
+        E("ft.engine.sample", 600, 150),  # 600..750
+        E("ft.engine.bookkeeping", 750, 70, {"blocks_in_use": 16, "blocks_total": 32}),
+    ]
+    ops = [
+        E("%fusion.1 = f32[4]{0} fusion(", 50, 100,
+          {"tf_op": "jit(step)/ft_mlp/dot_general"}),
+        E("%fusion.2 = f32[4]{0} fusion(", 230, 70,
+          {"tf_op": "jit(step)/transpose(jvp(ft_mlp))/dot_general"}),
+        E("%fusion.3 = f32[4]{0} fusion(", 450, 110,
+          {"tf_op": "jit(step)/ft_grad_sync/ft_bucket0_dp_128B/psum"}),
+        E("%fusion.4 = f32[4]{0} fusion(", 640, 60, {"tf_op": "jit(step)/add"}),
+        E("%while.5 = f32[4]{0} while(", 900, 100,
+          {"tf_op": "jit(step)/ft_optimizer/while"}),
+        E("%fusion.6 = f32[4]{0} fusion(", 920, 30,
+          {"tf_op": "jit(step)/ft_optimizer/mul"}),
+    ]
+    return host, ops
+
+
+def test_innermost_segments_tile_the_spans():
+    host, _ = _round_trace()
+    spans = sorted((e for e in host if e.name.startswith("ft.")),
+                   key=lambda e: (e.start_ns, -e.dur_ns))
+    segs = S.innermost_segments(spans)
+    assert segs[:5] == [
+        (0, 100, "ft.engine.round"), (100, 200, "ft.engine.decode_fetch"),
+        (200, 300, "ft.engine.sample"), (300, 400, "ft.engine.bookkeeping"),
+        (420, 430, "ft.engine.round"),
+    ]
+    assert all(a < b for a, b, _ in segs)
+    assert all(p[1] <= q[0] for p, q in zip(segs, segs[1:]))
+    assert sum(b - a for a, b, _ in segs) == 400 + 400
+
+
+def test_idle_gap_crossing_two_spans_is_split_by_overlap():
+    host, ops = _round_trace()
+    ctx = _ctx(host, ops)
+    # the gap [150,230] crosses decode_fetch (150..200) and sample (200..230)
+    per = "ft.engine.round"
+    fetch = S.idle_ms_per(ctx, ["ft.engine.decode_fetch"], per)
+    sample = S.idle_ms_per(ctx, ["ft.engine.sample"], per)
+    # fetch: [150,200] + [560,600]; sample: [200,230] + [600,640] + [700,750]
+    assert fetch * 2 * 1e6 == pytest.approx(50 + 40)
+    assert sample * 2 * 1e6 == pytest.approx(30 + 40 + 50)
+
+
+def test_idle_by_span_sums_to_the_idle_time():
+    host, ops = _round_trace()
+    ctx = _ctx(host, ops)
+    per = "ft.engine.round"
+    names = sorted({e.name for e in host if e.name.startswith("ft.")})
+    parts = [S.idle_ms_per(ctx, [n], per) for n in names]
+    outside = S.idle_ms_per(ctx, [], per)
+    # outside every ft. span: [400,420] between the rounds, [820,900] after
+    assert outside * 2 * 1e6 == pytest.approx(20 + 80)
+    busy_ns = X.busy_seconds(ctx.trace, ctx.window) * 1e9
+    assert busy_ns == pytest.approx(100 + 70 + 110 + 60 + 100)
+    assert (sum(parts) + outside) * 2 * 1e6 == pytest.approx(1000 - busy_ns)
+
+
+def test_span_median_and_the_rounds_that_admitted():
+    host, ops = _round_trace()
+    ctx = _ctx(host, ops)
+    assert S.span_ms_p50(ctx, "ft.engine.sample") * 1e6 == pytest.approx(125)
+    # only round 1 holds a prefill
+    assert S.span_ms_p50(
+        ctx, "ft.engine.sample", within="ft.engine.round",
+        having="ft.engine.prefill") * 1e6 == pytest.approx(150)
+    assert S.span_ms_p50(ctx, "ft.engine.nowhere") is None
+    assert S.count_ratio_p50(
+        ctx, "ft.engine.bookkeeping", "blocks_in_use", "blocks_total"
+    ) == pytest.approx(100 * (12 / 32 + 16 / 32) / 2)
+    assert S.count_ratio_p50(ctx, "ft.engine.round", "blocks_in_use",
+                             "blocks_total") is None
+
+
+def test_scope_shares_forward_transposed_nested_and_unscoped():
+    host, ops = _round_trace()
+    ctx = _ctx(host, ops)
+    busy = 100 + 70 + 110 + 60 + 100
+    assert S.scope_share(ctx, ["ft_mlp"]) == pytest.approx(100 * 170 / busy)
+    # the bucket's scope sits inside ft_grad_sync
+    assert S.scope_share(ctx, ["ft_grad_sync"]) == pytest.approx(100 * 110 / busy)
+    # a loop and its body: own times, nothing counted twice
+    assert S.scope_share(ctx, ["ft_optimizer", "ft_grad_clip"]) == pytest.approx(
+        100 * 100 / busy)
+    assert S.scope_share(ctx, []) == pytest.approx(100 * 60 / busy)
+    assert S.scope_share(ctx, ["ft_mlp_other"]) == 0.0
+
+
+def _pb(num, value):
+    """One protobuf field: a varint for an int, else length-delimited."""
+    def varint(n):
+        out = bytearray()
+        while True:
+            out.append((n & 0x7F) | (0x80 if n > 0x7F else 0))
+            n >>= 7
+            if not n:
+                return bytes(out)
+
+    if isinstance(value, int):
+        return varint(num << 3) + varint(value)
+    if isinstance(value, str):
+        value = value.encode()
+    return varint(num << 3 | 2) + varint(len(value)) + value
+
+
+def _xspace(ops_by_plane):
+    """An ``.xplane.pb`` holding only what ``op_paths`` reads: per plane,
+    the stat names and each operation's metadata record with its
+    ``tf_op`` (as a string, or for the last one as a reference to a stat
+    record of that name)."""
+    space = b""
+    for plane, ops in ops_by_plane.items():
+        body = _pb(1, 1) + _pb(2, plane) + _pb(3, _pb(2, "XLA Ops"))
+        body += _pb(5, _pb(1, 7) + _pb(2, _pb(1, 7) + _pb(2, "tf_op")))
+        body += _pb(5, _pb(1, 9) + _pb(2, _pb(1, 9) + _pb(2, "flops")))
+        for i, (text, path) in enumerate(ops.items(), start=1):
+            stats = _pb(5, _pb(1, 9) + _pb(4, 1234))
+            if path is not None and i == len(ops):
+                body += _pb(5, _pb(1, 100 + i) + _pb(
+                    2, _pb(1, 100 + i) + _pb(2, path)))
+                stats += _pb(5, _pb(1, 7) + _pb(7, 100 + i))
+            elif path is not None:
+                stats += _pb(5, _pb(1, 7) + _pb(5, path))
+            body += _pb(4, _pb(1, i) + _pb(
+                2, _pb(1, i) + _pb(2, text) + _pb(4, "shown") + stats))
+        space += _pb(1, body)
+    return space
+
+
+def test_op_paths_reads_the_metadata_table_and_joins_by_hlo_text(tmp_path):
+    """On the chip the scope path is no stat of the event but the
+    ``tf_op`` of its metadata record, which ``ProfileData`` does not hand
+    out: read from the file, joined by the HLO text."""
+    _, ops = _round_trace()
+    table = {o.name: o.stats["tf_op"] for o in ops}
+    table[ops[3].name] = None  # an operation the compiler put in: no path
+    where = tmp_path / "plugins" / "profile" / "run"
+    where.mkdir(parents=True)
+    (where / "host.xplane.pb").write_bytes(_xspace({
+        "/host:CPU": {"%not_a_device_op": "jit(f)/ft_mlp/mul"},
+        "/device:TPU:0": table,
+    }))
+    got = S.op_paths(str(where / "host.xplane.pb"))
+    assert got == {k: v for k, v in table.items() if v is not None}
+    host, _ = _round_trace()
+    bare = [E(o.name, o.start_ns, o.dur_ns) for o in ops]  # as xplane.load leaves them
+    ctx = _ctx(host, bare, trace_dir=str(tmp_path))
+    busy = 100 + 70 + 110 + 60 + 100
+    assert S.scope_share(ctx, ["ft_mlp"]) == pytest.approx(100 * 170 / busy)
+    assert S.scope_share(ctx, ["ft_optimizer"]) == pytest.approx(100 * 100 / busy)
+    assert S.scope_share(ctx, []) == pytest.approx(100 * 60 / busy)
+
+
+def test_readers_return_none_where_there_is_nothing_to_read():
+    host, ops = _round_trace()
+    # a rehearsal: host spans, no device plane
+    cpu = _ctx(host, None)
+    assert S.idle_ms_per(cpu, [], "ft.engine.round") is None
+    assert S.scope_share(cpu, ["ft_mlp"]) is None
+    assert S.span_ms_p50(cpu, "ft.engine.sample") is not None
+    # a parent commit: a device plane, no program span and no scope
+    bare = [E(o.name, o.start_ns, o.dur_ns) for o in ops]
+    parent = _ctx([E("bench_window", 0, 1000)], bare)
+    assert S.idle_ms_per(parent, ["ft.engine.sample"], "ft.engine.round") is None
+    assert S.span_ms_p50(parent, "ft.engine.sample") is None
+    assert S.scope_share(parent, []) is None
+    assert S.scope_share(parent, ["ft_mlp"]) is None
+
+
+def test_new_metric_files_name_readers_that_exist():
+    """Every per-layer entry of BENCHMARK.json that reads spans has its
+    metric file, and the file's arguments fit the reader's signature."""
+    import inspect
+    import json
+    import os
+
+    from benchmarks.lib import harness
+
+    bench = harness.load_benchmark()
+    seen = 0
+    for entry in bench["per_layer"]:
+        path = os.path.join(harness.ROOT, "metrics", f"{entry['name']}.json")
+        with open(path, encoding="utf-8") as f:
+            meta = json.load(f)
+        if not meta["reader"].startswith("spans:"):
+            continue
+        seen += 1
+        fn = getattr(S, meta["reader"].split(":")[1])
+        inspect.signature(fn).bind(None, **meta.get("args", {}))
+        assert entry["source"] in ("program_span", "program_counter", "device_trace")
+    assert seen == 16

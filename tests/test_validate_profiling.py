@@ -1,8 +1,5 @@
 """Tests for the schedule validator (the race-detection analog, SURVEY §5)
-and the profiling/tracing utilities (the SHOW_TIME / FT_DEBUG analogs)."""
-
-import glob
-import os
+and the debug-dump utilities (the FT_DEBUG analog)."""
 
 import pytest
 
@@ -13,7 +10,7 @@ from flextree_tpu.schedule import (
     validate_ring,
     validate_topology,
 )
-from flextree_tpu.utils import PhaseTimer, debug_dump_schedule, debug_enabled, trace
+from flextree_tpu.utils import debug_dump_schedule, debug_enabled
 
 
 ALL_SHAPES = [
@@ -121,23 +118,6 @@ class TestValidateTopology:
         assert time.perf_counter() - t0 < 10.0
 
 
-class TestPhaseTimer:
-    def test_checkpoints(self):
-        pt = PhaseTimer()
-        pt.checkpoint("a")
-        pt.checkpoint("b")
-        names = [n for n, _ in pt.phases]
-        assert names == ["a", "b"]
-        assert all(dt >= 0 for _, dt in pt.phases)
-        assert "total" in pt.summary()
-
-    def test_reset(self):
-        pt = PhaseTimer()
-        pt.checkpoint("a")
-        pt.reset()
-        assert pt.phases == []
-
-
 class TestDebugDump:
     def test_off_by_default(self, monkeypatch):
         monkeypatch.delenv("FT_DEBUG", raising=False)
@@ -159,17 +139,6 @@ class TestDebugDump:
         monkeypatch.delenv("FT_DEBUG", raising=False)
         out = debug_dump_schedule(Topology(4, (4,)), force=True)
         assert out.count("plan of node") == 4
-
-
-class TestProfilerTrace:
-    def test_trace_writes_xplane(self, tmp_path):
-        import jax
-        import jax.numpy as jnp
-
-        with trace(str(tmp_path)):
-            jax.block_until_ready(jax.jit(lambda x: x * 2)(jnp.ones(128)))
-        dumped = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
-        assert dumped, f"no xplane trace written under {tmp_path}"
 
 
 class TestNamedScopesCompile:
