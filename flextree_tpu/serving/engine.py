@@ -1,13 +1,15 @@
 """The serving engine: paged cache + continuous batcher + the model.
 
 One :class:`ServingEngine` is one replica: it owns a paged K/V pool, a
-:class:`~flextree_tpu.serving.batcher.ContinuousBatcher`, and two jitted
-programs — prefill (one compile per distinct prompt length) and the paged
+:class:`~flextree_tpu.serving.batcher.ContinuousBatcher`, and three jitted
+programs — prefill (one compile per distinct prompt length), the paged
 decode step (ONE compile for the server lifetime; slot count, table
-width, and pool shape are all static).  The decode step runs **fused**
-paged attention by default (``fused=True`` → ``ops.paged_attention``
-streams K/V blocks through an online softmax, never materializing the
-gathered row; within a pinned tolerance of the gather oracle);
+width, and pool shape are all static) and the greedy pick over the
+logits either leaves on the device (:func:`greedy_ids`).  The decode
+step runs **fused** paged attention by default (``fused=True`` →
+``ops.paged_attention`` streams K/V blocks through an online softmax,
+never materializing the gathered row; within a pinned tolerance of the
+gather oracle);
 ``fused=False`` keeps the gather path, which is the one proven bitwise
 against ``generate``.  ``step()`` is one scheduling round:
 
@@ -29,12 +31,14 @@ against ``generate``.  ``step()`` is one scheduling round:
 5. **retire** — finished sequences (stop token or ``max_new_tokens``)
    free their blocks immediately and land in ``completed``.
 
-Sampling is per request and host-side over the returned logits row:
-greedy is ``np.argmax`` (bitwise-identical to ``generate``'s
-``jnp.argmax`` on identical logits — the bench's floor); ``temperature``
-/ ``top_k`` requests thread the same presplit key schedule ``generate``
-uses, so a sampled request through the engine reproduces
-``generate(..., key=PRNGKey(seed))`` exactly.
+Sampling is per request, and token ids, not logits, cross to the host:
+the greedy pick is :func:`greedy_ids` on the device (``generate``'s
+``jnp.argmax`` on identical logits — the bench's floor), one ``(S,)``
+int32 fetch a decode round and one id a prefill.  Only a request with
+``temperature > 0`` has its own logits row fetched, for ``sample_token``
+under the same presplit key schedule ``generate`` uses, so a sampled
+request through the engine reproduces ``generate(..., key=PRNGKey(seed))``
+exactly.
 
 Timestamps come from the module-level ``_now`` (monotonic), injectable
 for tests the same way ``runtime.supervisor._wall`` is.
@@ -89,6 +93,15 @@ __all__ = ["CompletedRequest", "ServingEngine"]
 # injection point for tests (patch this, not time.monotonic) — one clock
 # for arrival stamps (load generator) and token stamps (engine)
 _now = time.monotonic
+
+
+def greedy_ids(logits):
+    """(N, V) logits -> (N,) int32 ids: ``generate``'s own greedy pick
+    (``sample_token`` at temperature 0), the first index of each row's
+    maximum as ``np.argmax`` takes it.  A named ``def`` so that its
+    program shows in a profile as ``jit_greedy_ids``, apart from the
+    decode program the benchmark finds by name."""
+    return sample_token(logits)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -176,6 +189,10 @@ class ServingEngine:
         self._prefill = jax.jit(
             lambda p, tok: prefill(p, tok, cfg, max_len=pcfg.max_len)
         )
+        # the greedy pick runs where the logits are, so that (S,) ids and
+        # not (S, V) logits cross to the host: two shapes for the server
+        # lifetime, the decode round's (S, V) and a prefill's (1, V)
+        self._greedy_ids = jax.jit(greedy_ids)
         # suffix-only prefill for prefix-cache hits, fused with the block
         # gather into ONE program: one compile per (chain_len, cached_len,
         # suffix_len) bucket — the prefix shapes carry the offset, so
@@ -291,14 +308,24 @@ class ServingEngine:
                     logits, self.pools = self._decode(
                         self.params, self.pools, tables, lengths, tokens
                     )
+                    ids = self._greedy_ids(logits)
+                # counted while the device decodes
+                sampled = sum(
+                    self.batcher.slots[slot].request.temperature > 0
+                    for slot in active
+                )
                 with span("ft.engine.decode_fetch"):
-                    # host fetch = the step boundary
-                    logits = np.asarray(logits)
+                    # host fetch = the step boundary; the logits stay on
+                    # the device and are dropped with the round
+                    ids = np.asarray(ids)
                 decode_s = _now() - t_dec
                 now = _now()
-                with span("ft.engine.sample"):
+                with span(
+                    "ft.engine.sample", on_device=len(active) - sampled,
+                    rows_fetched=sampled, active=len(active),
+                ):
                     for slot in active:
-                        tok = self._pick(slot, logits[slot])
+                        tok = self._pick(slot, ids, logits, slot)
                         self.batcher.record_decode_token(slot, tok, now)
                 self.decode_steps += 1
                 self.metrics.counter("serve.decode_tokens").inc(len(active))
@@ -559,7 +586,7 @@ class ServingEngine:
         )
         if self.chaos_prefill_sleep_s > 0:
             time.sleep(self.chaos_prefill_sleep_s * req.prompt_len)
-        first_token = int(np.argmax(np.asarray(logits[0])))
+        first_token = int(np.asarray(self._greedy_ids(logits))[0])
         kv = export_blocks(self.pools, blocks)
         kv = {
             "k": [np.asarray(a) for a in kv["k"]],
@@ -824,6 +851,7 @@ class ServingEngine:
         c = state.cached_tokens
         with span("ft.engine.prefill_dispatch"):
             logits = self._dispatch_prefill(state, prompt, c)
+            ids = self._greedy_ids(logits)
         if self.batcher.prefix_index is not None:
             self._note_prefix_admission(c > 0, t0)
         if self.chaos_prefill_sleep_s > 0:
@@ -840,7 +868,7 @@ class ServingEngine:
                 jax.random.PRNGKey(req.seed), req.max_new_tokens
             )
         with span("ft.engine.prefill_fetch_sample"):
-            tok = self._pick(slot, np.asarray(logits[0]))
+            tok = self._pick(slot, np.asarray(ids), logits, 0)
         now = _now()
         self.batcher.record_first_token(slot, tok, now)
         self.metrics.histogram("serve.ttft_ms").observe(
@@ -899,11 +927,17 @@ class ServingEngine:
                 self.metrics.counter("serve.prefix_misses").inc()
         return logits
 
-    def _pick(self, slot: int, logits_row: np.ndarray) -> int:
+    def _pick(self, slot: int, ids: np.ndarray, logits, row: int) -> int:
+        """The slot's next token from row ``row`` of one program's
+        logits: for a greedy request the id the device picked (``ids``,
+        already on the host); for a sampled one its logits row, the only
+        logits that cross, through ``sample_token``."""
         state = self.batcher.slots[slot]
         req = state.request
         if req.temperature <= 0:
-            return int(np.argmax(logits_row))
+            return int(ids[row])
+        logits_row = np.asarray(logits[row])
+        self.metrics.counter("serve.logits_rows_fetched").inc()
         key = self._keys[slot][len(state.generated)]
         tok = sample_token(
             logits_row[None],
@@ -949,7 +983,8 @@ class ServingEngine:
         import_counts=(),
     ) -> None:
         """Compile the decode step, each distinct prompt length's prefill,
-        and each distinct reservation size's pool write before a timed run
+        the greedy pick over the logits of both, and each distinct
+        reservation size's pool write before a timed run
         (compiles otherwise land inside the first requests' latency).
         ``block_counts``: the distinct ``pcfg.blocks_for(prompt + max_new)``
         values the workload will reserve.  Under on-demand admission the
@@ -964,18 +999,25 @@ class ServingEngine:
         hit was supposed to shrink.  Each bucket also warms the offset
         scatter for every remaining-block count it can need."""
         S, P = self.bcfg.slots, self.pcfg.blocks_per_seq
+        # the greedy pick is warmed on both of its shapes: the decode
+        # round's (S, V) logits here and a prefill's (1, V) below
         jax.block_until_ready(
-            self._decode(
-                self.params,
-                init_pools(self.cfg, self.pcfg),
-                np.zeros((S, P), np.int32),
-                np.zeros((S,), np.int32),
-                np.zeros((S,), np.int32),
-            )[0]
+            self._greedy_ids(
+                self._decode(
+                    self.params,
+                    init_pools(self.cfg, self.pcfg),
+                    np.zeros((S, P), np.int32),
+                    np.zeros((S,), np.int32),
+                    np.zeros((S,), np.int32),
+                )[0]
+            )
         )
         cache = None
         for t in sorted(set(int(t) for t in prompt_lens)):
-            _, cache = self._prefill(self.params, np.zeros((1, t), np.int32))
+            logits, cache = self._prefill(
+                self.params, np.zeros((1, t), np.int32)
+            )
+            jax.block_until_ready(self._greedy_ids(logits))
         for n in sorted(set(int(n) for n in block_counts)):
             if cache is None:
                 _, cache = self._prefill(

@@ -273,6 +273,183 @@ def test_engine_sampled_request_matches_generate_key_schedule(model):
     np.testing.assert_array_equal(eng.completed[0].tokens, want)
 
 
+# ------------------------------------------- the pick: ids cross, not logits
+
+
+def _rows_fetched(eng):
+    return eng.report()["counters"].get("serve.logits_rows_fetched", 0)
+
+
+def _spy_on_picks(eng):
+    """Every (logits, ids) pair the engine's pick program saw, as numpy."""
+    seen, pick = [], eng._greedy_ids
+
+    def spy(logits):
+        ids = pick(logits)
+        seen.append((np.asarray(logits), np.asarray(ids)))
+        return ids
+
+    eng._greedy_ids = spy
+    return seen
+
+
+def test_all_greedy_run_fetches_no_logits_row(model):
+    """Greedy tokens are the host argmax's, bit for bit: every id the
+    device picked is ``np.argmax`` of the same logits (what the engine
+    fetched and took before), the completed tokens are ``generate``'s, and
+    no logits row crossed to the host."""
+    cfg, params = model
+    pcfg = _pcfg()
+    eng = ServingEngine(params, cfg, pcfg, BatcherConfig(slots=3))
+    seen = _spy_on_picks(eng)
+    rng = np.random.default_rng(21)
+    reqs = [
+        Request(rid=i, prompt=_prompt(rng, t), max_new_tokens=m)
+        for i, (t, m) in enumerate([(5, 6), (9, 4), (13, 8), (7, 1)])
+    ]
+    for r in reqs:
+        assert eng.submit(r)
+    eng.run_until_idle()
+    for r in reqs:
+        want = np.asarray(
+            generate(params, jnp.asarray(r.prompt)[None], cfg,
+                     max_new_tokens=r.max_new_tokens, max_len=pcfg.max_len)
+        )[0]
+        np.testing.assert_array_equal(eng.completed[r.rid].tokens, want)
+    assert _rows_fetched(eng) == 0
+    # one pick a prefill over (1, V), one a decode round over (S, V)
+    shapes = [logits.shape for logits, _ in seen]
+    assert shapes.count((1, cfg.vocab_size)) == len(reqs)
+    assert shapes.count((3, cfg.vocab_size)) == eng.decode_steps > 0
+    for logits, ids in seen:
+        assert ids.dtype == np.int32
+        np.testing.assert_array_equal(ids, np.argmax(logits, axis=-1))
+
+
+def test_mixed_batch_fetches_only_the_sampled_rows(model):
+    """Greedy beside ``temperature > 0`` requests in one batch: a sampled
+    one reproduces ``generate(key=PRNGKey(seed))``, a greedy one the
+    argmax, and exactly one logits row crossed for each sampled token."""
+    cfg, params = model
+    pcfg = _pcfg()
+    eng = ServingEngine(params, cfg, pcfg, BatcherConfig(slots=4))
+    rng = np.random.default_rng(22)
+    knobs = [
+        dict(), dict(temperature=0.8, top_k=4, seed=17), dict(),
+        dict(temperature=1.3, seed=5), dict(temperature=0.5, top_k=2, seed=9),
+    ]
+    reqs = [
+        Request(rid=i, prompt=_prompt(rng, t), max_new_tokens=m, **kw)
+        for i, ((t, m), kw) in enumerate(
+            zip([(5, 6), (9, 4), (13, 8), (7, 5), (6, 3)], knobs))
+    ]
+    for r in reqs[:4]:
+        assert eng.submit(r)
+    eng.step()
+    assert _rows_fetched(eng) == 2 + 2  # two first tokens, two decoded
+    assert eng.submit(reqs[4])  # joins a batch in mid-decode
+    eng.run_until_idle()
+    for r, kw in zip(reqs, knobs):
+        key = jax.random.PRNGKey(kw["seed"]) if kw else None
+        want = np.asarray(
+            generate(params, jnp.asarray(r.prompt)[None], cfg,
+                     max_new_tokens=r.max_new_tokens, max_len=pcfg.max_len,
+                     temperature=r.temperature, top_k=r.top_k, key=key)
+        )[0]
+        np.testing.assert_array_equal(eng.completed[r.rid].tokens, want)
+    assert _rows_fetched(eng) == sum(
+        r.max_new_tokens for r, kw in zip(reqs, knobs) if kw)
+
+
+@pytest.mark.parametrize("rows", [
+    [[1.0, 3.0, 3.0, 2.0]],  # a tie at the maximum: the lower index
+    [[0.0, 0.0, 0.0, 0.0], [-1.0, -1.0, -2.0, -1.0]],  # all equal; ties below 0
+    [[-np.inf, 5.0, 5.0, -np.inf], [7.0, 7.0, 7.0, 7.5]],  # masked ends; no tie
+], ids=["tie", "all-equal", "masked"])
+def test_greedy_pick_takes_the_lowest_index_of_tied_maxima(rows):
+    from flextree_tpu.serving.engine import greedy_ids
+
+    logits = np.asarray(rows, np.float32)
+    ids = np.asarray(jax.jit(greedy_ids)(logits))
+    assert ids.dtype == np.int32 and ids.shape == (len(rows),)
+    np.testing.assert_array_equal(ids, np.argmax(logits, axis=-1))
+
+
+def test_tied_logits_through_the_engine_pick_the_lowest_id(model):
+    """All-zero embeddings make every logit equal: each token is id 0."""
+    cfg, params = model
+    flat = dict(params, embed=jnp.zeros_like(params["embed"]))
+    eng = ServingEngine(flat, cfg, _pcfg(), BatcherConfig(slots=2))
+    assert eng.submit(Request(rid=0, prompt=np.arange(5, dtype=np.int32),
+                              max_new_tokens=4))
+    eng.run_until_idle()
+    np.testing.assert_array_equal(eng.completed[0].tokens, np.zeros(4, np.int32))
+
+
+def test_benchmark_reference_check_runs_the_engines_own_programs():
+    """``benchmarks/lib/serve_closed.py::check_against_reference`` calls
+    ``engine._prefill``, ``_write`` and ``_decode`` and reads logits ROWS
+    from the first and the last: they keep those signatures and stay the
+    programs ``step()`` runs, so the check proves what is timed."""
+    from benchmarks.lib import harness, serve_closed
+
+    cell = harness.load_cell("pythia-6.9b.chat-closed-c32")
+    harness.apply_rehearsal(cell)
+    engine, cfg = serve_closed.build_engine(cell, 4)
+    t = cell.traffic
+    calls = {"_prefill": 0, "_write": 0, "_decode": 0}
+
+    def counted(name):
+        fn = getattr(engine, name)
+
+        def call(*args):
+            calls[name] += 1
+            return fn(*args)
+
+        setattr(engine, name, call)
+
+    for name in calls:
+        counted(name)
+    check = serve_closed.check_against_reference(
+        engine, cfg, 4, t["check_prompt"], t["check_steps"], t["check_blocks"])
+    assert check["ok"], check
+    assert calls == {"_prefill": 1, "_write": 1, "_decode": t["check_steps"]}
+    # and a round of the closed loop goes through the same three
+    loop = serve_closed.ClosedLoop(engine, t, 4, cfg.vocab_size)
+    loop.issue()
+    loop.round()
+    assert calls == {"_prefill": 2, "_write": 2,
+                     "_decode": t["check_steps"] + 1}
+    assert loop.rounds[-1][2:4] == (2, 1)  # a first token and a decoded one
+
+
+def test_pick_program_is_not_counted_as_the_decode_program(model):
+    """``kernels.decode_roofline`` finds the decode program by name and
+    divides its time by its run count: the pick program's name must match
+    neither pattern, or the count doubles."""
+    import os
+    import re
+
+    from benchmarks.lib import harness
+
+    match = re.compile(harness._read_json(os.path.join(
+        harness.ROOT, "metrics", "kernels.decode_roofline.json"))["args"]["match"])
+    cfg, params = model
+    pcfg = _pcfg()
+    eng = ServingEngine(params, cfg, pcfg, BatcherConfig(slots=2))
+
+    def module_name(lowered):
+        return re.search(r"module @(\S+)", lowered.as_text())[1]
+
+    pick = module_name(
+        eng._greedy_ids.lower(jnp.zeros((2, cfg.vocab_size), jnp.float32)))
+    assert pick == "jit_greedy_ids" and not match.search(pick)
+    decode = module_name(eng._decode.lower(
+        params, eng.pools, np.zeros((2, pcfg.blocks_per_seq), np.int32),
+        np.zeros((2,), np.int32), np.zeros((2,), np.int32)))
+    assert match.search(decode)  # the same look finds the decode program
+
+
 def test_engine_sampled_without_seed_rejected_at_submit(model):
     """Discovered mid-prefill this would wedge the slot (blocks reserved,
     no sampler key) — so it must be refused BEFORE admission."""

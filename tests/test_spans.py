@@ -228,6 +228,10 @@ def test_one_engine_round_emits_exactly_its_spans():
     assert book["blocks_total"] == total
     assert book["blocks_in_use"] == total - eng.batcher.allocator.num_free > 0
     assert (book["round"], book["decoded"], book["admitted"], book["finished"]) == (0, 1, 1, 0)
+    # the pick's counts, known before the span opens: one greedy slot
+    # decoded, its id picked on the device, no logits row fetched
+    sample = by_name["ft.engine.sample"]
+    assert (sample["on_device"], sample["rows_fetched"], sample["active"]) == (1, 0, 1)
     # the children tile the round in the order the work happens
     order = [e["name"] for e in sorted(spans, key=lambda e: e["start"])
              if e["parent"] == "ft.engine.round"]
@@ -252,11 +256,35 @@ def test_a_round_samples_under_one_span_and_later_rounds_count_on():
     decoding = [e for e in spans if e["name"] == "ft.engine.bookkeeping"
                 and e["decoded"] > 0]
     assert len(samples) == len(decoding)  # ONE a round, whatever the slots
+    fetches = [e for e in spans if e["name"] == "ft.engine.decode_fetch"]
+    assert len(fetches) == len(decoding) == eng.decode_steps
+    assert [e["active"] for e in samples] == [e["decoded"] for e in decoding]
+    assert all(e["on_device"] == e["active"] and e["rows_fetched"] == 0
+               for e in samples)
     assert any(e["decoded"] == 2 for e in decoding)
     assert sum(e["finished"] for e in spans
                if e["name"] == "ft.engine.bookkeeping") == 2
     # the round span took the round-time histogram's place
     assert not any(k.endswith("round_ms") for k in eng.report()["histograms"])
+
+
+def test_a_sampled_slot_is_counted_as_a_fetched_row():
+    from flextree_tpu.serving import Request
+
+    eng = _engine()
+    assert eng.submit(_request(1, 5, 4))
+    prompt = np.arange(6, dtype=np.int32)
+    assert eng.submit(Request(rid=2, prompt=prompt, max_new_tokens=2,
+                              temperature=0.7, top_k=3, seed=1))
+    with flight_recorder(None) as rec:
+        eng.run_until_idle()
+    samples = [e for e in _spans(rec) if e["name"] == "ft.engine.sample"]
+    # round 0 decodes both; the sampled request is done after it
+    assert [(e["on_device"], e["rows_fetched"], e["active"]) for e in samples] \
+        == [(1, 1, 2), (1, 0, 1), (1, 0, 1)]
+    # the counter adds the row its first token took in the prefill
+    rows = eng.report()["counters"]["serve.logits_rows_fetched"]
+    assert rows == 1 + sum(e["rows_fetched"] for e in samples) == 2
 
 
 def test_prefill_prediction_is_built_only_for_a_recorder(monkeypatch):
@@ -567,6 +595,68 @@ def test_op_paths_reads_the_metadata_table_and_joins_by_hlo_text(tmp_path):
     assert S.scope_share(ctx, []) == pytest.approx(100 * 60 / busy)
 
 
+def _metric_file(name):
+    import os
+
+    from benchmarks.lib import harness
+
+    return harness._read_json(
+        os.path.join(harness.ROOT, "metrics", f"{name}.json"))
+
+
+def test_device_pick_share_is_the_median_share_of_slots_picked_on_device():
+    """``engine.device_pick_share`` as its metric file reads it, on rounds
+    whose ``ft.engine.sample`` spans carry the pick's counts."""
+    meta = _metric_file("engine.device_pick_share")
+    assert meta["reader"] == "spans:count_ratio_p50"
+
+    def rounds(counts):
+        host = [E("bench_window", 0, 1000)] + [
+            E("ft.engine.sample", 100 * i, 50,
+              {"on_device": on, "rows_fetched": act - on, "active": act})
+            for i, (on, act) in enumerate(counts)
+        ]
+        return S.count_ratio_p50(_ctx(host, None), **meta["args"])
+
+    assert rounds([(32, 32)] * 3) == pytest.approx(100.0)
+    assert rounds([(32, 32), (24, 32), (31, 32)]) == pytest.approx(100 * 31 / 32)
+    assert rounds([(0, 2), (1, 2)]) == pytest.approx(25.0)  # 0 is a count too
+    # a parent commit's spans carry no counts: nothing to read, no error
+    bare = [E("bench_window", 0, 1000), E("ft.engine.sample", 100, 50)]
+    assert S.count_ratio_p50(_ctx(bare, None), **meta["args"]) is None
+
+
+@pytest.mark.filterwarnings("ignore:builtin type:DeprecationWarning")
+def test_device_pick_share_reads_the_engines_own_spans(tmp_path):
+    """The same reader over a real profile of a mixed batch: the counts
+    the engine opens ``ft.engine.sample`` with are the stats it finds."""
+    from flextree_tpu.serving import Request
+
+    eng = _engine()
+    assert eng.submit(_request(1, 5, 4))
+    assert eng.submit(Request(rid=2, prompt=np.arange(6, dtype=np.int32),
+                              max_new_tokens=2, temperature=0.7, seed=1))
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 1
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        eng.run_until_idle()
+    finally:
+        jax.profiler.stop_trace()
+    # no trace in the neutral form: the reader takes the spans from the file
+    run = Run(True, 0, 0, {}, {}, 0.0, str(tmp_path))
+    ctx = ReaderContext(types.SimpleNamespace(name="toy"), run, {}, None,
+                        (0.0, float("inf")))
+    meta = _metric_file("engine.device_pick_share")
+    # rounds of (1 of 2), (1 of 1), (1 of 1) slots picked on the device
+    assert S.count_ratio_p50(ctx, **meta["args"]) == pytest.approx(100.0)
+    shares = sorted(
+        float(s.stats["on_device"]) / float(s.stats["active"])
+        for s in S._named(ctx, "ft.engine.sample"))
+    assert shares == [0.5, 1.0, 1.0]
+
+
 def test_readers_return_none_where_there_is_nothing_to_read():
     host, ops = _round_trace()
     # a rehearsal: host spans, no device plane
@@ -587,21 +677,17 @@ def test_new_metric_files_name_readers_that_exist():
     """Every per-layer entry of BENCHMARK.json that reads spans has its
     metric file, and the file's arguments fit the reader's signature."""
     import inspect
-    import json
-    import os
 
     from benchmarks.lib import harness
 
     bench = harness.load_benchmark()
     seen = 0
     for entry in bench["per_layer"]:
-        path = os.path.join(harness.ROOT, "metrics", f"{entry['name']}.json")
-        with open(path, encoding="utf-8") as f:
-            meta = json.load(f)
+        meta = _metric_file(entry["name"])
         if not meta["reader"].startswith("spans:"):
             continue
         seen += 1
         fn = getattr(S, meta["reader"].split(":")[1])
         inspect.signature(fn).bind(None, **meta.get("args", {}))
         assert entry["source"] in ("program_span", "program_counter", "device_trace")
-    assert seen == 16
+    assert seen == 17
