@@ -73,7 +73,7 @@ def _qkv(layer, h, cfg: TransformerConfig):
     return q, k, v
 
 
-def cached_attention(q, k_cache, v_cache, q_pos):
+def cached_attention(q, k_cache, v_cache, q_pos, window: int | None = None):
     """Attend (B, Tq, H, D) queries over cached positions ``<= q_pos``
     (global query positions, (Tq,) shared or (B, Tq) per-sequence); the
     causal bound alone masks out every not-yet-written cache slot — masked
@@ -81,17 +81,43 @@ def cached_attention(q, k_cache, v_cache, q_pos):
     contributes exactly nothing (the paged cache's gather path leans on
     this).  Math order mirrors ``attention_reference`` exactly (einsum in
     the compute dtype, then f32) so decode logits are teacher-forcing-exact
-    in every dtype."""
+    in every dtype.
+
+    The cache may hold fewer heads than the queries have (grouped
+    queries: query head ``h`` reads K/V head ``h // (H // Hkv)``), and
+    ``window`` bounds the keys from below: a query at ``p`` sees
+    positions ``p - window + 1 .. p``."""
     scale = 1.0 / (q.shape[-1] ** 0.5)
-    s = jnp.einsum("bqhd,bkhd->bhqk", q, k_cache).astype(jnp.float32) * scale
+    hkv = k_cache.shape[2]
+    grouped = hkv != q.shape[2]
+    if grouped:
+        b, tq, h, d = q.shape
+        qg = q.reshape(b, tq, hkv, h // hkv, d)
+        # grouped scores leave the product in f32 (the dense line below
+        # rounds them to the compute dtype first, which its bitwise
+        # contracts with attention_reference pin)
+        s = jnp.einsum(
+            "bqkgd,bskd->bkgqs", qg, k_cache,
+            preferred_element_type=jnp.float32,
+        ).reshape(b, h, tq, k_cache.shape[1]) * scale
+    else:
+        s = jnp.einsum("bqhd,bkhd->bhqk", q, k_cache).astype(jnp.float32) * scale
     kpos = jnp.arange(k_cache.shape[1])
-    if q_pos.ndim == 1:  # shared positions: (Tq, K) mask over all rows
-        mask = (kpos[None, :] <= q_pos[:, None])[None, None]
-    else:  # per-sequence positions: (B, 1, Tq, K)
-        mask = (kpos[None, None, :] <= q_pos[:, :, None])[:, None]
+    qp = q_pos[..., None]  # (Tq, 1) shared, or (B, Tq, 1) per-sequence
+    mask = kpos <= qp
+    if window is not None:
+        mask &= kpos > qp - window
+    # (Tq, K) over all rows, or (B, 1, Tq, K)
+    mask = mask[None, None] if q_pos.ndim == 1 else mask[:, None]
     s = jnp.where(mask, s, -1e30)
     p = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum("bhqk,bkhd->bqhd", p, v_cache.astype(jnp.float32))
+    if grouped:
+        out = jnp.einsum(
+            "bkgqs,bskd->bqkgd", p.reshape(b, hkv, h // hkv, tq, -1),
+            v_cache.astype(jnp.float32),
+        ).reshape(b, tq, h, d)
+    else:
+        out = jnp.einsum("bhqk,bkhd->bqhd", p, v_cache.astype(jnp.float32))
     return out.astype(q.dtype)
 
 
@@ -136,7 +162,13 @@ def _forward_cached(params, tokens, cache, start_pos, cfg: TransformerConfig):
 
 def prefill(params, tokens, cfg: TransformerConfig, max_len: int):
     """Run the prompt through the model once.  Returns
-    ``(last_logits, cache)`` with the cache filled for ``tokens``."""
+    ``(last_logits, cache)`` with the cache filled for ``tokens``.  A
+    configuration of another block than the dense one brings its own walk
+    (``models.laguna.prefill``) behind the same signature."""
+    if not isinstance(cfg, TransformerConfig):
+        from . import laguna
+
+        return laguna.prefill(params, tokens, cfg, max_len)
     b, t = tokens.shape
     if t > max_len:
         raise ValueError(f"prompt length {t} exceeds max_len {max_len}")

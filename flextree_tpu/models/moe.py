@@ -24,6 +24,13 @@ The framework's second model family (next to the dense
 - **Load balancing**: the Switch-style auxiliary loss ``E * sum_e(
   token_frac_e * prob_mass_e)`` (1.0 at perfect balance), returned per
   layer and weighted into the training loss by ``router_aux_weight``.
+- **Dropless share** (the SERVED path; ``models.laguna``):
+  :func:`route_topk_normalized` + :func:`dropless_experts` route over all
+  the experts, sort the picks by expert and run one grouped product
+  (``lax.ragged_dot``) over the experts HELD here.  No capacity, no
+  one-hot over (tokens, experts, slots), no dropped pick whatever the
+  skew; picks of experts held elsewhere keep their weight and add
+  nothing, and on one chip there is no exchange.
 
 Determinism note: routing is greedy argmax with first-come-first-served
 capacity slots (position = running count of earlier same-expert tokens), so
@@ -60,6 +67,8 @@ __all__ = [
     "moe_layer",
     "route_topk",
     "expert_capacity",
+    "route_topk_normalized",
+    "dropless_experts",
 ]
 
 
@@ -302,3 +311,56 @@ def moe_forward(
     logits = final_logits(params["embed"], params["ln_f"], x)
     aux_mean = aux_total / max(n_moe, 1)
     return logits, aux_mean
+
+
+# ----------------------------------------------------- the dropless share
+
+
+def route_topk_normalized(h, router_w, k: int, scale: float = 1.0,
+                          normalize: bool = True):
+    """Scores, choices and weights of a softmax router: ``scores`` (N, E)
+    float32 softmax of ``h @ router_w`` (accumulated in f32), ``choices``
+    (N, k) int32 the ``k`` largest, ``weights`` (N, k) f32 their scores,
+    divided by their sum when ``normalize``, times ``scale``."""
+    logits = jnp.dot(h, router_w, preferred_element_type=jnp.float32)
+    scores = jax.nn.softmax(logits, axis=-1)
+    top, choices = lax.top_k(scores, k)
+    if normalize:
+        top = top / top.sum(axis=-1, keepdims=True)
+    return scores, choices.astype(jnp.int32), top * scale
+
+
+def dropless_experts(h, choices, weights, experts, held, rows=None):
+    """The part of a routed layer's output that the experts held here
+    give.  ``h`` (N, d); ``choices`` (N, k) expert ids over ALL the
+    experts; ``weights`` (N, k) f32; ``experts`` the stacked gated-SiLU
+    weights ``w_gate``/``w_up`` (n_held, d, f) and ``w_down`` (n_held, f,
+    d) of experts ``held = (lo, hi)``; ``rows`` (N,) bool, rows whose
+    picks are dispatched at all (default: every row).
+
+    Picks are sorted by expert (absent experts' picks last), each held
+    expert's rows are multiplied by its own matrices in one grouped
+    product, and the results return to their tokens weighted.  Every
+    local pick is computed, however many land on one expert.  Returns
+    ``(out, sizes)``: (N, d) float32 and the (n_held,) int32 picks each
+    held expert got."""
+    lo, hi = held
+    n_held = hi - lo
+    n, k = choices.shape
+    local = (choices >= lo) & (choices < hi)
+    if rows is not None:
+        local = local & rows[:, None]
+    key = jnp.where(local, choices - lo, n_held).reshape(-1)
+    order = jnp.argsort(key, stable=True)  # picks by expert, absent last
+    sizes = jnp.zeros((n_held + 1,), jnp.int32).at[key].add(1)[:n_held]
+    xs = h[order // k]  # (N*k, d): row j is the token of sorted pick j
+    f32 = jnp.float32
+    gate = lax.ragged_dot(xs, experts["w_gate"], sizes, preferred_element_type=f32)
+    up = lax.ragged_dot(xs, experts["w_up"], sizes, preferred_element_type=f32)
+    act = (jax.nn.silu(gate) * up).astype(h.dtype)
+    ys = lax.ragged_dot(act, experts["w_down"], sizes, preferred_element_type=f32)
+    # back to pick order; rows past the held experts' groups were never
+    # computed, so they are selected away, not multiplied by zero
+    per_pick = ys[jnp.argsort(order)].reshape(n, k, -1)
+    per_pick = jnp.where(local[..., None], per_pick, 0.0)
+    return jnp.einsum("nkd,nk->nd", per_pick, weights.astype(f32)), sizes

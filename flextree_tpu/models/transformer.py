@@ -95,6 +95,11 @@ class TransformerConfig:
     attn_opts: tuple = ()
 
     @property
+    def n_kv_heads(self) -> int:
+        """Heads of the K/V cache: every query head has its own here."""
+        return self.n_heads
+
+    @property
     def head_dim(self) -> int:
         if self.d_model % self.n_heads:
             raise ValueError("d_model must be divisible by n_heads")

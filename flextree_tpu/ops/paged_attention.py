@@ -78,19 +78,21 @@ FUSED_DECODE_ATOL = 2e-5
 def _check_shapes(q, k_new, v_new, k_pool, v_pool, tables, lengths):
     if q.ndim != 3:
         raise ValueError(f"expected (S, H, D) queries, got {q.shape}")
-    if k_new.shape != q.shape or v_new.shape != q.shape:
-        raise ValueError(
-            f"new-token K/V must match q's shape {q.shape}, got "
-            f"{k_new.shape} / {v_new.shape}"
-        )
     if k_pool.ndim != 4 or k_pool.shape != v_pool.shape:
         raise ValueError(
-            f"expected matching (N, bs, H, D) pools, got {k_pool.shape} "
+            f"expected matching (N, bs, Hkv, D) pools, got {k_pool.shape} "
             f"vs {v_pool.shape}"
         )
-    if k_pool.shape[2:] != q.shape[1:]:
+    new = (q.shape[0], *k_pool.shape[2:])
+    if k_new.shape != new or v_new.shape != new:
         raise ValueError(
-            f"pool head/dim {k_pool.shape[2:]} != query {q.shape[1:]}"
+            f"new-token K/V must be shaped {new} like the pool's rows, got "
+            f"{k_new.shape} / {v_new.shape}"
+        )
+    if k_pool.shape[3] != q.shape[2] or q.shape[1] % k_pool.shape[2]:
+        raise ValueError(
+            f"pool head/dim {k_pool.shape[2:]} does not group query "
+            f"{q.shape[1:]}"
         )
     if tables.ndim != 2 or tables.shape[0] != q.shape[0]:
         raise ValueError(f"expected (S, P) tables, got {tables.shape}")
@@ -98,13 +100,15 @@ def _check_shapes(q, k_new, v_new, k_pool, v_pool, tables, lengths):
         raise ValueError(f"expected (S,) lengths, got {lengths.shape}")
 
 
-def paged_attention_gather(q, k_new, v_new, k_pool, v_pool, tables, lengths):
+def paged_attention_gather(q, k_new, v_new, k_pool, v_pool, tables, lengths,
+                           window: int | None = None):
     """The gather-materialize oracle: gather every table block into a
     contiguous ``(S, P·bs, H, D)`` view, splice the new token's K/V at
     each row's ``length``, and attend with the full-row softmax — exactly
     the historical decode-step computation (``cached_attention`` on the
     gathered view), kept as THE correctness reference: this path is the
-    one proven bitwise against the contiguous-cache ``generate``."""
+    one proven bitwise against the contiguous-cache ``generate``.
+    ``window`` is ``cached_attention``'s mask over the same whole view."""
     from ..models.generate import cached_attention
 
     _check_shapes(q, k_new, v_new, k_pool, v_pool, tables, lengths)
@@ -117,18 +121,64 @@ def paged_attention_gather(q, k_new, v_new, k_pool, v_pool, tables, lengths):
     vc = upd(v_pool[tables].reshape(s, -1, *v_pool.shape[2:]),
              v_new[:, None], lengths)
     positions = lengths[:, None].astype(jnp.int32)
-    return cached_attention(q[:, None], kc, vc, positions)[:, 0]
+    return cached_attention(q[:, None], kc, vc, positions, window=window)[:, 0]
 
 
 # ------------------------------------------------------------ jnp streaming
 
 
 def _stream_jnp(q, k_new, v_new, k_pool, v_pool, tables, lengths, scale,
-                block_chunk):
+                block_chunk, window=None):
     s, h, d = q.shape
-    bs = k_pool.shape[1]
+    bs, hkv = k_pool.shape[1], k_pool.shape[2]
     p = tables.shape[1]
     cb = max(1, min(int(block_chunk), p))
+    if hkv == h:
+        def scores(kb):  # (S, B, H, D) keys -> (S, H, B)
+            return jnp.einsum("shd,sbhd->shb", q, kb)
+
+        def mix(pr, vb):  # (S, H, B) weights over (S, B, H, D) values
+            return jnp.einsum("shb,sbhd->shd", pr, vb.astype(jnp.float32))
+
+        k_mine, v_mine = k_new, v_new
+    else:
+        # grouped queries: query head j reads K/V head j // (H // Hkv)
+        g = h // hkv
+        qg = q.reshape(s, hkv, g, d)
+
+        def scores(kb):  # f32 out of the product, as cached_attention's
+            return jnp.einsum(
+                "skgd,sbkd->skgb", qg, kb,
+                preferred_element_type=jnp.float32,
+            ).reshape(s, h, -1)
+
+        def mix(pr, vb):
+            return jnp.einsum(
+                "skgb,sbkd->skgd", pr.reshape(s, hkv, g, -1),
+                vb.astype(jnp.float32),
+            ).reshape(s, h, d)
+
+        k_mine = jnp.repeat(k_new, g, axis=1)
+        v_mine = jnp.repeat(v_new, g, axis=1)
+    if window is None:
+        pos0 = None  # every row's walk starts at position 0
+        reach = lengths
+    else:
+        # a window layer walks only the table columns that meet its
+        # window: a per-row slice of the table from the block that holds
+        # position length - window + 1, wide enough for any alignment
+        first = jnp.maximum(lengths - (window - 1), 0) // bs  # (S,)
+        p = min(p, (window - 1 + bs - 1) // bs + 1)
+        cols = first[:, None] + jnp.arange(p)[None, :]
+        tables = jnp.where(
+            cols < tables.shape[1],
+            jnp.take_along_axis(
+                tables, jnp.minimum(cols, tables.shape[1] - 1), axis=1
+            ),
+            0,
+        )
+        pos0 = (first * bs)[:, None]  # (S, 1)
+        reach = lengths - first * bs
     # pad the table width to a chunk multiple with null blocks: the pad
     # columns gather block 0, whose positions sit past every row's causal
     # bound and mask to exactly zero weight
@@ -138,7 +188,7 @@ def _stream_jnp(q, k_new, v_new, k_pool, v_pool, tables, lengths, scale,
     # runtime frontier: blocks holding positions < max(lengths); the loop
     # never touches table columns past it (the gather oracle always pays
     # for all P — this bound is the streamed path's algorithmic win)
-    frontier = (jnp.max(lengths) + bs - 1) // bs
+    frontier = (jnp.max(reach) + bs - 1) // bs
     n_steps = (frontier + cb - 1) // cb
 
     lengths_b = lengths[:, None]  # (S, 1)
@@ -149,12 +199,16 @@ def _stream_jnp(q, k_new, v_new, k_pool, v_pool, tables, lengths, scale,
     def body(i, carry):
         m, l, acc = carry
         tb = lax.dynamic_slice_in_dim(tables, i * cb, cb, axis=1)  # (S, cb)
-        kb = k_pool[tb].reshape(s, cb * bs, h, d)
-        vb = v_pool[tb].reshape(s, cb * bs, h, d)
+        kb = k_pool[tb].reshape(s, cb * bs, hkv, d)
+        vb = v_pool[tb].reshape(s, cb * bs, hkv, d)
         # einsum in the compute dtype then f32, mirroring cached_attention
-        sc = jnp.einsum("shd,sbhd->shb", q, kb).astype(jnp.float32) * scale
+        sc = scores(kb).astype(jnp.float32) * scale
         kpos = i * cb * bs + jnp.arange(cb * bs)
-        valid = kpos[None, :] < lengths_b  # (S, cb*bs)
+        if pos0 is None:
+            valid = kpos[None, :] < lengths_b  # (S, cb*bs)
+        else:
+            kpos = pos0 + kpos[None, :]
+            valid = (kpos < lengths_b) & (kpos > lengths_b - window)
         sc = jnp.where(valid[:, None, :], sc, _NEG_INF)
         m_new = jnp.maximum(m, sc.max(axis=-1))
         pr = jnp.exp(sc - m_new[..., None])
@@ -163,20 +217,21 @@ def _stream_jnp(q, k_new, v_new, k_pool, v_pool, tables, lengths, scale,
         pr = jnp.where(valid[:, None, :], pr, 0.0)
         corr = jnp.exp(m - m_new)
         l = l * corr + pr.sum(axis=-1)
-        acc = acc * corr[..., None] + jnp.einsum(
-            "shb,sbhd->shd", pr, vb.astype(jnp.float32)
-        )
+        acc = acc * corr[..., None] + mix(pr, vb)
         return m_new, l, acc
 
     m, l, acc = lax.fori_loop(0, n_steps, body, (m0, l0, acc0))
 
     # the new token's K/V — position `length`, always visible to itself
-    s_new = jnp.einsum("shd,shd->sh", q, k_new).astype(jnp.float32) * scale
+    s_new = jnp.einsum(
+        "shd,shd->sh", q, k_mine,
+        preferred_element_type=None if hkv == h else jnp.float32,
+    ).astype(jnp.float32) * scale
     m_fin = jnp.maximum(m, s_new)
     p_new = jnp.exp(s_new - m_fin)
     corr = jnp.exp(m - m_fin)
     l = l * corr + p_new
-    acc = acc * corr[..., None] + p_new[..., None] * v_new.astype(jnp.float32)
+    acc = acc * corr[..., None] + p_new[..., None] * v_mine.astype(jnp.float32)
     return (acc / l[..., None]).astype(q.dtype)
 
 
@@ -286,12 +341,18 @@ def paged_attention(
     impl: str = "jnp",
     interpret: bool | None = None,
     block_chunk: int = 1,
+    window: int | None = None,
 ):
     """Fused paged decode attention for one token per slot.
 
-    ``q`` / ``k_new`` / ``v_new``: (S, H, D) — the decode step's query and
-    the new token's K/V, already RoPE'd at each row's position.
-    ``k_pool`` / ``v_pool``: (N, bs, H, D) per-layer pools; ``tables``:
+    ``q``: (S, H, D) — the decode step's query; ``k_new`` / ``v_new``:
+    (S, Hkv, D) the new token's K/V, all already RoPE'd at each row's
+    position.  ``H`` is a multiple of ``Hkv`` (grouped queries: query
+    head ``j`` reads K/V head ``j // (H // Hkv)``; the dense model has
+    ``Hkv == H``).  ``window``: each row sees only positions ``length -
+    window + 1 .. length``, and walks only the table columns that hold
+    them (``impl="jnp"``).
+    ``k_pool`` / ``v_pool``: (N, bs, Hkv, D) per-layer pools; ``tables``:
     (S, P) int32 block ids; ``lengths``: (S,) int32 cache positions
     already written per row, each ``< P*bs`` (a row AT the table's
     capacity has no position left to decode into — the serving layer
@@ -314,6 +375,11 @@ def paged_attention(
     lengths = jnp.asarray(lengths, jnp.int32)
     if impl == "jnp":
         return _stream_jnp(q, k_new, v_new, k_pool, v_pool, tables, lengths,
-                           float(scale), block_chunk)
+                           float(scale), block_chunk, window)
+    if window is not None or k_pool.shape[2] != q.shape[1]:
+        raise NotImplementedError(
+            "paged attention impl='pallas' has neither grouped queries nor "
+            "a window; use impl='jnp'"
+        )
     return _stream_pallas(q, k_new, v_new, k_pool, v_pool, tables, lengths,
                           float(scale), pallas_interpret(interpret))

@@ -15,6 +15,12 @@ admission modes stay drivable::
     python -m flextree_tpu.serving --admission ondemand --preempt swap \\
         --blocks 33 --requests 24
 
+    # a model from its configuration file (model_type + published keys):
+    # how a model other than the default dense block is chosen
+    python -m flextree_tpu.serving --config benchmarks/configs/laguna-s-2.1.json \\
+        --slots 64 --block-size 16 --blocks-per-seq 96 --blocks 6145 \\
+        --requests 64 --prompt-len 512 --max-new 64
+
     # the flagship width on the chip JAX finds (no --cpu: landing on the
     # CPU unasked is an error)
     python -m flextree_tpu.serving --d-model 2048 --n-heads 16 --n-layers 4 \\
@@ -45,6 +51,13 @@ def parse_args(argv=None) -> argparse.Namespace:
     ap.add_argument("--max-new", type=int, default=24)
     ap.add_argument("--prompt-len", type=int, default=12,
                     help="prompts are uniform over [4, prompt-len]")
+    ap.add_argument(
+        "--config", type=str, default=None,
+        help="a model configuration file (JSON: a model_type and its "
+        "published keys, as under benchmarks/configs/); the model, its "
+        "sizes and its dtypes come from it, and --vocab, --d-model, "
+        "--n-heads, --n-layers, --d-ff and --dtype are not read",
+    )
     ap.add_argument("--vocab", type=int, default=256)
     ap.add_argument("--d-model", type=int, default=128)
     ap.add_argument("--n-heads", type=int, default=8)
@@ -110,29 +123,33 @@ def serve(args: argparse.Namespace):
     from ..models.transformer import TransformerConfig, init_params
     from . import BatcherConfig, PagedCacheConfig, Request, ServingEngine
 
-    cfg = TransformerConfig(
-        vocab_size=args.vocab, d_model=args.d_model, n_heads=args.n_heads,
-        n_layers=args.n_layers, d_ff=args.d_ff,
-        dtype=getattr(jnp, args.dtype),
-    )
-    params = init_params(jax.random.PRNGKey(args.seed), cfg)
     pcfg = PagedCacheConfig(
         num_blocks=args.blocks, block_size=args.block_size,
         blocks_per_seq=args.blocks_per_seq,
     )
-    eng = ServingEngine(
-        params, cfg, pcfg,
-        BatcherConfig(slots=args.slots, admission=args.admission,
-                      preempt=args.preempt),
-        fused=args.fused_decode,
-        decode_impl=args.decode_impl,
-    )
+    bcfg = BatcherConfig(slots=args.slots, admission=args.admission,
+                         preempt=args.preempt)
+    how = {"fused": args.fused_decode, "decode_impl": args.decode_impl}
+    if args.config is not None:
+        with open(args.config, encoding="utf-8") as f:
+            eng = ServingEngine.from_config(
+                json.load(f), pcfg, bcfg, seed=args.seed, **how
+            )
+    else:
+        cfg = TransformerConfig(
+            vocab_size=args.vocab, d_model=args.d_model, n_heads=args.n_heads,
+            n_layers=args.n_layers, d_ff=args.d_ff,
+            dtype=getattr(jnp, args.dtype),
+        )
+        params = init_params(jax.random.PRNGKey(args.seed), cfg)
+        eng = ServingEngine(params, cfg, pcfg, bcfg, **how)
+    vocab = eng.cfg.vocab_size
     rng = np.random.default_rng(args.seed)
     reqs = [
         Request(
             rid=i,
             prompt=rng.integers(
-                0, args.vocab, (int(rng.integers(4, args.prompt_len + 1)),)
+                0, vocab, (int(rng.integers(4, args.prompt_len + 1)),)
             ).astype(np.int32),
             max_new_tokens=args.max_new,
         )

@@ -37,7 +37,11 @@ __all__ = [
 
 def _dense_flops_per_token(cfg) -> float:
     """Dense (projection + MLP + LM head) multiply-accumulate FLOPs to
-    decode one token: 2·weights touched."""
+    decode one token: 2·weights touched.  A configuration that knows
+    its own count (per-layer heads, routed experts) says so."""
+    active = getattr(cfg, "active_matmul_params", None)
+    if active is not None:
+        return 2.0 * active
     d, ff = cfg.d_model, cfg.d_ff
     per_layer = 4 * d * d + 2 * d * ff  # qkvo + in/out MLP
     return 2.0 * (cfg.n_layers * per_layer + d * cfg.vocab_size)
@@ -60,7 +64,7 @@ def decode_round_bytes(cfg, pcfg, n_active: int, frontier_blocks: int) -> float:
         itemsize = np.dtype(cfg.dtype).itemsize
     except TypeError:
         itemsize = 4
-    per_pos = 2 * cfg.n_heads * cfg.head_dim * itemsize * cfg.n_layers
+    per_pos = 2 * cfg.n_kv_heads * cfg.head_dim * itemsize * cfg.n_layers
     return float(n_active * frontier_blocks * pcfg.block_size * per_pos)
 
 
@@ -127,7 +131,7 @@ def kv_migration_elems(cfg, pcfg, prompt_len: int) -> int:
     ships the tail block too) × block positions × heads × head_dim.  One
     sequence ships ``2 * n_layers`` such tensors."""
     n_blocks = pcfg.blocks_for(max(int(prompt_len), 1))
-    return n_blocks * pcfg.block_size * cfg.n_heads * cfg.head_dim
+    return n_blocks * pcfg.block_size * cfg.n_kv_heads * cfg.head_dim
 
 
 def predict_migration_us(cfg, pcfg, prompt_len: int, codec="f32",

@@ -54,7 +54,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..models.configs import config_from_dict, init_model_params
 from ..models.generate import prefill, prefill_suffix, sample_token
+from ..models.laguna import MOE_COUNTS
 from ..models.transformer import TransformerConfig
 from ..obs import MetricsRegistry, current_recorder, record_event, span
 from .batcher import BatcherConfig, ContinuousBatcher, Request, SeqState
@@ -176,6 +178,15 @@ class ServingEngine:
             "serve.ttft_ms", interval_s=self.slo_window_s / 10.0, intervals=10
         )
         self.batcher = ContinuousBatcher(pcfg, self.bcfg)
+        if self.batcher.prefix_index is not None and not isinstance(
+            cfg, TransformerConfig
+        ):
+            # a hit runs models.generate.prefill_suffix, the dense block's
+            raise NotImplementedError(
+                f"the prefix cache is not implemented for "
+                f"{type(cfg).__name__}: build the engine with "
+                f"BatcherConfig(prefix_cache=False)"
+            )
         self.pools = init_pools(cfg, pcfg)
         # donation keeps steady-state decode allocation-free: the pool
         # scatter aliases in place instead of copying the whole pool every
@@ -242,6 +253,19 @@ class ServingEngine:
         if self.batcher.prefix_index is not None:
             self.batcher.prefix_index.on_evict = self._on_prefix_evict
 
+    @classmethod
+    def from_config(cls, config: dict, pcfg: PagedCacheConfig,
+                    bcfg: BatcherConfig | None = None, *, seed: int = 0,
+                    **kwargs) -> "ServingEngine":
+        """The engine for a model given as a configuration: a
+        ``model_type`` and its published keys (``models.configs``), with
+        parameters made on the device from ``seed``.  How a model is
+        chosen; every other argument is the constructor's."""
+        cfg = config_from_dict(config)
+        params = init_model_params(jax.random.PRNGKey(seed), cfg)
+        jax.block_until_ready(params)
+        return cls(params, cfg, pcfg, bcfg, **kwargs)
+
     # ---- intake ------------------------------------------------------------
 
     def submit(self, request: Request) -> bool:
@@ -272,6 +296,7 @@ class ServingEngine:
         newest resident sequence (swap-out or recompute per
         ``BatcherConfig.preempt``) until the rest fit."""
         t0 = _now()
+        moe_ids: dict = {}  # what the round's routers counted, if any
         with span("ft.engine.round", round=self.steps):
             with span("ft.engine.resume"):
                 resumed = self.batcher.try_resume(t0)
@@ -305,10 +330,13 @@ class ServingEngine:
                     tables, lengths, tokens, _ = self.batcher.batch_arrays()
                 t_dec = _now()
                 with span("ft.engine.decode_dispatch"):
-                    logits, self.pools = self._decode(
+                    # a model with routed experts hands out a third
+                    # result, what its routers did this round
+                    logits, self.pools, *routed = self._decode(
                         self.params, self.pools, tables, lengths, tokens
                     )
                     ids = self._greedy_ids(logits)
+                    counts = routed[0]["counts"] if routed else None
                 # counted while the device decodes
                 sampled = sum(
                     self.batcher.slots[slot].request.temperature > 0
@@ -316,8 +344,16 @@ class ServingEngine:
                 )
                 with span("ft.engine.decode_fetch"):
                     # host fetch = the step boundary; the logits stay on
-                    # the device and are dropped with the round
-                    ids = np.asarray(ids)
+                    # the device and are dropped with the round.  The
+                    # routers' counts come back beside the ids, in the
+                    # same fetch
+                    if counts is None:
+                        ids = np.asarray(ids)
+                    else:
+                        ids, counts = jax.device_get((ids, counts))
+                        moe_ids = {
+                            k: int(v) for k, v in zip(MOE_COUNTS, counts)
+                        }
                 decode_s = _now() - t_dec
                 now = _now()
                 with span(
@@ -346,10 +382,15 @@ class ServingEngine:
                 "ft.engine.bookkeeping", round=self.steps,
                 decoded=len(active), admitted=len(admitted),
                 finished=len(finished), blocks_in_use=total - free,
-                blocks_total=total,
+                blocks_total=total, **moe_ids,
             ):
                 self.steps += 1
                 m = self.metrics
+                if moe_ids:
+                    m.counter("serve.moe_picks").inc(moe_ids["picks"])
+                    m.counter("serve.moe_local_picks").inc(
+                        moe_ids["local_picks"]
+                    )
                 m.counter("serve.rounds").inc()
                 m.counter("serve.admitted").inc(len(admitted))
                 m.counter("serve.finished").inc(len(finished))
@@ -655,7 +696,7 @@ class ServingEngine:
         kv = unpack_kv(meta, blob)  # CRC + per-tensor verification
         if (
             int(meta["block_size"]) != self.pcfg.block_size
-            or int(meta["n_heads"]) != self.cfg.n_heads
+            or int(meta["n_heads"]) != self.cfg.n_kv_heads
             or int(meta["head_dim"]) != self.cfg.head_dim
             or int(meta["n_layers"]) != self.cfg.n_layers
         ):
@@ -1038,7 +1079,7 @@ class ServingEngine:
             # or the compile lands inside the TTFT / preemption stall it
             # was supposed to end
             bs = self.pcfg.block_size
-            shape = (self.cfg.n_heads, self.cfg.head_dim)
+            shape = (self.cfg.n_kv_heads, self.cfg.head_dim)
             if cache is None:
                 _, cache = self._prefill(
                     self.params, np.zeros((1, 1), np.int32)
@@ -1066,7 +1107,7 @@ class ServingEngine:
         # count — an unwarmed one stalls the decode replica's engine
         # loop mid-handoff, landing inside the very inter-token p99 the
         # disaggregation exists to protect
-        shape = (self.pcfg.block_size, self.cfg.n_heads, self.cfg.head_dim)
+        shape = (self.pcfg.block_size, self.cfg.n_kv_heads, self.cfg.head_dim)
         for n in sorted(set(int(n) for n in import_counts)):
             zeros = [
                 jnp.zeros((n, *shape), self.cfg.dtype)

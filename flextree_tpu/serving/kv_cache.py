@@ -251,11 +251,12 @@ class BlockAllocator:
         return self.alloc(1)[0]
 
 
-def init_pools(cfg: TransformerConfig, pcfg: PagedCacheConfig) -> dict:
-    """Per-layer (num_blocks, block_size, H, Dh) K/V pools, zeros in the
+def init_pools(cfg, pcfg: PagedCacheConfig) -> dict:
+    """Per-layer (num_blocks, block_size, Hkv, Dh) K/V pools, zeros in the
     compute dtype — mirrors ``init_kv_cache``'s structure with the batch
-    and length axes folded into (block, offset)."""
-    shape = (pcfg.num_blocks, pcfg.block_size, cfg.n_heads, cfg.head_dim)
+    and length axes folded into (block, offset).  ``Hkv`` is the
+    configuration's ``n_kv_heads`` (the dense model's ``n_heads``)."""
+    shape = (pcfg.num_blocks, pcfg.block_size, cfg.n_kv_heads, cfg.head_dim)
     return {
         "k": [jnp.zeros(shape, cfg.dtype) for _ in range(cfg.n_layers)],
         "v": [jnp.zeros(shape, cfg.dtype) for _ in range(cfg.n_layers)],
@@ -373,7 +374,17 @@ def paged_decode_step(params, pools, tables, lengths, tokens,
     argument.  ``fused=True`` attends through ``ops.paged_attention``
     (``impl=`` "jnp" block-streaming or "pallas"): same masking, online-
     softmax summation order, within ``FUSED_DECODE_ATOL`` of the oracle.
+
+    A configuration of another block than the dense one brings its own
+    walk behind these arguments (``models.laguna.paged_decode_step``),
+    which hands out a third result, what its routers did.
     """
+    if not isinstance(cfg, TransformerConfig):
+        from ..models import laguna
+
+        return laguna.paged_decode_step(
+            params, pools, tables, lengths, tokens, cfg, fused, impl
+        )
     s = tokens.shape[0]
     positions = lengths[:, None].astype(jnp.int32)  # (S, 1) per-sequence
     bs = pools["k"][0].shape[1]

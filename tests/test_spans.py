@@ -690,4 +690,4 @@ def test_new_metric_files_name_readers_that_exist():
         fn = getattr(S, meta["reader"].split(":")[1])
         inspect.signature(fn).bind(None, **meta.get("args", {}))
         assert entry["source"] in ("program_span", "program_counter", "device_trace")
-    assert seen == 17
+    assert seen == 23
