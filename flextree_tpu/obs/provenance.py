@@ -45,6 +45,7 @@ def bucket_provenance(
     chunks: int = 1,
     sharded: bool = False,
     fired: bool = False,
+    packed: bool | None = None,
 ) -> dict | None:
     """The plan-provenance payload for one bucket's comm event, or None
     when no recorder is installed (zero trace-time cost while telemetry
@@ -56,7 +57,8 @@ def bucket_provenance(
     per scheduled axis with the default calibrated params and summed —
     the same model the planner chose the bucket size with, so the
     residual read off a timeline is against the plan as priced, not a
-    re-derivation."""
+    re-derivation.  ``packed``: whether the bucket's leaves share one flat
+    buffer (False for a leaf that goes alone, in its own shape)."""
     if current_recorder() is None:
         return None
     axes = tuple(axes)
@@ -86,6 +88,8 @@ def bucket_provenance(
         prov["n_leaves"] = int(n_leaves)
     if dtype is not None:
         prov["dtype"] = str(dtype)
+    if packed is not None:
+        prov["packed"] = bool(packed)
     try:
         from ..planner.calibrate import default_params
         from ..planner.cost_model import allreduce_cost, lonely_allreduce_cost

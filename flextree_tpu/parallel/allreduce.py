@@ -26,6 +26,9 @@ Mapping from the reference:
     -> ``all_gather(axis_index_groups=topo.groups(i), tiled=True)``;
 - ``ring_allreduce`` (``mpi_mod.hpp:1113-1163``) -> ``ppermute`` ring with
   the same decrementing block walk;
+- an N-D array whose leading dimension divides by the axis size runs the
+  tree stages in its own shape (``_tree_keeps_shape``): on the TPU a
+  flatten is a copy through HBM, and the stages tile dimension 0 anyway;
 - non-divisible counts: the reference clamps trailing blocks
   (``mpi_mod.hpp:679-696``); XLA wants uniform shards, so the first
   ``(count//N)*N`` elements run through the scheduled collective unpadded
@@ -147,6 +150,30 @@ def _split_main_tail(x: jax.Array, n: int):
     return v[:main], v[main:]
 
 
+def _tree_keeps_shape(x: jax.Array, n: int, chunks: int = 1) -> bool:
+    """Whether the tree stages run on ``x`` as it is, with no flat view.
+
+    Every tree stage is elementwise across ranks and tiles dimension 0
+    (``psum_scatter(scatter_dimension=0, tiled=True)``, ``all_gather(axis=0,
+    tiled=True)``).  Tiling dimension 0 of a row-major N-D array whose
+    leading dimension divides by the axis size (the product of the stage
+    widths) cuts it into the same contiguous pieces as tiling its flattened
+    view, so the stages move the same elements between the same ranks and
+    every element keeps its reduction association: the result is bitwise
+    the flat path's, the tail is empty, and neither ``reshape(-1)`` nor
+    ``reshape(shape)`` is traced.  On a CPU those reshapes are free; on the
+    TPU a 2-D f32 array and its 1-D view are tiled differently and each is
+    a copy through HBM.  A 1-D array is its own flat view; the
+    chunk-pipelined mode slices the flat view and keeps it.
+    """
+    return (
+        x.ndim >= 2
+        and x.size > 0
+        and x.shape[0] % n == 0
+        and len(_chunk_sizes(x.size, n, chunks)) == 1
+    )
+
+
 def _small_dense_allreduce(t, axis_name, rop: ReduceOp):
     """Allreduce for a sub-N-element tail: one dense collective."""
     if rop.name == "sum":
@@ -225,7 +252,10 @@ def tree_allreduce(
 ) -> jax.Array:
     """Hierarchical allreduce with per-stage widths ``topo.widths``.
 
-    Non-divisible element counts run as an unpadded scheduled collective on
+    An N-D array whose leading dimension divides by N runs the stages in
+    its own shape (:func:`_tree_keeps_shape`: bitwise the flat path, with no
+    flatten in or out).  Everything else goes through its flat view:
+    non-divisible element counts run as an unpadded scheduled collective on
     the divisible head plus one tiny dense collective on the <N-element
     tail (``_split_main_tail``) — no full-buffer pad/slice copies.
 
@@ -247,6 +277,9 @@ def tree_allreduce(
     topo = Topology.resolve(n, topo)
     if isinstance(topo, LonelyTopology):
         return lonely_allreduce(x, axis_name, topo, op=rop)
+    if _tree_keeps_shape(x, n, chunks):
+        h = _tree_reduce_scatter(x, axis_name, topo, rop)
+        return _tree_allgather(h, axis_name, topo)
     shape = x.shape
     head, tail = _split_main_tail(x, n)
     parts = []

@@ -1,16 +1,32 @@
 """Gradient bucketing/fusion for the FlexTree gradient sync.
 
 The reference's whole value proposition is amortizing per-message latency
-across the fabric (``cost_model/CostModel.h``), yet a transformer gradient
-tree hands the sync dozens of tiny bias/layernorm leaves — and every leaf
+across the fabric (``cost_model/CostModel.h``), and a transformer gradient
+tree hands the sync a tail of tiny bias/layernorm leaves — every leaf
 synced alone pays the full per-stage launch+latency term (measured ~3.6 ms
 per extra dispatch on the bench host, WINS.md).  The standard fix is
-message fusion: pack leaves into a few flat buckets and run ONE scheduled
+message fusion: pack leaves into a flat bucket and run ONE scheduled
 collective per bucket — k small leaves pay ``k * (launch + latency)``
-per-leaf, one fused bucket pays it once.  The α-β decomposition behind the
-bucket-size choice is the time-cost model of arXiv:2409.04202; the size
-itself comes from the calibrated planner (``planner.choose_bucket_bytes``),
-not a magic constant.
+per-leaf, one fused bucket pays it once.
+
+Fusion is not free, and on the TPU it is dear: a bucket is a flat copy of
+its leaves, and there a flatten is a copy.  A 2-D f32 array lives in
+(8, 128) tiles and its 1-D view in another tiling, so ``reshape(-1)``,
+``concatenate`` and the slices and reshapes that take a bucket apart again
+are each a pass through HBM (PERF.md §6, PR 29: 29 ms of a 305 ms step in
+the four-chip cell, and the optimizer reading its gradients out of slices
+of a flat buffer besides).  So packing pays only for leaves under the size
+at which it still lowers the cost the planner prices
+(``planner.choose_in_place_bytes``: where a leaf's own byte time reaches
+twice a collective's fixed cost — 566 KB on the default ICI constants).  A
+leaf of that size or more is a bucket of its own: it is handed to
+``allreduce`` as it is, which keeps it in its own shape wherever its
+leading dimension divides by the axis size
+(``allreduce._tree_keeps_shape``).  In a model of this repo's widths that is
+every matrix; what is packed is the norm scales.  The α-β decomposition
+behind both sizes is the time-cost model of arXiv:2409.04202; they come
+from the calibrated planner (``planner.choose_bucket_bytes`` for the packed
+buckets' cap), not from magic constants.
 
 Grouping: leaves fuse only when they agree on **(replication-axis-set,
 dtype)** — the axis set because each bucket runs exactly one allreduce
@@ -62,6 +78,7 @@ __all__ = [
     "replication_key",
     "Bucket",
     "plan_buckets",
+    "plan_counts",
     "bucketed_sync_grads",
     "DEFAULT_MAX_BUCKET_BYTES",
     "CPU_MAX_BUCKET_BYTES",
@@ -69,7 +86,9 @@ __all__ = [
 
 #: Memory cap on a fused flat buffer when the planner-derived size is used —
 #: a bucket materializes one packed copy of its leaves, so an unbounded
-#: bucket would double peak gradient memory for the largest group.
+#: bucket would double peak gradient memory for the largest group.  It
+#: bounds the PACKED buckets only: under the derived plan a leaf large
+#: enough to matter here goes alone and is never copied.
 DEFAULT_MAX_BUCKET_BYTES = 64 << 20
 
 #: Planner-derived cap on CPU backends.  The alpha-beta chooser only prices
@@ -78,8 +97,7 @@ DEFAULT_MAX_BUCKET_BYTES = 64 << 20
 #: the train step, while 64-128 KiB buckets beat per-leaf by ~15%
 #: (BENCH_BUCKETING.json): in-step, the fused pack -> collective -> unpack
 #: -> AdamW chain must stay cache-hot, a locality term the dispatch model
-#: cannot see.  Real accelerators stream collectives from HBM, so the big
-#: DEFAULT_MAX_BUCKET_BYTES stays their cap.
+#: cannot see.
 CPU_MAX_BUCKET_BYTES = 128 << 10
 
 
@@ -124,6 +142,12 @@ class Bucket:
     indices: tuple[int, ...]
     nbytes: int
 
+    @property
+    def packed(self) -> bool:
+        """False for a leaf that goes alone: it is handed to ``allreduce``
+        in its own shape and no flat buffer is built around it."""
+        return len(self.indices) > 1
+
 
 def plan_buckets(
     leaves: Sequence[Any],
@@ -137,20 +161,32 @@ def plan_buckets(
     codec=None,
     sharded: bool = False,
 ) -> tuple[Bucket, ...]:
-    """Partition flattened gradient leaves into fused sync buckets.
+    """Partition flattened gradient leaves into sync buckets.
 
     ``leaves`` only need ``.size``/``.dtype`` (abstract values work, so HLO
     tests can plan without materializing).  Leaves group by
-    ``(replication_key, dtype)`` preserving flat order; within a group,
-    consecutive leaves pack greedily until the bucket reaches
-    ``bucket_bytes``.  ``bucket_bytes=None`` derives the size per group from
-    the calibrated cost model (``planner.choose_bucket_bytes`` on the
-    group's own topologies and total bytes, capped at ``max_bucket_bytes``
-    — backend-resolved when None: in-step cache locality caps CPU hosts at
-    ``CPU_MAX_BUCKET_BYTES``, see the constants above); an explicit value
-    is used as-is.  Groups with an empty axis set (leaves sharded over
-    every mesh axis) are skipped — they need no sync.
+    ``(replication_key, dtype)`` preserving flat order.  Groups with an
+    empty axis set (leaves sharded over every mesh axis) are skipped — they
+    need no sync.
+
+    ``bucket_bytes=None`` derives the plan per group from the calibrated
+    cost model.  A leaf of at least ``planner.choose_in_place_bytes`` (the
+    size at which packing stops lowering the predicted cost, from the
+    group's own topologies) is a bucket of its own and keeps its shape;
+    the leaves under it pack greedily, in flat order and across the large
+    leaves lying between them (the sums are elementwise, so which small
+    leaves share a buffer changes no value), up to
+    ``planner.choose_bucket_bytes`` of their own total, capped at
+    ``max_bucket_bytes`` — backend-resolved when None: in-step cache
+    locality caps CPU hosts at ``CPU_MAX_BUCKET_BYTES``, see the constants
+    above.  The sharded (ZeRO) and lossy-codec plans own their bucket's
+    layout and keep packing every leaf.
+
+    An explicit ``bucket_bytes`` is a plain cap: consecutive leaves of a
+    group pack greedily until the bucket reaches it.
     """
+    from ..planner.choose import choose_in_place_bytes
+
     if max_bucket_bytes is None:
         max_bucket_bytes = _default_max_bucket_bytes()
     groups: dict[tuple[tuple[str, ...], str], list[int]] = {}
@@ -165,29 +201,70 @@ def plan_buckets(
     buckets: list[Bucket] = []
     for (axes, dtype), idxs in groups.items():
         itemsize = jnp.dtype(dtype).itemsize
-        sizes = [leaves[i].size * itemsize for i in idxs]
+        sizes = {i: leaves[i].size * itemsize for i in idxs}
+        group: list[Bucket] = []
         cap = bucket_bytes
         if cap is None:
+            cost_topos = _cost_topos(axes, topos or {}, axis_sizes or {})
+            if cost_topos and codec is None and not sharded:
+                alone = choose_in_place_bytes(cost_topos, params=params)
+                group += [
+                    Bucket(axes, dtype, (i,), sizes[i])
+                    for i in idxs if sizes[i] >= alone
+                ]
+                idxs = [i for i in idxs if sizes[i] < alone]
             cap = _derived_bucket_bytes(
-                sum(sizes), len(idxs), axes, topos or {}, axis_sizes or {},
-                params, max_bucket_bytes, codec, sharded=sharded,
+                sum(sizes[i] for i in idxs), len(idxs), cost_topos, params,
+                max_bucket_bytes, codec, sharded=sharded,
             )
         cap = max(int(cap), 1)
         cur: list[int] = []
         cur_bytes = 0
-        for i, nb in zip(idxs, sizes):
-            if cur and cur_bytes + nb > cap:
-                buckets.append(Bucket(axes, dtype, tuple(cur), cur_bytes))
+        for i in idxs:
+            if cur and cur_bytes + sizes[i] > cap:
+                group.append(Bucket(axes, dtype, tuple(cur), cur_bytes))
                 cur, cur_bytes = [], 0
             cur.append(i)
-            cur_bytes += nb
+            cur_bytes += sizes[i]
         if cur:
-            buckets.append(Bucket(axes, dtype, tuple(cur), cur_bytes))
+            group.append(Bucket(axes, dtype, tuple(cur), cur_bytes))
+        # a group's buckets in the flat-tree order of their first leaves
+        buckets += sorted(group, key=lambda b: b.indices[0])
     return tuple(buckets)
 
 
+def plan_counts(buckets: Sequence[Bucket]) -> dict[str, int]:
+    """How often the plan keeps a leaf in its own shape: leaves and bytes
+    that go alone (``in_place_*``) against those packed into a shared flat
+    buffer (``packed_*``)."""
+    alone = [b for b in buckets if not b.packed]
+    packed = [b for b in buckets if b.packed]
+    return {
+        "in_place_leaves": len(alone),
+        "packed_leaves": sum(len(b.indices) for b in packed),
+        "in_place_bytes": sum(b.nbytes for b in alone),
+        "packed_bytes": sum(b.nbytes for b in packed),
+    }
+
+
+def _cost_topos(axes, topos, axis_sizes) -> list:
+    """The resolved topologies the planner prices a group's sync with, one
+    per replication axis; the ``"psum"`` sentinel (None) is priced as the
+    flat tree of its axis, and an axis of unknown size is left out."""
+    cost_topos = []
+    for ax in axes:
+        n = int(axis_sizes.get(ax, 0)) or None
+        topo = topos.get(ax)
+        if topo is None:  # the "psum" sentinel: one fused native collective
+            if n is None:
+                continue
+            topo = Topology.flat(n)
+        cost_topos.append(Topology.resolve(n or topo.num_nodes, topo))
+    return cost_topos
+
+
 def _derived_bucket_bytes(
-    total_bytes, n_leaves, axes, topos, axis_sizes, params, max_bucket_bytes,
+    total_bytes, n_leaves, cost_topos, params, max_bucket_bytes,
     codec=None, sharded: bool = False,
 ):
     """Planner-derived bucket size for one (axes, dtype) group: the sync
@@ -200,15 +277,6 @@ def _derived_bucket_bytes(
     allreduce on the rest — ``planner.choose_bucket_bytes``)."""
     from ..planner.choose import choose_bucket_bytes
 
-    cost_topos = []
-    for ax in axes:
-        n = int(axis_sizes.get(ax, 0)) or None
-        topo = topos.get(ax)
-        if topo is None:  # the "psum" sentinel: one fused native collective
-            if n is None:
-                continue
-            topo = Topology.flat(n)
-        cost_topos.append(Topology.resolve(n or topo.num_nodes, topo))
     if not cost_topos:
         return max_bucket_bytes
     derived = choose_bucket_bytes(
@@ -231,9 +299,10 @@ def _unpack(fused, segments):
 def _fused_native_psum(leaves, axis_name):
     """Fuse the ``"psum"``-sentinel axis: one native all-reduce per bucket.
     ``psum`` is elementwise across ranks, so fusion is value-preserving."""
+    if len(leaves) == 1:  # a leaf that goes alone keeps its shape
+        return [_NATIVE_PSUM(leaves[0], axis_name)]
     flats = [g.reshape(-1) for g in leaves]
-    fused = flats[0] if len(flats) == 1 else jnp.concatenate(flats)
-    red = _NATIVE_PSUM(fused, axis_name)
+    red = _NATIVE_PSUM(jnp.concatenate(flats), axis_name)
     return [
         p.reshape(g.shape) for p, g in zip(_unpack(red, [f.size for f in flats]), leaves)
     ]
@@ -377,7 +446,9 @@ def bucketed_sync_grads(
     axis in ``mesh_axes`` order) and the result is bitwise-identical to the
     per-leaf sync; the collective count drops from leaves x stages to
     buckets x stages (+ one fused tail per bucket per axis).
-    ``bucket_bytes=None`` derives the size from the calibrated planner;
+    ``bucket_bytes=None`` derives the plan from the calibrated planner (a
+    leaf large enough is a bucket of its own and keeps its shape, the
+    small ones are packed: :func:`plan_buckets`);
     ``chunks > 1`` runs each bucket's tree collectives chunk-pipelined.
     Per-bucket ``comm_span`` scopes (``ft_bucket*``) mark each bucket's
     collectives in profiler traces so comm time is attributable per bucket.
@@ -387,6 +458,7 @@ def bucketed_sync_grads(
     codec only); ``return_residual=True`` then also returns the per-leaf
     error-feedback residuals.
     """
+    from ..obs import bucket_provenance, record_event
     from ..ops.quantize import get_codec
 
     codec = get_codec(codec)
@@ -398,6 +470,8 @@ def bucketed_sync_grads(
         bucket_bytes=bucket_bytes, params=params,
         codec=codec if codec.lossy else None,
     )
+    # trace time, and a no-op without a flight recorder
+    record_event("bucket_plan", n_buckets=len(buckets), **plan_counts(buckets))
     out = list(flat_g)
     residuals = [jnp.zeros_like(g) for g in flat_g] if return_residual else None
     for bi, b in enumerate(buckets):
@@ -410,13 +484,11 @@ def bucketed_sync_grads(
                 for i, r in zip(b.indices, res):
                     residuals[i] = r
         else:
-            from ..obs import bucket_provenance
-
             for ax in b.axes:
                 name = f"ft_bucket{bi}_{ax}_{len(b.indices)}leaves_{b.nbytes}B"
                 prov = bucket_provenance(
                     (ax,), topos, b.nbytes, n_leaves=len(b.indices),
-                    dtype=b.dtype, chunks=chunks,
+                    dtype=b.dtype, chunks=chunks, packed=b.packed,
                 )
                 with comm_span(name, provenance=prov):
                     if topos[ax] is None:

@@ -53,6 +53,7 @@ from .allreduce import (
     _jnp_fn,
     _small_dense_allreduce,
     _split_main_tail,
+    _tree_keeps_shape,
     ring_allreduce,
 )
 
@@ -218,13 +219,18 @@ def _tree_ag_from_stages(v, axis_name, stages, topo: Topology):
 
 
 def _tree_exec(x, axis_name, prog: IRProgram, rop):
-    """The tree program: chunk-interleaved grouped stages, head/tail
-    split — trace-for-trace what ``tree_allreduce`` emits (the golden
-    suite holds the compiled HLO equal)."""
+    """The tree program: the stages on ``x`` in its own shape where its
+    leading dimension divides, else chunk-interleaved grouped stages on the
+    flat view with a head/tail split — trace-for-trace what
+    ``tree_allreduce`` emits (the golden suite holds the compiled HLO
+    equal)."""
     topo: Topology = prog.topo
     n = topo.num_nodes
     rs_stages = [s for s in prog.stages if s.phase == "rs" and s.chunk == 0]
     ag_stages = [s for s in prog.stages if s.phase == "ag" and s.chunk == prog.chunks - 1]
+    if _tree_keeps_shape(x, n, prog.chunks):
+        h = _tree_rs_from_stages(x, axis_name, rs_stages, topo, rop)
+        return _tree_ag_from_stages(h, axis_name, ag_stages, topo)
     shape = x.shape
     head, tail = _split_main_tail(x, n)
     parts = []

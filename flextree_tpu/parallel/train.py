@@ -101,10 +101,12 @@ class TrainConfig:
     # gradient leaves grouped by (replication-axis-set, dtype) into fused
     # flat buckets and runs ONE FlexTree allreduce per bucket — bitwise-
     # identical to per-leaf, but buckets x stages collectives instead of
-    # leaves x stages.  None (default) -> bucket size derived from the
-    # calibrated planner (planner.choose_bucket_bytes); 0 -> per-leaf sync
-    # (the A/B oracle / escape hatch); > 0 -> explicit bucket-size cap in
-    # bytes.
+    # leaves x stages.  None (default) -> the plan derived from the
+    # calibrated planner: a leaf of planner.choose_in_place_bytes or more
+    # goes alone, in its own shape (on the TPU a flatten is a copy), the
+    # smaller ones pack up to planner.choose_bucket_bytes; 0 -> per-leaf
+    # sync (the A/B oracle / escape hatch); > 0 -> explicit bucket-size
+    # cap in bytes.
     bucket_bytes: int | None = None
     # chunk-pipelined allreduce: > 1 splits each bucket's tree collective
     # into C chunks with phase-2/phase-1 interleaving (allreduce chunks=C);
@@ -396,8 +398,9 @@ def sync_grads(
     historical behavior) syncs per leaf — one allreduce sequence per
     gradient leaf; any other value routes through the bucketed/fused sync
     (``parallel.bucketing.bucketed_sync_grads`` — ``None`` derives the
-    bucket size from the calibrated planner, ``> 0`` is an explicit cap),
-    which is bitwise-identical but runs one fused collective per *bucket*.
+    plan from the calibrated planner: large leaves alone and in their own
+    shape, small ones packed; ``> 0`` is an explicit cap), which is
+    bitwise-identical but runs one collective per *bucket*.
     The train-step builders pass their ``TrainConfig.bucket_bytes`` through,
     so the bucketed path is the production default.  ``chunks > 1`` runs
     tree collectives chunk-pipelined (both paths).

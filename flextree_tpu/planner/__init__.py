@@ -57,6 +57,7 @@ from .choose import (
     Plan,
     candidate_topologies,
     choose_bucket_bytes,
+    choose_in_place_bytes,
     choose_topology,
     replan_for_survivors,
 )
@@ -114,6 +115,7 @@ __all__ = [
     "Plan",
     "candidate_topologies",
     "choose_bucket_bytes",
+    "choose_in_place_bytes",
     "choose_topology",
     "replan_for_survivors",
     "count_ordered_factorizations",

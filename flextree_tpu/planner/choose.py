@@ -26,6 +26,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
+import sys
 from dataclasses import dataclass
 
 from ..schedule.ir import IRFamilySpec
@@ -49,6 +50,7 @@ __all__ = [
     "choose_topology",
     "candidate_topologies",
     "choose_bucket_bytes",
+    "choose_in_place_bytes",
     "choose_overlap_boundaries",
     "predict_overlap_schedule",
     "overlap_comm_us",
@@ -378,43 +380,15 @@ def choose_topology(
     return Plan(n, nbytes, topo, tuple(cands), advisory)
 
 
-def choose_bucket_bytes(
-    nbytes: int,
-    topos,
-    *,
-    n_leaves: int | None = None,
-    params: TpuCostParams | None = None,
-    max_buckets: int = 64,
-    codec=None,
-    sharded: bool = False,
-) -> int:
-    """Cost-model-driven gradient-bucket size: the fused-sync bucket cap
-    that minimizes predicted sync time for ``nbytes`` of gradients.
-
-    With ``k`` buckets the sync pays the per-collective fixed overhead
-    (launch + per-hop latency + control — every byte-independent term of
-    :func:`allreduce_cost`) ``k`` times, while consecutive buckets give the
-    compiler pipelining slack: bucket ``i``'s phase-2 allgather can overlap
-    bucket ``i+1``'s phase-1 reduce-scatter, which at the model level turns
-    the byte-proportional terms from ``B`` into ``B * (k+1) / (2k)`` (the
-    classic α-β chunking tradeoff — arXiv:2409.04202's latency-vs-bandwidth
-    decomposition; perfect overlap halves the exposed byte time as k grows).
-    So
-
-        T(k) = k * fixed + byte_terms(nbytes) * (k + 1) / (2 * k)
-
-    is evaluated for ``k`` in 1..min(max_buckets, n_leaves) and the argmin's
-    ``ceil(nbytes / k)`` is returned.  ``topos`` is one resolved
-    ``Topology`` (or a sequence of them, one per replication axis the sync
-    loops over — the fixed and byte terms then sum across axes).  ``params``
-    defaults to the calibrated constants (``FLEXTREE_CALIBRATION``) like
-    every other chooser entry point; on hosts where calibration measured a
-    large launch overhead the argmin lands on few, large buckets, and on
-    fabrics where bandwidth dominates it shrinks them toward the pipelined
-    regime.  Interior optimum: ``dT/dk = 0`` at ``k* = sqrt(byte/(2*fixed))``.
-    """
-    if nbytes < 0:
-        raise ValueError(f"nbytes must be >= 0, got {nbytes}")
+def _sync_cost_terms(
+    nbytes: int, topos, params, codec=None, sharded: bool = False
+) -> tuple[float, float]:
+    """(fixed, byte) microseconds of ONE bucket sync of ``nbytes``: the
+    byte-independent terms of the per-axis collectives (launch + per-hop
+    latency + control) and the byte-proportional ones (wire + reduce +
+    codec passes), summed over the replication-axis topologies in
+    ``topos`` — the two terms :func:`choose_bucket_bytes` and
+    :func:`choose_in_place_bytes` trade against each other."""
     if params is None:
         from .calibrate import default_params
 
@@ -423,9 +397,7 @@ def choose_bucket_bytes(
         [topos] if isinstance(topos, (Topology, LonelyTopology)) else list(topos)
     )
     if not topo_list:
-        raise ValueError("choose_bucket_bytes needs at least one topology")
-    if nbytes == 0:
-        return 1
+        raise ValueError("the bucket choosers need at least one topology")
 
     def cost(t, nb):
         if isinstance(t, LonelyTopology):
@@ -465,6 +437,49 @@ def choose_bucket_bytes(
             # bandwidth — the argmin shifts toward fewer, larger buckets as
             # the wire gets cheaper relative to the fixed launch cost
             byte_us += full.bandwidth_us + full.reduce_us + full.codec_us
+    return fixed, byte_us
+
+
+def choose_bucket_bytes(
+    nbytes: int,
+    topos,
+    *,
+    n_leaves: int | None = None,
+    params: TpuCostParams | None = None,
+    max_buckets: int = 64,
+    codec=None,
+    sharded: bool = False,
+) -> int:
+    """Cost-model-driven gradient-bucket size: the fused-sync bucket cap
+    that minimizes predicted sync time for ``nbytes`` of gradients.
+
+    With ``k`` buckets the sync pays the per-collective fixed overhead
+    (launch + per-hop latency + control — every byte-independent term of
+    :func:`allreduce_cost`) ``k`` times, while consecutive buckets give the
+    compiler pipelining slack: bucket ``i``'s phase-2 allgather can overlap
+    bucket ``i+1``'s phase-1 reduce-scatter, which at the model level turns
+    the byte-proportional terms from ``B`` into ``B * (k+1) / (2k)`` (the
+    classic α-β chunking tradeoff — arXiv:2409.04202's latency-vs-bandwidth
+    decomposition; perfect overlap halves the exposed byte time as k grows).
+    So
+
+        T(k) = k * fixed + byte_terms(nbytes) * (k + 1) / (2 * k)
+
+    is evaluated for ``k`` in 1..min(max_buckets, n_leaves) and the argmin's
+    ``ceil(nbytes / k)`` is returned.  ``topos`` is one resolved
+    ``Topology`` (or a sequence of them, one per replication axis the sync
+    loops over — the fixed and byte terms then sum across axes).  ``params``
+    defaults to the calibrated constants (``FLEXTREE_CALIBRATION``) like
+    every other chooser entry point; on hosts where calibration measured a
+    large launch overhead the argmin lands on few, large buckets, and on
+    fabrics where bandwidth dominates it shrinks them toward the pipelined
+    regime.  Interior optimum: ``dT/dk = 0`` at ``k* = sqrt(byte/(2*fixed))``.
+    """
+    if nbytes < 0:
+        raise ValueError(f"nbytes must be >= 0, got {nbytes}")
+    fixed, byte_us = _sync_cost_terms(nbytes, topos, params, codec, sharded)
+    if nbytes == 0:
+        return 1
     k_max = max(1, min(max_buckets, n_leaves or max_buckets))
     best_k, best_t = 1, float("inf")
     for k in range(1, k_max + 1):
@@ -472,6 +487,32 @@ def choose_bucket_bytes(
         if t_k < best_t:
             best_k, best_t = k, t_k
     return -(-nbytes // best_k)  # ceil
+
+
+def choose_in_place_bytes(
+    topos, *, params: TpuCostParams | None = None
+) -> int:
+    """Leaf size (bytes) from which the fused gradient sync gives a leaf a
+    collective of its own, in its own shape, instead of packing it.
+
+    Same model as :func:`choose_bucket_bytes`: two leaves of ``b`` bytes
+    packed into one bucket cost ``T(1) = fixed + byte(2b)``, synced apart
+    ``T(2) = 2 * fixed + byte(2b) * 3/4``.  Packing lowers the predicted
+    cost only while ``T(1) < T(2)``, i.e. while ``byte(b) < 2 * fixed``;
+    the size returned is where the two meet, ``byte(b) = 2 * fixed`` (the
+    byte terms are linear in ``b``).  A leaf that large already moves for
+    longer than the two launches packing could save, and what the model
+    does not price points the same way: a packed bucket is a flat copy of
+    its leaves in and another out, which on the TPU (where a 2-D array and
+    its flat view are tiled differently) is a pass through HBM each way.
+    Derived, like the bucket size, from the calibrated constants
+    (``FLEXTREE_CALIBRATION``) or the built-in defaults.
+    """
+    probe = 1 << 20
+    fixed, byte_us = _sync_cost_terms(probe, topos, params)
+    if byte_us <= 0:
+        return sys.maxsize  # bytes are free in this model: always pack
+    return math.ceil(2.0 * fixed * probe / byte_us)
 
 
 #: Wire pessimism band for the overlap boundary argmin: candidate

@@ -113,6 +113,46 @@ def build(args, init_state=True):
         unpack = make_reshard_fn(mesh, pspecs, layout, tc.grad_topo, lossy)
         return packed_specs, pack, unpack
 
+    def announce_sync_plan(mesh, pspecs, params_shapes, axis_names, tc):
+        """The start-up line: which constants price the gradient sync's
+        buckets, and how the plan they give treats the leaves (the plan
+        ``bucketed_sync_grads`` will trace: it plans what a device holds,
+        so from the abstract shapes of the parameters' shards)."""
+        from jax.sharding import NamedSharding
+
+        from .parallel.bucketing import plan_buckets, plan_counts
+        from .parallel.train import resolve_axis_topos
+
+        line = "planner constants: " + (
+            os.environ.get("FLEXTREE_CALIBRATION")
+            or "built-in defaults (not calibrated on this fabric)"
+        )
+        if tc.bucket_bytes != 0 and not (tc.overlap or tc.shard_optimizer):
+            from .ops.quantize import get_codec
+
+            codec = get_codec(tc.codec)
+            flat, treedef = jax.tree.flatten(params_shapes)
+            specs = treedef.flatten_up_to(pspecs)
+            shards = [
+                jax.ShapeDtypeStruct(
+                    NamedSharding(mesh, s).shard_shape(g.shape), g.dtype
+                )
+                for g, s in zip(flat, specs)
+            ]
+            counts = plan_counts(plan_buckets(
+                shards, specs, axis_names,
+                topos=resolve_axis_topos(mesh, axis_names, tc.grad_topo),
+                axis_sizes={ax: int(mesh.shape[ax]) for ax in axis_names},
+                bucket_bytes=tc.bucket_bytes,
+                codec=codec if codec.lossy else None,
+            ))
+            line += (
+                "; gradient sync: {in_place_leaves} leaves / {in_place_bytes} "
+                "bytes in place, {packed_leaves} leaves / {packed_bytes} "
+                "bytes packed".format(**counts)
+            )
+        print(line, flush=True)
+
     if args.model == "dense":
         from .models.transformer import init_params, param_specs
         from .parallel.train import (
@@ -133,6 +173,9 @@ def build(args, init_state=True):
         sspecs = state_specs(cfg, train_cfg=tc, mesh=mesh)
         params_shapes = jax.eval_shape(
             lambda k: init_params(k, cfg), jax.random.PRNGKey(0)
+        )
+        announce_sync_plan(
+            mesh, param_specs(cfg, "tp"), params_shapes, axis_names, tc
         )
         restore_specs, pack, unpack = sharded_hooks(
             mesh, param_specs(cfg, "tp"), params_shapes, axis_names, sspecs, tc
@@ -169,6 +212,9 @@ def build(args, init_state=True):
         params_shapes = jax.eval_shape(
             lambda k: stack_layer_params(init_params(k, cfg)),
             jax.random.PRNGKey(0),
+        )
+        announce_sync_plan(
+            mesh, pipeline_param_specs(cfg), params_shapes, axis_names, tc
         )
         restore_specs, pack, unpack = sharded_hooks(
             mesh, pipeline_param_specs(cfg), params_shapes, axis_names, sspecs,
@@ -210,6 +256,9 @@ def build(args, init_state=True):
         sspecs = moe_state_specs(cfg, train_cfg=tc, mesh=mesh)
         params_shapes = jax.eval_shape(
             lambda k: init_moe_params(k, cfg), jax.random.PRNGKey(0)
+        )
+        announce_sync_plan(
+            mesh, moe_param_specs(cfg), params_shapes, axis_names, tc
         )
         restore_specs, pack, unpack = sharded_hooks(
             mesh, moe_param_specs(cfg), params_shapes, axis_names, sspecs, tc
@@ -393,15 +442,6 @@ def train(args: argparse.Namespace) -> TrainRun:
         jax.config.update("jax_num_cpu_devices", args.cpu)
     enable_compile_cache()
     announce_devices("flextree_tpu.trainer")
-    # the bucket planner prices collectives with these constants; nothing
-    # has calibrated them on ICI yet, so say which ones a run used
-    print(
-        "planner constants: "
-        + (os.environ.get("FLEXTREE_CALIBRATION")
-           or "built-in defaults (not calibrated on this fabric)"),
-        flush=True,
-    )
-
     from .data import LMDataset, synthetic_tokens
     from .parallel.loop import FitConfig, Supervision, fit
 

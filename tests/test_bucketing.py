@@ -123,16 +123,30 @@ def test_plan_buckets_skips_fully_sharded_and_size1_axes():
     assert buckets[0].indices == (1,)
 
 
-def test_plan_buckets_derived_size_is_capped():
-    leaves = [_sds((1 << 22,)) for _ in range(4)]  # 16 MiB each
+@pytest.mark.parametrize(
+    "leaf,want",
+    [
+        # 16 MiB each: past the size at which packing lowers the priced
+        # cost (planner.choose_in_place_bytes), every leaf goes alone, in
+        # its own shape
+        ((1 << 22,), [(0,), (1,), (2,), (3,)]),
+        # 4 KiB each: under it, the four are packed into one capped bucket
+        ((1 << 10,), [(0, 1, 2, 3)]),
+    ],
+    ids=["large_leaves_go_alone", "small_leaves_are_packed"],
+)
+def test_plan_buckets_derived_plan(leaf, want):
+    leaves = [_sds(leaf) for _ in range(4)]
     specs = [P()] * 4
     topos = {ax: Topology.flat(2) for ax in MESH_AXES}
     buckets = plan_buckets(
         leaves, specs, MESH_AXES, topos=topos,
         axis_sizes={ax: 2 for ax in MESH_AXES}, bucket_bytes=None,
     )
-    assert all(b.nbytes <= max(DEFAULT_MAX_BUCKET_BYTES, 16 << 20) for b in buckets)
-    assert sorted(i for b in buckets for i in b.indices) == [0, 1, 2, 3]
+    assert [b.indices for b in buckets] == want
+    assert [b.packed for b in buckets] == [len(w) > 1 for w in want]
+    # a packed bucket stays under the memory cap; a lone leaf is its own size
+    assert all(b.nbytes <= DEFAULT_MAX_BUCKET_BYTES for b in buckets)
 
 
 # ---------------------------------------------------------- bucket chooser

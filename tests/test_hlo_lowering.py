@@ -120,7 +120,20 @@ def _collective_counts(ir: str) -> dict:
     }
 
 
-def test_bucketed_train_step_collectives_bounded_by_buckets():
+@pytest.mark.parametrize(
+    "bucket_bytes,widths",
+    [
+        (1 << 30, dict(d_model=32, d_ff=64)),
+        # the derived plan (PR 29) at widths whose MLP matrices (1 MiB) go
+        # alone, in their own shape, while everything else is packed:
+        # still buckets x stages, with more buckets of one leaf
+        (None, dict(d_model=64, d_ff=8192)),
+    ],
+    ids=["one_bucket_a_group", "derived_plan_large_leaves_alone"],
+)
+def test_bucketed_train_step_collectives_bounded_by_buckets(
+    bucket_bytes, widths
+):
     """Regression tripwire against silently falling back to per-leaf sync:
     the lowered bucketed train step's scheduled-collective count must be
     bounded by buckets x stages, not leaves x stages.
@@ -141,7 +154,7 @@ def test_bucketed_train_step_collectives_bounded_by_buckets():
     )
 
     model_cfg = TransformerConfig(
-        vocab_size=64, d_model=32, n_heads=4, n_layers=2, d_ff=64
+        vocab_size=64, n_heads=4, n_layers=2, **widths
     )
     mesh = make_mesh_nd(8, (2, 2, 2), ("dp", "sp", "tp"))
     state_sds = jax.eval_shape(
@@ -154,7 +167,7 @@ def test_bucketed_train_step_collectives_bounded_by_buckets():
         return step.lower(state_sds, tok, tok).as_text()
 
     per_leaf = _collective_counts(lower(TrainConfig(bucket_bytes=0)))
-    bucketed = _collective_counts(lower(TrainConfig(bucket_bytes=1 << 30)))
+    bucketed = _collective_counts(lower(TrainConfig(bucket_bytes=bucket_bytes)))
     native = _collective_counts(lower(TrainConfig(grad_topo="psum")))
 
     # the sync's own scheduled collectives, by subtraction
@@ -169,10 +182,23 @@ def test_bucketed_train_step_collectives_bounded_by_buckets():
     flat_g, treedef = jax.tree.flatten(state_sds["params"])
     flat_s = treedef.flatten_up_to(pspecs)
     axis_sizes = {"dp": 2, "sp": 2, "tp": 2}
+
+    def local(g, spec):
+        # the sync plans what a device holds: the shard of a tp-sharded leaf
+        shape = jax.sharding.NamedSharding(mesh, spec).shard_shape(g.shape)
+        return jax.ShapeDtypeStruct(shape, g.dtype)
+
+    from flextree_tpu.schedule.stages import Topology
+
     buckets = plan_buckets(
-        flat_g, flat_s, ("dp", "sp", "tp"),
-        axis_sizes=axis_sizes, bucket_bytes=1 << 30,
+        [local(g, s) for g, s in zip(flat_g, flat_s)], flat_s,
+        ("dp", "sp", "tp"),
+        topos={ax: Topology.flat(2) for ax in axis_sizes},
+        axis_sizes=axis_sizes, bucket_bytes=bucket_bytes,
     )
+    if bucket_bytes is None:
+        alone = [b for b in buckets if not b.packed and b.nbytes >= 1 << 19]
+        assert len(alone) == 4 and any(b.packed for b in buckets)
     expected_bucket_rs = sum(len(b.axes) for b in buckets)
     n_synced_leaves = sum(
         1 for s in flat_s if replication_key(s, ("dp", "sp", "tp"))

@@ -211,3 +211,57 @@ def test_flagship_paged_decode_and_prefill_compile(v5e, tpu_lowering):
     _compile(
         lambda p, t: prefill(p, t, cfg, max_len=pcfg.max_len), params, prompt
     )
+
+
+def test_dp4_step_syncs_large_leaves_in_their_own_shape(v5e, tpu_lowering):
+    """The four-chip data-parallel step (mesh (4,1,1), the shape of the
+    benchmark's ``train-dp4`` cell at small widths), compiled for the
+    chip: every matrix is past ``planner.choose_in_place_bytes`` and is
+    reduce-scattered and all-gathered in its own (8, 128)-tiled shape.
+    Before PR 29 each went through a flat ``f32[rows*cols]`` view, which
+    on the TPU is tiled differently: a ``copy`` through HBM in and another
+    out (29 ms of a 305 ms step in the cell, PERF.md §6)."""
+    import re
+
+    from flextree_tpu.parallel.train import (
+        TrainConfig,
+        init_train_state,
+        make_train_step,
+        state_specs,
+    )
+
+    cfg = TransformerConfig(
+        vocab_size=1024, d_model=512, n_heads=4, n_layers=1, d_ff=2048,
+        dtype=jnp.bfloat16, attn_impl="flash",
+    )
+    mesh = _mesh(v5e, (4, 1, 1))
+    tc = TrainConfig()
+    shardings = jax.tree.map(
+        lambda spec: NamedSharding(mesh, spec),
+        state_specs(cfg, train_cfg=tc, mesh=mesh),
+        is_leaf=lambda x: isinstance(x, P),
+    )
+    state = _on(
+        jax.eval_shape(
+            lambda k: init_train_state(k, cfg, tc), jax.random.PRNGKey(0)
+        ),
+        shardings,
+    )
+    tok = jax.ShapeDtypeStruct(
+        (4, 256), jnp.int32, sharding=NamedSharding(mesh, P("dp", "sp"))
+    )
+    hlo = _compile(make_train_step(mesh, cfg, tc), state, tok, tok).as_text()
+    matrices = {(1024, 512): 1, (512, 512): 4, (512, 2048): 1, (2048, 512): 1}
+    for (rows, cols), n in matrices.items():
+        assert f"f32[{rows * cols}]" not in hlo, (rows, cols)
+        # (an operation may be printed again inside its async wrapper)
+        assert len(re.findall(
+            rf"= f32\[{rows},{cols}\]\S* all-gather\(", hlo
+        )) >= n, (rows, cols)
+        assert not re.search(rf"= f32\[{rows},{cols}\]\S* copy\(", hlo)
+    # one bucket a matrix, one reduce-scatter stage each, in two dimensions
+    alone = set(re.findall(r"ft_bucket\d+_dp_1leaves_\d+B/ft_rs_stage0_w4", hlo))
+    assert len(alone) == sum(matrices.values())
+    assert re.search(r"= f32\[\d+,\d+\]\S* reduce-scatter\(", hlo)
+    # the three norm scales share one flat bucket
+    assert "_dp_3leaves_6144B" in hlo
