@@ -146,8 +146,8 @@ _TENSOR_RE = re.compile(r"tensor<([0-9x]*)([a-z][a-z0-9]*)>")
 
 def collective_wire_bytes(ir: str) -> dict[str, float]:
     """Per-chip wire bytes of every collective in ``ir``, from the lowered
-    StableHLO — the static accounting BENCH_SHARDED.json's floor is
-    checked against.
+    StableHLO — the static accounting the sharded step's wire floors
+    (``tests/test_sharded.py::TestWireBytes``) are checked against.
 
     Per op the operand bytes (every tensor in its ``: (...) ->``
     signature; region ops close with ``}) : (tensor<..>)``, and their
@@ -723,7 +723,7 @@ def lower_entrypoints(full: bool = True) -> list[tuple[str, str, HloBudget]]:
     """(name, stablehlo text, budget) for every linted entrypoint.
 
     ``full=False`` lowers only the allreduce-family entrypoints (no model
-    steps) — the fast subset ``bench.py``'s tripwire uses.
+    steps) — the fast subset.
     """
     _require_devices(8)
     rows: list[tuple[str, str, HloBudget]] = [

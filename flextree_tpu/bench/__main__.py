@@ -16,12 +16,6 @@ import argparse
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(prog="flextree_tpu.bench")
-    ap.add_argument(
-        "--bench",
-        choices=["allreduce", "attention"],
-        default="allreduce",
-        help="allreduce A/B (default) or fused-attention kernel benchmark",
-    )
     ap.add_argument("--size", type=int, default=35, help="elements per chip")
     ap.add_argument("--repeat", type=int, default=10)
     ap.add_argument("--comm-type", choices=["flextree", "xla"], default="flextree")
@@ -41,45 +35,6 @@ def main(argv=None) -> int:
         action="store_true",
         help="time without buffer donation (default times the reference's "
         "MPI_IN_PLACE-style compounding loop, benchmark.cpp:149-159)",
-    )
-    # attention-bench geometry (--bench attention)
-    ap.add_argument("--batch", type=int, default=4)
-    ap.add_argument("--seq-len", type=int, default=4096)
-    ap.add_argument("--heads", type=int, default=16)
-    ap.add_argument("--head-dim", type=int, default=128)
-    ap.add_argument(
-        "--attn-impl", choices=["flash", "reference", "stock"], default="flash"
-    )
-    ap.add_argument(
-        "--autotune", action="store_true",
-        help="sweep the shortlisted (block_q, block_k) pairs from the v5e "
-        "block sweep (flash/stock; reference runs once, blocks unused)",
-    )
-    ap.add_argument("--block-q", type=int, default=256)
-    ap.add_argument("--block-k", type=int, default=512)
-    ap.add_argument(
-        "--attn-variant", choices=["loop", "pipelined", "kvgrid"],
-        default="loop",
-        help="flash forward k-walk structure (ablation knob for the "
-        "MXU/VPU-overlap question; loop = the carry-serialized kernel)",
-    )
-    ap.add_argument(
-        "--attn-mode", choices=["fwd", "grad"], default="fwd",
-        help="grad: time grads of sum(attention) wrt (q, k, v) — the "
-        "fwd-with-residuals pass plus both blockwise backward kernels "
-        "(hw FLOPs incl. recompute); flash, stock, and reference",
-    )
-    ap.add_argument(
-        "--attn-timing", choices=["device_loop", "chained"],
-        default="device_loop",
-        help="device_loop: in-jit fori_loop slope (device time only, immune "
-        "to dispatch latency); chained: per-call python loop (includes it)",
-    )
-    ap.add_argument(
-        "--attn-dtype",
-        type=str,
-        default="bfloat16",
-        help="compute dtype for --bench attention (independent of --dtype)",
     )
     ap.add_argument("--tag", type=str, default="flextree")
     ap.add_argument("--to-file", action="store_true")
@@ -102,44 +57,6 @@ def main(argv=None) -> int:
         jax.config.update("jax_num_cpu_devices", args.cpu)
     enable_compile_cache()
     announce_devices("flextree_tpu.bench")
-
-    if args.bench == "attention":
-        from .harness import (
-            AttentionBenchConfig,
-            autotune_attention,
-            run_attention_bench,
-        )
-
-        acfg_kw = dict(
-            batch=args.batch,
-            seq_len=args.seq_len,
-            heads=args.heads,
-            head_dim=args.head_dim,
-            dtype=args.attn_dtype,
-            impl=args.attn_impl,
-            block_q=args.block_q,
-            block_k=args.block_k,
-            timing=args.attn_timing,
-            mode=args.attn_mode,
-            variant=args.attn_variant,
-        )
-        if args.attn_timing == "chained":
-            acfg_kw["repeat"] = args.repeat  # device_loop ignores repeat
-        acfg = AttentionBenchConfig(**acfg_kw)
-        if args.autotune:
-            report = autotune_attention(acfg, impl=args.attn_impl)
-        else:
-            report = run_attention_bench(
-                acfg, tag=args.tag, to_file=args.to_file, out_dir=args.out_dir
-            )
-        mfu = f" ({report.mfu * 100:.1f}% MFU)" if report.mfu is not None else ""
-        print(
-            f"{report.config.impl}(bq={report.config.block_q}, "
-            f"bk={report.config.block_k}): {report.per_call_s * 1e3:.3f} "
-            f"ms/call, {report.tflops:.2f} TFLOP/s{mfu}"
-            + (f" -> {report.result_path}" if report.result_path else "")
-        )
-        return 0
 
     from .harness import BenchConfig, run_allreduce_bench
 

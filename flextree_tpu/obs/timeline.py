@@ -39,7 +39,7 @@ Perfetto (https://ui.perfetto.dev) or ``chrome://tracing``:
 Timestamps are wall-clock (the recorders stamp with ``time.time`` for
 exactly this reason); the merger rebases to the earliest event so the
 trace starts at 0 µs.  :func:`validate_trace` is the schema check the
-tests, the chaos driver, and the bench tripwire share — "loadable
+tests and the chaos drivers share — "loadable
 Chrome-trace JSON" is machine-checked, not assumed.
 """
 

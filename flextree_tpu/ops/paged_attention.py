@@ -35,8 +35,8 @@ proven **bitwise** against the contiguous-cache ``generate``.  The fused
 paths change only floating-point summation order (online softmax folds
 block by block; the oracle reduces the whole row at once), so they are
 gated against the oracle within a pinned tolerance
-(``FUSED_DECODE_ATOL`` — enforced per rep in ``tools/bench_paged.py``
-and pinned in ``tests/test_paged_attention.py``), not bitwise.
+(``FUSED_DECODE_ATOL`` — pinned in ``tests/test_paged_attention.py``),
+not bitwise.
 
 Masking mirrors ``models.generate.cached_attention``: pool positions at
 or past a row's ``length`` are driven to ``-1e30`` *before* the running

@@ -49,11 +49,10 @@ import os as _os
 
 _FWD_UNROLL = int(_os.environ.get("FLEXTREE_FLASH_UNROLL", "1"))
 
-# Default forward k-walk schedule.  "loop" is the only variant with a chip
-# number behind it (BENCH_ATTENTION.json, 2026-07-30); "pipelined"/"kvgrid"
-# are CPU-parity-pinned and not measured on today's code (ROADMAP S2 times
-# all three and keeps one).  Env-overridable so a bench can sweep without
-# editing call sites.
+# Default forward k-walk schedule.  "loop" is the variant the benchmark's
+# train cells run; "pipelined"/"kvgrid" are CPU-parity-pinned and not
+# measured on the chip (ROADMAP D2 times all three and keeps one).
+# Env-overridable so a sweep needs no edit of call sites.
 DEFAULT_FWD_VARIANT = _os.environ.get("FLEXTREE_FLASH_VARIANT", "loop")
 
 # Mosaic's default scoped-VMEM budget on v5e: 16 MiB unless a kernel raises

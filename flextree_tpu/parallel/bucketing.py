@@ -92,12 +92,12 @@ __all__ = [
 DEFAULT_MAX_BUCKET_BYTES = 64 << 20
 
 #: Planner-derived cap on CPU backends.  The alpha-beta chooser only prices
-#: dispatch + bytes, and on the 1-core bench host it lands on one giant
-#: bucket — which measured ~25% SLOWER end-to-end than per-leaf sync inside
-#: the train step, while 64-128 KiB buckets beat per-leaf by ~15%
-#: (BENCH_BUCKETING.json): in-step, the fused pack -> collective -> unpack
-#: -> AdamW chain must stay cache-hot, a locality term the dispatch model
-#: cannot see.
+#: dispatch + bytes, and on a one-core CPU host it lands on one giant
+#: bucket, which ran slower inside the train step than per-leaf sync
+#: while 64-128 KiB buckets ran faster: in-step, the fused pack ->
+#: collective -> unpack -> AdamW chain must stay cache-hot, a locality
+#: term the dispatch model cannot see.  (A CPU observation; the chip's cap
+#: is ``DEFAULT_MAX_BUCKET_BYTES``.)
 CPU_MAX_BUCKET_BYTES = 128 << 10
 
 

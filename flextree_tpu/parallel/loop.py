@@ -227,8 +227,7 @@ class RunReport:
 
     def to_json(self) -> str:
         """The machine-readable form ``fit`` persists as run_report.json
-        (recovery events as stable keys, so tooling can gate on them the
-        way ``bench.py`` gates on ``analysis_violations``)."""
+        (recovery events as stable keys, so tooling can gate on them)."""
         return json.dumps(self.to_payload(), indent=2, sort_keys=True)
 
 

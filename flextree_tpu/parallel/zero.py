@@ -23,8 +23,9 @@ sharded path moves ``B(N-1)/N`` of gradients down and ``B(N-1)/N`` of
 *parameters* up — identical for f32, but the codec now applies to BOTH
 phases (grads down, params up), so the quantized sharded step moves
 ``~2·r·B(N-1)/N`` bytes (``r`` = wire ratio, ~0.25 for int8) against the
-replicated fused f32 baseline's ``2B(N-1)/N`` — the measured floor
-``BENCH_SHARDED.json`` enforces.  Parameter quantization is safe because
+replicated fused f32 baseline's ``2B(N-1)/N`` — the floor
+``tests/test_sharded.py::TestWireBytes`` holds on the static count
+(``hlo_lint.collective_wire_bytes``).  Parameter quantization is safe because
 the authoritative **master copy is sharded f32** (``master_*`` state
 entries, lossy codecs only): every rank's working params are
 ``decode(encode(master))`` of identical bytes, so replicas cannot drift
@@ -86,7 +87,6 @@ __all__ = [
     "maybe_clip_shards",
     "make_consolidate_fn",
     "make_reshard_fn",
-    "zero_shard_bytes",
 ]
 
 
@@ -911,27 +911,3 @@ def make_reshard_fn(mesh, pspecs, layout: ZeroLayout, grad_topo, lossy: bool):
             check_vma=False,
         )
     )
-
-
-def zero_shard_bytes(layout: ZeroLayout, lossy: bool = False) -> dict:
-    """Analytic per-rank optimizer-state bytes under ``layout`` vs the
-    replicated layout — the accounting BENCH_SHARDED.json verifies
-    against live buffer sizes.  Counts mu+nu (+the sharded master when
-    lossy); the working params are excluded on both sides (both keep a
-    full copy).  Sizes are per-device (layout sizes are local)."""
-    sharded = replicated = 0
-    for l in layout.leaves:
-        leaf_rep = 2 * 4 * l.size  # mu + nu, f32
-        replicated += leaf_rep
-        if l.sharded:
-            per_rank = 2 * 4 * (l.tile + l.tail)
-            if lossy:
-                per_rank += 4 * (l.tile + l.tail)
-            sharded += per_rank
-        else:
-            sharded += leaf_rep
-    return {
-        "replicated_bytes": replicated,
-        "sharded_bytes_per_rank": sharded,
-        "ratio": (sharded / replicated) if replicated else 1.0,
-    }

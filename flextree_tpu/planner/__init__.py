@@ -28,7 +28,6 @@ from .calibrate import (
     feature_vector,
     fit_cost_params,
     load_calibration,
-    measure_points,
     plan_cache_key,
     predict_us,
     save_calibration,
@@ -86,7 +85,6 @@ __all__ = [
     "ring_cost",
     "bus_bandwidth_GBps",
     "MeasuredPoint",
-    "measure_points",
     "feature_vector",
     "fit_cost_params",
     "predict_us",

@@ -4,8 +4,9 @@
 models must be anchored to measurement; ``calibrate.py`` does that for
 the model's *constants* but the final plan pick was still pure argmin.
 This module finishes the loop: take the top-K **analytic** candidates
-over the (tree shape x wire codec) product, time each with the bench
-harness's shuffled-interleaved rep protocol on the live backend, pick the
+over the (tree shape x wire codec) product, time each with the
+shuffled-interleaved rep protocol (``utils.timing.time_interleaved``) on
+the live backend, pick the
 **measured** winner, and persist it in a plan cache so the second run is
 a pure cache hit.
 
@@ -249,10 +250,9 @@ def invalidate_plan_cache(predicate, cache_path=None) -> int:
 
 
 def _default_timer(candidates, n, nbytes, dtype, repeat, sharded: bool = False):
-    """Measure every candidate with the bench harness's shuffled-
-    interleaved protocol (one warmed jitted fn per candidate, reps
-    interleaved in shuffled rounds so a host-contention episode cannot
-    land on one candidate — the BENCH_ALLREDUCE r03/r04 lesson).
+    """Measure every candidate with the shuffled-interleaved protocol
+    (one warmed jitted fn per candidate, reps interleaved in shuffled
+    rounds so a host-contention episode cannot land on one candidate).
     Returns measured seconds per candidate, aligned with ``candidates``.
     ``sharded`` times the split round the ZeRO step actually runs
     (``all_gather(reduce_scatter(x))`` with the codec on both wires).
@@ -262,10 +262,10 @@ def _default_timer(candidates, n, nbytes, dtype, repeat, sharded: bool = False):
     import numpy as np
     from jax.sharding import PartitionSpec as P
 
-    from ..bench.harness import _interleaved_times
     from ..parallel.allreduce import all_gather, reduce_scatter
     from ..parallel.compressed import compressed_allreduce
     from ..parallel.mesh import flat_mesh
+    from ..utils.timing import time_interleaved
 
     mesh = flat_mesh(n, "ft")
     size = max(1, nbytes // jnp.dtype(dtype).itemsize)
@@ -297,7 +297,7 @@ def _default_timer(candidates, n, nbytes, dtype, repeat, sharded: bool = False):
         )
         jax.block_until_ready(fn(x))  # compile outside the timed reps
         calls[str(i)] = (fn, (x,))
-    rows = _interleaved_times(calls, repeat)
+    rows = time_interleaved(calls, repeat)
     return [rows[str(i)]["min_ms"] * 1e-3 for i in range(len(candidates))]
 
 

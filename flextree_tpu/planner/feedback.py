@@ -61,7 +61,8 @@ Honest limits (docs/FEEDBACK.md): probes measure the collective ALONE on
 the live backend — in-step contention is not in the sample (the overlap
 planner's pessimism band covers that seam); one-address-space memcpy
 wires produce residuals whose bandwidth/latency split the fit cannot
-attribute (the same negative control BENCH_QUANT documents); lonely
+attribute (docs/QUANTIZED_COLLECTIVES.md, "Where compression can
+win"); lonely
 ``+k`` shapes have no feature row, so their samples inform drift but not
 the α-β solve; and per-step samples are step totals apportioned over the
 plan, so the byte phase is only identifiable against a compute floor and
@@ -267,9 +268,9 @@ def fit_bwd_gflops(compute_samples) -> float | None:
     """Median achieved backward GFLOP/s from ``(flops, seconds)`` compute
     probes (>= 2 positive samples required), or None — the overlap
     boundary equalizer's absolute compute scale.  Compute probes need a
-    sync-free step to time (``bench.harness.make_nosync_train_step``);
-    runs without one keep the backend-resolved default, documented in
-    docs/FEEDBACK.md."""
+    sync-free step to time (``make_nosync_train_step`` of
+    ``tools/probe_free_feedback.py``); runs without one keep the
+    backend-resolved default, documented in docs/FEEDBACK.md."""
     rates = [
         flops / seconds / 1e9
         for flops, seconds in compute_samples
@@ -1262,7 +1263,7 @@ class FeedbackConfig:
     trigger.  ``min_samples``: the fitter's starvation floor.
     ``probes``: explicit :class:`ProbePoint` set (None derives
     :func:`default_probe_points`).  ``repeat``: timed reps per probe per
-    tick (shuffled-interleaved, the harness protocol).
+    tick (shuffled-interleaved, ``utils.timing.time_interleaved``).
     ``calibration_path``: where refits are written back
     (``save_calibration(source="feedback")``); None skips persistence.
     ``plan_cache_path``: the autotune cache to drift-invalidate (None =
@@ -1317,7 +1318,7 @@ class FeedbackConfig:
     # full passes over the variant set (variants + the base size, so the
     # base is re-sampled in later windows too).  >1 interleaves each
     # plan's samples across the run's whole wall-clock window — the step
-    # -scale version of the bench harness's shuffled-interleaved rounds:
+    # -scale version of ``time_interleaved``'s shuffled rounds:
     # a timeshared host's contention drifts over seconds, and a plan
     # sampled only in one window would absorb that drift as phase signal
     rotation_cycles: int = 2
@@ -1352,8 +1353,8 @@ class FeedbackController:
     from); residuals are judged against these until a refit replaces
     them.  ``timer(probes, n) -> [seconds]`` and ``clock`` are
     injectable for tests; the default timer runs each probe's collective
-    on the live backend with the bench harness's shuffled-interleaved
-    protocol, compiling once per probe point and caching the jitted fn
+    on the live backend with the shuffled-interleaved protocol
+    (``utils.timing.time_interleaved``), compiling once per probe point and caching the jitted fn
     across ticks.
 
     :meth:`maybe_tick` is the ``fit`` hook.  Its recorder-off cost is
@@ -2017,16 +2018,16 @@ class FeedbackController:
     # -- the default live-wire probe timer ------------------------------
 
     def _default_timer(self, probes, n):
-        """Time each probe's collective on the live backend — the bench
-        harness's shuffled-interleaved protocol over jitted, warmed fns
-        (compiled once per probe point, cached across ticks)."""
+        """Time each probe's collective on the live backend — the
+        shuffled-interleaved protocol over jitted, warmed fns (compiled
+        once per probe point, cached across ticks)."""
         import jax
         import jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
 
-        from ..bench.harness import _interleaved_times
         from ..parallel.compressed import compressed_allreduce
         from ..parallel.mesh import flat_mesh
+        from ..utils.timing import time_interleaved
 
         calls = {}
         for i, p in enumerate(probes):
@@ -2061,5 +2062,5 @@ class FeedbackController:
                 cached = (fn, (x,))
                 self._fns[p] = cached
             calls[str(i)] = cached
-        rows = _interleaved_times(calls, max(1, self.cfg.repeat))
+        rows = time_interleaved(calls, max(1, self.cfg.repeat))
         return [rows[str(i)]["min_ms"] * 1e-3 for i in range(len(probes))]

@@ -17,9 +17,9 @@ The simulator backend carries the same contract at message granularity:
 ``FaultPlan.recv_timeout`` turns a hung sender into a typed
 ``StageTimeout`` instead of a deadlock (``backends.simulator``).
 
-Fault-free overhead is one queue round-trip per step (~tens of µs — the
-worker thread is persistent, never spawned per step); measured ≤ 2% of
-``run_train_step_bench``'s step time (WINS.md).
+Fault-free overhead is one queue round-trip per step (the worker thread
+is persistent, never spawned per step); what it costs a step on the chip
+is not measured.
 """
 
 from __future__ import annotations

@@ -1,7 +1,7 @@
 """Serving front-end: continuous-batching generation over a paged KV cache.
 
 The traffic-facing layer of the framework — requests in, tokens out,
-benchmarked in throughput and latency percentiles instead of step time:
+measured in throughput and latency percentiles instead of step time:
 
 - :mod:`.kv_cache` — the paged/blocked KV cache: fixed-size blocks, a
   host-side free-list allocator, per-sequence block tables; ragged
@@ -19,7 +19,7 @@ benchmarked in throughput and latency percentiles instead of step time:
   over prompt token ids at block granularity, refcounted copy-on-write
   sharing of full prompt blocks, LRU eviction under pool pressure, and
   suffix-only prefill on a hit — bitwise-identical to a cold engine
-  (``tools/bench_prefix.py`` → ``BENCH_PREFIX.json``).  The front door
+  (``tests/test_prefix_cache.py``).  The front door
   routes by prefix affinity so shared prompts land where their blocks
   already are.
 - :mod:`.pool` — the elastic replica pool: ``runtime.Supervisor``
@@ -38,12 +38,12 @@ benchmarked in throughput and latency percentiles instead of step time:
   out) or ``--role decode`` (admit migrated blocks mid-stream), the KV
   payload rides the frame protocol as int8/f32 block-scaled tensors
   with per-tensor CRCs, and the cost planner's migration-vs-recompute
-  crossover decides per request whether the hop pays — proven by
-  ``tools/bench_disagg.py`` → ``BENCH_DISAGG.json``.
+  crossover decides per request whether the hop pays — exactly-once
+  and bitwise in ``tests/test_disagg.py``.
 
-Measured artifact: ``tools/bench_serving.py`` → ``BENCH_SERVING.json``
-(open-loop Poisson load; machine-checked floors).  Design notes and the
-honest limits: ``docs/SERVING.md``.
+Measured by the benchmark's serving cells (``BENCHMARK.json``,
+``benchmarks/run.py``; ``PERF.md`` has the numbers).  Design notes and
+the honest limits: ``docs/SERVING.md``.
 """
 
 from .batcher import (

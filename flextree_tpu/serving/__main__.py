@@ -74,7 +74,7 @@ def parse_args(argv=None) -> argparse.Namespace:
         help="fused paged-attention decode (ops/paged_attention.py): "
         "stream K/V blocks through an online softmax instead of "
         "materializing the gathered row — within a pinned tolerance of "
-        "the gather oracle (the default; see BENCH_PAGED.json). "
+        "the gather oracle (the default). "
         "--no-fused-decode keeps the gather path, which is bitwise vs "
         "generate",
     )

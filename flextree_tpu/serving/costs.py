@@ -13,8 +13,8 @@ byte-stream rate).
 The estimate is deliberately first-order: dense projection FLOPs per
 decoded token plus the attention walk's K/V byte traffic over the batch
 causal frontier (the paged pools are read once per round up to the
-frontier — exactly the quantity the fused kernel's win shrinks with,
-BENCH_PAGED.json).  It does not model dispatch overlap or sampling-host
+frontier — exactly the quantity the fused kernel's win shrinks with).
+It does not model dispatch overlap or sampling-host
 time; that is what the residual loop is FOR — drift between this
 estimate and the measured rounds is the serving-side feedback signal,
 per-phase attributable like the training residuals (compute-bound vs

@@ -238,12 +238,6 @@ class ServingEngine:
         # the bytes on the wire are a VIEW of these blocks until the
         # receiver confirms it owns a copy
         self._exported: dict = {}
-        # chaos knob (set by replica_main from FT_RPC_PREFILL_SLEEP):
-        # stretches every prefill by this many seconds PER COMPUTED
-        # PROMPT TOKEN — prefill cost scales with tokens, so the knob
-        # must too — amplifying the prefill-stall mechanism the disagg
-        # bench measures at CPU scale
-        self.chaos_prefill_sleep_s = 0.0
         self.completed: dict = {}
         self.steps = 0
         self.decode_steps = 0
@@ -625,8 +619,6 @@ class ServingEngine:
         self.pools = self._write(
             self.pools, cache, np.asarray(blocks, np.int32)
         )
-        if self.chaos_prefill_sleep_s > 0:
-            time.sleep(self.chaos_prefill_sleep_s * req.prompt_len)
         first_token = int(np.asarray(self._greedy_ids(logits))[0])
         kv = export_blocks(self.pools, blocks)
         kv = {
@@ -895,9 +887,6 @@ class ServingEngine:
             ids = self._greedy_ids(logits)
         if self.batcher.prefix_index is not None:
             self._note_prefix_admission(c > 0, t0)
-        if self.chaos_prefill_sleep_s > 0:
-            # per COMPUTED token: a prefix-cache hit only pays its suffix
-            time.sleep(self.chaos_prefill_sleep_s * (req.prompt_len - c))
         if req.temperature > 0:
             if req.seed is None:  # unreachable via submit(); guard direct use
                 raise ValueError(

@@ -212,8 +212,9 @@ class ReplicaClient:
                 loser = conn
                 conn = None
             else:
-                self.conn = conn
-                loser = None
+                # a dead connection we dial over is closed, not dropped:
+                # its reader has exited, but the socket is still open
+                loser, self.conn = self.conn, conn
             winner = self.conn
         if loser is not None:
             loser.close()

@@ -22,7 +22,7 @@ always beyond the causal bound, where ``cached_attention``'s mask drives
 the softmax weight to exactly 0.0 in f32 — so whatever the null block
 holds contributes exactly nothing, and the paged decode stays **bitwise
 identical** to the contiguous-cache decode (the property
-``tools/bench_serving.py`` machine-checks).
+``tests/test_serving.py::test_null_block_content_is_invisible`` pins).
 
 The decode step has two attention paths behind a ``fused=`` switch:
 
@@ -34,13 +34,11 @@ The decode step has two attention paths behind a ``fused=`` switch:
   ORACLE: it is the path proven bitwise against ``generate``.
 - **fused** (``fused=True``) — ``ops.paged_attention`` walks the block
   table with an online-softmax accumulator, reading K/V straight from
-  the pools and never materializing the (S, P*bs, H, Dh) view (the ~5 MB
-  of per-round copies the gather path pays at the bench config), and
+  the pools and never materializing the (S, P*bs, H, Dh) view, and
   stops at the batch's causal frontier instead of the full table width.
   Identical masking, different floating-point summation order: gated
   against the gather oracle within ``ops.paged_attention.
-  FUSED_DECODE_ATOL`` (tests + every ``tools/bench_paged.py`` rep), not
-  bitwise.
+  FUSED_DECODE_ATOL`` (``tests/test_paged_attention.py``), not bitwise.
 
 Either way all phases live in one jitted function with the pool buffers
 donated, so steady-state decode is two compiled programs total (prefill

@@ -14,8 +14,8 @@ the sequence.  This module is the wire format — the pure function pair
   ships block-scaled 8-bit at ~4x less wire, with per-element error
   bounded by ``Codec.error_bound(amax, 1, widths=(1,))`` = ``amax/127``
   for the single migration hop (one encode, one decode, no accumulation)
-  — ``tools/bench_disagg.py`` machine-checks both the bound and greedy
-  token identity against the oracle.
+  — ``tests/test_disagg.py`` checks both the bound and greedy token
+  identity against the oracle.
 - **refuse, don't guess**: the decode side verifies the whole-payload
   CRC, every per-tensor CRC, the declared geometry against its OWN model
   config, and the byte counts before a single element lands in its pool.

@@ -46,19 +46,13 @@ normal engine loop and additionally lands migrated sequences
 ``both`` (the default) is the colocated engine unchanged.  The endpoint
 file carries the role so the front door can tier its routing.
 
-Chaos knobs (env, used by ``tools/rpc_chaos.py`` and
-``tools/bench_disagg.py``; OFF by default):
+Chaos knobs (env, used by ``tools/rpc_chaos.py``; OFF by default):
 
 - ``FT_RPC_TEAR_EVERY=k`` — corrupt a byte inside every k-th response
   frame's payload (length header intact, so the stream stays aligned
   and the client's CRC check is what catches it);
 - ``FT_RPC_DECODE_SLEEP=s`` — stretch every decode round by ``s``
-  seconds, widening the window for a mid-decode SIGKILL / SIGSTOP;
-- ``FT_RPC_PREFILL_SLEEP=s`` — stretch every prefill by ``s`` seconds
-  per computed prompt token
-  (applied to ALL roles equally): scales the prefill:decode cost ratio
-  toward production shapes so the colocated prefill stall the disagg
-  bench measures is visible at tiny-model CPU scale.
+  seconds, widening the window for a mid-decode SIGKILL / SIGSTOP.
 """
 
 from __future__ import annotations
@@ -98,7 +92,6 @@ ROLES = ("both", "prefill", "decode")
 #: chaos env knobs (documented in docs/FAILURE_MODEL.md §RPC failures)
 FT_RPC_TEAR_EVERY_ENV = "FT_RPC_TEAR_EVERY"
 FT_RPC_DECODE_SLEEP_ENV = "FT_RPC_DECODE_SLEEP"
-FT_RPC_PREFILL_SLEEP_ENV = "FT_RPC_PREFILL_SLEEP"
 
 
 class ReplicaConfig:
@@ -159,11 +152,6 @@ class ReplicaServer:
         self._tear_every = int(tear) if tear else 0
         sleep = os.environ.get(FT_RPC_DECODE_SLEEP_ENV)
         self._decode_sleep = float(sleep) if sleep else 0.0
-        psleep = os.environ.get(FT_RPC_PREFILL_SLEEP_ENV)
-        if psleep:
-            # applied to EVERY role (colocated included): the knob scales
-            # the prefill:decode ratio, it must not bias the comparison
-            engine.chaos_prefill_sleep_s = float(psleep)
         # migration state — engine-thread only (like the engine itself):
         # rid -> buffered inbound KV chunks, and cached client
         # connections to decode replicas for outbound shipping

@@ -36,7 +36,6 @@ __all__ = [
     "SpanLedger",
     "span_ledger",
     "plan_capture",
-    "exposed_split",
     "Ewma",
     "step_scope",
     "debug_dump_schedule",
@@ -101,10 +100,8 @@ class SpanLedger:
     into the ledger.  Bucket-sync span names carry their payload bytes as
     a ``_{nbytes}B`` suffix (``ft_bucket*`` / ``ft_overlap_bucket*``), so
     the ledger can attribute *planned wire bytes per bucket* for a traced
-    step: the bench's exposed-vs-hidden comm split uses this to assert
-    which buckets actually fired and what they carried, next to the
-    measured step-time delta (``exposed_split``).  Host-side bookkeeping
-    only — nothing enters the traced program.
+    step: which buckets actually fired and what they carried.  Host-side
+    bookkeeping only — nothing enters the traced program.
     """
 
     def __init__(self):
@@ -180,22 +177,6 @@ def plan_capture():
         yield cap
     finally:
         _ACTIVE_PLAN_CAPTURES.remove(cap)
-
-
-def exposed_split(step_ms: float, nosync_step_ms: float, comm_total_ms: float):
-    """(exposed_ms, hidden_ms) of a train step's comm time.
-
-    ``exposed`` is the step-time delta over the sync-free twin — the sync
-    time that extended the step.  ``hidden`` is the remainder of the
-    measured sync-only time (``comm_total_ms``, the ``comm_span``-scoped
-    collectives timed alone): wire time that ran under compute instead of
-    extending the step.  Clamped at zero both ways: on a noisy host the
-    deltas can cross zero, and a negative exposure means "fully hidden",
-    not negative time.
-    """
-    exposed = max(float(step_ms) - float(nosync_step_ms), 0.0)
-    hidden = max(float(comm_total_ms) - exposed, 0.0)
-    return exposed, hidden
 
 
 @contextlib.contextmanager

@@ -12,6 +12,7 @@ missing completion gate).
 
 from __future__ import annotations
 
+import random
 import time
 from dataclasses import dataclass
 
@@ -22,7 +23,7 @@ __all__ = [
     "BenchResult",
     "time_jax_fn",
     "time_jax_fn_inplace",
-    "time_chained",
+    "time_interleaved",
     "time_device_loop",
 ]
 
@@ -132,6 +133,40 @@ def time_jax_fn_inplace(fn, x, repeat: int = 10, warmup: int = 2) -> BenchResult
     return BenchResult(tuple(times), compile_s)
 
 
+def time_interleaved(calls: dict, repeat: int) -> dict:
+    """Per-variant min/avg ms with the timed reps INTERLEAVED per round in
+    a (deterministically) shuffled order instead of back-to-back blocks: on
+    a timeshared host a sustained contention episode otherwise lands
+    entirely on one variant and swings the A/B ratio ~20% run-to-run, and
+    a FIXED round-robin order adds a position bias — each variant always
+    inherits the cache state its fixed predecessor leaves behind.
+    ``calls`` maps name -> (jitted_fn, args); every fn must already be
+    compiled/warm."""
+    order = list(calls)
+    shuffler = random.Random(0)
+    times: dict[str, list[float]] = {name: [] for name in calls}
+    for _ in range(repeat):
+        shuffler.shuffle(order)
+        for name in order:
+            fn, fargs = calls[name]
+            t = Timer()
+            jax.block_until_ready(fn(*fargs))
+            times[name].append(t.stop())
+    return {
+        name: {
+            "min_ms": min(ts) * 1e3,
+            "avg_ms": sum(ts) / len(ts) * 1e3,
+            # raw per-round samples (round i of every variant ran in the
+            # same shuffled round), so callers can form PAIRED per-round
+            # statistics — on a heavily timeshared host the min of two
+            # variants' independent draws swings far more than any
+            # per-round ratio does
+            "times_ms": [t * 1e3 for t in ts],
+        }
+        for name, ts in times.items()
+    }
+
+
 def time_device_loop(
     fn,
     x0,
@@ -199,25 +234,3 @@ def time_device_loop(
             )
         slopes.append(slope)
     return statistics.median(slopes)
-
-
-def time_chained(fn, q, *rest, n_calls: int = 10) -> float:
-    """Per-call seconds for ``fn(q, *rest)`` with each output fed back as
-    the next first argument and a final host scalar fetch.
-
-    The data-dependency chain is a completion gate no backend can fake:
-    the final fetch cannot produce bytes until every chained call has
-    executed.  Per-call dispatch cost is included (``time_device_loop``
-    cancels it).  Requires ``fn``'s output to have the shape/dtype of its
-    first argument.
-    """
-    import jax.numpy as jnp
-
-    warm = fn(q, *rest)
-    float(jnp.sum(warm.astype(jnp.float32)))  # compile + forced warmup
-    t0 = time.perf_counter()
-    acc = q
-    for _ in range(n_calls):
-        acc = fn(acc, *rest)
-    float(jnp.sum(acc.astype(jnp.float32)))
-    return (time.perf_counter() - t0) / n_calls

@@ -131,7 +131,7 @@ class TestAutotune:
         """One tiny live-backend run through the default shuffled-
         interleaved timer: compiles the candidates, returns a measured
         winner.  Small payload + 2 candidates keeps this a smoke test,
-        not a perf assertion (those live in BENCH_QUANT.json)."""
+        not a perf assertion."""
         t = autotune_plan(
             8, 1 << 12, top_k=2, repeat=2, codecs=("f32",), use_cache=False
         )

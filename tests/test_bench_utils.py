@@ -1,9 +1,7 @@
-"""Tests for the benchmark harness, timing/logging utils, the Pallas
-reduction kernel, and the bench.py driver contract."""
+"""Tests for the allreduce benchmark harness, timing/logging utils and
+the Pallas reduction kernel."""
 
 import json
-import subprocess
-import sys
 
 import numpy as np
 import pytest
@@ -162,75 +160,6 @@ class TestPallasReduce:
             reduce_stacked(jnp.ones((2, 8), jnp.float32), op="band")
 
 
-class TestBenchPyContract:
-    @pytest.mark.slow
-    def test_one_json_line(self):
-        """bench.py must print exactly one JSON line with the driver's keys
-        (the CPU A/B, asked for by name: the default mode needs a chip).
-
-        Slow-marked: the tripwire sweep bench.py grew (quantize gloo A/B,
-        serving/paged/prefix smokes, chaos matrices, rpc kill chaos) takes
-        >10 minutes on a single core — it silently outlived the old 600 s
-        subprocess budget inside the "~1-minute core subset" and timed out
-        on every default run.  CI runs it as its own bench-contract job."""
-        env = {"FLEXTREE_BENCH_PLATFORM": "cpu", "PATH": "/usr/bin:/bin"}
-        p = subprocess.run(
-            [sys.executable, "/root/repo/bench.py"],
-            capture_output=True,
-            text=True,
-            timeout=1500,
-            env=env,
-        )
-        assert p.returncode == 0, p.stderr[-500:]
-        lines = [l for l in p.stdout.strip().splitlines() if l.strip()]
-        assert len(lines) == 1, p.stdout
-        payload = json.loads(lines[0])
-        # the 4 contract keys plus the git provenance stamp (the reference's
-        # CMake git stamping, CMakeLists.txt:10-31); supplementary keys are
-        # allowed on both paths (the TPU path's honesty metrics, the CPU
-        # path's grad-bucketing rows — see bench.py)
-        assert set(payload) >= {"metric", "value", "unit", "vs_baseline", "git"}
-        assert payload["metric"] != "bench_error", payload
-        # the bucketing rows are supplementary, but their failure is not: a
-        # broken bench_grad_bucketing must trip CI, not vanish silently
-        assert "bucketing_error" not in payload, payload["bucketing_error"]
-        assert payload["value"] > 0
-
-
-def test_attention_bench_runs_on_cpu():
-    from flextree_tpu.bench.harness import (
-        AttentionBenchConfig,
-        run_attention_bench,
-    )
-
-    cfg = AttentionBenchConfig(
-        batch=1, seq_len=32, heads=2, head_dim=16, dtype="float32",
-        impl="flash", repeat=1, block_q=16, block_k=16,
-    )
-    rep = run_attention_bench(cfg)
-    assert rep.per_call_s > 0 and rep.tflops > 0
-
-    ref = run_attention_bench(
-        AttentionBenchConfig(
-            batch=1, seq_len=32, heads=2, head_dim=16, dtype="float32",
-            impl="reference", repeat=1,
-        )
-    )
-    assert ref.per_call_s > 0
-
-
-def test_attention_bench_rejects_unknown_impl():
-    import pytest
-
-    from flextree_tpu.bench.harness import (
-        AttentionBenchConfig,
-        run_attention_bench,
-    )
-
-    with pytest.raises(ValueError, match="impl"):
-        run_attention_bench(AttentionBenchConfig(impl="nope", repeat=1))
-
-
 def test_time_device_loop_measures_slope():
     """The slope protocol returns a positive per-call time that scales with
     the work, and rejects an output-shape-changing fn at trace time."""
@@ -253,28 +182,32 @@ def test_time_device_loop_measures_slope():
         time_device_loop(bad, x)
 
 
-def test_attention_bench_grad_mode():
-    from flextree_tpu.bench.harness import (
-        AttentionBenchConfig,
-        run_attention_bench,
-    )
+def test_time_interleaved_times_every_variant_once_a_round():
+    """The shuffled-interleaved timer (the planner's autotune and feedback
+    probes time their candidates with it): each round calls every variant
+    exactly once, in an order that changes between rounds, and each row
+    carries min/avg and the raw per-round samples."""
+    from flextree_tpu.utils.timing import time_interleaved
 
-    rep = run_attention_bench(
-        AttentionBenchConfig(
-            batch=1, seq_len=32, heads=2, head_dim=16, dtype="float32",
-            impl="flash", mode="grad", repeat=1, block_q=16, block_k=16,
-            timing="chained",
-        )
-    )
-    assert rep.per_call_s > 0 and rep.tflops > 0
-    assert rep.payload()["mode"] == "grad"
+    log = []
 
-    # stock grad is wired: the derived BlockSizes must
-    # carry a complete, self-consistent backward set (the stock bwd raises
-    # at trace time otherwise; the kernel itself only runs on TPU)
-    from flextree_tpu.bench.harness import stock_block_sizes
+    def variant(name):
+        def fn(x):
+            log.append(name)
+            return x + 1
 
-    bs = stock_block_sizes(1024, 512)
-    assert bs.has_backward_blocks
-    assert bs.block_k_major_dq == bs.block_k_major_dkv == 1024
-    assert stock_block_sizes(256, 512).has_backward_blocks
+        return fn, (jnp.ones(4),)
+
+    names = ["a", "b", "c", "d"]
+    repeat = 6
+    rows = time_interleaved({n: variant(n) for n in names}, repeat)
+    rounds = [log[i : i + len(names)] for i in range(0, len(log), len(names))]
+    assert len(rounds) == repeat
+    assert all(sorted(r) == names for r in rounds)
+    assert len({tuple(r) for r in rounds}) > 1  # shuffled, not round-robin
+    assert list(rows) == names
+    for row in rows.values():
+        assert set(row) == {"min_ms", "avg_ms", "times_ms"}
+        assert len(row["times_ms"]) == repeat
+        assert row["min_ms"] == min(row["times_ms"]) > 0
+        assert row["avg_ms"] == pytest.approx(sum(row["times_ms"]) / repeat)

@@ -305,13 +305,39 @@ def test_bucketed_sync_bitwise_identical_3axis_mixed_specs():
     _assert_bitwise(per_leaf, fused)
 
 
+@pytest.mark.parametrize("grad_chunks", [1, 2])
+def test_fused_train_step_bitwise_identical_to_per_leaf(grad_chunks):
+    """The sync identity carried through the whole step: forward, backward,
+    the planner-derived bucketed sync (plain and chunk-pipelined) and AdamW
+    leave every parameter with exactly the per-leaf step's bits."""
+    from flextree_tpu.models.transformer import TransformerConfig
+    from flextree_tpu.parallel.train import (
+        TrainConfig,
+        init_train_state,
+        make_train_step,
+    )
+
+    model = TransformerConfig(
+        vocab_size=64, d_model=32, n_heads=4, n_layers=2, d_ff=64
+    )
+    mesh = make_mesh_nd(8, (8, 1, 1), MESH_AXES)
+    toks = jax.random.randint(jax.random.PRNGKey(1), (8, 32), 0, 64)
+    state = init_train_state(jax.random.PRNGKey(0), model)
+    per_leaf, _ = make_train_step(
+        mesh, model, TrainConfig(grad_topo="4,2", bucket_bytes=0)
+    )(state, toks, toks)
+    fused, _ = make_train_step(
+        mesh, model, TrainConfig(grad_topo="4,2", grad_chunks=grad_chunks)
+    )(state, toks, toks)
+    _assert_bitwise(per_leaf["params"], fused["params"])
+
+
 def test_single_leaf_bucket_compiles_identically():
     """The single-large-tensor regression guard, structurally: with one
     leaf there is nothing to fuse, and the bucketed sync must compile to
     the SAME program as per-leaf (modulo op-name metadata from the
-    comm_span scopes) — so any measured fused-vs-per-leaf delta in that
-    regime (BENCH_BUCKETING.json sync_single_large) is host noise, not a
-    fusion cost."""
+    comm_span scopes) — so a fused-vs-per-leaf delta measured in that
+    regime is noise, not a fusion cost."""
     from conftest import strip_hlo_debug
 
     mesh = flat_mesh(8, "dp")

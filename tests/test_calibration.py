@@ -13,10 +13,10 @@ import pytest
 
 import jax
 
+from flextree_tpu.bench import measure_points
 from flextree_tpu.planner import (
     choose_topology,
     fit_cost_params,
-    measure_points,
     predict_us,
     spearman,
 )

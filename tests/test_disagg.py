@@ -32,8 +32,8 @@ The decisive properties, in dependency order:
   chip whose reclaim would strand prefill or decode below its tenancy
   floor.
 
-The executed real-process proof is ``tools/bench_disagg.py`` →
-``BENCH_DISAGG.json``.
+What the handoff costs and buys on the chip is not measured (ROADMAP.md
+Queue 2, W4).
 """
 
 from __future__ import annotations
@@ -237,9 +237,9 @@ class TestExportImport:
 class TestEngineMigration:
     # f32 is bitwise at every offset, unconditionally.  int8 identity is
     # workload-dependent — at this toy scale plen=13 deterministically
-    # flips one greedy near-tie, which is exactly why production gates
-    # int8 behind the per-run token-identity oracle (see
-    # tools/bench_disagg.py); the remaining offsets still cover partial,
+    # flips one greedy near-tie, which is exactly why int8 on this wire
+    # wants a token-identity check against f32 on the deployment's own
+    # traffic; the remaining offsets still cover partial,
     # exact-boundary, and mid-block-tail block counts for the codec.
     @pytest.mark.parametrize("codec,plen", [
         ("f32", 3), ("f32", 4), ("f32", 5), ("f32", 8), ("f32", 9),
@@ -500,6 +500,67 @@ class TestFrontDoorRoles:
         assert c.get("serve.shed_prefill", 0) == 0
         assert c["serve.shed_decode"] == 1
         fd.close()
+
+
+    def test_handoff_over_rpc_is_exactly_once_and_bitwise(self, model, tmp_path):
+        """The whole handoff on a real wire: a prefill-role and a
+        decode-role replica server behind the front door.  Every request
+        completes exactly once and bitwise vs ``generate``; every prompt
+        at or past the threshold migrated or is a counted fallback, no
+        shorter one migrated, and ``serve.migrations`` agrees with the
+        per-result ``migrated`` flags."""
+        from flextree_tpu.serving.replica_main import (
+            ReplicaConfig,
+            ReplicaServer,
+        )
+
+        cfg, params = model
+        servers = [
+            ReplicaServer(
+                _engine(params, cfg),
+                ReplicaConfig(rank, str(tmp_path), role=role),
+            ).start()
+            for rank, role in enumerate(("prefill", "decode"))
+        ]
+        fd = self._fd(
+            tmp_path, dispatchers=2, max_hedges=0, request_timeout_s=90.0,
+            attempt_timeout_s=60.0,
+        ).start()
+        rng = np.random.default_rng(29)
+        prompts = {
+            rid: _prompt(rng, t) for rid, t in enumerate((3, 9, 4, 13, 6))
+        }
+        long_rids = {r for r, p in prompts.items() if len(p) >= 5}
+        pre = servers[0].engine
+        try:
+            for rid, p in prompts.items():
+                assert fd.submit(rid, p, 6)
+            assert fd.wait_idle(timeout_s=120.0)
+            assert fd.failed == {}
+            assert sorted(fd.completed) == sorted(prompts)
+            for rid, p in prompts.items():
+                want = np.asarray(
+                    generate(params, jnp.asarray(p)[None], cfg,
+                             max_new_tokens=6, max_len=_pcfg().max_len)
+                )[0]
+                np.testing.assert_array_equal(fd.completed[rid].tokens, want)
+            c = dict(fd.metrics.snapshot()["counters"])
+            migrated = {r for r, res in fd.completed.items() if res.migrated}
+            assert migrated and migrated <= long_rids
+            assert len(migrated) + c.get("serve.migration_fallback", 0) >= len(
+                long_rids
+            )
+            assert c.get("serve.migrations", 0) == len(migrated)
+            assert c.get("serve.duplicate_results", 0) == 0
+            # the prefill side let every exported block go on the ack
+            assert pre.metrics.counter("serve.migration_acked").value == len(
+                migrated
+            )
+        finally:
+            fd.close()
+            for srv in servers:
+                srv.stop()
+        assert pre.batcher.allocator.num_free == _pcfg().num_blocks - 1
 
 
 # ------------------------------------------------- timeline flow rendering

@@ -161,8 +161,7 @@ def test_ragged_decode_matches_per_row_contiguous():
 
 
 def test_prefill_ragged_matches_per_row_generate():
-    """Right-padded batched prefill + ragged decode == each row alone:
-    the static-batching baseline in tools/bench_serving.py leans on this."""
+    """Right-padded batched prefill + ragged decode == each row alone."""
     from flextree_tpu.models.generate import prefill_ragged
 
     cfg, params, tokens = _setup(t=12)

@@ -479,7 +479,7 @@ def test_overlapped_program_differs_and_has_no_barrier():
 
 
 def test_span_ledger_records_overlap_buckets():
-    from flextree_tpu.utils.profiling import exposed_split, span_ledger
+    from flextree_tpu.utils.profiling import span_ledger
 
     mesh = make_mesh_nd(8, (8, 1, 1), ("dp", "sp", "tp"))
     tc = TrainConfig(overlap=True)
@@ -498,12 +498,6 @@ def test_span_ledger_records_overlap_buckets():
         l.size * 4 for l in jax.tree.leaves(state_sds["params"])
     )
     assert total == expect
-    # the split helper: exposed+hidden partition the comm total
-    exp, hid = exposed_split(12.0, 10.0, 5.0)
-    assert exp == pytest.approx(2.0)
-    assert hid == pytest.approx(3.0)
-    exp, hid = exposed_split(9.0, 10.0, 5.0)  # noisy negative -> clamped
-    assert exp == 0.0 and hid == 5.0
 
 
 def test_autotune_cache_never_aliases_overlap_and_serial(tmp_path):

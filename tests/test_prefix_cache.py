@@ -376,19 +376,27 @@ def test_prefill_suffix_rejects_empty_suffix_and_overflow(model):
 # --------------------------------------------------------- the warm engine
 
 
-def test_warm_engine_bitwise_equals_cold_and_generate(model):
+@pytest.mark.parametrize("shared", [True, False], ids=["shared", "unique"])
+def test_warm_engine_bitwise_equals_cold_and_generate(model, shared):
     """The certification oracle: a shared-system-prompt workload through
     a warm-index engine produces BITWISE the tokens of a cold engine and
     of contiguous generate — and the warm engine actually hit (including
-    one COW full-prompt hit) and drains every block."""
+    one COW full-prompt hit) and drains every block.  The negative
+    control: prompts that share nothing are as bitwise and as leak-free,
+    and the index invents no sharing — no hit, no fork, no token saved."""
     cfg, params = model
     pcfg = _pcfg()
     rng = np.random.default_rng(0)
     sysp = _prompt(rng, 32)  # 4 full blocks at block_size 8
     suffixes = [5, 9, 3, 10, 0, 7]  # 0: the bare prompt — the COW case
     reqs = [
-        Request(rid=i, prompt=np.concatenate([sysp, _prompt(rng, k)]),
-                max_new_tokens=6)
+        Request(
+            rid=i,
+            prompt=np.concatenate(
+                [sysp if shared else _prompt(rng, 32), _prompt(rng, k)]
+            ),
+            max_new_tokens=6,
+        )
         for i, k in enumerate(suffixes)
     ]
 
@@ -408,10 +416,15 @@ def test_warm_engine_bitwise_equals_cold_and_generate(model):
         np.testing.assert_array_equal(warm.completed[r.rid].tokens, want)
         np.testing.assert_array_equal(cold.completed[r.rid].tokens, want)
     snap = warm.report()
-    assert snap["counters"]["serve.prefix_hits"] >= 1
-    assert snap["counters"]["serve.prefix_cow"] >= 1
-    assert snap["counters"]["serve.cached_tokens_saved"] >= 32
-    assert 0.0 < snap["gauges"]["serve.prefix_hit_rate"] <= 1.0
+    if shared:
+        assert snap["counters"]["serve.prefix_hits"] >= 1
+        assert snap["counters"]["serve.prefix_cow"] >= 1
+        assert snap["counters"]["serve.cached_tokens_saved"] >= 32
+        assert 0.0 < snap["gauges"]["serve.prefix_hit_rate"] <= 1.0
+    else:
+        for name in ("prefix_hits", "prefix_cow", "cached_tokens_saved"):
+            assert snap["counters"].get(f"serve.{name}", 0) == 0
+        assert snap["gauges"]["serve.prefix_hit_rate"] == 0.0
     # no leaked blocks: dropping the index's references drains the pool
     warm.batcher.prefix_index.check()
     assert warm.release_prefix_cache() > 0

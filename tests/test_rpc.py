@@ -276,6 +276,20 @@ def _server(params, dir, rank=0, **rkw):
     return srv.start()
 
 
+def _wait_dispatched(fd, rank, timeout_s) -> bool:
+    """True once the front door has an attempt outstanding on ``rank``."""
+    deadline = time.monotonic() + timeout_s
+    while time.monotonic() < deadline:
+        with fd._lock:
+            client = fd.clients.get(rank)
+        if client is not None:
+            with client._lock:
+                if client.outstanding > 0:
+                    return True
+        time.sleep(0.005)
+    return False
+
+
 def _dial(srv) -> RpcConnection:
     return RpcConnection.connect("127.0.0.1", srv.port, timeout_s=2.0)
 
@@ -720,9 +734,9 @@ class TestFrontDoor:
         try:
             for rid, p in prompts.items():
                 assert fd.submit(rid, p, 4)
-            # yank one replica once work is flowing: its connections die
-            # and the front door re-routes to the survivor
-            time.sleep(0.2)
+            # yank one replica once it holds work in flight: its
+            # connections die and the front door re-routes to the survivor
+            assert _wait_dispatched(fd, rank=1, timeout_s=60.0)
             srv1.stop()
             assert fd.wait_idle(timeout_s=90.0)
             assert fd.failed == {}

@@ -321,6 +321,18 @@ class TestShardedStep:
         cons = make_consolidate_fn(mesh, pspecs, layout, None, False)(states["sh"])
         assert _leaves_bytes(cons["mu"]) == _leaves_bytes(states["rep"]["mu"])
         assert _leaves_bytes(cons["nu"]) == _leaves_bytes(states["rep"]["nu"])
+        # and is not duplicated: a rank holds ~1/dp of the moment bytes the
+        # replicated layout holds (tails stay replicated, so a hair above)
+        def rank_bytes(state):
+            return sum(
+                leaf.addressable_shards[0].data.nbytes
+                for key, sub in state.items() if key.startswith(("mu", "nu"))
+                for leaf in jax.tree.leaves(sub)
+            )
+
+        ratio = rank_bytes(states["sh"]) / rank_bytes(states["rep"])
+        dp = mesh.shape["dp"]
+        assert 1.0 / dp - 0.02 <= ratio <= 1.0 / dp + 0.10
         # reshard is the exact inverse: consolidate ∘ reshard is a fixed point
         resh = make_reshard_fn(mesh, pspecs, layout, None, False)(cons)
         cons2 = make_consolidate_fn(mesh, pspecs, layout, None, False)(resh)

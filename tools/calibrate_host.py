@@ -5,7 +5,7 @@ Two sections:
 
 - ``cpu``: the 4 cost-model constants fitted on the 8-virtual-device CPU
   mesh (``fit_cost_params`` over measured (topology, size) points — the
-  same calibrate-then-trust protocol bench.py and the sweep use).  These
+  same calibrate-then-trust protocol tools/sweep_allreduce.py uses).  These
   are the constants the planner should use when ranking topologies for
   *this host's* virtual meshes.
 - ``tpu_v5e`` (only when a TPU is reachable): ``reduce_bw_GBps`` measured
@@ -44,11 +44,8 @@ def cpu_section(out: str) -> None:
 
     jax.config.update("jax_platforms", "cpu")
     jax.config.update("jax_num_cpu_devices", 8)
-    from flextree_tpu.planner import (
-        fit_cost_params,
-        measure_points,
-        save_calibration,
-    )
+    from flextree_tpu.bench import measure_points
+    from flextree_tpu.planner import fit_cost_params, save_calibration
 
     topos = ["8", "4,2", "2,2,2", "2,4", "1"]
     sizes = [1 << 14, 1 << 17, 1 << 20]

@@ -11,8 +11,7 @@ real jitted train steps, real flight records:
 2. **Deliberate mis-calibration**: write a CALIBRATION whose α-β skew
    (near-zero launch/latency, starved bandwidth) drives
    ``choose_bucket_bytes`` to a provably different argmin — tiny
-   per-leaf-scale buckets instead of the oracle's fused ones (the ~1.2×
-   train-step regression BENCH_BUCKETING measured) — and build the
+   per-leaf-scale buckets instead of the oracle's fused ones — and build the
    mis-calibrated step from it.  The tool REFUSES the scenario if the two
    plans coincide (nothing would be proven).
 3. **The feedback run**: ``fit(supervision=Supervision(feedback=...))``
@@ -116,7 +115,7 @@ def main(argv=None) -> int:
 
     import tempfile
 
-    from flextree_tpu.bench.harness import _interleaved_times
+    from flextree_tpu.bench import measure_points
     from flextree_tpu.data import LMDataset, synthetic_tokens
     from flextree_tpu.models.transformer import TransformerConfig
     from flextree_tpu.obs import flight_recorder
@@ -139,7 +138,6 @@ def main(argv=None) -> int:
         autotune_plan,
         choose_topology,
         fit_cost_params,
-        measure_points,
         save_calibration,
     )
     from flextree_tpu.planner.choose import choose_bucket_bytes
@@ -150,6 +148,7 @@ def main(argv=None) -> int:
     )
     from flextree_tpu.schedule.stages import Topology
     from flextree_tpu.utils.buildstamp import artifact_meta
+    from flextree_tpu.utils.timing import time_interleaved
 
     smoke = args.smoke
     n = 8
@@ -221,8 +220,7 @@ def main(argv=None) -> int:
         # ---- 2. deliberate mis-calibration -----------------------------
         # near-zero fixed costs + starved bandwidth: the byte term
         # dominates every fixed term, so choose_bucket_bytes' argmin runs
-        # to k_max — per-leaf-scale buckets, the regime BENCH_BUCKETING
-        # measured ~1.2x slower end-to-end than the fused plan
+        # to k_max — per-leaf-scale buckets
         skew_params = TpuCostParams(
             ici=LinkParams(bandwidth_GBps=0.01, latency_us=0.001),
             dcn=LinkParams(bandwidth_GBps=0.01, latency_us=0.001),
@@ -407,7 +405,7 @@ def main(argv=None) -> int:
 
         # ---- 5. paired timing: oracle vs miscal vs recovered -----------
         print("== phase 5: paired step timing (oracle / miscal / recovered)")
-        rows = _interleaved_times(
+        rows = time_interleaved(
             {
                 "oracle": (step_oracle, (state, toks, tgts)),
                 "miscal": (step_miscal, (state, toks, tgts)),

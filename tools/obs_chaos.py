@@ -31,9 +31,7 @@ the floors (non-zero exit on any violation):
 2. the survivor's dump exists with the shrink context;
 3. the merged timeline is loadable Chrome-trace JSON containing the
    killed rank's track, the survivor's shrink marker, and
-   provenance-annotated bucket spans;
-4. recorder overhead on the train-step bench <= 2% (same
-   shuffled-interleaved min-of-reps protocol as the supervised row).
+   provenance-annotated bucket spans.
 
 Artifacts: ``OBS_CHAOS.json`` (checks + floors) and ``OBS_TIMELINE.json``
 (the merged timeline itself — open it at https://ui.perfetto.dev).
@@ -62,8 +60,6 @@ HB_INTERVAL = 0.2
 STRAGGLER_S = 0.8
 LEASE_S = 2.0
 STEP_SLEEP = 0.1
-
-OVERHEAD_BUDGET = 1.02  # recorder-on / recorder-off train step
 
 
 # --------------------------------------------------------------------------
@@ -338,46 +334,12 @@ def run_kill_scenario(workdir: str) -> dict:
     }
 
 
-def run_overhead_bench(repeat: int) -> dict:
-    """Recorder-on vs recorder-off fused train step, <= 2% floor."""
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
-    jax.config.update("jax_num_cpu_devices", 8)
-    from flextree_tpu.bench.harness import (
-        TrainStepBenchConfig,
-        run_train_step_bench,
-    )
-
-    out = run_train_step_bench(
-        TrainStepBenchConfig(repeat=repeat, supervised=False, recorder=True)
-    )
-    overhead = out["rows"]["ours_fused_recorded"]["recorder_overhead"]
-    return {
-        "ok": overhead <= OVERHEAD_BUDGET,
-        "recorder_overhead": round(overhead, 4),
-        "budget": OVERHEAD_BUDGET,
-        "rows": {
-            name: {k: round(v, 3) for k, v in row.items()}
-            for name, row in out["rows"].items()
-            if name in ("ours_fused", "ours_fused_recorded")
-        },
-    }
-
-
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--child", action="store_true")
     ap.add_argument("--out", default=os.path.join(REPO, "OBS_CHAOS.json"))
     ap.add_argument(
         "--timeline-out", default=os.path.join(REPO, "OBS_TIMELINE.json")
-    )
-    ap.add_argument(
-        "--repeat", type=int, default=24,
-        help="train-step bench reps for the overhead floor: the recorder "
-        "adds ~40 us to a ~50 ms step, but on a timeshared 1-core host "
-        "min-of-few swings far past the 2%% budget — min-of-many is what "
-        "makes the floor a recorder check instead of a host-noise check",
     )
     ap.add_argument("--no-artifact", action="store_true")
     args = ap.parse_args(argv)
@@ -400,19 +362,8 @@ def main(argv=None) -> int:
         flush=True,
     )
 
-    print("=== recorder overhead bench ===", flush=True)
-    try:
-        overhead = run_overhead_bench(args.repeat)
-    except Exception as e:
-        overhead = {"ok": False, "error": f"{type(e).__name__}: {e}"}
-    print(
-        f"overhead: {'OK' if overhead['ok'] else 'FAILED'} "
-        + json.dumps({k: v for k, v in overhead.items() if k != "rows"}),
-        flush=True,
-    )
-
     timeline = scenario.pop("timeline", None)
-    ok = scenario["ok"] and overhead["ok"]
+    ok = scenario["ok"]
     if not args.no_artifact:
         from flextree_tpu.obs import write_trace
         from flextree_tpu.utils.buildstamp import artifact_meta
@@ -431,8 +382,7 @@ def main(argv=None) -> int:
                                "schema-valid Chrome-trace timeline (killed "
                                "rank's final events, survivor's shrink + "
                                "guaranteed dump, provenance-annotated bucket "
-                               "spans), plus the recorder-overhead budget — "
-                               "see docs/OBSERVABILITY.md",
+                               "spans) — see docs/OBSERVABILITY.md",
                 "build": artifact_meta(),
                 "ok": ok,
                 "budgets": {
@@ -440,10 +390,8 @@ def main(argv=None) -> int:
                     "straggler_s": STRAGGLER_S,
                     "lease_s": LEASE_S,
                     "step_sleep_s": STEP_SLEEP,
-                    "recorder_overhead_budget": OVERHEAD_BUDGET,
                 },
                 "scenario": scenario,
-                "overhead": overhead,
                 "timeline_artifact": os.path.basename(args.timeline_out),
             },
         )

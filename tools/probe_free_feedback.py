@@ -103,6 +103,62 @@ def _calibration_env(path: str):
                 os.environ[key] = val
 
 
+def make_nosync_train_step(mesh, model_cfg, train_cfg, axis_names=("dp", "sp", "tp")):
+    """The sync-free twin of ``make_train_step``: identical forward,
+    backward and AdamW, gradient sync elided — NOT a training step (the
+    replicas would diverge) but the compute floor: it runs zero
+    collectives on the gradients, so timing it keeps the scenario
+    probe-free on the wire."""
+    import jax
+    from jax import lax
+    from jax.sharding import PartitionSpec as P
+
+    from flextree_tpu.models.transformer import cross_entropy_loss, forward
+    from flextree_tpu.parallel.train import (
+        adamw_apply,
+        maybe_clip_grads,
+        metric_specs,
+        state_specs,
+        validate_tp,
+    )
+
+    dp, sp, tp = axis_names
+    validate_tp(model_cfg, mesh.shape[tp])
+    sspecs = state_specs(model_cfg, tp, train_cfg)
+    data_spec = P(dp, sp)
+
+    def device_step(state, tokens, targets):
+        n_total_tokens = (
+            tokens.size
+            * lax.axis_size(dp)
+            * lax.axis_size(sp)
+            * lax.axis_size(tp)
+        )
+
+        def local_loss(params):
+            logits = forward(params, tokens, model_cfg, tp_axis=tp, sp_axis=sp)
+            loss_sum, _ = cross_entropy_loss(logits, targets)
+            return loss_sum / n_total_tokens
+
+        loss, grads = jax.value_and_grad(local_loss)(state["params"])
+        global_loss = lax.psum(lax.psum(lax.psum(loss, dp), sp), tp)
+        metrics = {"loss": global_loss}
+        # clip compute stays (compute parity with the real step — only
+        # the SYNC is elided), and it also keeps the metrics pytree
+        # matching metric_specs when clipping is configured
+        grads = maybe_clip_grads(grads, sspecs["params"], train_cfg, metrics)
+        new_state = adamw_apply(state, grads, train_cfg)
+        return new_state, metrics
+
+    mspec = metric_specs(train_cfg, {"loss": P()})
+    return jax.jit(
+        jax.shard_map(
+            device_step, mesh=mesh, in_specs=(sspecs, data_spec, data_spec),
+            out_specs=(sspecs, mspec), check_vma=False,
+        )
+    )
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--out", default=os.path.join(REPO, "OBS_ATTRIBUTION.json"))
@@ -123,10 +179,7 @@ def main(argv=None) -> int:
 
     import numpy as np  # noqa: F401 (assertions below)
 
-    from flextree_tpu.bench.harness import (
-        _interleaved_times,
-        make_nosync_train_step,
-    )
+    from flextree_tpu.bench import measure_points
     from flextree_tpu.data import LMDataset, synthetic_tokens
     from flextree_tpu.models.transformer import TransformerConfig
     from flextree_tpu.obs import flight_recorder
@@ -152,7 +205,6 @@ def main(argv=None) -> int:
         autotune_plan,
         choose_topology,
         fit_cost_params,
-        measure_points,
         save_calibration,
     )
     from flextree_tpu.planner.choose import choose_bucket_bytes
@@ -164,6 +216,7 @@ def main(argv=None) -> int:
     from flextree_tpu.schedule.stages import Topology
     from flextree_tpu.utils.buildstamp import artifact_meta
     from flextree_tpu.utils.profiling import span_ledger
+    from flextree_tpu.utils.timing import time_interleaved
 
     smoke = args.smoke
     n = 8
@@ -581,7 +634,7 @@ def main(argv=None) -> int:
 
         # ---- 6. paired timing: oracle / miscal / recovered -------------
         print("== phase 7: paired step timing (oracle / miscal / recovered)")
-        rows = _interleaved_times(
+        rows = time_interleaved(
             {
                 "oracle": (step_oracle, (state, toks, tgts)),
                 "miscal": (step_miscal, (state, toks, tgts)),
@@ -668,7 +721,7 @@ def main(argv=None) -> int:
                 span_ctl.observe_step(ov_step["i"], time.perf_counter() - t0)
                 return out
 
-            ov_rows = _interleaved_times(
+            ov_rows = time_interleaved(
                 {
                     "plain": (plain_step, (state, toks, tgts)),
                     "spanclock": (clocked_step, (state, toks, tgts)),

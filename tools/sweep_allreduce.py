@@ -2,11 +2,13 @@
 """Allreduce A/B sweep over the BASELINE.md config matrix.
 
 Runs every BASELINE.md config (4/8/16/64/60 ranks) as a virtual-CPU-device
-mesh A/B — FlexTree topologies vs ``lax.psum`` — and writes the committed
-evidence file ``BENCH_ALLREDUCE.json``.  This is the rebuild of the
-reference's per-run result files workflow (``benchmark.cpp:193-213``): the
-reference wrote one ``{tag}.{N}.{size}.{topo}...txt`` per run and committed
-none; we commit the aggregate.
+mesh A/B — FlexTree topologies vs ``lax.psum`` — and writes
+``BENCH_ALLREDUCE.json``.  This is the rebuild of the reference's per-run
+result files workflow (``benchmark.cpp:193-213``): the reference wrote one
+``{tag}.{N}.{size}.{topo}...txt`` per run; this writes the aggregate.  It
+is a CPU measurement of the schedules' control flow, not of the chip: the
+file is git-ignored, and the collective's speed is what ``chip_smoke.py``
+and the benchmark's four-chip cell read.
 
 Each rank count runs in a subprocess because ``jax_num_cpu_devices`` must be
 set before the backend initializes.  Timing protocol: in-place chained loop
@@ -116,14 +118,13 @@ def child_main(cfg: dict) -> None:
     import logging
 
     logging.disable(logging.INFO)
-    from flextree_tpu.bench.harness import BenchConfig, run_allreduce_bench
-    from flextree_tpu.planner import choose_topology, fit_cost_params, measure_points
+    from flextree_tpu.bench import BenchConfig, measure_points, run_allreduce_bench
+    from flextree_tpu.planner import choose_topology, fit_cost_params
 
     n = int(cfg["ranks"])
     # calibrate the cost model on THIS host before asking the planner —
     # the r02 sweep ranked with the invented v5e defaults, so its "planner"
-    # row predicted ICI behavior on a 1-core host;
-    # bench.py already follows this calibrate-then-trust protocol
+    # row predicted ICI behavior on a 1-core host
     cal_params = None
     if "planner" in cfg["topos"]:
         cal_topos = [t for t in cfg["topos"] if t != "planner"]
@@ -223,7 +224,7 @@ def main() -> int:
             "case: WINS.md ('Why the single-host benchmark cannot show "
             "this') and tests/test_planner_wins.py. The 'planner' rows "
             "here use host-calibrated cost params (fit_cost_params on "
-            "small measured points), matching bench.py's protocol.",
+            "small measured points).",
         "elapsed_s": None,  # filled below
         "results": all_rows,
     }
