@@ -459,8 +459,7 @@ def prefill(params, tokens, cfg: LagunaConfig, max_len: int):
 
 
 def paged_decode_step(params, pools, tables, lengths, tokens,
-                      cfg: LagunaConfig, fused: bool = False,
-                      impl: str = "jnp"):
+                      cfg: LagunaConfig, fused: bool = False):
     """One decode step for S slots over the paged pool: the counterpart
     of ``serving.kv_cache.paged_decode_step`` (same arguments, same pool
     layout, ``n_kv_heads`` wide).  A window layer attends only through
@@ -477,7 +476,6 @@ def paged_decode_step(params, pools, tables, lengths, tokens,
     off = lengths % bs
     active = lengths > 0
     attend = paged_attention if fused else paged_attention_gather
-    kwargs = {"impl": impl} if fused else {}
     x = _embed(params, tokens[:, None], cfg)
     new_k, new_v, moes = [], [], []
     for i, (layer, pk, pv) in enumerate(
@@ -489,7 +487,6 @@ def paged_decode_step(params, pools, tables, lengths, tokens,
             attn = attend(
                 q[:, 0], k[:, 0], v[:, 0], pk, pv, tables, lengths,
                 window=cfg.window if cfg.layer_types[i] == WINDOW else None,
-                **kwargs,
             )[:, None]
             x = _attention_output(layer, x, attn, gate)
             new_k.append(pk.at[blk, off].set(k[:, 0]))

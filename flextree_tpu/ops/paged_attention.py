@@ -13,21 +13,28 @@ each K/V block from the pool exactly once and stopping at the batch's
 causal frontier — blocks past ``max(lengths)`` are never touched, where
 the gather path always paid for the full table width.
 
-Two implementations, one contract:
+Two paths, one contract, and :func:`paged_attention` picks between them
+from what it can observe (the backend it compiles for, and the shapes):
 
-- ``impl="jnp"`` — a pure-JAX block-streaming twin: a ``fori_loop`` whose
-  trip count is the *runtime* block frontier walks ``block_chunk`` table
-  columns per step, batched over all S slots.  This is the production
-  path on the CPU backend.  ``block_chunk=1`` measured fastest there
-  (1.5x over the gather round at the bench config's mid-run lengths —
-  wider chunks gather more masked positions back in and lost the win);
-  the knob exists because the trade flips on hardware where fewer,
-  larger contractions beat tighter masking.
-- ``impl="pallas"`` — a Pallas kernel, one grid step per slot, same
-  accumulation order.  It runs under the Pallas interpreter on the CPU
-  only, where it validates the kernel's numerics.  It CANNOT lower for a
-  TPU as written (see :func:`require_runnable`), so on a TPU it is
-  refused with one sentence; rewriting it is ROADMAP S6.
+- the Pallas kernel (``_decode_kernel``), on a TPU at shapes Mosaic's
+  tiling admits (:func:`kernel_admits`).  The block tables and lengths
+  are scalar-prefetched into SMEM, the pools stay in HBM, and every slot
+  walks ITS OWN live blocks (for a window layer those that meet the
+  window; none for an empty slot), each brought in once by its own DMA,
+  three chunks in flight across slot boundaries.  On the v5e it reads
+  the dense cell's live K/V at 725 GB/s and the Laguna cell's at 370 to
+  470 (PERF.md §6, PR 31; ROADMAP S1).
+- the ``fori_loop`` (``_stream_jnp``), everywhere else: the CPU backend
+  (tier 1, the chaos drivers, an RPC replica on the CPU) and shapes the
+  kernel refuses.  Its trip count is the *runtime* block frontier of the
+  LONGEST slot, ``block_chunk`` table columns a step, batched over all S
+  slots.  ``block_chunk=1`` measured fastest on the CPU (1.5x over the
+  gather round at the old CPU bench's mid-run lengths — wider chunks
+  gather more masked positions back in); that is a statement about the
+  CPU and not about the chip, where the sweep at the Laguna cell's
+  shapes reads 1.45, 0.95, 0.73, 0.63 ms a layer at 1, 2, 4, 8 (and is
+  flat at the dense cell's: 0.94, 0.95, 0.92, 0.96), so on a TPU the
+  loop derives its chunk from the shapes.
 
 ``paged_attention_gather`` is the retained gather-materialize oracle —
 the exact computation the historical decode step ran, and the thing
@@ -42,7 +49,9 @@ Masking mirrors ``models.generate.cached_attention``: pool positions at
 or past a row's ``length`` are driven to ``-1e30`` *before* the running
 max and their probabilities zeroed after it, so whatever an unwritten or
 null-block position holds — including deliberately poisoned values —
-contributes exactly ``0.0`` to the f32 accumulator.  The new token's K/V
+contributes exactly ``0.0`` to the f32 accumulator (the kernel never
+brings such a block in at all, and zeroes its buffers once, so that a
+masked weight of 0.0 cannot meet a NaN left in VMEM).  The new token's K/V
 (position ``length``, which the gather path spliced into the view) is
 folded as a final always-visible online-softmax step instead.
 """
@@ -53,16 +62,19 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from ..utils.backend import pallas_interpret
+from ..utils import backend
 
 __all__ = [
     "FUSED_DECODE_ATOL",
+    "kernel_admits",
     "paged_attention",
     "paged_attention_gather",
-    "require_runnable",
+    "runs_kernel",
 ]
 
 _NEG_INF = -1e30
@@ -237,95 +249,275 @@ def _stream_jnp(q, k_new, v_new, k_pool, v_pool, tables, lengths, scale,
 
 # ------------------------------------------------------------ pallas kernel
 
+#: Rows of K (or V) one compute step of the kernel holds: a chunk of
+#: ``_CHUNK_ROWS // (bs * Hkv)`` blocks, 256 KB of bf16 at D = 128 in
+#: either cell (2 dense blocks of 16 x 32 rows, 8 Laguna blocks of 16 x 8).
+#: A step is a serial chain (product, softmax, product, rescale), so it
+#: has to be long enough to hide under its own DMAs.
+_CHUNK_ROWS = 1024
+#: Chunks of K and of V in VMEM at once; all but the one being read are
+#: in flight.  1.5 MB in flight covers the HBM's bandwidth x latency.
+_CHUNKS_IN_VMEM = 4
 
-def _paged_kernel(q_ref, kn_ref, vn_ref, tab_ref, len_ref, kp_ref, vp_ref,
-                  o_ref, *, bs: int, scale: float):
-    """One grid step = one slot: walk the row's block table with the
-    online-softmax accumulator, then fold the new token's K/V.  Same
-    accumulation order as ``_stream_jnp`` at ``block_chunk=1``."""
-    q = q_ref[0]  # (H, D) native dtype — the score matmul stays native
+
+def _kernel_chunk(bs: int, hkv: int, p: int) -> int:
+    """Blocks a compute step of the kernel takes."""
+    return max(1, min(_CHUNK_ROWS // (bs * hkv), p))
+
+
+def kernel_admits(q, k_pool) -> bool:
+    """Whether Mosaic's tiling takes these shapes: a head dimension that
+    fills the 128 lanes, and blocks of whole tiles whose scores fill the
+    lanes too, a whole number of them a chunk.  Anything else (the CPU
+    tests' toy head dimensions) walks the table in ``_stream_jnp``."""
+    bs, hkv, d = k_pool.shape[1:]
+    rows = bs * hkv
+    return (
+        d % 128 == 0
+        and bs % 8 == 0
+        and rows % 128 == 0
+        and _CHUNK_ROWS % rows == 0
+        and q.shape[1] % 8 == 0
+        and k_pool.dtype in (jnp.bfloat16, jnp.float32)
+        and q.dtype == k_pool.dtype
+    )
+
+
+def _weighted_values(pr, v):
+    """``pr @ v`` with f32 weights over the pool's values, f32 out.  A
+    bf16 pool goes through the MXU in its own type: the weights are split
+    into three bf16 parts that sum to the f32 value exactly, stacked, and
+    multiplied in one pass (the parts' products are exact in f32, so this
+    is the f32 product, not an approximation of it)."""
+    if v.dtype == jnp.float32:
+        return jnp.dot(pr, v, preferred_element_type=jnp.float32,
+                       precision=lax.Precision.HIGHEST)
+    h = pr.shape[0]
+    parts, rest = [], pr
+    for _ in range(3):
+        part = rest.astype(v.dtype).astype(jnp.float32)
+        parts.append(part)
+        rest = rest - part
+    out = jnp.dot(jnp.concatenate(parts, axis=0).astype(v.dtype), v,
+                  preferred_element_type=jnp.float32)
+    return out[:h] + out[h:2 * h] + out[2 * h:]
+
+
+def _decode_kernel(len_ref, tab_ref, q_ref, kn_ref, vn_ref, match_ref,
+                   colpos_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sems, st,
+                   *, bs, cb, p, n_slots, window, scale, grouped):
+    """One grid step = one slot.  The slot walks ITS OWN live blocks (for
+    a window layer those that meet the window; none for an empty slot),
+    ``cb`` a compute step, each block brought from the pool in HBM by its
+    own DMA, and folds them into the online softmax; then the new token.
+
+    The DMAs run ahead of the compute ACROSS slots: ``st`` (SMEM) holds
+    the walk's issue cursor (slot, column, chunks issued) and the count
+    of chunks consumed, so the first chunk of a slot is already in VMEM
+    when its grid step starts.
+
+    A chunk is ``cb * bs * Hkv`` rows ``(position, K/V head)`` of K and
+    of V, as the pool holds them.  Every query head is multiplied with
+    every row (one MXU product, ``(H, D) x (rows, D)``), and ``match``
+    keeps, for query head ``j``, the rows of K/V head ``j // (H // Hkv)``;
+    the other products are masked like a position past ``length``.  The
+    pool's layout puts a position's heads side by side, and the MXU is
+    idle in a decode round: wasting its products costs less than moving
+    the rows.
+    """
+    s = pl.program_id(0)
+    nbuf = kbuf.shape[0]
+    rows = k_hbm.shape[1]  # of one block
+    f32 = jnp.float32
+
+    def walk(slot):
+        """First and end column of the slot's walk."""
+        length = len_ref[slot]
+        end = (length + (bs - 1)) // bs
+        if window is None:
+            return jnp.int32(0), end
+        return jnp.maximum(length - (window - 1), 0) // bs, end
+
+    def copies(blk, b, j):
+        dst = pl.ds(j * rows, rows)
+        return (
+            pltpu.make_async_copy(k_hbm.at[blk], kbuf.at[b, dst], sems.at[0, b]),
+            pltpu.make_async_copy(v_hbm.at[blk], vbuf.at[b, dst], sems.at[1, b]),
+        )
+
+    def issue():
+        """Start the DMAs of the walk's next chunk, if one is left."""
+        last = n_slots - 1
+
+        def exhausted(c):
+            slot, col = c
+            return (slot < n_slots) & (col >= walk(jnp.minimum(slot, last))[1])
+
+        def next_slot(c):
+            slot = c[0] + 1
+            return slot, walk(jnp.minimum(slot, last))[0]
+
+        slot, col = lax.while_loop(exhausted, next_slot, (st[0], st[1]))
+        st[0] = slot
+        st[1] = col
+
+        @pl.when(slot < n_slots)
+        def _():
+            end = walk(slot)[1]
+            b = lax.rem(st[2], jnp.int32(nbuf))
+            for j in range(cb):
+                @pl.when(col + j < end)
+                def _():
+                    for c in copies(tab_ref[slot * p + col + j], b, j):
+                        c.start()
+            st[1] = col + cb
+            st[2] = st[2] + 1
+
+    @pl.when(s == 0)
+    def _():
+        # a chunk's tail past the slot's last block is never written by
+        # a DMA: it holds an earlier chunk's rows, or these zeros, so a
+        # masked weight of 0.0 never meets a NaN
+        kbuf[...] = jnp.zeros_like(kbuf)
+        vbuf[...] = jnp.zeros_like(vbuf)
+        st[0] = 0
+        st[1] = walk(0)[0]
+        st[2] = 0
+        st[3] = 0
+        for _ in range(nbuf - 1):
+            issue()
+
+    length = len_ref[s]
+    first, end = walk(s)
+    n_steps = (end - first + (cb - 1)) // cb
+    done = st[3]
+    q = q_ref[0]  # (H, D)
     h, d = q.shape
-    length = len_ref[0]
-    nb = (length + bs - 1) // bs  # blocks holding positions < length
+    match = match_ref[...] != 0  # (H, cb * rows)
+    colpos = colpos_ref[...]  # (1, cb * rows): position inside the chunk
+    precision = lax.Precision.HIGHEST if q.dtype == f32 else None
 
-    m0 = jnp.full((h, 1), _NEG_INF, jnp.float32)
-    l0 = jnp.zeros((h, 1), jnp.float32)
-    acc0 = jnp.zeros((h, d), jnp.float32)
-
-    def body(p_i, carry):
+    def step(i, carry):
         m, l, acc = carry
-        blk = tab_ref[0, p_i]
-        kb = kp_ref[blk]  # (bs, H, D)
-        vb = vp_ref[blk]
-        sc = jnp.einsum("hd,bhd->hb", q, kb).astype(jnp.float32) * scale
-        kpos = p_i * bs + jnp.arange(bs)
-        valid = (kpos < length)[None, :]  # (1, bs)
-        sc = jnp.where(valid, sc, _NEG_INF)
+        # into the buffer the step before this one read
+        issue()
+        b = lax.rem(done + i, jnp.int32(nbuf))
+        col0 = first + i * cb
+        for j in range(cb):
+            @pl.when(col0 + j < end)
+            def _():
+                for c in copies(0, b, j):
+                    c.wait()
+        sc = lax.dot_general(
+            q, kbuf[b], (((1,), (1,)), ((), ())),
+            preferred_element_type=f32, precision=precision,
+        )  # (H, cb * rows)
+        if not grouped:
+            # the dense model rounds its scores to the compute type, as
+            # cached_attention does
+            sc = sc.astype(q.dtype).astype(f32)
+        kpos = col0 * bs + colpos
+        seen = kpos < length
+        if window is not None:
+            seen = seen & (kpos > length - window)
+        valid = match & seen
+        sc = jnp.where(valid, sc * scale, _NEG_INF)
         m_new = jnp.maximum(m, sc.max(axis=-1, keepdims=True))
         pr = jnp.where(valid, jnp.exp(sc - m_new), 0.0)
         corr = jnp.exp(m - m_new)
         l = l * corr + pr.sum(axis=-1, keepdims=True)
-        acc = acc * corr + jnp.einsum(
-            "hb,bhd->hd", pr, vb.astype(jnp.float32)
-        )
+        acc = acc * corr + _weighted_values(pr, vbuf[b])
         return m_new, l, acc
 
-    m, l, acc = lax.fori_loop(0, nb, body, (m0, l0, acc0))
+    m, l, acc = lax.fori_loop(
+        jnp.int32(0), n_steps, step,
+        (jnp.full((h, 1), _NEG_INF, f32), jnp.zeros((h, 1), f32),
+         jnp.zeros((h, d), f32)),
+    )
+    st[3] = done + n_steps
 
-    kn = kn_ref[0]
-    vn = vn_ref[0]
-    s_new = jnp.einsum("hd,hd->h", q, kn)[:, None].astype(jnp.float32) * scale
+    # the new token's K/V, already one row a query head
+    s_new = (q.astype(f32) * kn_ref[0].astype(f32)).sum(axis=-1, keepdims=True)
+    if not grouped:
+        s_new = s_new.astype(q.dtype).astype(f32)
+    s_new = s_new * scale
     m_fin = jnp.maximum(m, s_new)
     p_new = jnp.exp(s_new - m_fin)
     corr = jnp.exp(m - m_fin)
     l = l * corr + p_new
-    acc = acc * corr + p_new * vn.astype(jnp.float32)
+    acc = acc * corr + p_new * vn_ref[0].astype(f32)
     o_ref[0] = (acc / l).astype(o_ref.dtype)
 
 
-def require_runnable(impl: str, interpret: bool | None = None) -> None:
-    """Raise unless ``impl`` can run where this process runs.
-
-    Mosaic refuses the ``"pallas"`` kernel at lowering, whatever the
-    shape: the ``(1, P)`` block on the ``(S, P)`` int32 table breaks the
-    (8, 128) tiling rule, the whole K/V pool is one VMEM block, and the
-    table and lengths are read as scalars out of VMEM instead of SMEM.
-    Saying so here, once, beats a lowering dump from inside the first
-    decode round; nothing swaps in the ``jnp`` path on the caller's
-    behalf.
-    """
-    if impl not in ("jnp", "pallas"):
-        raise ValueError(f"unknown paged-attention impl {impl!r}")
-    if impl == "pallas" and not pallas_interpret(interpret):
-        raise NotImplementedError(
-            "paged attention impl='pallas' cannot lower for TPU (its block "
-            "shapes break Mosaic's (8, 128) rule and it holds the whole K/V "
-            "pool in VMEM); use impl='jnp' on a TPU."
-        )
-
-
-def _stream_pallas(q, k_new, v_new, k_pool, v_pool, tables, lengths, scale,
-                   interpret):
+# jitted so that the layers of a decode program that share their shapes
+# share ONE traced kernel and one Mosaic lowering (a function the program
+# calls eight times), not eight: lowering is paid by every process before
+# the persistent cache is asked, 0.6 s a kernel in the dense cell's set-up
+@functools.partial(
+    jax.jit, static_argnames=("scale", "window", "cb", "nbuf", "interpret")
+)
+def _stream_kernel(q, k_new, v_new, k_pool, v_pool, tables, lengths, *,
+                   scale, window, cb, nbuf, interpret):
+    """``cb`` blocks a compute step, ``nbuf`` chunks of K and of V in
+    VMEM."""
     s, h, d = q.shape
-    n, bs = k_pool.shape[:2]
+    n, bs, hkv = k_pool.shape[:3]
     p = tables.shape[1]
+    g = h // hkv
+    rows = bs * hkv
+    if g > 1:
+        k_new = jnp.repeat(k_new, g, axis=1)
+        v_new = jnp.repeat(v_new, g, axis=1)
+    # the chunk's rows are (position, K/V head), a position's heads side
+    # by side: which query heads a row serves, and its position
+    row = np.arange(cb * rows)
+    match = (row[None, :] % hkv == (np.arange(h) // g)[:, None])
+    colpos = (row // hkv)[None, :]
+    slot_block = pl.BlockSpec((1, h, d), lambda i, *_: (i, 0, 0))
+    whole = lambda *shape: pl.BlockSpec(shape, lambda i, *_: (0,) * len(shape))  # noqa: E731
     return pl.pallas_call(
-        functools.partial(_paged_kernel, bs=bs, scale=scale),
+        functools.partial(
+            _decode_kernel, bs=bs, cb=cb, p=p, n_slots=s, window=window,
+            scale=scale, grouped=g > 1,
+        ),
         out_shape=jax.ShapeDtypeStruct((s, h, d), q.dtype),
-        grid=(s,),
-        in_specs=[
-            pl.BlockSpec((1, h, d), lambda i: (i, 0, 0)),   # q row
-            pl.BlockSpec((1, h, d), lambda i: (i, 0, 0)),   # new k
-            pl.BlockSpec((1, h, d), lambda i: (i, 0, 0)),   # new v
-            pl.BlockSpec((1, p), lambda i: (i, 0)),         # table row
-            pl.BlockSpec((1,), lambda i: (i,)),             # length
-            pl.BlockSpec((n, bs, h, d), lambda i: (0, 0, 0, 0)),  # k pool
-            pl.BlockSpec((n, bs, h, d), lambda i: (0, 0, 0, 0)),  # v pool
-        ],
-        out_specs=pl.BlockSpec((1, h, d), lambda i: (i, 0, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(s,),
+            in_specs=[
+                slot_block, slot_block, slot_block,
+                whole(h, cb * rows), whole(1, cb * rows),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=slot_block,
+            scratch_shapes=[
+                pltpu.VMEM((nbuf, cb * rows, d), k_pool.dtype),
+                pltpu.VMEM((nbuf, cb * rows, d), v_pool.dtype),
+                pltpu.SemaphoreType.DMA((2, nbuf)),
+                pltpu.SMEM((4,), jnp.int32),
+            ],
+        ),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+        ),
+        name="paged_decode_attention",
         interpret=interpret,
-    )(q, k_new, v_new, tables.astype(jnp.int32), lengths.astype(jnp.int32),
-      k_pool, v_pool)
+    )(
+        lengths, tables.reshape(-1), q, k_new, v_new,
+        jnp.asarray(match, jnp.int32), jnp.asarray(colpos, jnp.int32),
+        k_pool.reshape(n, rows, d), v_pool.reshape(n, rows, d),
+    )
+
+
+# ------------------------------------------------------------------- entry
+
+
+def runs_kernel(q, k_pool) -> bool:
+    """Whether :func:`paged_attention` takes the kernel for these shapes
+    where this process compiles for: on a TPU, at shapes the kernel's
+    tiling admits.  ``q`` / ``k_pool`` need a shape and a dtype only."""
+    return backend.kernel_platform() == "tpu" and kernel_admits(q, k_pool)
 
 
 def paged_attention(
@@ -338,9 +530,9 @@ def paged_attention(
     lengths,
     *,
     scale: float | None = None,
-    impl: str = "jnp",
+    impl: str | None = None,
     interpret: bool | None = None,
-    block_chunk: int = 1,
+    block_chunk: int | None = None,
     window: int | None = None,
 ):
     """Fused paged decode attention for one token per slot.
@@ -351,7 +543,7 @@ def paged_attention(
     head ``j`` reads K/V head ``j // (H // Hkv)``; the dense model has
     ``Hkv == H``).  ``window``: each row sees only positions ``length -
     window + 1 .. length``, and walks only the table columns that hold
-    them (``impl="jnp"``).
+    them.
     ``k_pool`` / ``v_pool``: (N, bs, Hkv, D) per-layer pools; ``tables``:
     (S, P) int32 block ids; ``lengths``: (S,) int32 cache positions
     already written per row, each ``< P*bs`` (a row AT the table's
@@ -362,24 +554,34 @@ def paged_attention(
     position ``length``, equal to :func:`paged_attention_gather` within
     :data:`FUSED_DECODE_ATOL` (summation order is the only difference).
 
-    ``impl="jnp"`` is the batched block-streaming path (``block_chunk``
-    table columns per loop step); ``impl="pallas"`` runs the kernel under
-    the interpreter on the CPU and is refused on a TPU
-    (:func:`require_runnable`).
+    Which path runs is this function's to decide, from what it can
+    observe (:func:`runs_kernel`): the kernel on a TPU at shapes its
+    tiling admits, the ``fori_loop`` everywhere else.  ``impl`` is the
+    tests' way to force one (``"pallas"``: the kernel, under the
+    interpreter on the CPU; ``"jnp"``: the loop, ``block_chunk`` table
+    columns a step), not a choice of speed.  ``block_chunk=None`` is
+    derived: 1 on the CPU, and on a TPU, where the loop is left with the
+    shapes the kernel refuses, the kernel's own chunk (the sweep's winner
+    at the Laguna cell's shapes, 8; flat at the dense cell's).
     """
-    require_runnable(impl, interpret)
+    if impl not in (None, "jnp", "pallas"):
+        raise ValueError(f"unknown paged-attention impl {impl!r}")
     _check_shapes(q, k_new, v_new, k_pool, v_pool, tables, lengths)
     if scale is None:
         scale = 1.0 / (q.shape[-1] ** 0.5)
     tables = jnp.asarray(tables, jnp.int32)
     lengths = jnp.asarray(lengths, jnp.int32)
-    if impl == "jnp":
-        return _stream_jnp(q, k_new, v_new, k_pool, v_pool, tables, lengths,
-                           float(scale), block_chunk, window)
-    if window is not None or k_pool.shape[2] != q.shape[1]:
-        raise NotImplementedError(
-            "paged attention impl='pallas' has neither grouped queries nor "
-            "a window; use impl='jnp'"
+    if impl == "pallas" or (impl is None and runs_kernel(q, k_pool)):
+        return _stream_kernel(
+            q, k_new, v_new, k_pool, v_pool, tables, lengths,
+            scale=float(scale), window=window,
+            cb=_kernel_chunk(*k_pool.shape[1:3], tables.shape[1]),
+            nbuf=_CHUNKS_IN_VMEM,
+            interpret=backend.pallas_interpret(interpret),
         )
-    return _stream_pallas(q, k_new, v_new, k_pool, v_pool, tables, lengths,
-                          float(scale), pallas_interpret(interpret))
+    if block_chunk is None:
+        block_chunk = 1
+        if backend.kernel_platform() == "tpu":
+            block_chunk = _kernel_chunk(*k_pool.shape[1:3], tables.shape[1])
+    return _stream_jnp(q, k_new, v_new, k_pool, v_pool, tables, lengths,
+                       float(scale), block_chunk, window)

@@ -80,9 +80,9 @@ def parse_args(argv=None) -> argparse.Namespace:
     )
     ap.add_argument(
         "--decode-impl", choices=["jnp", "pallas"], default="jnp",
-        help="fused-path implementation: the batched block-streaming jnp "
-        "twin (default) or the Pallas kernel, which runs interpreted on "
-        "the CPU only and is refused on a TPU (it cannot lower there)",
+        help="accepted and checked, selects nothing: the fused path picks "
+        "the Pallas kernel (a TPU, shapes its tiling admits) or the "
+        "block-streaming loop (anywhere else) from what it observes",
     )
     ap.add_argument(
         "--admission", choices=["reserve", "ondemand"], default="reserve",

@@ -63,6 +63,7 @@ from .batcher import BatcherConfig, ContinuousBatcher, Request, SeqState
 from .kv_cache import (
     CacheExhausted,
     PagedCacheConfig,
+    decode_attention_layers,
     export_blocks,
     gather_seq,
     init_pools,
@@ -162,6 +163,8 @@ class ServingEngine:
         self.pcfg = pcfg
         self.bcfg = bcfg or BatcherConfig()
         self.fused = bool(fused)
+        # accepted and checked (make_paged_decode_fn), selects nothing:
+        # ops.paged_attention picks its path from the backend and shapes
         self.decode_impl = decode_impl
         # the engine's accounting lives in a metrics registry (shareable —
         # the replica pool passes one per replica so its report is a view
@@ -196,6 +199,12 @@ class ServingEngine:
         # device with one shape, the case JAX's donation matching needs
         self._decode = make_paged_decode_fn(
             cfg, donate=True, fused=self.fused, impl=decode_impl
+        )
+        # how often the kernel engages: attention layers in the decode
+        # program and those of them that run the Pallas kernel, fixed
+        # when the program is built (ft.engine.decode_dispatch, report())
+        self.attn_layers, self.attn_kernel_layers = decode_attention_layers(
+            cfg, pcfg, self.fused
         )
         self._prefill = jax.jit(
             lambda p, tok: prefill(p, tok, cfg, max_len=pcfg.max_len)
@@ -323,7 +332,11 @@ class ServingEngine:
                 with span("ft.batcher.batch_arrays"):
                     tables, lengths, tokens, _ = self.batcher.batch_arrays()
                 t_dec = _now()
-                with span("ft.engine.decode_dispatch"):
+                with span(
+                    "ft.engine.decode_dispatch",
+                    attn_layers=self.attn_layers,
+                    attn_kernel_layers=self.attn_kernel_layers,
+                ):
                     # a model with routed experts hands out a third
                     # result, what its routers did this round
                     logits, self.pools, *routed = self._decode(
@@ -1003,6 +1016,8 @@ class ServingEngine:
             "steps": self.steps,
             "decode_steps": self.decode_steps,
             "completed": len(self.completed),
+            "attn_layers": self.attn_layers,
+            "attn_kernel_layers": self.attn_kernel_layers,
             **self.metrics.snapshot(),
         }
 

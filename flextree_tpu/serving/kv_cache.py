@@ -58,7 +58,7 @@ from ..models.generate import _qkv
 from ..ops.paged_attention import (
     paged_attention,
     paged_attention_gather,
-    require_runnable,
+    runs_kernel,
 )
 from ..models.transformer import (
     TransformerConfig,
@@ -79,6 +79,7 @@ __all__ = [
     "write_swapped",
     "paged_decode_step",
     "make_paged_decode_fn",
+    "decode_attention_layers",
     "gather_seq",
     "export_blocks",
     "write_imported",
@@ -347,6 +348,35 @@ def write_swapped(pools: dict, kv: dict, block_ids) -> dict:
     return {"k": out_k, "v": out_v}
 
 
+def check_decode_impl(impl: str) -> None:
+    """``decode_impl`` once chose between the loop and a kernel that could
+    not lower; the choice is ``ops.paged_attention``'s now.  The key is
+    still accepted wherever it was, so it is still checked."""
+    if impl not in ("jnp", "pallas"):
+        raise ValueError(f"unknown paged-attention impl {impl!r}")
+
+
+def decode_attention_layers(cfg, pcfg: PagedCacheConfig,
+                            fused: bool = True) -> tuple:
+    """``(attention layers in the decode program, those of them that run
+    the Pallas kernel)``, as :func:`paged_decode_step` will build them
+    for this configuration in this process: fixed by the backend and the
+    shapes, so known before the program is traced."""
+    heads = getattr(cfg, "layer_heads", None) or (cfg.n_heads,) * cfg.n_layers
+    if not fused:
+        return len(heads), 0
+    pool = jax.ShapeDtypeStruct(
+        (pcfg.num_blocks, pcfg.block_size, cfg.n_kv_heads, cfg.head_dim),
+        cfg.dtype,
+    )
+    return len(heads), sum(
+        runs_kernel(
+            jax.ShapeDtypeStruct((1, h, cfg.head_dim), cfg.dtype), pool
+        )
+        for h in heads
+    )
+
+
 def paged_decode_step(params, pools, tables, lengths, tokens,
                       cfg: TransformerConfig, fused: bool = False,
                       impl: str = "jnp"):
@@ -369,19 +399,24 @@ def paged_decode_step(params, pools, tables, lengths, tokens,
     ``fused=False`` attends through ``ops.paged_attention_gather`` — the
     gathered view has the same (S, P*bs) key length the contiguous cache
     would, which plus exact-zero masking is the whole bitwise-identity
-    argument.  ``fused=True`` attends through ``ops.paged_attention``
-    (``impl=`` "jnp" block-streaming or "pallas"): same masking, online-
-    softmax summation order, within ``FUSED_DECODE_ATOL`` of the oracle.
+    argument.  ``fused=True`` attends through ``ops.paged_attention``:
+    same masking, online-softmax summation order, within
+    ``FUSED_DECODE_ATOL`` of the oracle.  Which of its two paths runs (the
+    Pallas kernel on a TPU, the block-streaming loop elsewhere) is
+    ``paged_attention``'s to decide from the backend and the shapes;
+    ``impl`` ("jnp" or "pallas") is accepted, checked and passed nowhere:
+    the benchmark's traffic files still carry the key (ROADMAP D3).
 
     A configuration of another block than the dense one brings its own
     walk behind these arguments (``models.laguna.paged_decode_step``),
     which hands out a third result, what its routers did.
     """
+    check_decode_impl(impl)
     if not isinstance(cfg, TransformerConfig):
         from ..models import laguna
 
         return laguna.paged_decode_step(
-            params, pools, tables, lengths, tokens, cfg, fused, impl
+            params, pools, tables, lengths, tokens, cfg, fused
         )
     s = tokens.shape[0]
     positions = lengths[:, None].astype(jnp.int32)  # (S, 1) per-sequence
@@ -390,7 +425,6 @@ def paged_decode_step(params, pools, tables, lengths, tokens,
     blk = tables[row, lengths // bs]  # (S,) current block per slot
     off = lengths % bs
     attend = paged_attention if fused else paged_attention_gather
-    kwargs = {"impl": impl} if fused else {}
     x = params["embed"][tokens[:, None]].astype(cfg.dtype)
     new_k, new_v = [], []
     for layer, pk, pv in zip(params["layers"], pools["k"], pools["v"]):
@@ -399,7 +433,7 @@ def paged_decode_step(params, pools, tables, lengths, tokens,
         q = apply_rope(q, positions, cfg.rope_theta)
         k = apply_rope(k, positions, cfg.rope_theta)
         attn = attend(
-            q[:, 0], k[:, 0], v[:, 0], pk, pv, tables, lengths, **kwargs
+            q[:, 0], k[:, 0], v[:, 0], pk, pv, tables, lengths
         )[:, None]
         o = attn.reshape(s, 1, -1) @ layer["wo"].astype(cfg.dtype)
         x = x + o
@@ -415,13 +449,12 @@ def make_paged_decode_fn(cfg: TransformerConfig, donate: bool = True,
                          fused: bool = False, impl: str = "jnp"):
     """Jit ``paged_decode_step`` with the pool buffers donated (the old
     pool is dead the moment the new one exists — donation keeps steady-
-    state decode allocation-free).  ``fused=``/``impl=`` select the
-    attention path (see :func:`paged_decode_step`); an ``impl`` that
-    cannot run on this backend is refused here, at construction."""
-    if fused:
-        require_runnable(impl)
+    state decode allocation-free).  ``fused=`` selects the attention
+    path (see :func:`paged_decode_step`); ``impl`` is checked here, at
+    construction, and selects nothing."""
+    check_decode_impl(impl)
     return jax.jit(
-        partial(paged_decode_step, cfg=cfg, fused=fused, impl=impl),
+        partial(paged_decode_step, cfg=cfg, fused=fused),
         donate_argnums=(1,) if donate else (),
     )
 

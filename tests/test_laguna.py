@@ -253,10 +253,15 @@ def test_a_window_layer_reads_only_the_blocks_that_meet_its_window():
     assert np.array_equal(np.asarray(got), np.asarray(again))
 
 
-def test_pallas_decode_refuses_what_it_lacks():
-    q, kn, vn, kp, vp, tables, lens = _paged_case((3, 5))
-    with pytest.raises(NotImplementedError):
-        paged_attention(q, kn, vn, kp, vp, tables, lens, impl="pallas")
+@pytest.mark.parametrize("window", [8, None])
+def test_pallas_decode_takes_grouped_queries_and_a_window(window):
+    """What the old kernel refused in one sentence, the new one computes
+    (here under the interpreter): three queries a K/V head, a window."""
+    q, kn, vn, kp, vp, tables, lens = _paged_case((21, 8, 0, 16, 27))
+    want = paged_attention_gather(q, kn, vn, kp, vp, tables, lens, window=window)
+    got = paged_attention(q, kn, vn, kp, vp, tables, lens, window=window,
+                          impl="pallas")
+    assert float(jnp.abs(got - want).max()) <= FUSED_DECODE_ATOL
 
 
 # ----------------------------------------------------------------- rotary

@@ -1,7 +1,9 @@
 """Fused paged-attention decode vs the gather-materialize oracle.
 
 The contract (``ops/paged_attention.py``): the block-streaming paths —
-pure-JAX twin and Pallas kernel — attend over exactly the positions the
+the ``fori_loop`` and the Pallas kernel, here under the interpreter (the
+chip's compiler is asked in ``tests/test_tpu_aot.py``) — attend over
+exactly the positions the
 gather path attends over (pool positions ``< length`` plus the new
 token at ``length``), differing only in floating-point summation order
 (online softmax folds block by block; the oracle reduces the whole
@@ -18,24 +20,33 @@ gathered row at once).  So:
   scatters (the scatter is shared code, only attention differs).
 """
 
+from functools import partial
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from flextree_tpu.models.transformer import TransformerConfig, init_params
+from flextree_tpu.ops import paged_attention as paged_attention_mod
 from flextree_tpu.ops.paged_attention import (
     FUSED_DECODE_ATOL,
+    kernel_admits,
     paged_attention,
     paged_attention_gather,
+    runs_kernel,
 )
+from flextree_tpu.serving import kv_cache
 from flextree_tpu.serving.kv_cache import (
     NULL_BLOCK,
     BlockAllocator,
     PagedCacheConfig,
+    decode_attention_layers,
     init_pools,
+    make_paged_decode_fn,
     paged_decode_step,
 )
+from flextree_tpu.utils import backend
 
 S, H, D, N, BS, P = 5, 4, 16, 32, 8, 7
 #: ragged mix: empty row, short, block-aligned, mid-block, near-full
@@ -148,6 +159,91 @@ def test_jnp_and_pallas_agree():
                                atol=FUSED_DECODE_ATOL, rtol=0)
 
 
+def _grouped_case(lengths, g, hkv=2, d=16, bs=8, p=7, seed=3, chain=None):
+    """Grouped queries (``g`` a K/V head) over a pool whose blocks are
+    dealt out of order; ``chain``: rows whose table is full to the last
+    column, whatever their length."""
+    rng = np.random.default_rng(seed)
+    s = len(lengths)
+    f = lambda *shape: jnp.asarray(  # noqa: E731
+        rng.standard_normal(shape), jnp.float32)
+    n = 1 + s * p
+    free = rng.permutation(np.arange(1, n)).tolist()
+    tables = np.zeros((s, p), np.int32)
+    for i, length in enumerate(lengths):
+        if length == 0 and i not in (chain or ()):
+            continue  # an empty slot: a null row
+        need = p if i in (chain or ()) else length // bs + 1
+        tables[i, :need] = [free.pop() for _ in range(need)]
+    return (f(s, g * hkv, d), f(s, hkv, d), f(s, hkv, d), f(n, bs, hkv, d),
+            f(n, bs, hkv, d), jnp.asarray(tables),
+            jnp.asarray(lengths, jnp.int32))
+
+
+#: empty slots first, in the middle and last; lengths on (8, 16, 48) and
+#: off (3, 17, 41) a block boundary; one slot in the table's last block
+RAGGED = (0, 3, 8, 0, 17, 41, 16, 48, 55, 0)
+
+
+@pytest.mark.parametrize("window", [None, 5, 24, 200],
+                         ids=["full", "w5", "w24", "wider-than-any-row"])
+@pytest.mark.parametrize("g", [1, 6, 9])
+def test_kernel_grouped_queries_and_windows(g, window):
+    """What the old kernel refused: 6 and 9 queries a K/V head, a window
+    narrower and wider than the longest row, empty slots, a slot at the
+    table's last block, lengths on and off a block boundary, in one
+    batch; every slot walks its own blocks."""
+    args = _grouped_case(RAGGED, g)
+    ref = paged_attention_gather(*args, window=window)
+    out = paged_attention(*args, impl="pallas", window=window)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               atol=FUSED_DECODE_ATOL, rtol=0)
+    loop = paged_attention(*args, impl="jnp", window=window)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(loop),
+                               atol=FUSED_DECODE_ATOL, rtol=0)
+
+
+@pytest.mark.parametrize("window", [None, 12])
+def test_kernel_reads_only_its_own_live_blocks_bitwise(window):
+    """No slot pays for another: blocks a slot holds past its length, the
+    blocks behind its window, the null block and every block of the pool
+    that no table names can hold anything — the output does not move by
+    a bit, because the kernel never brings them in."""
+    lengths = (0, 3, 8, 17, 41, 30)
+    args = _grouped_case(lengths, 3, chain=(0, 2, 3))
+    q, kn, vn, kp, vp, tables, lens = args
+    tab = np.asarray(tables)
+    live = set()
+    for i, length in enumerate(lengths):
+        first = 0 if window is None else max(length - (window - 1), 0) // 8
+        live |= set(tab[i, first:-(-length // 8)].tolist())
+    dead = np.array(sorted(set(range(kp.shape[0])) - live))
+    assert NULL_BLOCK in dead and len(dead) > len(live)
+    a = paged_attention(*args, impl="pallas", window=window)
+    b = paged_attention(q, kn, vn, kp.at[dead].set(1e30),
+                        vp.at[dead].set(jnp.nan), tables, lens,
+                        impl="pallas", window=window)
+    np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    np.testing.assert_allclose(
+        np.asarray(a), np.asarray(paged_attention_gather(*args, window=window)),
+        atol=FUSED_DECODE_ATOL, rtol=0)
+
+
+def test_kernel_chunks_several_blocks_a_step(monkeypatch):
+    """The cells' shapes put 2 and 8 blocks in a compute step and keep 4
+    chunks in VMEM; the toy shapes put the whole row in one.  Shrink the
+    chunk so that a row is several steps with a ragged last one, and the
+    ring of buffers wraps inside a row and across rows."""
+    args = _grouped_case(RAGGED, 6)
+    ref = paged_attention_gather(*args, window=24)
+    for rows, nbuf in ((32, 2), (48, 3), (16, 4)):
+        monkeypatch.setattr(paged_attention_mod, "_CHUNK_ROWS", rows)
+        monkeypatch.setattr(paged_attention_mod, "_CHUNKS_IN_VMEM", nbuf)
+        out = paged_attention(*args, impl="pallas", window=24)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   atol=FUSED_DECODE_ATOL, rtol=0)
+
+
 def test_shape_validation_is_loud():
     q, kn, vn, kp, vp, tables, lengths = _inputs()
     with pytest.raises(ValueError, match="queries"):
@@ -192,11 +288,15 @@ def _decode_state(cfg, pcfg, lengths, seed=4):
     return pools, jnp.asarray(tables), jnp.asarray(lengths, jnp.int32), tokens
 
 
-def test_decode_step_fused_vs_gather(model):
+@pytest.mark.parametrize("impl", ["jnp", "pallas"])
+def test_decode_step_fused_vs_gather(model, impl, monkeypatch):
     """Logits within tolerance; layer 0's pool scatter is BITWISE (its
     K/V depend only on the embedding, before any attention differs) and
     deeper layers' scatters inherit the attention tolerance through the
-    residual stream."""
+    residual stream.  The step no longer hands ``impl`` down, so the
+    kernel's case forces it where the step looks the entry up."""
+    monkeypatch.setattr(kv_cache, "paged_attention",
+                        partial(paged_attention, impl=impl))
     cfg, params = model
     pcfg = PagedCacheConfig(num_blocks=24, block_size=8, blocks_per_seq=6)
     pools, tables, lengths, tokens = _decode_state(
@@ -205,29 +305,28 @@ def test_decode_step_fused_vs_gather(model):
     ref_logits, ref_pools = paged_decode_step(
         params, pools, tables, lengths, tokens, cfg, fused=False
     )
-    for impl in ("jnp", "pallas"):
-        logits, out_pools = paged_decode_step(
-            params, pools, tables, lengths, tokens, cfg, fused=True, impl=impl
+    logits, out_pools = paged_decode_step(
+        params, pools, tables, lengths, tokens, cfg, fused=True
+    )
+    np.testing.assert_allclose(
+        np.asarray(logits), np.asarray(ref_logits),
+        atol=FUSED_DECODE_ATOL * 10, rtol=0,
+    )  # logits pass through 2 more matmul layers than the attention out
+    np.testing.assert_array_equal(
+        np.asarray(out_pools["k"][0]), np.asarray(ref_pools["k"][0])
+    )
+    np.testing.assert_array_equal(
+        np.asarray(out_pools["v"][0]), np.asarray(ref_pools["v"][0])
+    )
+    for l in range(1, cfg.n_layers):
+        np.testing.assert_allclose(
+            np.asarray(out_pools["k"][l]), np.asarray(ref_pools["k"][l]),
+            atol=FUSED_DECODE_ATOL, rtol=0,
         )
         np.testing.assert_allclose(
-            np.asarray(logits), np.asarray(ref_logits),
-            atol=FUSED_DECODE_ATOL * 10, rtol=0,
-        )  # logits pass through 2 more matmul layers than the attention out
-        np.testing.assert_array_equal(
-            np.asarray(out_pools["k"][0]), np.asarray(ref_pools["k"][0])
+            np.asarray(out_pools["v"][l]), np.asarray(ref_pools["v"][l]),
+            atol=FUSED_DECODE_ATOL, rtol=0,
         )
-        np.testing.assert_array_equal(
-            np.asarray(out_pools["v"][0]), np.asarray(ref_pools["v"][0])
-        )
-        for l in range(1, cfg.n_layers):
-            np.testing.assert_allclose(
-                np.asarray(out_pools["k"][l]), np.asarray(ref_pools["k"][l]),
-                atol=FUSED_DECODE_ATOL, rtol=0,
-            )
-            np.testing.assert_allclose(
-                np.asarray(out_pools["v"][l]), np.asarray(ref_pools["v"][l]),
-                atol=FUSED_DECODE_ATOL, rtol=0,
-            )
 
 
 def test_decode_step_fused_greedy_tokens_match_oracle(model):
@@ -249,3 +348,102 @@ def test_decode_step_fused_greedy_tokens_match_oracle(model):
         np.argmax(np.asarray(logits), axis=-1),
         np.argmax(np.asarray(ref_logits), axis=-1),
     )
+
+
+# ------------------------------------------------- who decides which path runs
+
+
+#: the dense cell's decode shapes at two layers: S32, 32 heads of 128,
+#: blocks of 16, 48 a row, bf16 (traced from shapes, never run)
+CELL = TransformerConfig(
+    vocab_size=512, d_model=4096, n_heads=32, n_layers=2, d_ff=256,
+    dtype=jnp.bfloat16,
+)
+CELL_POOL = PagedCacheConfig(num_blocks=97, block_size=16, blocks_per_seq=48)
+
+
+def _decode_avals(cfg, pcfg, slots=32):
+    params = jax.eval_shape(lambda k: init_params(k, cfg), jax.random.PRNGKey(0))
+    pools = jax.eval_shape(lambda: init_pools(cfg, pcfg))
+    tables = jax.ShapeDtypeStruct((slots, pcfg.blocks_per_seq), jnp.int32)
+    row = jax.ShapeDtypeStruct((slots,), jnp.int32)
+    return params, pools, tables, row, row
+
+
+def _kernels_in(cfg, pcfg):
+    """Calls of the kernel in the traced decode program.  Layers of one
+    shape share ONE traced ``pallas_call`` (the jitted wrapper: one
+    Mosaic lowering a process, not one a layer)."""
+    text = str(make_paged_decode_fn(cfg, donate=False, fused=True).trace(
+        *_decode_avals(cfg, pcfg)
+    ).jaxpr)
+    calls = text.count("jit[name=_stream_kernel")
+    assert text.count("pallas_call") == min(calls, 1)
+    return calls
+
+
+def test_on_the_cpu_every_layer_walks_the_table_in_the_loop(model):
+    """The observable is the backend and the shape: the CPU backend keeps
+    the ``fori_loop`` even at the cell's shapes, the counter says 0, and
+    the compiled decode program holds no kernel."""
+    assert backend.kernel_platform() == "cpu"
+    assert decode_attention_layers(CELL, CELL_POOL) == (2, 0)
+    assert _kernels_in(CELL, CELL_POOL) == 0
+    cfg, _ = model
+    toy = PagedCacheConfig(num_blocks=24, block_size=8, blocks_per_seq=6)
+    assert decode_attention_layers(cfg, toy) == (cfg.n_layers, 0)
+    # the gather path has attention layers and no fused walk at all
+    assert decode_attention_layers(CELL, CELL_POOL, fused=False) == (2, 0)
+
+
+def test_on_a_tpu_the_eligible_shapes_take_the_kernel_in_every_layer(
+    model, monkeypatch
+):
+    """``kernel_platform`` patched to "tpu", trace only: the counter and
+    the traced program agree, layer for layer; a head dimension that does
+    not fill the lanes stays in the loop without a raise."""
+    monkeypatch.setattr(backend, "kernel_platform", lambda: "tpu")
+    assert decode_attention_layers(CELL, CELL_POOL) == (2, 2)
+    assert _kernels_in(CELL, CELL_POOL) == 2
+    cfg, _ = model  # heads of 8: nothing Mosaic's tiling takes
+    toy = PagedCacheConfig(num_blocks=24, block_size=8, blocks_per_seq=6)
+    assert decode_attention_layers(cfg, toy) == (cfg.n_layers, 0)
+    assert _kernels_in(cfg, toy) == 0
+
+
+@pytest.mark.parametrize("shape,dtype,admitted", [
+    ((16, 32, 128), jnp.bfloat16, True),   # the dense cell
+    ((16, 8, 128), jnp.bfloat16, True),    # the Laguna cell
+    ((16, 32, 128), jnp.float32, True),
+    ((16, 32, 64), jnp.bfloat16, False),   # half the lanes
+    ((4, 32, 128), jnp.bfloat16, False),   # a block of 4 positions
+    ((8, 8, 128), jnp.bfloat16, False),    # a block of 64 rows: half a tile of scores
+    ((16, 128, 128), jnp.bfloat16, False), # a block past the chunk
+], ids=["dense", "laguna", "f32", "d64", "bs4", "rows64", "rows2048"])
+def test_kernel_admits_what_its_tiling_takes(shape, dtype, admitted, monkeypatch):
+    pool = jax.ShapeDtypeStruct((9, *shape), dtype)
+    q = jax.ShapeDtypeStruct((2, 2 * shape[1] if shape[1] == 8 else shape[1],
+                              shape[2]), dtype)
+    assert kernel_admits(q, pool) is admitted
+    assert runs_kernel(q, pool) is False  # the CPU backend: never
+    monkeypatch.setattr(backend, "kernel_platform", lambda: "tpu")
+    assert runs_kernel(q, pool) is admitted
+
+
+def test_decode_impl_of_either_value_builds_the_same_program(model):
+    """``decode_impl`` is accepted wherever it was (the benchmark's
+    traffic files carry ``"jnp"``), checked, and selects nothing."""
+    cfg, _ = model
+    pcfg = PagedCacheConfig(num_blocks=24, block_size=8, blocks_per_seq=6)
+    avals = _decode_avals(cfg, pcfg, slots=4)
+    texts = {
+        impl: make_paged_decode_fn(cfg, donate=True, fused=True, impl=impl)
+        .lower(*avals).as_text()
+        for impl in ("jnp", "pallas")
+    }
+    assert texts["jnp"] == texts["pallas"]
+    assert "while" in texts["jnp"]  # the loop over table columns
+    with pytest.raises(ValueError, match="impl"):
+        make_paged_decode_fn(cfg, fused=True, impl="cuda")
+    with pytest.raises(ValueError, match="impl"):
+        make_paged_decode_fn(cfg, fused=False, impl="cuda")

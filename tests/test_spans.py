@@ -232,6 +232,11 @@ def test_one_engine_round_emits_exactly_its_spans():
     # decoded, its id picked on the device, no logits row fetched
     sample = by_name["ft.engine.sample"]
     assert (sample["on_device"], sample["rows_fetched"], sample["active"]) == (1, 0, 1)
+    # how often the paged kernel engages, fixed when the program was
+    # built: on the CPU every attention layer walks the table in the loop
+    dispatch = by_name["ft.engine.decode_dispatch"]
+    assert (dispatch["attn_layers"], dispatch["attn_kernel_layers"]) \
+        == (eng.cfg.n_layers, 0)
     # the children tile the round in the order the work happens
     order = [e["name"] for e in sorted(spans, key=lambda e: e["start"])
              if e["parent"] == "ft.engine.round"]
@@ -258,6 +263,15 @@ def test_a_round_samples_under_one_span_and_later_rounds_count_on():
     assert len(samples) == len(decoding)  # ONE a round, whatever the slots
     fetches = [e for e in spans if e["name"] == "ft.engine.decode_fetch"]
     assert len(fetches) == len(decoding) == eng.decode_steps
+    dispatches = [e for e in spans if e["name"] == "ft.engine.decode_dispatch"]
+    assert len(dispatches) == eng.decode_steps
+    report = eng.report()
+    assert all(
+        (e["attn_layers"], e["attn_kernel_layers"])
+        == (report["attn_layers"], report["attn_kernel_layers"])
+        == (eng.cfg.n_layers, 0)
+        for e in dispatches
+    )
     assert [e["active"] for e in samples] == [e["decoded"] for e in decoding]
     assert all(e["on_device"] == e["active"] and e["rows_fetched"] == 0
                for e in samples)
@@ -626,6 +640,28 @@ def test_device_pick_share_is_the_median_share_of_slots_picked_on_device():
     assert S.count_ratio_p50(_ctx(bare, None), **meta["args"]) is None
 
 
+def test_paged_kernel_share_is_the_share_of_layers_that_run_the_kernel():
+    """``kernels.paged_kernel_share`` as its metric file reads it: the two
+    counts every ``ft.engine.decode_dispatch`` span carries."""
+    meta = _metric_file("kernels.paged_kernel_share")
+    assert meta["reader"] == "spans:count_ratio_p50"
+
+    def rounds(took, layers, n=3):
+        host = [E("bench_window", 0, 1000)] + [
+            E("ft.engine.decode_dispatch", 100 * i, 50,
+              {"attn_layers": layers, "attn_kernel_layers": took})
+            for i in range(n)
+        ]
+        return S.count_ratio_p50(_ctx(host, None), **meta["args"])
+
+    assert rounds(8, 8) == pytest.approx(100.0)  # the dense cell on a TPU
+    assert rounds(0, 8) == pytest.approx(0.0)  # the CPU: the loop, counted
+    assert rounds(3, 5) == pytest.approx(60.0)  # a layer kind kept in the loop
+    # a parent commit's span carries no counts: left out, no error
+    bare = [E("bench_window", 0, 1000), E("ft.engine.decode_dispatch", 100, 50)]
+    assert S.count_ratio_p50(_ctx(bare, None), **meta["args"]) is None
+
+
 @pytest.mark.filterwarnings("ignore:builtin type:DeprecationWarning")
 def test_device_pick_share_reads_the_engines_own_spans(tmp_path):
     """The same reader over a real profile of a mixed batch: the counts
@@ -690,4 +726,4 @@ def test_new_metric_files_name_readers_that_exist():
         fn = getattr(S, meta["reader"].split(":")[1])
         inspect.signature(fn).bind(None, **meta.get("args", {}))
         assert entry["source"] in ("program_span", "program_counter", "device_trace")
-    assert seen == 23
+    assert seen == 24  # PR 31: kernels.paged_kernel_share

@@ -118,22 +118,59 @@ def test_flash_backward_that_cannot_fit_vmem_is_refused_at_trace(
     jax.jit(flash_attention).trace(*_qkv(v5e[0], jnp.float32, t=4096))
 
 
-def test_pallas_paged_attention_is_refused_on_tpu_in_one_sentence(
+def _paged_avals(dev, s, h, hkv, d, bs, p, n, dtype=jnp.bfloat16):
+    one = NamedSharding(_mesh([dev], (1, 1, 1)), P())
+
+    def a(*shape, dt=dtype):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one)
+
+    return (a(s, h, d), a(s, hkv, d), a(s, hkv, d), a(n, bs, hkv, d),
+            a(n, bs, hkv, d), a(s, p, dt=jnp.int32), a(s, dt=jnp.int32))
+
+
+#: the two serving cells' decode shapes (S, H, Hkv, D, bs, P, N), window
+CELL_DECODE = {
+    "dense": ((32, 32, 32, 128, 16, 48, 1537), None),
+    "laguna-48-full": ((64, 48, 8, 128, 16, 96, 6145), None),
+    "laguna-48-window": ((64, 48, 8, 128, 16, 96, 6145), 512),
+    "laguna-72-full": ((64, 72, 8, 128, 16, 96, 6145), None),
+    "laguna-72-window": ((64, 72, 8, 128, 16, 96, 6145), 512),
+}
+
+
+@pytest.mark.parametrize("cell", list(CELL_DECODE))
+def test_paged_decode_is_one_mosaic_kernel_that_copies_no_pool(
+    v5e, tpu_lowering, cell
+):
+    """Mosaic takes the kernel at both cells' exact shapes (grouped
+    queries and a window among them), one ``tpu_custom_call`` a call; the
+    pools go in as they are (the ``(N, bs * Hkv, D)`` view is a bitcast
+    under the (8, 128)(2, 1) tiling, not a 201 MB copy); and nothing is
+    left of the loop over table columns."""
+    import re
+
+    shape, window = CELL_DECODE[cell]
+    hlo = _compile(
+        lambda *a: paged_attention(*a, window=window),
+        *_paged_avals(v5e[0], *shape),
+    ).as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 1
+    n = shape[-1]
+    assert not re.search(rf"= \w+\[{n},[\d,]*\]\S* copy\(", hlo)
+    assert len(re.findall(rf"= bf16\[{n},\d+,128\]\S* bitcast\(", hlo)) == 2
+    assert " while(" not in hlo
+
+
+def test_a_shape_the_paged_kernel_refuses_walks_the_loop_without_a_raise(
     v5e, tpu_lowering
 ):
-    from flextree_tpu.serving.kv_cache import make_paged_decode_fn
-
-    q = jnp.zeros((2, H, D), jnp.bfloat16)
-    pool = jnp.zeros((5, 16, H, D), jnp.bfloat16)
-    args = (q, q, q, pool, pool, jnp.zeros((2, 3), jnp.int32),
-            jnp.zeros((2,), jnp.int32))
-    with pytest.raises(NotImplementedError, match="cannot lower for TPU"):
-        paged_attention(*args, impl="pallas")
-    # ... and at construction, before any decode round exists
-    with pytest.raises(NotImplementedError, match="use impl='jnp'"):
-        make_paged_decode_fn(FLAGSHIP, fused=True, impl="pallas")
-    # nothing swapped anything in: the jnp path is its own request
-    jax.jit(lambda *a: paged_attention(*a, impl="jnp")).trace(*args)
+    """Heads of 64 do not fill the lanes: the entry falls to the
+    ``fori_loop`` on what it observes, and the program compiles."""
+    hlo = _compile(
+        paged_attention, *_paged_avals(v5e[0], 32, 32, 32, 64, 16, 48, 1537)
+    ).as_text()
+    assert 'custom_call_target="tpu_custom_call"' not in hlo
+    assert " while(" in hlo
 
 
 # -------------------------------------------------------------- step level
@@ -202,6 +239,10 @@ def test_flagship_paged_decode_and_prefill_compile(v5e, tpu_lowering):
         make_paged_decode_fn(cfg, donate=True, fused=True, impl="jnp"),
         params, pools, tables, row, row,
     )
+    # the paged kernel in every layer, whatever ``impl`` says
+    assert decode.as_text().count(
+        'custom_call_target="tpu_custom_call"'
+    ) == cfg.n_layers
     # every donated pool buffer is aliased to an output, not copied
     pool_bytes = sum(
         x.size * x.dtype.itemsize for x in jax.tree.leaves(pools)
