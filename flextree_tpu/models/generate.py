@@ -33,6 +33,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..ops.paged_attention import paged_attention, paged_attention_gather
 from .transformer import (
     TransformerConfig,
     apply_rope,
@@ -44,6 +45,8 @@ from .transformer import (
 __all__ = [
     "init_kv_cache",
     "prefill",
+    "prefill_dense",
+    "paged_decode_dense",
     "prefill_suffix",
     "prefill_ragged",
     "decode_step",
@@ -160,15 +163,18 @@ def _forward_cached(params, tokens, cache, start_pos, cfg: TransformerConfig):
     return logits, cache
 
 
-def prefill(params, tokens, cfg: TransformerConfig, max_len: int):
+def prefill(params, tokens, cfg, max_len: int):
     """Run the prompt through the model once.  Returns
-    ``(last_logits, cache)`` with the cache filled for ``tokens``.  A
-    configuration of another block than the dense one brings its own walk
-    (``models.laguna.prefill``) behind the same signature."""
-    if not isinstance(cfg, TransformerConfig):
-        from . import laguna
+    ``(last_logits, cache)`` with the cache filled for ``tokens``: the
+    walk of ``cfg``'s block (``models.configs.block_of``; the dense one
+    is :func:`prefill_dense`)."""
+    from .configs import block_of
 
-        return laguna.prefill(params, tokens, cfg, max_len)
+    return block_of(cfg).prefill(params, tokens, cfg, max_len)
+
+
+def prefill_dense(params, tokens, cfg: TransformerConfig, max_len: int):
+    """:func:`prefill` for the dense block."""
     b, t = tokens.shape
     if t > max_len:
         raise ValueError(f"prompt length {t} exceeds max_len {max_len}")
@@ -261,6 +267,43 @@ def decode_step(params, cache, token, cfg: TransformerConfig):
         params, token[:, None], cache, cache["length"], cfg
     )
     return logits[:, 0], cache
+
+
+def paged_decode_dense(params, pools, tables, lengths, tokens,
+                       cfg: TransformerConfig, fused: bool = False):
+    """The dense block's decode step over the paged pool
+    (``serving.kv_cache.paged_decode_step`` has the contract).  The
+    per-layer math calls the SAME helpers as the contiguous decode
+    (``_qkv`` / ``apply_rope`` / ``mlp_block`` / ``final_logits``):
+    ``fused=False`` attends through ``ops.paged_attention_gather``, whose
+    gathered view has the (S, P*bs) key length the contiguous cache would,
+    which plus exact-zero masking is the whole bitwise-identity argument;
+    ``fused=True`` through ``ops.paged_attention``."""
+    s = tokens.shape[0]
+    positions = lengths[:, None].astype(jnp.int32)  # (S, 1) per-sequence
+    bs = pools["k"][0].shape[1]
+    row = jnp.arange(s)
+    blk = tables[row, lengths // bs]  # (S,) current block per slot
+    off = lengths % bs
+    attend = paged_attention if fused else paged_attention_gather
+    x = params["embed"][tokens[:, None]].astype(cfg.dtype)
+    new_k, new_v = [], []
+    for layer, pk, pv in zip(params["layers"], pools["k"], pools["v"]):
+        h = rms_norm(x, layer["ln1"])
+        q, k, v = _qkv(layer, h, cfg)
+        q = apply_rope(q, positions, cfg.rope_theta)
+        k = apply_rope(k, positions, cfg.rope_theta)
+        attn = attend(
+            q[:, 0], k[:, 0], v[:, 0], pk, pv, tables, lengths
+        )[:, None]
+        o = attn.reshape(s, 1, -1) @ layer["wo"].astype(cfg.dtype)
+        x = x + o
+        x = mlp_block(layer, x, cfg)
+        # scatter the appended K/V back into each row's current block
+        new_k.append(pk.at[blk, off].set(k[:, 0]))
+        new_v.append(pv.at[blk, off].set(v[:, 0]))
+    logits = final_logits(params["embed"], params["ln_f"], x)
+    return logits[:, 0], {"k": new_k, "v": new_v}
 
 
 def sample_token(logits, *, temperature: float = 0.0, top_k: int | None = None,

@@ -55,7 +55,7 @@ import numpy as np
 
 from ..ops.paged_attention import paged_attention, paged_attention_gather
 from .generate import cached_attention
-from .moe import dropless_experts, route_topk_normalized
+from .moe import expert_layer, gated_ffn, round_counts, stack_router
 from .transformer import apply_rope, rms_norm
 
 __all__ = [
@@ -67,18 +67,10 @@ __all__ = [
     "head_logits",
     "prefill",
     "paged_decode_step",
-    "MOE_COUNTS",
 ]
 
 FULL, WINDOW = "full_attention", "sliding_attention"
 DENSE, SPARSE = "dense", "sparse"
-
-#: what a decode round counts over its sparse layers and active slots, in
-#: the order of the ``counts`` vector the decode program hands out
-MOE_COUNTS = (
-    "picks", "local_picks", "experts_hit", "experts_held", "max_expert_load",
-)
-
 
 @dataclasses.dataclass(frozen=True)
 class RopeSpec:
@@ -365,36 +357,20 @@ def _attention_output(layer, x, attn, gate):
     return x + gated.reshape(b, t, -1) @ layer["wo"]
 
 
-def _gated_ffn(w, h):
-    g = jnp.dot(h, w["w_gate"], preferred_element_type=jnp.float32)
-    u = jnp.dot(h, w["w_up"], preferred_element_type=jnp.float32)
-    return (jax.nn.silu(g) * u).astype(h.dtype) @ w["w_down"]
-
-
 def _ffn(layer, x, cfg: LagunaConfig, i: int, rows=None):
     """The residual after layer ``i``'s FFN, and what its router did
-    (``None`` for a dense layer): ``scores`` (N, E) f32, ``choices``
-    (N, k) int32, ``sizes`` (n_held,) picks of each held expert.
+    (``None`` for a dense layer; ``moe.expert_layer``'s otherwise).
     ``rows`` (N,) bool: rows whose picks are dispatched and counted (a
     decode round's inactive slots are not)."""
     b, t, d = x.shape
     h = rms_norm(x, layer["ln2"], cfg.rms_eps)
     if cfg.mlp_types[i] == DENSE:
         with jax.named_scope("ft_mlp"):
-            return x + _gated_ffn(layer["mlp"], h), None
-    flat = h.reshape(b * t, d)
-    with jax.named_scope("ft_moe_router"):
-        scores, choices, weights = route_topk_normalized(
-            flat, layer["router"], cfg.top_k, cfg.routed_scale, cfg.norm_topk
-        )
-    with jax.named_scope("ft_moe_experts"):
-        routed, sizes = dropless_experts(
-            flat, choices, weights, layer["experts"], cfg.experts_held, rows
-        )
-    with jax.named_scope("ft_moe_shared"):
-        shared = _gated_ffn(layer["shared"], flat)
-        y = (routed + shared.astype(jnp.float32)).astype(x.dtype)
-    moe = {"scores": scores, "choices": choices, "sizes": sizes}
+            return x + gated_ffn(layer["mlp"], h), None
+    y, moe = expert_layer(
+        layer, h.reshape(b * t, d), top_k=cfg.top_k, scale=cfg.routed_scale,
+        normalize=cfg.norm_topk, held=cfg.experts_held, rows=rows,
+    )
     return x + y.reshape(b, t, d), moe
 
 
@@ -410,13 +386,6 @@ def head_logits(params, x, cfg: LagunaConfig):
 def _embed(params, tokens, cfg: LagunaConfig):
     with jax.named_scope("ft_embed"):
         return params["embed"][tokens].astype(cfg.dtype)
-
-
-def _stack_router(moes):
-    """What the sparse layers' routers did, stacked over those layers."""
-    return {
-        k: jnp.stack([m[k] for m in moes]) for k in ("scores", "choices")
-    }
 
 
 # ------------------------------------------------------------------ walks
@@ -453,7 +422,7 @@ def prefill(params, tokens, cfg: LagunaConfig, max_len: int):
     logits = head_logits(params, x[:, -1], cfg)
     cache = {
         "k": ks, "v": vs, "length": jnp.full((b,), t, jnp.int32),
-        "moe": _stack_router(moes),
+        "moe": stack_router(moes),
     }
     return logits, cache
 
@@ -466,7 +435,7 @@ def paged_decode_step(params, pools, tables, lengths, tokens,
     the table columns that meet its window.  Returns ``(logits, pools,
     moe)``; ``moe`` holds the sparse layers' ``scores`` (L_s, S, E) and
     ``choices`` (L_s, S, k), and ``counts``, int32 in the order of
-    :data:`MOE_COUNTS`, over the sparse layers and the ACTIVE slots
+    :data:`moe.MOE_COUNTS`, over the sparse layers and the ACTIVE slots
     (``lengths > 0``; an empty slot's row dispatches nothing)."""
     s = tokens.shape[0]
     positions = lengths[:, None].astype(jnp.int32)
@@ -495,17 +464,9 @@ def paged_decode_step(params, pools, tables, lengths, tokens,
         if moe is not None:
             moes.append(moe)
     logits = head_logits(params, x[:, 0], cfg)
-    out = _stack_router(moes)
-    lo, hi = cfg.experts_held
-    picked = out["choices"]  # (L_s, S, k)
-    counted = active[None, :, None]
-    local = (picked >= lo) & (picked < hi) & counted
-    sizes = jnp.stack([m["sizes"] for m in moes])  # (L_s, n_held)
-    loads = jnp.zeros((len(moes), cfg.n_experts), jnp.int32).at[
-        jnp.arange(len(moes))[:, None, None], picked
-    ].add(counted.astype(jnp.int32))
-    out["counts"] = jnp.stack([
-        active.sum() * cfg.top_k * len(moes), local.sum(),
-        (sizes > 0).sum(), jnp.asarray(cfg.n_held * len(moes)), loads.max(),
-    ]).astype(jnp.int32)
+    out = stack_router(moes)
+    out["counts"] = round_counts(
+        moes, active, top_k=cfg.top_k, held=cfg.experts_held,
+        n_experts=cfg.n_experts,
+    )
     return logits, {"k": new_k, "v": new_v}, out

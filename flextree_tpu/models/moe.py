@@ -67,8 +67,14 @@ __all__ = [
     "moe_layer",
     "route_topk",
     "expert_capacity",
+    "MOE_COUNTS",
+    "ROUTER_SCORES",
     "route_topk_normalized",
     "dropless_experts",
+    "gated_ffn",
+    "expert_layer",
+    "stack_router",
+    "round_counts",
 ]
 
 
@@ -316,14 +322,31 @@ def moe_forward(
 # ----------------------------------------------------- the dropless share
 
 
+#: what a decode round counts over its sparse layers and active slots, in
+#: the order of the ``counts`` vector a decode program with routed experts
+#: hands out (:func:`round_counts`)
+MOE_COUNTS = (
+    "picks", "local_picks", "experts_hit", "experts_held", "max_expert_load",
+)
+
+#: a router's score function by name: ``softmax`` over the experts
+#: (``models.laguna``), or an independent ``sigmoid`` of each logit
+#: (``models.pangu_ultra_moe``)
+ROUTER_SCORES = {
+    "softmax": lambda logits: jax.nn.softmax(logits, axis=-1),
+    "sigmoid": jax.nn.sigmoid,
+}
+
+
 def route_topk_normalized(h, router_w, k: int, scale: float = 1.0,
-                          normalize: bool = True):
-    """Scores, choices and weights of a softmax router: ``scores`` (N, E)
-    float32 softmax of ``h @ router_w`` (accumulated in f32), ``choices``
-    (N, k) int32 the ``k`` largest, ``weights`` (N, k) f32 their scores,
-    divided by their sum when ``normalize``, times ``scale``."""
+                          normalize: bool = True, score: str = "softmax"):
+    """Scores, choices and weights of a router: ``scores`` (N, E) float32
+    ``score`` (:data:`ROUTER_SCORES`) of ``h @ router_w`` (accumulated in
+    f32), ``choices`` (N, k) int32 the ``k`` largest, ``weights`` (N, k)
+    f32 their scores, divided by their sum when ``normalize``, times
+    ``scale``."""
     logits = jnp.dot(h, router_w, preferred_element_type=jnp.float32)
-    scores = jax.nn.softmax(logits, axis=-1)
+    scores = ROUTER_SCORES[score](logits)
     top, choices = lax.top_k(scores, k)
     if normalize:
         top = top / top.sum(axis=-1, keepdims=True)
@@ -364,3 +387,61 @@ def dropless_experts(h, choices, weights, experts, held, rows=None):
     per_pick = ys[jnp.argsort(order)].reshape(n, k, -1)
     per_pick = jnp.where(local[..., None], per_pick, 0.0)
     return jnp.einsum("nkd,nk->nd", per_pick, weights.astype(f32)), sizes
+
+
+def gated_ffn(w, h):
+    """``W_down(silu(W_gate h) * W_up h)`` in the held type, the two inner
+    products accumulated in f32."""
+    g = jnp.dot(h, w["w_gate"], preferred_element_type=jnp.float32)
+    u = jnp.dot(h, w["w_up"], preferred_element_type=jnp.float32)
+    return (jax.nn.silu(g) * u).astype(h.dtype) @ w["w_down"]
+
+
+def expert_layer(layer, flat, *, top_k: int, scale: float, normalize: bool,
+                 held, score: str = "softmax", rows=None):
+    """A served expert layer on normed rows ``flat`` (N, d): the router
+    over ALL the experts (``layer["router"]``), the part of the routed sum
+    that the experts ``held`` here give (``layer["experts"]``), plus the
+    shared expert (``layer["shared"]``), ungated.  Returns ``(y, moe)``:
+    (N, d) in ``flat``'s dtype, and what the router did: ``scores`` (N, E)
+    f32, ``choices`` (N, k) int32, ``sizes`` (n_held,) picks of each held
+    expert.  ``rows`` (N,) bool: rows whose picks are dispatched and
+    counted (a decode round's inactive slots are not).  Scopes:
+    ``ft_moe_router``, ``ft_moe_experts``, ``ft_moe_shared``."""
+    with jax.named_scope("ft_moe_router"):
+        scores, choices, weights = route_topk_normalized(
+            flat, layer["router"], top_k, scale, normalize, score
+        )
+    with jax.named_scope("ft_moe_experts"):
+        routed, sizes = dropless_experts(
+            flat, choices, weights, layer["experts"], held, rows
+        )
+    with jax.named_scope("ft_moe_shared"):
+        shared = gated_ffn(layer["shared"], flat)
+        y = (routed + shared.astype(jnp.float32)).astype(flat.dtype)
+    return y, {"scores": scores, "choices": choices, "sizes": sizes}
+
+
+def stack_router(moes):
+    """What the sparse layers' routers did, stacked over those layers."""
+    return {
+        k: jnp.stack([m[k] for m in moes]) for k in ("scores", "choices")
+    }
+
+
+def round_counts(moes, active, *, top_k: int, held, n_experts: int):
+    """A decode round's :data:`MOE_COUNTS`, int32, over the sparse layers
+    ``moes`` (:func:`expert_layer`'s second results) and the ACTIVE slots
+    (``active`` (S,) bool; an empty slot's row dispatches nothing)."""
+    lo, hi = held
+    picked = jnp.stack([m["choices"] for m in moes])  # (L_s, S, k)
+    counted = active[None, :, None]
+    local = (picked >= lo) & (picked < hi) & counted
+    sizes = jnp.stack([m["sizes"] for m in moes])  # (L_s, n_held)
+    loads = jnp.zeros((len(moes), n_experts), jnp.int32).at[
+        jnp.arange(len(moes))[:, None, None], picked
+    ].add(counted.astype(jnp.int32))
+    return jnp.stack([
+        active.sum() * top_k * len(moes), local.sum(),
+        (sizes > 0).sum(), jnp.asarray((hi - lo) * len(moes)), loads.max(),
+    ]).astype(jnp.int32)

@@ -74,7 +74,11 @@ __all__ = [
     "kernel_admits",
     "paged_attention",
     "paged_attention_gather",
+    "paged_attention_latent",
+    "paged_attention_latent_gather",
+    "latent_kernel_admits",
     "runs_kernel",
+    "runs_latent_kernel",
 ]
 
 _NEG_INF = -1e30
@@ -585,3 +589,272 @@ def paged_attention(
             block_chunk = _kernel_chunk(*k_pool.shape[1:3], tables.shape[1])
     return _stream_jnp(q, k_new, v_new, k_pool, v_pool, tables, lengths,
                        float(scale), block_chunk, window)
+
+
+# ------------------------------------------------------------------ latent
+
+def _check_latent(q, row_new, pool, tables, lengths, value_dim):
+    if q.ndim != 3 or pool.ndim != 3 or pool.shape[2] != q.shape[2]:
+        raise ValueError(
+            f"expected (S, H, R) queries over an (N, bs, R) pool of rows, "
+            f"got {q.shape} over {pool.shape}"
+        )
+    if row_new.shape != (q.shape[0], pool.shape[2]):
+        raise ValueError(
+            f"the new token's row must be shaped {(q.shape[0], pool.shape[2])}"
+            f", got {row_new.shape}"
+        )
+    if not 0 < value_dim <= pool.shape[2]:
+        raise ValueError(
+            f"value_dim {value_dim} is no prefix of a {pool.shape[2]}-wide row"
+        )
+    if tables.ndim != 2 or tables.shape[0] != q.shape[0]:
+        raise ValueError(f"expected (S, P) tables, got {tables.shape}")
+    if lengths.shape != (q.shape[0],):
+        raise ValueError(f"expected (S,) lengths, got {lengths.shape}")
+
+
+def paged_attention_latent_gather(q, row_new, pool, tables, lengths, *,
+                                  value_dim: int, scale: float):
+    """The latent walk's oracle: gather every table block into a
+    contiguous (S, P*bs, R) view, splice the new token's row in at each
+    slot's ``length``, and attend with the full-row softmax.  Arguments
+    and result as :func:`paged_attention_latent`."""
+    _check_latent(q, row_new, pool, tables, lengths, value_dim)
+    s = q.shape[0]
+    rows = jax.vmap(
+        lambda c, u, p: lax.dynamic_update_slice_in_dim(c, u, p, axis=0)
+    )(pool[tables].reshape(s, -1, pool.shape[2]), row_new[:, None], lengths)
+    sc = jnp.einsum(
+        "shr,skr->shk", q, rows, preferred_element_type=jnp.float32
+    ) * scale
+    seen = jnp.arange(rows.shape[1])[None, :] <= lengths[:, None]
+    pr = jax.nn.softmax(jnp.where(seen[:, None, :], sc, _NEG_INF), axis=-1)
+    return jnp.einsum(
+        "shk,skv->shv", pr, rows[..., :value_dim].astype(jnp.float32)
+    ).astype(q.dtype)
+
+
+def _stream_latent_jnp(q, row_new, pool, tables, lengths, value_dim, scale,
+                       block_chunk):
+    s, h, r = q.shape
+    bs, p = pool.shape[1], tables.shape[1]
+    cb = max(1, min(int(block_chunk), p))
+    p_pad = -(-p // cb) * cb
+    if p_pad != p:  # null blocks: past every slot's causal bound
+        tables = jnp.pad(tables, ((0, 0), (0, p_pad - p)))
+    n_steps = ((jnp.max(lengths) + bs - 1) // bs + cb - 1) // cb
+    f32 = jnp.float32
+
+    def fold(carry, rows, valid):
+        """One online-softmax step over ``rows`` (S, K, R)."""
+        m, l, acc = carry
+        sc = jnp.einsum("shr,skr->shk", q, rows, preferred_element_type=f32)
+        sc = jnp.where(valid[:, None, :], sc * scale, _NEG_INF)
+        m_new = jnp.maximum(m, sc.max(axis=-1))
+        pr = jnp.where(valid[:, None, :], jnp.exp(sc - m_new[..., None]), 0.0)
+        corr = jnp.exp(m - m_new)
+        l = l * corr + pr.sum(axis=-1)
+        acc = acc * corr[..., None] + jnp.einsum(
+            "shk,skv->shv", pr.astype(rows.dtype), rows[..., :value_dim],
+            preferred_element_type=f32,
+        )
+        return m_new, l, acc
+
+    def body(i, carry):
+        tb = lax.dynamic_slice_in_dim(tables, i * cb, cb, axis=1)  # (S, cb)
+        kpos = i * cb * bs + jnp.arange(cb * bs)
+        return fold(
+            carry, pool[tb].reshape(s, cb * bs, r),
+            kpos[None, :] < lengths[:, None],
+        )
+
+    carry = lax.fori_loop(0, n_steps, body, (
+        jnp.full((s, h), _NEG_INF, f32), jnp.zeros((s, h), f32),
+        jnp.zeros((s, h, value_dim), f32),
+    ))
+    _, l, acc = fold(carry, row_new[:, None], jnp.ones((s, 1), bool))
+    return (acc / l[..., None]).astype(q.dtype)
+
+
+def latent_kernel_admits(q, pool, value_dim: int) -> bool:
+    """Whether Mosaic takes the latent kernel at these shapes: whole
+    sublane tiles of positions a block and of heads, values that fill
+    whole lane tiles (the rest of the row, the rotary key, may be a part
+    of one: the block's last axis is the array's own)."""
+    bs, r = pool.shape[1:]
+    return (
+        bs % 16 == 0
+        and q.shape[1] % 8 == 0
+        and value_dim % 128 == 0 and 0 < value_dim <= r
+        and r % 8 == 0
+        and pool.dtype in (jnp.bfloat16, jnp.float32)
+        and q.dtype == pool.dtype
+    )
+
+
+def runs_latent_kernel(q, pool, value_dim: int) -> bool:
+    """:func:`runs_kernel` for :func:`paged_attention_latent`."""
+    return backend.kernel_platform() == "tpu" and latent_kernel_admits(
+        q, pool, value_dim
+    )
+
+
+def _latent_kernel(len_ref, tab_ref, q_ref, new_ref, pool_ref, o_ref,
+                   m_ref, l_ref, acc_ref, *, bs, p, scale, value_dim):
+    """Grid (slot, table column).  A step holds ONE block of the slot,
+    brought in by the pipeline: the block's index comes from the table in
+    SMEM, and a column past the slot's last live block names that last
+    block again, which costs no DMA (the index did not change) and whose
+    compute is skipped.  A 576-wide row cannot be sliced out of a VMEM
+    buffer by hand (Mosaic wants slices of whole 128-lane tiles), so the
+    manual DMAs of ``_decode_kernel`` are not to be had here; a block whose
+    last axis is the array's own is.
+
+    Every query head is multiplied with every row (``(H, R) x (bs, R)``:
+    the MXU's own shape, no masked head pairs), the values are the first
+    ``value_dim`` numbers of the very rows the scores were taken with, and
+    the last column folds the new token's row in."""
+    s, j = pl.program_id(0), pl.program_id(1)
+    f32 = jnp.float32
+    length = len_ref[s]
+    q = q_ref[0]  # (H, R)
+    h = q.shape[0]
+    precision = lax.Precision.HIGHEST if q.dtype == f32 else None
+
+    @pl.when(j == 0)
+    def _():
+        m_ref[...] = jnp.full(m_ref.shape, _NEG_INF, f32)
+        l_ref[...] = jnp.zeros(l_ref.shape, f32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, f32)
+
+    @pl.when(j * bs < length)
+    def _():
+        rows = pool_ref[0]  # (bs, R)
+        sc = lax.dot_general(
+            q, rows, (((1,), (1,)), ((), ())),
+            preferred_element_type=f32, precision=precision,
+        )  # (H, bs)
+        kpos = j * bs + lax.broadcasted_iota(jnp.int32, (h, bs), 1)
+        valid = kpos < length
+        sc = jnp.where(valid, sc * scale, _NEG_INF)
+        m = m_ref[:, 0:1]
+        m_new = jnp.maximum(m, sc.max(axis=-1, keepdims=True))
+        pr = jnp.where(valid, jnp.exp(sc - m_new), 0.0)
+        corr = jnp.exp(m - m_new)
+        l_ref[...] = jnp.broadcast_to(
+            l_ref[:, 0:1] * corr + pr.sum(axis=-1, keepdims=True), l_ref.shape
+        )
+        # weights drop to the rows' type for the MXU in ONE pass (flash
+        # practice; exact for an f32 pool): the three-part product of
+        # ``_weighted_values`` made the step MXU-bound at 128 heads
+        acc_ref[...] = acc_ref[...] * corr + lax.dot_general(
+            pr.astype(rows.dtype), rows[:, :value_dim],
+            (((1,), (0,)), ((), ())), preferred_element_type=f32,
+            precision=precision,
+        )
+        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+
+    @pl.when(j == p - 1)
+    def _():
+        new = new_ref[0]  # (1, R): position `length`, always visible
+        s_new = (q.astype(f32) * new.astype(f32)).sum(
+            axis=-1, keepdims=True
+        ) * scale
+        m = m_ref[:, 0:1]
+        m_fin = jnp.maximum(m, s_new)
+        p_new = jnp.exp(s_new - m_fin)
+        corr = jnp.exp(m - m_fin)
+        l = l_ref[:, 0:1] * corr + p_new
+        acc = acc_ref[...] * corr + p_new * new[:, :value_dim].astype(f32)
+        o_ref[0] = (acc / l).astype(o_ref.dtype)
+
+
+@functools.partial(
+    jax.jit, static_argnames=("scale", "value_dim", "interpret")
+)
+def _stream_latent_kernel(q, row_new, pool, tables, lengths, *, scale,
+                          value_dim, interpret):
+    s, h, r = q.shape
+    bs, p = pool.shape[1], tables.shape[1]
+
+    def block_of(i, j, len_ref, tab_ref):
+        # the slot's j-th block, or its last live one again past that
+        last = jnp.maximum((len_ref[i] + bs - 1) // bs - 1, 0)
+        return tab_ref[i * p + jnp.minimum(j, last)], 0, 0
+
+    return pl.pallas_call(
+        functools.partial(
+            _latent_kernel, bs=bs, p=p, scale=scale, value_dim=value_dim
+        ),
+        out_shape=jax.ShapeDtypeStruct((s, h, value_dim), q.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(s, p),
+            in_specs=[
+                pl.BlockSpec((1, h, r), lambda i, j, *_: (i, 0, 0)),
+                pl.BlockSpec((1, 1, r), lambda i, j, *_: (i, 0, 0)),
+                pl.BlockSpec((1, bs, r), block_of),
+            ],
+            out_specs=pl.BlockSpec(
+                (1, h, value_dim), lambda i, j, *_: (i, 0, 0)
+            ),
+            scratch_shapes=[
+                pltpu.VMEM((h, 128), jnp.float32),  # m, lane-replicated
+                pltpu.VMEM((h, 128), jnp.float32),  # l
+                pltpu.VMEM((h, value_dim), jnp.float32),  # acc
+            ],
+        ),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+        ),
+        name="paged_latent_attention",
+        interpret=interpret,
+    )(lengths, tables.reshape(-1), q, row_new[:, None, :], pool)
+
+
+def paged_attention_latent(q, row_new, pool, tables, lengths, *,
+                           value_dim: int, scale: float,
+                           impl: str | None = None,
+                           interpret: bool | None = None,
+                           block_chunk: int = 1):
+    """Fused paged decode attention over a LATENT pool, one token a slot:
+    the counterpart of :func:`paged_attention` where a cached position is
+    one row that is key and value at once.
+
+    ``q``: (S, H, R) the absorbed queries, every head against the same
+    rows; ``row_new``: (S, R) the new token's row (position ``length``,
+    always visible to itself); ``pool``: (N, bs, R), ONE array a layer;
+    ``tables`` (S, P), ``lengths`` (S,) as for :func:`paged_attention`.
+    Scores are ``q . row * scale`` over all R numbers, values the rows'
+    first ``value_dim``.  Returns (S, H, value_dim) in ``q``'s dtype: what
+    :func:`paged_attention_latent_gather` returns, in another summation
+    order.
+
+    Two paths, chosen as :func:`paged_attention` chooses
+    (:func:`runs_latent_kernel`): on a TPU at shapes its tiling admits, a
+    Pallas kernel that walks every slot's own live blocks
+    (``_latent_kernel``); elsewhere (the CPU, a block that is no whole
+    number of sublane tiles) the ``fori_loop`` (``_stream_latent_jnp``):
+    all S slots a step to the frontier of the LONGEST one, ``block_chunk``
+    table columns a step.  On the v5e the loop also pays copies of the
+    whole pool a layer a round (PERF.md section 6, PR 32): it is no path
+    to serve from there.  ``impl`` forces a path, for the tests.
+    """
+    if impl not in (None, "jnp", "pallas"):
+        raise ValueError(f"unknown paged-attention impl {impl!r}")
+    _check_latent(q, row_new, pool, tables, lengths, value_dim)
+    tables = jnp.asarray(tables, jnp.int32)
+    lengths = jnp.asarray(lengths, jnp.int32)
+    if impl == "pallas" or (
+        impl is None and runs_latent_kernel(q, pool, value_dim)
+    ):
+        return _stream_latent_kernel(
+            q, row_new, pool, tables, lengths, scale=float(scale),
+            value_dim=int(value_dim),
+            interpret=backend.pallas_interpret(interpret),
+        )
+    return _stream_latent_jnp(
+        q, row_new, pool, tables, lengths, value_dim, float(scale),
+        block_chunk,
+    )

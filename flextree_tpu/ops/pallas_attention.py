@@ -37,7 +37,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ..utils.backend import pallas_interpret
 
-__all__ = ["flash_attention", "attention_with_offsets"]
+__all__ = ["flash_attention", "attention_with_offsets", "kvgrid_tiles"]
 
 _NEG_INF = -1e30
 _LANE = 128  # lse is lane-replicated to satisfy Mosaic's (8, 128) block rule
@@ -421,6 +421,48 @@ def _blocks(q, k, block_q, block_k):
     return bq, bk, -(-tq // bq) * bq, -(-tk // bk) * bk
 
 
+def _kvgrid_vmem_need(bq, bk, bkM, d, dv, dtype) -> int:
+    """An estimate of a kvgrid forward step's VMEM: the double-buffered q,
+    k, v and out blocks with their last axis padded to whole lane tiles,
+    the f32 carry, and three (bq, bk) f32 score tiles (scores,
+    probabilities, a mask)."""
+    lanes = lambda w: -(-w // _LANE) * _LANE  # noqa: E731
+    item = jnp.dtype(dtype).itemsize
+    blocks = 2 * item * (
+        bq * lanes(d) + bkM * lanes(d) + bkM * lanes(dv) + bq * lanes(dv)
+    )
+    carry = 4 * bq * (lanes(dv) + 2 * _LANE)
+    return blocks + carry + 3 * 4 * bq * bk
+
+
+def _kvgrid_vmem_limit(bq, bk, bkM, d, dv, dtype):
+    """``vmem_limit_bytes`` for the kvgrid forward: None (Mosaic's scoped
+    default, what the train cells' tiles have always compiled under) while
+    :func:`_kvgrid_vmem_need` stays well inside it, else twice the
+    estimate, capped under the v5e's 128 MiB."""
+    need = _kvgrid_vmem_need(bq, bk, bkM, d, dv, dtype)
+    if need <= (_SCOPED_VMEM_BYTES * 3) // 4:
+        return None
+    return min(2 * need, 96 * 1024 * 1024)
+
+
+def kvgrid_tiles(d: int, dv: int, dtype) -> dict:
+    """``block_q`` and ``block_k`` for a forward-only ``kvgrid`` call over
+    a long sequence at many heads (a prefill), from the head widths and
+    the type: the largest tiles, keys twice the queries and at most the
+    kernel's 2,048-row DMA granule, whose :func:`_kvgrid_vmem_need` stays
+    inside a quarter of the v5e's 128 MiB.  At 192-wide bf16 keys over
+    128-wide values that is 1,024 x 2,048, the best of eleven sizes tried
+    there at 2,048 to 8,192 tokens (PERF.md section 6, PR 32); a shorter
+    sequence clamps them (:func:`_blocks`)."""
+    bk = 2048
+    while bk > 256 and _kvgrid_vmem_need(
+        bk // 2, bk, bk, d, dv, dtype
+    ) > 32 * 1024 * 1024:
+        bk //= 2
+    return {"block_q": bk // 2, "block_k": bk}
+
+
 def _to_bhd(x, t_pad):
     """(B, T, H, D) -> (B*H, T_pad, D)."""
     b, t, h, d = x.shape
@@ -453,13 +495,22 @@ def _flash_fwd_impl(
         raise ValueError(f"unknown flash variant {variant!r}")
     b, tq, h, d = q.shape
     tk = k.shape[1]
+    # values may be narrower or wider than queries and keys (latent
+    # attention's expanded form: 192-wide q and k over 128-wide v); only
+    # the kvgrid forward, whose kernel never names a width, takes that
+    dv = v.shape[-1]
+    if dv != d and variant != "kvgrid":
+        raise ValueError(
+            f"values {dv} wide under {d}-wide keys need variant='kvgrid' "
+            f"(forward only), got {variant!r}"
+        )
     interpret = pallas_interpret(interpret)
     bq, bk, tq_pad, tk_pad = _blocks(q, k, block_q, block_k)
     q3, k3, v3 = _to_bhd(q, tq_pad), _to_bhd(k, tk_pad), _to_bhd(v, tk_pad)
 
-    out_shape = [jax.ShapeDtypeStruct((b * h, tq_pad, d), q.dtype)]
+    out_shape = [jax.ShapeDtypeStruct((b * h, tq_pad, dv), q.dtype)]
     if variant == "kvgrid":
-        out_specs = [pl.BlockSpec((1, bq, d), lambda bh, i, j: (bh, i, 0))]
+        out_specs = [pl.BlockSpec((1, bq, dv), lambda bh, i, j: (bh, i, 0))]
         if emit_lse:
             out_shape.append(
                 jax.ShapeDtypeStruct((b * h, tq_pad, _LANE), jnp.float32)
@@ -467,9 +518,6 @@ def _flash_fwd_impl(
             out_specs.append(
                 pl.BlockSpec((1, bq, _LANE), lambda bh, i, j: (bh, i, 0))
             )
-        compiler_params = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
-        )
         # k/v-major DMA granule: up to 4 minor tiles (<= 2048 rows) per
         # grid step, statically unrolled in the kernel — bigger transfers
         # for the pipeline to double-buffer, with per-minor-tile compute
@@ -481,6 +529,10 @@ def _flash_fwd_impl(
             (u for u in (4, 2, 1) if n_minor % u == 0 and bk * u <= 2048), 1
         )
         bkM = bk * u
+        compiler_params = pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_kvgrid_vmem_limit(bq, bk, bkM, d, dv, q.dtype),
+        )
         res = pl.pallas_call(
             functools.partial(
                 _flash_kernel_kvgrid,
@@ -499,11 +551,11 @@ def _flash_fwd_impl(
             in_specs=[
                 pl.BlockSpec((1, bq, d), lambda bh, i, j: (bh, i, 0)),
                 pl.BlockSpec((1, bkM, d), lambda bh, i, j: (bh, j, 0)),
-                pl.BlockSpec((1, bkM, d), lambda bh, i, j: (bh, j, 0)),
+                pl.BlockSpec((1, bkM, dv), lambda bh, i, j: (bh, j, 0)),
             ],
             out_specs=tuple(out_specs),
             scratch_shapes=[
-                pltpu.VMEM((bq, d), jnp.float32),      # acc
+                pltpu.VMEM((bq, dv), jnp.float32),     # acc
                 pltpu.VMEM((bq, _LANE), jnp.float32),  # m
                 pltpu.VMEM((bq, _LANE), jnp.float32),  # l
             ],
@@ -952,7 +1004,9 @@ def flash_attention(
     return_lse: bool = False,
     variant: str | None = None,
 ):
-    """Fused attention on (B, Tq, H, D) queries / (B, Tk, H, D) keys-values.
+    """Fused attention on (B, Tq, H, D) queries / (B, Tk, H, D) keys-values
+    (values may have a width of their own under ``variant="kvgrid"``,
+    forward only: the output then has theirs).
 
     Same contract as ``attention_reference`` (output for the local queries
     in ``q``'s dtype) plus global ``q_offset``/``k_offset`` positions for
@@ -986,7 +1040,7 @@ def flash_attention(
     """
     if q.ndim != 4 or k.ndim != 4 or v.ndim != 4:
         raise ValueError(f"expected (B, T, H, D) inputs, got {q.shape}")
-    if k.shape != v.shape:
+    if k.shape[:-1] != v.shape[:-1]:
         raise ValueError(f"k/v shapes differ: {k.shape} vs {v.shape}")
     if scale is None:
         scale = 1.0 / (q.shape[-1] ** 0.5)

@@ -20,6 +20,11 @@ admission modes stay drivable::
     python -m flextree_tpu.serving --config benchmarks/configs/laguna-s-2.1.json \\
         --slots 64 --block-size 16 --blocks-per-seq 96 --blocks 6145 \\
         --requests 64 --prompt-len 512 --max-new 64
+    # (latent attention: one 576-wide cached row a token, blocks of 128)
+    python -m flextree_tpu.serving \\
+        --config benchmarks/configs/openpangu-ultra-moe-718b.json \\
+        --slots 32 --block-size 128 --blocks-per-seq 68 --blocks 2177 \\
+        --requests 32 --prompt-len 4096 --max-new 64
 
     # the flagship width on the chip JAX finds (no --cpu: landing on the
     # CPU unasked is an error)

@@ -23,11 +23,16 @@ byte-bound terms are separate fields of the prediction).
 
 from __future__ import annotations
 
+import math
+
+from ..models.configs import pool_layout
+
 __all__ = [
     "decode_round_flops",
     "decode_round_bytes",
     "predict_decode_round_us",
     "predict_prefill_us",
+    "cache_bytes_per_position",
     "kv_migration_elems",
     "predict_migration_us",
     "plan_migration",
@@ -54,18 +59,28 @@ def decode_round_flops(cfg, n_active: int, max_len: int) -> float:
     return n_active * (_dense_flops_per_token(cfg) + attn)
 
 
-def decode_round_bytes(cfg, pcfg, n_active: int, frontier_blocks: int) -> float:
-    """K/V pool bytes streamed in one decode round: every active slot
-    reads the pools up to the batch frontier (blocks × block_size
-    positions × K and V × heads × head_dim × itemsize × layers)."""
+def cache_bytes_per_position(cfg) -> int:
+    """Bytes the cache holds a position over all the layers, from the
+    model's pool layout and compute dtype: what ``init_pools`` allocates
+    a position, and ``engine.report()["cache_bytes_per_position"]``."""
     try:
         import numpy as np
 
         itemsize = np.dtype(cfg.dtype).itemsize
     except TypeError:
         itemsize = 4
-    per_pos = 2 * cfg.n_kv_heads * cfg.head_dim * itemsize * cfg.n_layers
-    return float(n_active * frontier_blocks * pcfg.block_size * per_pos)
+    per_layer = sum(math.prod(row) for row in pool_layout(cfg).values())
+    return per_layer * itemsize * cfg.n_layers
+
+
+def decode_round_bytes(cfg, pcfg, n_active: int, frontier_blocks: int) -> float:
+    """Cache bytes streamed in one decode round: every active slot reads
+    the pools up to the batch frontier (blocks × block_size positions ×
+    the pool's real bytes a position, :func:`cache_bytes_per_position`)."""
+    return float(
+        n_active * frontier_blocks * pcfg.block_size
+        * cache_bytes_per_position(cfg)
+    )
 
 
 def predict_decode_round_us(
@@ -125,13 +140,17 @@ def predict_prefill_us(cfg, prompt_len: int, params=None,
     return (dense + attn) / (gflops * 1e3)
 
 
-def kv_migration_elems(cfg, pcfg, prompt_len: int) -> int:
-    """f32 elements per K-or-V tensor of one migrated sequence: the block
-    footprint of the prompt (``blocks_for``, whole blocks — migration
-    ships the tail block too) × block positions × heads × head_dim.  One
-    sequence ships ``2 * n_layers`` such tensors."""
+def kv_migration_elems(cfg, pcfg, prompt_len: int) -> list:
+    """f32 elements of each tensor ONE layer of one migrated sequence
+    ships, a tensor a part of the pool's layout (K and V, or one latent
+    row): the block footprint of the prompt (``blocks_for``, whole blocks
+    — migration ships the tail block too) × block positions × the part's
+    numbers a position.  One sequence ships ``n_layers`` times these."""
     n_blocks = pcfg.blocks_for(max(int(prompt_len), 1))
-    return n_blocks * pcfg.block_size * cfg.n_kv_heads * cfg.head_dim
+    return [
+        n_blocks * pcfg.block_size * math.prod(row)
+        for row in pool_layout(cfg).values()
+    ]
 
 
 def predict_migration_us(cfg, pcfg, prompt_len: int, codec="f32",
@@ -150,14 +169,13 @@ def predict_migration_us(cfg, pcfg, prompt_len: int, codec="f32",
         params = default_params()
     c = get_codec(codec)
     elems = kv_migration_elems(cfg, pcfg, prompt_len)
-    n_tensors = 2 * cfg.n_layers
-    bytes_on_wire = n_tensors * c.wire_bytes(elems)
+    bytes_on_wire = cfg.n_layers * sum(c.wire_bytes(e) for e in elems)
     wire_us = params.dcn.latency_us + bytes_on_wire / (
         max(params.dcn.bandwidth_GBps, 1e-6) * 1e3
     )
     codec_us = 0.0
     if c.hop_cost:
-        codec_us = 2.0 * (n_tensors * elems * 4) / (
+        codec_us = 2.0 * (cfg.n_layers * sum(elems) * 4) / (
             max(params.codec_bw_GBps, 1e-6) * 1e3
         )
     return {

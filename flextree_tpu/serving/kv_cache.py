@@ -5,9 +5,14 @@ per sequence up front; a serving batch of ragged lengths wastes most of
 that and, worse, couples every sequence's lifetime to the batch's.  The
 paged layout breaks the coupling the way vLLM's PagedAttention does:
 
-- the pool is per layer ``(num_blocks, block_size, H, Dh)`` — one static
+- the pool is per layer ``(num_blocks, block_size, *row)`` — one static
   shape for the whole server lifetime, so the decode step stays ONE
-  compiled program regardless of which sequences are resident;
+  compiled program regardless of which sequences are resident.  What a
+  ``row`` (one cached position) is comes from the configuration
+  (``models.configs.pool_layout``): K and V of ``(H, Dh)`` each for the
+  dense and the Laguna block, ONE array of ``(kv_rank + d_rope,)`` for
+  latent attention.  The pools are ``{part: [array a layer]}``, and every
+  function below works on whatever parts they name;
 - each sequence owns a **block table** (a row of block ids): block
   ``p`` of the table holds cache positions ``p*block_size ..``; tables
   are plain int32 inputs to the jitted step, so the host can remap them
@@ -52,21 +57,8 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from jax import lax
 
-from ..models.generate import _qkv
-from ..ops.paged_attention import (
-    paged_attention,
-    paged_attention_gather,
-    runs_kernel,
-)
-from ..models.transformer import (
-    TransformerConfig,
-    apply_rope,
-    final_logits,
-    mlp_block,
-    rms_norm,
-)
+from ..models.configs import block_of, pool_layout
 
 __all__ = [
     "NULL_BLOCK",
@@ -251,39 +243,40 @@ class BlockAllocator:
 
 
 def init_pools(cfg, pcfg: PagedCacheConfig) -> dict:
-    """Per-layer (num_blocks, block_size, Hkv, Dh) K/V pools, zeros in the
-    compute dtype — mirrors ``init_kv_cache``'s structure with the batch
-    and length axes folded into (block, offset).  ``Hkv`` is the
-    configuration's ``n_kv_heads`` (the dense model's ``n_heads``)."""
-    shape = (pcfg.num_blocks, pcfg.block_size, cfg.n_kv_heads, cfg.head_dim)
+    """``{part: [(num_blocks, block_size, *row) a layer]}``, zeros in the
+    compute dtype: the parts and their rows are the configuration's
+    (``models.configs.pool_layout``) — K and V of ``(Hkv, Dh)`` for the
+    dense block (``Hkv`` its ``n_heads``) and the Laguna block, one
+    ``ckv`` of ``(kv_rank + d_rope,)`` for latent attention."""
     return {
-        "k": [jnp.zeros(shape, cfg.dtype) for _ in range(cfg.n_layers)],
-        "v": [jnp.zeros(shape, cfg.dtype) for _ in range(cfg.n_layers)],
+        part: [
+            jnp.zeros((pcfg.num_blocks, pcfg.block_size, *row), cfg.dtype)
+            for _ in range(cfg.n_layers)
+        ]
+        for part, row in pool_layout(cfg).items()
+    }
+
+
+def _map_pools(fn, pools: dict, source: dict) -> dict:
+    """``fn(pool, source array)`` over every part and layer of ``pools``;
+    ``source`` holds the same parts (and may hold more: a prefill's cache
+    has its lengths beside them)."""
+    return {
+        part: [fn(p, a) for p, a in zip(layers, source[part])]
+        for part, layers in pools.items()
     }
 
 
 def write_prefill(pools: dict, cache: dict, block_ids) -> dict:
     """Scatter a single-sequence contiguous prefill cache into the pool.
 
-    ``cache`` is ``prefill``'s output for a batch of ONE (its per-layer
-    K/V is (1, max_len, H, Dh) with zeros past the prompt); the first
+    ``cache`` is ``prefill``'s output for a batch of ONE (per part and
+    layer (1, max_len, *row) with zeros past the prompt); the first
     ``len(block_ids) * block_size`` positions land in ``block_ids`` in
     order.  Positions past the prompt scatter zeros — the same zeros the
     contiguous cache holds there, which the decode writes then fill in.
     """
-    idx = jnp.asarray(block_ids, jnp.int32)
-    n = len(block_ids)
-    out_k, out_v = [], []
-    for pk, pv, kc, vc in zip(pools["k"], pools["v"], cache["k"], cache["v"]):
-        bs = pk.shape[1]
-        if kc.shape[1] < n * bs:
-            raise ValueError(
-                f"prefill cache holds {kc.shape[1]} positions, "
-                f"{n} blocks need {n * bs}"
-            )
-        out_k.append(pk.at[idx].set(kc[0, : n * bs].reshape(n, bs, *pk.shape[2:])))
-        out_v.append(pv.at[idx].set(vc[0, : n * bs].reshape(n, bs, *pv.shape[2:])))
-    return {"k": out_k, "v": out_v}
+    return write_prefill_at(pools, cache, block_ids, 0)
 
 
 def write_prefill_at(pools: dict, cache: dict, block_ids,
@@ -302,50 +295,48 @@ def write_prefill_at(pools: dict, cache: dict, block_ids,
     n = int(idx.shape[0])
     if start_block < 0:
         raise ValueError(f"start_block must be >= 0, got {start_block}")
-    out_k, out_v = [], []
-    for pk, pv, kc, vc in zip(pools["k"], pools["v"], cache["k"], cache["v"]):
-        bs = pk.shape[1]
+
+    def scatter(pool, c):
+        bs = pool.shape[1]
         s0 = start_block * bs
-        if kc.shape[1] < s0 + n * bs:
+        if c.shape[1] < s0 + n * bs:
             raise ValueError(
-                f"prefill cache holds {kc.shape[1]} positions, blocks "
+                f"prefill cache holds {c.shape[1]} positions, blocks "
                 f"{start_block}..{start_block + n} need {s0 + n * bs}"
             )
-        out_k.append(
-            pk.at[idx].set(kc[0, s0 : s0 + n * bs].reshape(n, bs, *pk.shape[2:]))
+        return pool.at[idx].set(
+            c[0, s0 : s0 + n * bs].reshape(n, bs, *pool.shape[2:])
         )
-        out_v.append(
-            pv.at[idx].set(vc[0, s0 : s0 + n * bs].reshape(n, bs, *pv.shape[2:]))
-        )
-    return {"k": out_k, "v": out_v}
+
+    return _map_pools(scatter, pools, cache)
 
 
 def write_swapped(pools: dict, kv: dict, block_ids) -> dict:
-    """Scatter a swapped-out sequence's saved K/V back into newly
+    """Scatter a swapped-out sequence's saved rows back into newly
     assigned blocks — the resume half of preemption.
 
-    ``kv`` is per-layer ``{"k": [(n*bs, H, Dh)], "v": [...]}`` with
-    exactly ``len(block_ids) * block_size`` positions (the engine pads
-    the saved ``length`` positions with zeros host-side).  The pad
-    positions sit at or past the sequence's causal bound, so — the same
-    argument as ``write_prefill``'s over-scatter — they are invisible
-    until the decode writes overwrite them.  The restored bytes are the
-    exact bytes ``gather_seq`` saved, which is what makes swap-in resume
+    ``kv`` is per part and layer ``(n*bs, *row)`` with exactly
+    ``len(block_ids) * block_size`` positions (the engine pads the saved
+    ``length`` positions with zeros host-side).  The pad positions sit at
+    or past the sequence's causal bound, so — the same argument as
+    ``write_prefill``'s over-scatter — they are invisible until the
+    decode writes overwrite them.  The restored bytes are the exact bytes
+    ``gather_seq`` saved, which is what makes swap-in resume
     bit-identical.
     """
     idx = jnp.asarray(block_ids, jnp.int32)
     n = idx.shape[0]
-    out_k, out_v = [], []
-    for pk, pv, k, v in zip(pools["k"], pools["v"], kv["k"], kv["v"]):
-        bs = pk.shape[1]
-        if k.shape[0] != n * bs:
+
+    def scatter(pool, a):
+        bs = pool.shape[1]
+        if a.shape[0] != n * bs:
             raise ValueError(
-                f"swapped K/V holds {k.shape[0]} positions, "
+                f"swapped rows hold {a.shape[0]} positions, "
                 f"{n} blocks need {n * bs}"
             )
-        out_k.append(pk.at[idx].set(k.reshape(n, bs, *pk.shape[2:])))
-        out_v.append(pv.at[idx].set(v.reshape(n, bs, *pv.shape[2:])))
-    return {"k": out_k, "v": out_v}
+        return pool.at[idx].set(a.reshape(n, bs, *pool.shape[2:]))
+
+    return _map_pools(scatter, pools, kv)
 
 
 def check_decode_impl(impl: str) -> None:
@@ -362,31 +353,21 @@ def decode_attention_layers(cfg, pcfg: PagedCacheConfig,
     the Pallas kernel)``, as :func:`paged_decode_step` will build them
     for this configuration in this process: fixed by the backend and the
     shapes, so known before the program is traced."""
-    heads = getattr(cfg, "layer_heads", None) or (cfg.n_heads,) * cfg.n_layers
-    if not fused:
-        return len(heads), 0
-    pool = jax.ShapeDtypeStruct(
-        (pcfg.num_blocks, pcfg.block_size, cfg.n_kv_heads, cfg.head_dim),
-        cfg.dtype,
-    )
-    return len(heads), sum(
-        runs_kernel(
-            jax.ShapeDtypeStruct((1, h, cfg.head_dim), cfg.dtype), pool
-        )
-        for h in heads
-    )
+    layers, kernel = block_of(cfg).kernel_layers(cfg, pcfg)
+    return layers, kernel if fused else 0
 
 
-def paged_decode_step(params, pools, tables, lengths, tokens,
-                      cfg: TransformerConfig, fused: bool = False,
-                      impl: str = "jnp"):
+def paged_decode_step(params, pools, tables, lengths, tokens, cfg,
+                      fused: bool = False, impl: str = "jnp"):
     """One decode step for S slots over the paged pool.
 
     ``tables`` (S, P) int32 block tables, ``lengths`` (S,) int32 cache
     positions already filled per slot, ``tokens`` (S,) int32 the token to
     decode at each slot's position.  Returns ``(logits, pools)`` — (S,
-    vocab) f32 next-position logits and the pool with each slot's new K/V
-    scattered at ``(tables[s, lengths[s]//bs], lengths[s] % bs)``.
+    vocab) f32 next-position logits and the pool with each slot's new row
+    scattered at ``(tables[s, lengths[s]//bs], lengths[s] % bs)`` — and,
+    from a block with routed experts, a third result: what its routers
+    did.
 
     Inactive slots are driven with table rows of all-NULL_BLOCK and
     length 0: their writes land in the null block and their logits are
@@ -394,58 +375,25 @@ def paged_decode_step(params, pools, tables, lengths, tokens,
     below their causal bound, so pollution there is invisible (masked
     weights are exactly 0.0 — see the module docstring).
 
-    The per-layer math calls the SAME helpers as the contiguous decode
-    (``_qkv`` / ``apply_rope`` / ``mlp_block`` / ``final_logits``).
-    ``fused=False`` attends through ``ops.paged_attention_gather`` — the
-    gathered view has the same (S, P*bs) key length the contiguous cache
-    would, which plus exact-zero masking is the whole bitwise-identity
-    argument.  ``fused=True`` attends through ``ops.paged_attention``:
+    The walk over the layers is the block's own (``models.configs.
+    BLOCKS``: ``models.generate.paged_decode_dense``, ``models.laguna.
+    paged_decode_step``, ``models.pangu_ultra_moe.paged_decode_step``).
+    ``fused=False`` attends through the gather oracle of
+    ``ops.paged_attention``, ``fused=True`` through its streamed entry:
     same masking, online-softmax summation order, within
-    ``FUSED_DECODE_ATOL`` of the oracle.  Which of its two paths runs (the
-    Pallas kernel on a TPU, the block-streaming loop elsewhere) is
-    ``paged_attention``'s to decide from the backend and the shapes;
+    ``FUSED_DECODE_ATOL`` of the oracle.  Which of the streamed paths runs
+    (the Pallas kernel on a TPU, the block-streaming loop elsewhere) is
+    ``ops.paged_attention``'s to decide from the backend and the shapes;
     ``impl`` ("jnp" or "pallas") is accepted, checked and passed nowhere:
     the benchmark's traffic files still carry the key (ROADMAP D3).
-
-    A configuration of another block than the dense one brings its own
-    walk behind these arguments (``models.laguna.paged_decode_step``),
-    which hands out a third result, what its routers did.
     """
     check_decode_impl(impl)
-    if not isinstance(cfg, TransformerConfig):
-        from ..models import laguna
-
-        return laguna.paged_decode_step(
-            params, pools, tables, lengths, tokens, cfg, fused
-        )
-    s = tokens.shape[0]
-    positions = lengths[:, None].astype(jnp.int32)  # (S, 1) per-sequence
-    bs = pools["k"][0].shape[1]
-    row = jnp.arange(s)
-    blk = tables[row, lengths // bs]  # (S,) current block per slot
-    off = lengths % bs
-    attend = paged_attention if fused else paged_attention_gather
-    x = params["embed"][tokens[:, None]].astype(cfg.dtype)
-    new_k, new_v = [], []
-    for layer, pk, pv in zip(params["layers"], pools["k"], pools["v"]):
-        h = rms_norm(x, layer["ln1"])
-        q, k, v = _qkv(layer, h, cfg)
-        q = apply_rope(q, positions, cfg.rope_theta)
-        k = apply_rope(k, positions, cfg.rope_theta)
-        attn = attend(
-            q[:, 0], k[:, 0], v[:, 0], pk, pv, tables, lengths
-        )[:, None]
-        o = attn.reshape(s, 1, -1) @ layer["wo"].astype(cfg.dtype)
-        x = x + o
-        x = mlp_block(layer, x, cfg)
-        # scatter the appended K/V back into each row's current block
-        new_k.append(pk.at[blk, off].set(k[:, 0]))
-        new_v.append(pv.at[blk, off].set(v[:, 0]))
-    logits = final_logits(params["embed"], params["ln_f"], x)
-    return logits[:, 0], {"k": new_k, "v": new_v}
+    return block_of(cfg).decode_step(
+        params, pools, tables, lengths, tokens, cfg, fused
+    )
 
 
-def make_paged_decode_fn(cfg: TransformerConfig, donate: bool = True,
+def make_paged_decode_fn(cfg, donate: bool = True,
                          fused: bool = False, impl: str = "jnp"):
     """Jit ``paged_decode_step`` with the pool buffers donated (the old
     pool is dead the moment the new one exists — donation keeps steady-
@@ -461,10 +409,11 @@ def make_paged_decode_fn(cfg: TransformerConfig, donate: bool = True,
 
 def export_blocks(pools: dict, block_ids) -> dict:
     """Pull a sequence's blocks out of the pool at BLOCK granularity —
-    per-layer ``(n, bs, H, Dh)`` — for migration to another replica.
+    per part and layer ``(n, bs, *row)`` — for migration to another
+    replica.
 
-    This is deliberately NOT :func:`gather_seq`: no ``(n*bs, H, Dh)``
-    contiguous row is ever materialized.  The wire payload ships blocks
+    This is deliberately NOT :func:`gather_seq`: no ``(n*bs, *row)``
+    contiguous view is ever materialized.  The wire payload ships blocks
     exactly as the pool stores them, and the importing side scatters the
     same block-shaped arrays straight back with :func:`write_imported` —
     so the f32 path moves the pool bytes verbatim (the bitwise-identity
@@ -472,19 +421,16 @@ def export_blocks(pools: dict, block_ids) -> dict:
     transfer itself.
     """
     idx = jnp.asarray(block_ids, jnp.int32)
-    return {
-        "k": [pk[idx] for pk in pools["k"]],
-        "v": [pv[idx] for pv in pools["v"]],
-    }
+    return {part: [p[idx] for p in layers] for part, layers in pools.items()}
 
 
 def write_imported(pools: dict, kv: dict, block_ids) -> dict:
-    """Scatter migrated block-shaped K/V into newly assigned blocks — the
+    """Scatter migrated block-shaped rows into newly assigned blocks — the
     receiving half of :func:`export_blocks`.
 
-    ``kv`` is per-layer ``{"k": [(n, bs, H, Dh)], "v": [...]}`` with
-    exactly ``len(block_ids)`` blocks.  Positions in the final block past
-    the migrated sequence's length sit at or beyond its causal bound, so
+    ``kv`` is per part and layer ``(n, bs, *row)`` with exactly
+    ``len(block_ids)`` blocks.  Positions in the final block past the
+    migrated sequence's length sit at or beyond its causal bound, so
     — the same over-scatter argument as :func:`write_swapped` — whatever
     the tail holds is invisible until decode writes overwrite it.  On the
     f32 codec the scattered bytes are the exact bytes
@@ -493,28 +439,23 @@ def write_imported(pools: dict, kv: dict, block_ids) -> dict:
     """
     idx = jnp.asarray(block_ids, jnp.int32)
     n = idx.shape[0]
-    out_k, out_v = [], []
-    for pk, pv, k, v in zip(pools["k"], pools["v"], kv["k"], kv["v"]):
-        if k.shape[0] != n or k.shape[1:] != pk.shape[1:]:
+
+    def scatter(pool, a):
+        if a.shape[0] != n or a.shape[1:] != pool.shape[1:]:
             raise ValueError(
-                f"imported K/V shaped {tuple(k.shape)}, "
-                f"{n} blocks of {tuple(pk.shape[1:])} expected"
+                f"imported rows shaped {tuple(a.shape)}, "
+                f"{n} blocks of {tuple(pool.shape[1:])} expected"
             )
-        out_k.append(pk.at[idx].set(k))
-        out_v.append(pv.at[idx].set(v))
-    return {"k": out_k, "v": out_v}
+        return pool.at[idx].set(a)
+
+    return _map_pools(scatter, pools, kv)
 
 
 def gather_seq(pools: dict, block_ids, length: int | None = None) -> dict:
-    """Test/debug helper: one sequence's contiguous K/V view — per-layer
-    (n_blocks*bs, H, Dh), truncated to ``length`` if given."""
+    """One sequence's contiguous view — per part and layer
+    ``(n_blocks*bs, *row)``, truncated to ``length`` if given."""
     idx = jnp.asarray(block_ids, jnp.int32)
-    out = {"k": [], "v": []}
-    for pk, pv in zip(pools["k"], pools["v"]):
-        k = pk[idx].reshape(-1, *pk.shape[2:])
-        v = pv[idx].reshape(-1, *pv.shape[2:])
-        if length is not None:
-            k, v = k[:length], v[:length]
-        out["k"].append(k)
-        out["v"].append(v)
-    return out
+    return {
+        part: [p[idx].reshape(-1, *p.shape[2:])[:length] for p in layers]
+        for part, layers in pools.items()
+    }

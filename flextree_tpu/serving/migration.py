@@ -1,10 +1,12 @@
-"""KV migration payloads: block-shaped K/V packed for the replica wire.
+"""KV migration payloads: block-shaped cache rows packed for the replica wire.
 
 Prefill/decode disaggregation ships a sequence's KV blocks from the
 prefill replica that computed them to the decode replica that will own
 the sequence.  This module is the wire format — the pure function pair
 ``pack_kv`` / ``unpack_kv`` between the engine's block-granular export
-(``kv_cache.export_blocks``, per-layer ``(n, bs, H, Dh)``) and bytes:
+(``kv_cache.export_blocks``, per part and layer ``(n, bs, *row)``, the
+parts and their rows being the model's pool layout: K and V of ``(H,
+Dh)``, or one latent ``ckv`` row) and bytes:
 
 - **codec per hop, reusing ``ops/quantize.py``** (EQuARX's move applied
   to the migration hop instead of the allreduce hop): ``f32`` ships the
@@ -24,8 +26,9 @@ the sequence.  This module is the wire format — the pure function pair
   would silently poison one sequence's attention, the exact failure
   class the CRC-trailered RPC framing exists to make loud.
 
-Tensor order on the wire is fixed (layer-major, K before V) so two
-replicas never need to negotiate layout; the meta dict travels in the
+Tensor order on the wire is fixed (layer-major, the parts in the order
+the payload's ``layout`` states them: K before V) and the meta states the
+layout, which the receiver holds against its own model's; the meta dict travels in the
 RPC JSON body, the blob rides base64-chunked frames (``rpc.chunk_blob``).
 """
 
@@ -62,18 +65,19 @@ def _crc(data: bytes) -> int:
 
 
 def _tensors(kv: dict):
-    """Fixed wire order: layer-major, K before V."""
-    for layer, (k, v) in enumerate(zip(kv["k"], kv["v"])):
-        yield layer, "k", k
-        yield layer, "v", v
+    """Fixed wire order: layer-major, the parts in ``kv``'s order."""
+    for layer in range(len(next(iter(kv.values())))):
+        for part, layers in kv.items():
+            yield layer, part, layers[layer]
 
 
 def pack_kv(kv: dict, *, codec: str = "f32") -> tuple[dict, bytes]:
-    """Pack block-shaped K/V into ``(meta, blob)`` for the wire.
+    """Pack block-shaped cache rows into ``(meta, blob)`` for the wire.
 
-    ``kv`` is ``export_blocks`` output: per-layer ``(n, bs, H, Dh)``.
-    ``meta`` declares the geometry, codec, and per-tensor byte spans +
-    CRCs; ``blob`` is the concatenated tensor payload in fixed order.
+    ``kv`` is ``export_blocks`` output: per part and layer ``(n, bs,
+    *row)``.  ``meta`` declares the geometry (``layout``: each part's
+    row shape), codec, and per-tensor byte spans + CRCs; ``blob`` is the
+    concatenated tensor payload in fixed order.
     The f32 codec emits each tensor's float32 bytes verbatim (bitwise);
     int8 emits ``encode_int8``'s (q, scales) pair per tensor, flattened,
     with the tensor's amax recorded so the receiver can state the
@@ -84,15 +88,18 @@ def pack_kv(kv: dict, *, codec: str = "f32") -> tuple[dict, bytes]:
         raise MigrationError(
             f"codec {c.name!r} is not a migration codec (f32 | int8)"
         )
-    first = np.asarray(kv["k"][0])
-    n, bs, heads, dh = first.shape
+    n, bs = np.asarray(next(iter(kv.values()))[0]).shape[:2]
+    layout = {
+        part: [int(x) for x in np.asarray(layers[0]).shape[2:]]
+        for part, layers in kv.items()
+    }
     tensors, parts = [], []
     for layer, part, arr in _tensors(kv):
         a = np.ascontiguousarray(np.asarray(arr, dtype=np.float32))
-        if a.shape != (n, bs, heads, dh):
+        if a.shape != (n, bs, *layout[part]):
             raise MigrationError(
                 f"layer {layer} {part} shaped {a.shape}, expected "
-                f"{(n, bs, heads, dh)}"
+                f"{(n, bs, *layout[part])}"
             )
         if c.name == "f32":
             payload = a.tobytes()
@@ -120,9 +127,8 @@ def pack_kv(kv: dict, *, codec: str = "f32") -> tuple[dict, bytes]:
         "codec_block": c.block,
         "n_blocks": int(n),
         "block_size": int(bs),
-        "n_heads": int(heads),
-        "head_dim": int(dh),
-        "n_layers": len(kv["k"]),
+        "layout": layout,
+        "n_layers": len(next(iter(kv.values()))),
         "nbytes": len(blob),
         "crc32": _crc(blob),
         "tensors": tensors,
@@ -131,23 +137,25 @@ def pack_kv(kv: dict, *, codec: str = "f32") -> tuple[dict, bytes]:
 
 
 def unpack_kv(meta: dict, blob: bytes) -> dict:
-    """Verify and decode a migration payload back to block-shaped K/V.
+    """Verify and decode a migration payload back to block-shaped rows.
 
     Refuses loudly (:class:`MigrationError`) on: whole-blob CRC or byte
-    count drift, per-tensor CRC drift, tensor count vs declared layers,
-    byte spans that do not reconstruct the declared geometry, unknown
-    codec.  On success returns ``{"k": [np (n, bs, H, Dh) f32], "v":
-    [...]}`` ready for ``kv_cache.write_imported``.
+    count drift, per-tensor CRC drift, tensor count vs declared layers
+    and parts, byte spans that do not reconstruct the declared geometry,
+    unknown codec.  On success returns ``{part: [np (n, bs, *row) f32 a
+    layer]}`` ready for ``kv_cache.write_imported``.
     """
     try:
         codec = get_codec(meta["codec"])
         n = int(meta["n_blocks"])
         bs = int(meta["block_size"])
-        heads = int(meta["n_heads"])
-        dh = int(meta["head_dim"])
+        layout = {
+            str(part): tuple(int(x) for x in row)
+            for part, row in meta["layout"].items()
+        }
         layers = int(meta["n_layers"])
         tensors = list(meta["tensors"])
-    except (KeyError, TypeError, ValueError) as e:
+    except (KeyError, TypeError, ValueError, AttributeError) as e:
         raise MigrationError(f"malformed migration meta: {e}") from None
     if len(blob) != int(meta.get("nbytes", -1)):
         raise MigrationError(
@@ -155,14 +163,12 @@ def unpack_kv(meta: dict, blob: bytes) -> dict:
         )
     if _crc(blob) != int(meta.get("crc32", -1)):
         raise MigrationError("payload CRC mismatch — corrupt migration blob")
-    if len(tensors) != 2 * layers:
+    if len(tensors) != len(layout) * layers:
         raise MigrationError(
             f"{len(tensors)} tensors declared for {layers} layers "
-            f"(expected {2 * layers})"
+            f"(expected {len(layout) * layers})"
         )
-    shape = (n, bs, heads, dh)
-    count = int(np.prod(shape))
-    out = {"k": [None] * layers, "v": [None] * layers}
+    out = {part: [None] * layers for part in layout}
     off = 0
     for i, entry in enumerate(tensors):
         try:
@@ -170,10 +176,12 @@ def unpack_kv(meta: dict, blob: bytes) -> dict:
             nbytes, crc = int(entry["nbytes"]), int(entry["crc32"])
         except (KeyError, TypeError, ValueError) as e:
             raise MigrationError(f"malformed tensor entry {i}: {e}") from None
-        if not (0 <= layer < layers and part in ("k", "v")):
+        if not (0 <= layer < layers and part in layout):
             raise MigrationError(f"tensor entry {i} addresses {part}@{layer}")
         if out[part][layer] is not None:
             raise MigrationError(f"duplicate tensor {part}@{layer}")
+        shape = (n, bs, *layout[part])
+        count = int(np.prod(shape))
         payload = blob[off : off + nbytes]
         if len(payload) != nbytes:
             raise MigrationError(

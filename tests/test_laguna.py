@@ -20,7 +20,9 @@ from benchmarks.lib import harness, serve_closed_model as driver
 from benchmarks.reference import laguna_decoder as ref
 from flextree_tpu.models import laguna
 from flextree_tpu.models.configs import config_from_dict
-from flextree_tpu.models.moe import dropless_experts, route_topk_normalized
+from flextree_tpu.models.moe import (
+    MOE_COUNTS, dropless_experts, gated_ffn, route_topk_normalized,
+)
 from flextree_tpu.obs import flight_recorder
 from flextree_tpu.ops.paged_attention import (
     FUSED_DECODE_ATOL, paged_attention, paged_attention_gather,
@@ -170,7 +172,7 @@ def test_the_shares_add_up_to_the_uncut_layer():
             share, x, dataclasses.replace(cfg, experts_held=(lo, hi)), 2)
         parts.append(np.asarray(out - x)[0])
         assert int(moe["sizes"].sum()) == int(((picks >= lo) & (picks < hi)).sum())
-    shared = np.asarray(laguna._gated_ffn(layer["shared"], h))
+    shared = np.asarray(gated_ffn(layer["shared"], h))
     np.testing.assert_allclose(
         parts[0] + parts[1] - shared, np.asarray(whole), atol=2e-5)
     # and a share alone is the reference given the same share
@@ -372,7 +374,7 @@ def test_a_round_carries_what_its_routers_counted():
              if e["kind"] == "span" and e["name"] == "ft.engine.bookkeeping"]
     assert len(books) == 2
     for book in books:
-        assert set(laguna.MOE_COUNTS) <= set(book)
+        assert set(MOE_COUNTS) <= set(book)
         # 3 active slots x 4 picks x 4 sparse layers; 8 held experts a layer
         assert book["picks"] == 3 * 4 * 4 and book["experts_held"] == 8 * 4
         assert 0 < book["local_picks"] <= book["picks"]
@@ -394,7 +396,7 @@ def test_the_dense_engine_round_carries_no_router_counts():
     with flight_recorder(None) as rec:
         eng.step()
     book = [e for e in rec.events if e.get("name") == "ft.engine.bookkeeping"][0]
-    assert not set(laguna.MOE_COUNTS) & set(book)
+    assert not set(MOE_COUNTS) & set(book)
     assert "serve.moe_picks" not in eng.report()["counters"]
 
 

@@ -20,6 +20,7 @@ gathered row at once).  So:
   scatters (the scatter is shared code, only attention differs).
 """
 
+import importlib
 from functools import partial
 
 import jax
@@ -36,7 +37,6 @@ from flextree_tpu.ops.paged_attention import (
     paged_attention_gather,
     runs_kernel,
 )
-from flextree_tpu.serving import kv_cache
 from flextree_tpu.serving.kv_cache import (
     NULL_BLOCK,
     BlockAllocator,
@@ -47,6 +47,10 @@ from flextree_tpu.serving.kv_cache import (
     paged_decode_step,
 )
 from flextree_tpu.utils import backend
+
+# the MODULE that holds the dense decode walk (the package re-exports the
+# function of the same name)
+generate_module = importlib.import_module("flextree_tpu.models.generate")
 
 S, H, D, N, BS, P = 5, 4, 16, 32, 8, 7
 #: ragged mix: empty row, short, block-aligned, mid-block, near-full
@@ -295,7 +299,7 @@ def test_decode_step_fused_vs_gather(model, impl, monkeypatch):
     deeper layers' scatters inherit the attention tolerance through the
     residual stream.  The step no longer hands ``impl`` down, so the
     kernel's case forces it where the step looks the entry up."""
-    monkeypatch.setattr(kv_cache, "paged_attention",
+    monkeypatch.setattr(generate_module, "paged_attention",
                         partial(paged_attention, impl=impl))
     cfg, params = model
     pcfg = PagedCacheConfig(num_blocks=24, block_size=8, blocks_per_seq=6)

@@ -726,4 +726,4 @@ def test_new_metric_files_name_readers_that_exist():
         fn = getattr(S, meta["reader"].split(":")[1])
         inspect.signature(fn).bind(None, **meta.get("args", {}))
         assert entry["source"] in ("program_span", "program_counter", "device_trace")
-    assert seen == 24  # PR 31: kernels.paged_kernel_share
+    assert seen == 26  # PR 32: attn.mla_proj_share, attn.mla_core_share

@@ -20,8 +20,10 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from flextree_tpu.models.transformer import TransformerConfig, init_params
-from flextree_tpu.ops.paged_attention import paged_attention
-from flextree_tpu.ops.pallas_attention import flash_attention
+from flextree_tpu.ops.paged_attention import (
+    paged_attention, paged_attention_latent,
+)
+from flextree_tpu.ops.pallas_attention import flash_attention, kvgrid_tiles
 from flextree_tpu.utils import backend
 
 FLAGSHIP = TransformerConfig(
@@ -159,6 +161,71 @@ def test_paged_decode_is_one_mosaic_kernel_that_copies_no_pool(
     assert not re.search(rf"= \w+\[{n},[\d,]*\]\S* copy\(", hlo)
     assert len(re.findall(rf"= bf16\[{n},\d+,128\]\S* bitcast\(", hlo)) == 2
     assert " while(" not in hlo
+
+
+#: the latent cell's decode shapes: 32 slots, 128 heads over one 576-wide
+#: row whose first 512 numbers are the values, 513 blocks of 544
+LATENT = dict(s=32, h=128, r=576, bs=544, p=16, n=513, value_dim=512)
+
+
+def _latent_avals(dev, s, h, r, bs, p, n, **_):
+    one = NamedSharding(_mesh([dev], (1, 1, 1)), P())
+
+    def a(*shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one)
+
+    return (a(s, h, r), a(s, r), a(n, bs, r), a(s, p, dt=jnp.int32),
+            a(s, dt=jnp.int32))
+
+
+@pytest.mark.parametrize("bs,kernel", [(544, True), (128, True), (40, False)])
+def test_latent_decode_is_one_mosaic_kernel_that_copies_no_pool(
+    v5e, tpu_lowering, bs, kernel
+):
+    """Mosaic takes the latent kernel at the cell's shapes: one
+    ``tpu_custom_call``, no loop.  At the cell's block size (544: no
+    multiple of the 128 lanes) XLA:TPU keeps a (N, bs, 576) array
+    row-major and the pool goes in as it is; at a block of 128 it keeps
+    the 128-wide axis minor, and every use of the rows as rows is a copy
+    of the whole pool (PERF.md section 6, PR 32).  A block that is no
+    whole number of sublane tiles walks the loop."""
+    import re
+
+    shape = dict(LATENT, bs=bs, p=8704 // bs if 8704 % bs == 0 else 218,
+                 n=32 * (8704 // bs) + 1)
+    hlo = _compile(
+        lambda *a: paged_attention_latent(*a, value_dim=512, scale=0.07),
+        *_latent_avals(v5e[0], **shape),
+    ).as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') == int(kernel)
+    assert (" while(" in hlo) == (not kernel)
+    copies = re.findall(rf"= bf16\[{shape['n']},{bs},576\]\S* copy\(", hlo)
+    if kernel:
+        assert len(copies) == (1 if bs == 128 else 0)
+
+
+def test_the_flash_forward_takes_values_narrower_than_its_keys(v5e, tpu_lowering):
+    """The latent prefill's expanded form at the cell's longest prompt:
+    128 heads of 192-wide queries and keys over 128-wide values, through
+    the ``kvgrid`` forward at the tiles the prefill derives from those
+    widths (the ``loop`` forward keeps whole k and v in VMEM and is
+    refused there at this length)."""
+    one = NamedSharding(_mesh([v5e[0]], (1, 1, 1)), P())
+    a = lambda d: jax.ShapeDtypeStruct((1, 8192, 128, d), jnp.bfloat16, sharding=one)  # noqa: E731
+    tiles = kvgrid_tiles(192, 128, jnp.bfloat16)
+    assert tiles == {"block_q": 1024, "block_k": 2048}
+    hlo = _compile(
+        lambda q, k, v: flash_attention(
+            q, k, v, scale=0.07, variant="kvgrid", **tiles
+        ),
+        a(192), a(192), a(128),
+    ).as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 1
+    with pytest.raises(ValueError, match="kvgrid"):
+        _compile(
+            lambda q, k, v: flash_attention(q, k, v, variant="loop"),
+            a(192), a(192), a(128),
+        )
 
 
 def test_a_shape_the_paged_kernel_refuses_walks_the_loop_without_a_raise(
