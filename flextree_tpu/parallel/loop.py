@@ -101,10 +101,17 @@ class FitConfig:
     # NaN/Inf guard: skip steps whose loss (or grad_norm, when the step
     # reports one) is non-finite; after max_bad_steps CONSECUTIVE skips,
     # rewind to the last verified checkpoint; after max_rewinds rewinds
-    # raise TrainingDiverged.  The check device_gets the metrics every
-    # step, so it synchronizes host and device (on accelerators this
-    # trades dispatch pipelining for catching the FIRST bad update before
-    # it compounds); nan_guard=False restores the fail-fast async loop.
+    # raise TrainingDiverged.  A built step (make_train_step and its two
+    # siblings) makes the verdict itself (metrics["applied"]) and refuses
+    # a bad update on the device, so the FIRST bad update never lands
+    # and fit reads step n's verdict while step n+1 runs: the accounting
+    # (skips, streak, rewind) comes one step late and the device never
+    # waits for the host.  A step that gives no verdict is guarded here,
+    # on the host: its metrics are fetched before the next dispatch and a
+    # bad update is dropped by keeping the old state.  nan_guard=False
+    # turns fit's part off: nothing is fetched but the logged losses,
+    # nothing is counted and nothing rewinds; a built step still refuses
+    # its bad updates, silently.
     nan_guard: bool = True
     max_bad_steps: int = 3
     max_rewinds: int = 2
@@ -250,6 +257,26 @@ def _metrics_finite(metrics) -> bool:
     return True
 
 
+def _own_buffers(packed, state):
+    """``packed`` with every leaf that IS one of ``state``'s device arrays
+    copied on the device: what a background writer may keep while the
+    next step consumes (donates) the state itself."""
+    from .train import copy_leaf
+
+    live = {id(x) for x in jax.tree.leaves(state) if isinstance(x, jax.Array)}
+    return jax.tree.map(
+        lambda x: copy_leaf(x) if id(x) in live else x, packed
+    )
+
+
+def _consumed(state) -> bool:
+    """True when a step has taken (donated) ``state``'s buffers."""
+    return any(
+        isinstance(x, jax.Array) and x.is_deleted()
+        for x in jax.tree.leaves(state)
+    )
+
+
 def _apply_rebuild(rebuilt, cur_pack, cur_unpack):
     """Normalize a rebuild-hook result to the full 5-tuple swap.
 
@@ -363,6 +390,89 @@ def fit(
 
     batches = _batches(start)
 
+    # a self-guarded step whose verdict the host has not read yet, as
+    # (step index, metrics): the step in flight.  Depth one.
+    pending = None
+
+    def _log_loss(done, metrics):
+        if cfg.log_every and (done % cfg.log_every == 0 or done == cfg.num_steps):
+            loss = float(metrics["loss"])
+            losses.append((done, loss))
+            rate = (done - start) / (time.perf_counter() - t0)
+            log.info("step %d loss %.4f (%.1f steps/s)", done, loss, rate)
+
+    def _back_to_checkpoint():
+        """``state``, ``step`` and the batch stream at the newest
+        checkpoint that verifies; a step in flight is dropped with the
+        state it ran from."""
+        nonlocal state, step, batches, pending
+        if sup is not None:
+            # never race an in-flight background save's rotation
+            # with the restore (the saver forbids two writers)
+            _drained_saves(timeout=None)
+        pending = None
+        state = _restore()
+        step = int(np.asarray(jax.device_get(state["step"])))
+        batches = _batches(step)
+
+    def _bad_step(at) -> bool:
+        """The accounting of the non-finite step ``at``.  True when it
+        rewound (:func:`_back_to_checkpoint`)."""
+        nonlocal bad_streak
+        report.anomalies += 1
+        report.skipped_steps.append(at)
+        bad_streak += 1
+        record_event("nan_skip", step=at, streak=bad_streak)
+        log.warning(
+            "step %d: non-finite loss/grad (%d consecutive) — update skipped",
+            at, bad_streak,
+        )
+        if bad_streak < cfg.max_bad_steps:
+            return False
+        if not (cfg.ckpt_dir and latest_checkpoint(cfg.ckpt_dir)):
+            raise TrainingDiverged(
+                f"{bad_streak} consecutive non-finite steps at step "
+                f"{at} and no checkpoint to rewind to"
+            )
+        if report.rewinds >= cfg.max_rewinds:
+            raise TrainingDiverged(
+                f"still diverging after {report.rewinds} rewinds "
+                f"(step {at})"
+            )
+        dump_current("nan_rewind", step=at)  # pre-rewind context
+        _back_to_checkpoint()
+        report.rewinds += 1
+        bad_streak = 0
+        record_event("nan_rewind", step=step)
+        log.warning("rewound to checkpointed step %d", step)
+        return True
+
+    def _settle(entry, lagged) -> str:
+        """Read a self-guarded step's verdict and do that step's
+        accounting: "applied", "skipped" (the step refused its update and
+        advanced past the batch) or "rewound".  ``lagged``: 1 when a later
+        step is already on the device, 0 when the host waits for this one."""
+        nonlocal bad_streak
+        at, metrics = entry
+        with span("ft.loop.guard_fetch", lagged=lagged, steps=1):
+            applied = not cfg.nan_guard or bool(
+                np.asarray(jax.device_get(metrics["applied"]))
+            )
+        if not applied:
+            return "rewound" if _bad_step(at) else "skipped"
+        bad_streak = 0
+        _log_loss(at + 1, metrics)
+        return "applied"
+
+    def _drain() -> str:
+        """Settle the step in flight, if there is one: before anything
+        that needs the state (a save, a shrink, a resize) or ends the run."""
+        nonlocal pending
+        if pending is None:
+            return "applied"
+        entry, pending = pending, None
+        return _settle(entry, 0)
+
     def _lease_resize(at_step, directive):
         """Apply an arbiter grant change: checkpoint now, rebuild for the
         new chip count, restore, prove the resume bitwise, ack.
@@ -379,6 +489,7 @@ def fit(
         nonlocal cur_step_fn, cur_mesh, cur_specs, cur_pack, cur_unpack
         from ..planner.choose import replan_for_survivors
 
+        _drain()
         n = directive.n
         if n < 1:
             raise ValueError(
@@ -520,8 +631,8 @@ def fit(
             # collective — the block would then happen OUTSIDE the deadline
             # at the metrics fetch.  Materialize inside the watchdogged
             # call so FT_STEP_TIMEOUT covers device execution, not just
-            # dispatch.  (The nan_guard device_gets the metrics every step
-            # anyway, so this adds no extra host-device sync per step.)
+            # dispatch.  (The guard then reads this step's verdict at
+            # once, not one step late: nothing is left in flight.)
             return jax.block_until_ready(cur_step_fn(st, tk, tg))
 
         def _shrink(at_step, new_dead, *, alive=None, plan=None):
@@ -535,6 +646,7 @@ def fit(
             nonlocal cur_step_fn, cur_mesh, cur_specs, cur_pack, cur_unpack
             from ..planner.choose import replan_for_survivors
 
+            _drain()
             prev_world = world
             n_alive = (
                 int(alive) if alive is not None
@@ -812,7 +924,12 @@ def fit(
         resumed_from=resumed_from,
     )
     try:
-        while step < cfg.num_steps:
+        while step < cfg.num_steps or pending is not None:
+            if step >= cfg.num_steps:
+                # the last step is still in flight: read its verdict (a
+                # rewind re-enters the loop) before the run may end
+                _drain()
+                continue
             with span("ft.loop.step", step=step):
                 if sup is not None or arbiter is not None:
                     # supervisor, coordination and lease ticks
@@ -820,6 +937,7 @@ def fit(
                         if sup is not None:
                             if sup.preemption is not None and sup.preemption.preempted:
                                 # the checkpoint-now fast path: at most one step lost
+                                _drain()
                                 if cfg.ckpt_dir and _drained_saves():
                                     # drain timed out -> the in-flight background save
                                     # IS a recent checkpoint; racing its rotation with
@@ -858,6 +976,7 @@ def fit(
                         next(batches) if batches is not None else dataset.batch_at(step)
                     )
                 record_event("step_start", step=step)
+                materialized = False
                 if sup is None:
                     with span("ft.loop.dispatch"):
                         new_state, metrics = cur_step_fn(
@@ -879,6 +998,7 @@ def fit(
                         and fb.wants_step_spans()
                     )
                     fb_cap = None
+                    materialized = watchdog is not None or fb_spans
                     t_step0 = time.perf_counter()
                     try:
                         with contextlib.ExitStack() as _stack:
@@ -912,6 +1032,14 @@ def fit(
                             timeout_retries = 0
                             continue
                         if timeout_retries < sup.max_step_retries:
+                            if _consumed(state):
+                                # the abandoned step owned (donated) its
+                                # state: the retry starts from the last
+                                # checkpoint, or there is nothing to retry
+                                if not (cfg.ckpt_dir
+                                        and latest_checkpoint(cfg.ckpt_dir)):
+                                    raise
+                                _back_to_checkpoint()
                             timeout_retries += 1
                             report.step_retries += 1
                             log.warning(
@@ -941,51 +1069,50 @@ def fit(
                                 "planner feedback disarmed for the run", step,
                             )
                 record_event("step_end", step=step)
-                # where the host waits for the device: the guard fetches
-                # the step's loss
-                with span("ft.loop.guard_fetch"):
-                    finite = not cfg.nan_guard or _metrics_finite(metrics)
-                if not finite:
-                    report.anomalies += 1
-                    report.skipped_steps.append(step)
-                    bad_streak += 1
-                    record_event("nan_skip", step=step, streak=bad_streak)
-                    log.warning(
-                        "step %d: non-finite loss/grad (%d consecutive) — update skipped",
-                        step, bad_streak,
-                    )
-                    if bad_streak >= cfg.max_bad_steps:
-                        if not (cfg.ckpt_dir and latest_checkpoint(cfg.ckpt_dir)):
-                            raise TrainingDiverged(
-                                f"{bad_streak} consecutive non-finite steps at step "
-                                f"{step} and no checkpoint to rewind to"
-                            )
-                        if report.rewinds >= cfg.max_rewinds:
-                            raise TrainingDiverged(
-                                f"still diverging after {report.rewinds} rewinds "
-                                f"(step {step})"
-                            )
-                        if sup is not None:
-                            # never race an in-flight background save's rotation
-                            # with the restore (the saver forbids two writers)
-                            _drained_saves(timeout=None)
-                        dump_current("nan_rewind", step=step)  # pre-rewind context
-                        state = _restore()
-                        report.rewinds += 1
-                        bad_streak = 0
-                        step = int(np.asarray(jax.device_get(state["step"])))
-                        record_event("nan_rewind", step=step)
-                        log.warning("rewound to checkpointed step %d", step)
-                        batches = _batches(step)
-                        continue
-                    # skip: discard the poisoned update, advance past the batch
-                    step += 1
-                    state = _stamp_step(state, step)
-                    continue
-                with span("ft.loop.bookkeeping"):
+                ckpt_due = bool(
+                    cfg.ckpt_dir and cfg.ckpt_every
+                    and (step + 1) % cfg.ckpt_every == 0
+                )
+                self_guarded = "applied" in metrics
+                if self_guarded:
+                    # the step made its own verdict, refused a bad update
+                    # on the device and took (donated) its state: the
+                    # result IS the state, and the host reads the step
+                    # BEFORE this one while this one runs
                     state = new_state
-                    bad_streak = 0
+                    waiting, pending = pending, (step, metrics)
                     step += 1
+                    if (waiting is not None
+                            and _settle(waiting, 1) == "rewound"):
+                        continue
+                    # read this step's own verdict now where the step was
+                    # materialised on purpose (watchdog, span clock), where
+                    # a feedback controller ticks between steps, and where
+                    # a checkpoint is due: never a refused step's state
+                    if (
+                        materialized or ckpt_due
+                        or (sup is not None and sup.feedback is not None)
+                    ) and _drain() != "applied":
+                        continue
+                else:
+                    if _drain() == "rewound":  # a swapped-out step's last
+                        continue
+                    # where the host waits for the device: the guard
+                    # fetches the step's loss
+                    with span("ft.loop.guard_fetch", lagged=0, steps=1):
+                        finite = not cfg.nan_guard or _metrics_finite(metrics)
+                    if not finite:
+                        if not _bad_step(step):
+                            # skip: discard the poisoned update, advance
+                            # past the batch
+                            step += 1
+                            state = _stamp_step(state, step)
+                        continue
+                with span("ft.loop.bookkeeping"):
+                    if not self_guarded:
+                        state = new_state
+                        bad_streak = 0
+                        step += 1
                     if (sup is not None and sup.feedback is not None
                             and not feedback_dead and step < cfg.num_steps):
                         # closed-loop planner feedback (docs/FEEDBACK.md): with no
@@ -1056,18 +1183,19 @@ def fit(
                                 "feedback tick failed at step %d; planner feedback "
                                 "disarmed for the rest of the run", step,
                             )
-                    if cfg.log_every and (step % cfg.log_every == 0 or step == cfg.num_steps):
-                        loss = float(metrics["loss"])
-                        losses.append((step, loss))
-                        rate = (step - start) / (time.perf_counter() - t0)
-                        log.info("step %d loss %.4f (%.1f steps/s)", step, loss, rate)
-                    if cfg.ckpt_dir and cfg.ckpt_every and step % cfg.ckpt_every == 0:
+                    if not self_guarded:
+                        _log_loss(step, metrics)
+                    if ckpt_due:
                         if sup is not None and sup.background_saver is not None:
                             # off-step-path save: the step loop never blocks on
                             # serialization + fsync, so ckpt_every can be small
                             # (the pack conversion, when set, runs on-path — it
-                            # is the consolidation collective, not the fsync)
-                            sup.background_saver.submit(_packed(state))
+                            # is the consolidation collective, not the fsync).
+                            # The writer keeps buffers of its own: the next
+                            # step consumes the state's
+                            sup.background_saver.submit(
+                                _own_buffers(_packed(state), state)
+                            )
                         else:
                             save_train_state(
                                 cfg.ckpt_dir, _packed(state), max_to_keep=cfg.max_to_keep

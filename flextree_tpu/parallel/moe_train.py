@@ -25,7 +25,8 @@ from ..models.transformer import cross_entropy_loss
 from .pipeline import factor_devices_4d, make_mesh_4d
 from .train import (
     TrainConfig,
-    adamw_apply,
+    guarded_adamw,
+    jit_step,
     maybe_clip_grads,
     metric_specs,
     make_state_specs,
@@ -202,21 +203,15 @@ def make_moe_train_step(
         }
         if train_cfg.shard_optimizer:
             from .zero import (
-                maybe_clip_shards,
-                zero_apply_and_gather,
+                zero_clip_apply_and_gather,
                 zero_sync_and_update,
             )
 
             if train_cfg.overlap:
-                shard_tree = maybe_clip_shards(
-                    grads, sspecs["params"], train_cfg, zero_layout, metrics
+                new_state = zero_clip_apply_and_gather(
+                    state, grads, new_ef, sspecs["params"], mesh_axes,
+                    topos, train_cfg, zero_layout, metrics,
                 )
-                new_state = zero_apply_and_gather(
-                    state, shard_tree, sspecs["params"], mesh_axes, topos,
-                    train_cfg, zero_layout,
-                )
-                if new_ef is not None:
-                    new_state["ef"] = new_ef
             else:
                 new_state = zero_sync_and_update(
                     state, grads, sspecs["params"], mesh_axes, topos,
@@ -224,10 +219,7 @@ def make_moe_train_step(
                 )
             return new_state, metrics
         grads = maybe_clip_grads(grads, sspecs["params"], train_cfg, metrics)
-        new_state = adamw_apply(state, grads, train_cfg)
-        if new_ef is not None:
-            new_state["ef"] = new_ef
-        return new_state, metrics
+        return guarded_adamw(state, grads, new_ef, train_cfg, metrics), metrics
 
     mspec = metric_specs(train_cfg, {"loss": P(), "aux": P(), "total": P()})
     sharded = jax.shard_map(
@@ -237,4 +229,4 @@ def make_moe_train_step(
         out_specs=(sspecs, mspec),
         check_vma=False,
     )
-    return jax.jit(sharded)
+    return jit_step(sharded, mesh, sspecs)

@@ -49,7 +49,8 @@ from ..models.transformer import (
 )
 from .train import (
     TrainConfig,
-    adamw_apply,
+    guarded_adamw,
+    jit_step,
     maybe_clip_grads,
     metric_specs,
     make_mesh_nd,
@@ -366,10 +367,7 @@ def make_pipeline_train_step(
 
         metrics = {"loss": global_loss}
         grads = maybe_clip_grads(grads, sspecs["params"], train_cfg, metrics)
-        new_state = adamw_apply(state, grads, train_cfg)
-        if new_ef is not None:
-            new_state["ef"] = new_ef
-        return new_state, metrics
+        return guarded_adamw(state, grads, new_ef, train_cfg, metrics), metrics
 
     mspec = metric_specs(train_cfg, {"loss": P()})
     sharded = jax.shard_map(
@@ -379,4 +377,4 @@ def make_pipeline_train_step(
         out_specs=(sspecs, mspec),
         check_vma=False,
     )
-    return jax.jit(sharded)
+    return jit_step(sharded, mesh, sspecs)

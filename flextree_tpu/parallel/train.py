@@ -40,7 +40,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..models.transformer import (
     TransformerConfig,
@@ -65,6 +65,8 @@ __all__ = [
     "sync_with_feedback",
     "maybe_autotune_grad_topo",
     "adamw_apply",
+    "copy_state",
+    "keeping_state",
     "schedule_lr",
     "global_grad_norm",
     "clip_by_global_norm",
@@ -616,41 +618,148 @@ def maybe_clip_grads(grads, pspecs, train_cfg: "TrainConfig", metrics: dict):
 
 def metric_specs(train_cfg: "TrainConfig", base: dict) -> dict:
     """Out-specs for a step's metrics dict: ``base`` plus the clip norm
-    when clipping is on — must mirror :func:`maybe_clip_grads`."""
+    when clipping is on — must mirror :func:`maybe_clip_grads` — and the
+    guard's verdict (:func:`step_verdict`)."""
     out = dict(base)
     if train_cfg.grad_clip_norm:
         out["grad_norm"] = P()
+    out["applied"] = P()
     return out
 
 
+def step_verdict(metrics: dict):
+    """The NaN guard's verdict, made inside the step: true where the
+    step's loss (and its gradient norm, when clipping computed one) is
+    finite — the two numbers the host's guard reads for a step that gives
+    no verdict.  Recorded as ``metrics["applied"]``, which ``loop.fit``
+    reads one step late; the update takes it (:func:`adamw_elem`) and
+    leaves the state as it was where it is false."""
+    ok = jnp.isfinite(metrics["loss"])
+    if "grad_norm" in metrics:
+        ok = ok & jnp.isfinite(metrics["grad_norm"])
+    metrics["applied"] = ok
+    return ok
+
+
+def keep_if_refused(ok, new, old):
+    """``new`` where the verdict holds, else ``old``, leaf for leaf: for
+    what the step writes outside the AdamW arithmetic (the error-feedback
+    residual; parameters gathered through a lossy codec)."""
+    return jax.tree.map(lambda n, o: jnp.where(ok, n, o), new, old)
+
+
+def adamw_elem(p, g, mu, nu, t, lr, train_cfg: "TrainConfig", ok=None):
+    """One leaf's AdamW arithmetic, ``(new_p, new_mu, new_nu)``: the one
+    expression tree of the replicated update and the sharded one
+    (``parallel.zero``), so that the two cannot drift (bitwise for f32:
+    same inputs, same tree).
+
+    ``ok`` is the step's verdict (:func:`step_verdict`).  Where it is
+    false the update is refused INSIDE its own arithmetic: the gradient
+    reads as zero, the moments' decay as 1, their gain and the learning
+    rate as 0, so every result is its argument (``1 * mu + 0 * 0``,
+    ``p - 0 * delta``; a ``-0.0`` may come back ``+0.0``).  Scalars, not
+    a ``where`` on the results: the tree stays the unguarded update's, so
+    with the verdict true the bits are the unguarded update's on every
+    backend (XLA:CPU contracts ``a * b + c * d`` differently once a
+    select follows the sum), and no pass over the state is added: the one
+    select is on the gradient, where it is produced.  ``lax.cond`` would
+    pull the update out of the fusions that write it.
+    """
+    c1 = 1.0 - train_cfg.b1 ** t
+    c2 = 1.0 - train_cfg.b2 ** t
+    b1, gain1 = train_cfg.b1, 1.0 - train_cfg.b1
+    b2, gain2 = train_cfg.b2, 1.0 - train_cfg.b2
+    if ok is not None:
+
+        def scalar(value, idle):
+            return jnp.where(ok, value, idle).astype(mu.dtype)
+
+        b1, gain1 = scalar(b1, 1.0), scalar(gain1, 0.0)
+        b2, gain2 = scalar(b2, 1.0), scalar(gain2, 0.0)
+        lr = jnp.where(ok, lr, 0.0)
+        g = jnp.where(ok, g, jnp.zeros((), g.dtype))
+    mu = b1 * mu + gain1 * g
+    nu = b2 * nu + gain2 * (g * g)
+    delta = (mu / c1) / (jnp.sqrt(nu / c2) + train_cfg.eps)
+    if train_cfg.weight_decay:
+        delta = delta + train_cfg.weight_decay * p
+    return p - lr * delta, mu, nu
+
+
+def jit_step(sharded, mesh: Mesh, sspecs: dict):
+    """The jitted form of a built step.  The state (argument 0) is
+    DONATED: the step updates it in place, and the caller's handle to the
+    state it passed in is dead once the call returns.  The state's
+    shardings are stated (``sspecs`` over ``mesh``): a state that arrives
+    unplaced (``init_train_state`` builds it on one device) is placed by
+    the call, so that every result leaf can take its argument's buffer
+    ("Some donated buffers were not usable" otherwise)."""
+    placed = jax.tree.map(
+        lambda spec: NamedSharding(mesh, spec), sspecs,
+        is_leaf=lambda x: isinstance(x, P),
+    )
+    return jax.jit(
+        sharded, in_shardings=(placed, None, None), donate_argnums=0
+    )
+
+
+def copy_leaf(x):
+    """A device array with a buffer of its own (same sharding)."""
+    if x.size == 0:  # jax 0.9 cannot copy an empty sharded array
+        return jax.device_put(jnp.zeros(x.shape, x.dtype), x.sharding)
+    return jnp.copy(x)
+
+
+def copy_state(state):
+    """``state`` with buffers of its own: what to hand a built step, which
+    donates its argument, when the caller goes on using the state."""
+    return jax.tree.map(copy_leaf, state)
+
+
+def keeping_state(step):
+    """``step`` as a function that leaves its argument alive: each call
+    hands the built step a copy.  For harnesses that call many steps, many
+    times, on ONE state (the same cost in every variant they compare)."""
+    return lambda state, tokens, targets: step(
+        copy_state(state), tokens, targets
+    )
+
+
 @jax.named_scope("ft_optimizer")
-def adamw_apply(state: dict, grads, train_cfg: "TrainConfig") -> dict:
-    """One AdamW update on (sharded) state; moments shard like the params."""
+def adamw_apply(state: dict, grads, train_cfg: "TrainConfig", ok=None) -> dict:
+    """One AdamW update on (sharded) state; moments shard like the params.
+    ``ok``: the step's verdict (:func:`adamw_elem`); ``step`` advances
+    either way, so a refused update is a skipped batch."""
     step = state["step"] + 1
     t = step.astype(jnp.float32)
-    c1 = 1.0 - train_cfg.b1**t
-    c2 = 1.0 - train_cfg.b2**t
     lr = schedule_lr(train_cfg, step)
-
-    def upd(p, g, mu, nu):
-        mu = train_cfg.b1 * mu + (1.0 - train_cfg.b1) * g
-        nu = train_cfg.b2 * nu + (1.0 - train_cfg.b2) * (g * g)
-        delta = (mu / c1) / (jnp.sqrt(nu / c2) + train_cfg.eps)
-        if train_cfg.weight_decay:
-            delta = delta + train_cfg.weight_decay * p
-        return p - lr * delta, mu, nu
 
     flat_p, treedef = jax.tree.flatten(state["params"])
     flat_g = treedef.flatten_up_to(grads)
     flat_mu = treedef.flatten_up_to(state["mu"])
     flat_nu = treedef.flatten_up_to(state["nu"])
-    out = [upd(p, g, m, v) for p, g, m, v in zip(flat_p, flat_g, flat_mu, flat_nu)]
+    out = [
+        adamw_elem(p, g, m, v, t, lr, train_cfg, ok)
+        for p, g, m, v in zip(flat_p, flat_g, flat_mu, flat_nu)
+    ]
     return {
         "params": treedef.unflatten([o[0] for o in out]),
         "mu": treedef.unflatten([o[1] for o in out]),
         "nu": treedef.unflatten([o[2] for o in out]),
         "step": step,
     }
+
+
+def guarded_adamw(state: dict, grads, new_ef, train_cfg, metrics: dict) -> dict:
+    """The replicated step's tail, shared by its three builders: the
+    verdict on ``metrics``, then the AdamW update and the error-feedback
+    residual under it."""
+    ok = step_verdict(metrics)
+    new_state = adamw_apply(state, grads, train_cfg, ok)
+    if new_ef is not None:
+        new_state["ef"] = keep_if_refused(ok, new_ef, state["ef"])
+    return new_state
 
 
 def make_train_step(
@@ -734,23 +843,17 @@ def make_train_step(
         metrics = {"loss": global_loss}
         if train_cfg.shard_optimizer:
             from .zero import (
-                maybe_clip_shards,
-                zero_apply_and_gather,
+                zero_clip_apply_and_gather,
                 zero_sync_and_update,
             )
 
             if train_cfg.overlap:
                 # the engine already reduce-scattered per fired bucket;
                 # grads is a tree of ZeroShard (and new_ef the residuals)
-                shard_tree = maybe_clip_shards(
-                    grads, sspecs["params"], train_cfg, zero_layout, metrics
+                new_state = zero_clip_apply_and_gather(
+                    state, grads, new_ef, sspecs["params"], mesh_axes,
+                    topos, train_cfg, zero_layout, metrics,
                 )
-                new_state = zero_apply_and_gather(
-                    state, shard_tree, sspecs["params"], mesh_axes, topos,
-                    train_cfg, zero_layout,
-                )
-                if new_ef is not None:
-                    new_state["ef"] = new_ef
             else:
                 new_state = zero_sync_and_update(
                     state, grads, sspecs["params"], mesh_axes, topos,
@@ -758,9 +861,7 @@ def make_train_step(
                 )
         else:
             grads = maybe_clip_grads(grads, sspecs["params"], train_cfg, metrics)
-            new_state = adamw_apply(state, grads, train_cfg)
-            if new_ef is not None:
-                new_state["ef"] = new_ef
+            new_state = guarded_adamw(state, grads, new_ef, train_cfg, metrics)
         return new_state, metrics
 
     mspec = metric_specs(train_cfg, {"loss": P()})
@@ -771,4 +872,4 @@ def make_train_step(
         out_specs=(sspecs, mspec),
         check_vma=False,
     )
-    return jax.jit(sharded)
+    return jit_step(sharded, mesh, sspecs)

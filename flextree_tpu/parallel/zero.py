@@ -494,20 +494,6 @@ def zero_reduce_scatter_grads(
 # ----------------------------------------------------------- update + AG
 
 
-def _adamw_elem(p, g, mu, nu, t, train_cfg):
-    """The exact :func:`train.adamw_apply` element math, factored so the
-    sharded update cannot drift from the replicated one (bitwise for f32:
-    same inputs, same expression tree)."""
-    c1 = 1.0 - train_cfg.b1 ** t
-    c2 = 1.0 - train_cfg.b2 ** t
-    mu = train_cfg.b1 * mu + (1.0 - train_cfg.b1) * g
-    nu = train_cfg.b2 * nu + (1.0 - train_cfg.b2) * (g * g)
-    delta = (mu / c1) / (jnp.sqrt(nu / c2) + train_cfg.eps)
-    if train_cfg.weight_decay:
-        delta = delta + train_cfg.weight_decay * p
-    return delta, mu, nu
-
-
 def sharded_grad_norm(shard_tree, pspecs, layout: ZeroLayout):
     """True global L2 norm of a sharded gradient tree: owned head blocks
     partition each leaf's head over the shard axis (psum restores the
@@ -579,6 +565,7 @@ def zero_apply_and_gather(
     topos: Mapping[str, Any],
     train_cfg,
     layout: ZeroLayout,
+    ok=None,
 ):
     """Phase 2 of the sharded step: AdamW on the owned shards, then one
     fused parameter all-gather per bucket (wire-compressed under the
@@ -587,9 +574,15 @@ def zero_apply_and_gather(
     the master bootstraps from the working params at step 0, when they
     are still exact).  Returns the new state dict (params fully
     materialized).  Collective-context function.
+
+    ``ok``: the step's verdict (``train.step_verdict``).  Where it is
+    false ``train.adamw_elem`` leaves every owned shard (moments, and the
+    master copy of a lossy codec) as it was, so an exact gather returns
+    the parameters as they were; a lossy codec's gather, whose rounding
+    is keyed by the step, is put back leaf for leaf.
     """
     from ..ops.quantize import get_codec
-    from .train import schedule_lr
+    from .train import adamw_elem, keep_if_refused, schedule_lr
 
     codec = get_codec(train_cfg.codec)
     step = state["step"] + 1
@@ -637,10 +630,10 @@ def zero_apply_and_gather(
         if i in bucketed:
             continue
         g = flat_g[i]
-        delta, mu, nu = _adamw_elem(
-            flat_p[i], g.astype(flat_p[i].dtype), mu_rp[i], nu_rp[i], t, train_cfg
+        new_p[i], mu, nu = adamw_elem(
+            flat_p[i], g.astype(flat_p[i].dtype), mu_rp[i], nu_rp[i], t, lr,
+            train_cfg, ok,
         )
-        new_p[i] = flat_p[i] - lr * delta
         new["mu_rep"][i], new["nu_rep"][i] = mu, nu
         new["mu_shard"][i], new["nu_shard"][i] = mu_sh[i], nu_sh[i]
         new["mu_tail"][i], new["nu_tail"][i] = mu_tl[i], nu_tl[i]
@@ -668,10 +661,9 @@ def zero_apply_and_gather(
                 p_tile = (
                     jnp.where(bootstrap, own_block, ma_sh[i]) if lossy else own_block
                 )
-                d, mu, nu = _adamw_elem(
-                    p_tile, g.tile, mu_sh[i], nu_sh[i], t, train_cfg
+                new_tile, mu, nu = adamw_elem(
+                    p_tile, g.tile, mu_sh[i], nu_sh[i], t, lr, train_cfg, ok
                 )
-                new_tile = p_tile - lr * d
                 new["mu_shard"][i], new["nu_shard"][i] = mu, nu
                 if lossy:
                     new["master_shard"][i] = new_tile
@@ -685,10 +677,9 @@ def zero_apply_and_gather(
                 p_tail = p_flat[plan.head :]
                 if lossy:
                     p_tail = jnp.where(bootstrap, p_tail, ma_tl[i])
-                d, mu, nu = _adamw_elem(
-                    p_tail, g.tail, mu_tl[i], nu_tl[i], t, train_cfg
+                new_tail, mu, nu = adamw_elem(
+                    p_tail, g.tail, mu_tl[i], nu_tl[i], t, lr, train_cfg, ok
                 )
-                new_tail = p_tail - lr * d
                 new["mu_tail"][i], new["nu_tail"][i] = mu, nu
                 if lossy:
                     new["master_tail"][i] = new_tail
@@ -728,14 +719,36 @@ def zero_apply_and_gather(
             new_p[i] = flat_new.reshape(flat_p[i].shape).astype(flat_p[i].dtype)
 
     out = {"params": treedef.unflatten(new_p), "step": step}
+    if lossy and ok is not None:
+        out["params"] = keep_if_refused(ok, out["params"], params)
     for k, vals in new.items():
         out[k] = treedef.unflatten(vals)
     return out
 
 
+def zero_clip_apply_and_gather(
+    state, shard_tree, new_ef, pspecs, mesh_axes, topos, train_cfg,
+    layout: ZeroLayout, metrics: dict,
+):
+    """The sharded step's tail from the reduce-scattered gradients on:
+    (optional) global-norm clipping from shards, the step's verdict on
+    ``metrics`` (``train.step_verdict``), then the sharded AdamW, the
+    parameter all-gather and the error-feedback residual under it."""
+    from .train import keep_if_refused, step_verdict
+
+    shard_tree = maybe_clip_shards(shard_tree, pspecs, train_cfg, layout, metrics)
+    ok = step_verdict(metrics)
+    new_state = zero_apply_and_gather(
+        state, shard_tree, pspecs, mesh_axes, topos, train_cfg, layout, ok
+    )
+    if new_ef is not None:
+        new_state["ef"] = keep_if_refused(ok, new_ef, state["ef"])
+    return new_state
+
+
 def zero_sync_and_update(
     state, grads, pspecs, mesh_axes, topos, train_cfg, layout: ZeroLayout,
-    metrics: dict | None = None,
+    metrics: dict,
 ):
     """The whole sharded optimizer step: EF merge, per-bucket quantized
     reduce-scatter, (optional) global-norm clipping from shards, sharded
@@ -759,13 +772,10 @@ def zero_sync_and_update(
             grads, pspecs, mesh_axes, topos, layout=layout,
             bucket_bytes=train_cfg.bucket_bytes,
         )
-    shard_tree = maybe_clip_shards(shard_tree, pspecs, train_cfg, layout, metrics)
-    new_state = zero_apply_and_gather(
-        state, shard_tree, pspecs, mesh_axes, topos, train_cfg, layout
+    return zero_clip_apply_and_gather(
+        state, shard_tree, new_ef, pspecs, mesh_axes, topos, train_cfg,
+        layout, metrics,
     )
-    if new_ef is not None:
-        new_state["ef"] = new_ef
-    return new_state
 
 
 # -------------------------------------------------- host-side re-sharding

@@ -577,9 +577,15 @@ def train(args: argparse.Namespace) -> TrainRun:
             seq_len=args.seq_len,
             seed=args.seed,
         )
+        # the built step donates its state, so fit gets the ONLY
+        # reference to it (popped in the call itself): a name kept here
+        # would hold the initial state's buffers until the first step
+        # consumed them, and a deleted array after it
+        hand_over = [state]
+        del state
         try:
             result = fit(
-                state,
+                hand_over.pop(),
                 step_fn,
                 dataset,
                 FitConfig(

@@ -58,6 +58,15 @@ def strip_hlo_debug(hlo_text: str) -> str:
     return _HLO_DEBUG.sub("", hlo_text)
 
 
+def own_copy(state):
+    """A train state with buffers of its own.  A built step DONATES the
+    state it is given (``parallel.train.jit_step``): a test that hands
+    ONE state to several steps gives each step but the last a copy."""
+    from flextree_tpu.parallel.train import copy_state
+
+    return copy_state(state)
+
+
 def pytest_collection_modifyitems(config, items):
     """Deselect ``perf``-marked tests unless the -m expression names perf.
 

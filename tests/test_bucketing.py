@@ -310,6 +310,7 @@ def test_fused_train_step_bitwise_identical_to_per_leaf(grad_chunks):
     """The sync identity carried through the whole step: forward, backward,
     the planner-derived bucketed sync (plain and chunk-pipelined) and AdamW
     leave every parameter with exactly the per-leaf step's bits."""
+    from conftest import own_copy
     from flextree_tpu.models.transformer import TransformerConfig
     from flextree_tpu.parallel.train import (
         TrainConfig,
@@ -325,7 +326,7 @@ def test_fused_train_step_bitwise_identical_to_per_leaf(grad_chunks):
     state = init_train_state(jax.random.PRNGKey(0), model)
     per_leaf, _ = make_train_step(
         mesh, model, TrainConfig(grad_topo="4,2", bucket_bytes=0)
-    )(state, toks, toks)
+    )(own_copy(state), toks, toks)
     fused, _ = make_train_step(
         mesh, model, TrainConfig(grad_topo="4,2", grad_chunks=grad_chunks)
     )(state, toks, toks)

@@ -13,6 +13,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+
+from conftest import own_copy
 from jax.sharding import PartitionSpec as P
 
 from flextree_tpu.backends import simulate_allreduce
@@ -287,7 +289,7 @@ def test_lonely_grad_sync_through_train_step():
     tgts = jnp.asarray(rng.integers(0, 64, (16, 8)), jnp.int32)
     lone_step = make_train_step(mesh, cfg, TrainConfig(lr=1e-3, grad_topo="7+1"))
     psum_step = make_train_step(mesh, cfg, TrainConfig(lr=1e-3, grad_topo="psum"))
-    l_state, l_metrics = lone_step(state, toks, tgts)
+    l_state, l_metrics = lone_step(own_copy(state), toks, tgts)
     p_state, p_metrics = psum_step(state, toks, tgts)
     jax.block_until_ready((l_state, p_state))
     assert abs(float(l_metrics["loss"]) - float(p_metrics["loss"])) < 1e-5

@@ -11,6 +11,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+
+from conftest import own_copy
 from jax.sharding import PartitionSpec as P
 
 from flextree_tpu.models.transformer import (
@@ -170,7 +172,9 @@ def test_train_step_8dev_matches_single_device():
     cfg = _tiny_cfg()
     state = init_train_state(jax.random.PRNGKey(0), cfg)
     tokens, targets = _batch(cfg)
-    s8, m8 = make_train_step(make_mesh_3d(8, (2, 2, 2)), cfg)(state, tokens, targets)
+    s8, m8 = make_train_step(make_mesh_3d(8, (2, 2, 2)), cfg)(
+        own_copy(state), tokens, targets
+    )
     s1, m1 = make_train_step(make_mesh_3d(1, (1, 1, 1)), cfg)(state, tokens, targets)
     np.testing.assert_allclose(float(m8["loss"]), float(m1["loss"]), rtol=1e-5)
     p8, p1 = _np_tree(s8["params"]), _np_tree(s1["params"])
@@ -184,7 +188,9 @@ def test_train_step_other_mesh_shapes(shape):
     cfg = _tiny_cfg()
     state = init_train_state(jax.random.PRNGKey(0), cfg)
     tokens, targets = _batch(cfg, b=8)
-    s1, m1 = make_train_step(make_mesh_3d(1, (1, 1, 1)), cfg)(state, tokens, targets)
+    s1, m1 = make_train_step(make_mesh_3d(1, (1, 1, 1)), cfg)(
+        own_copy(state), tokens, targets
+    )
     s, m = make_train_step(make_mesh_3d(8, shape), cfg)(state, tokens, targets)
     np.testing.assert_allclose(float(m["loss"]), float(m1["loss"]), rtol=1e-5)
     for a, b in zip(
@@ -199,7 +205,9 @@ def test_train_step_with_tree_grad_topo():
     state = init_train_state(jax.random.PRNGKey(0), cfg)
     tokens, targets = _batch(cfg)
     mesh = make_mesh_3d(8, (4, 1, 2))
-    s_flat, m_flat = make_train_step(mesh, cfg)(state, tokens, targets)
+    s_flat, m_flat = make_train_step(mesh, cfg)(
+        own_copy(state), tokens, targets
+    )
     s_tree, m_tree = make_train_step(mesh, cfg, TrainConfig(grad_topo="2,2"))(
         state, tokens, targets
     )

@@ -14,6 +14,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from conftest import own_copy
+
 pytestmark = pytest.mark.slow  # multi-minute train-step tests (fast subset: -m 'not slow')
 from jax.sharding import PartitionSpec as P
 
@@ -186,7 +188,7 @@ def test_moe_train_step_matches_single_device():
     state = init_moe_train_state(jax.random.PRNGKey(0), cfg)
 
     s1, m1 = make_moe_train_step(make_mesh_moe(1, (1, 1, 1, 1)), cfg)(
-        state, tokens, targets
+        own_copy(state), tokens, targets
     )
     s8, m8 = make_moe_train_step(make_mesh_moe(8, (1, 4, 1, 2)), cfg)(
         state, tokens, targets
@@ -202,7 +204,7 @@ def test_moe_train_step_mesh_shapes(shape):
     tokens, targets = _batch(cfg)
     state = init_moe_train_state(jax.random.PRNGKey(0), cfg)
     s1, m1 = make_moe_train_step(make_mesh_moe(1, (1, 1, 1, 1)), cfg)(
-        state, tokens, targets
+        own_copy(state), tokens, targets
     )
     s, m = make_moe_train_step(make_mesh_moe(8, shape), cfg)(state, tokens, targets)
     np.testing.assert_allclose(float(m["loss"]), float(m1["loss"]), rtol=1e-4)
@@ -231,7 +233,9 @@ def test_moe_train_step_with_tree_grad_topo():
     tokens, targets = _batch(cfg)
     state = init_moe_train_state(jax.random.PRNGKey(0), cfg)
     mesh = make_mesh_moe(8, (4, 2, 1, 1))
-    s_flat, m_flat = make_moe_train_step(mesh, cfg)(state, tokens, targets)
+    s_flat, m_flat = make_moe_train_step(mesh, cfg)(
+        own_copy(state), tokens, targets
+    )
     s_tree, m_tree = make_moe_train_step(mesh, cfg, TrainConfig(grad_topo="2,2"))(
         state, tokens, targets
     )

@@ -20,6 +20,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+
+from conftest import own_copy
 from jax.sharding import Mesh
 
 from flextree_tpu.models.transformer import TransformerConfig
@@ -65,8 +67,9 @@ def test_planner_picked_tree_sync_matches_psum(setup):
         mesh, CFG, TrainConfig(lr=1e-3, grad_topo={"dp": plan.to_ft_topo()})
     )
     psum_step = make_train_step(mesh, CFG, TrainConfig(lr=1e-3, grad_topo="psum"))
-    t_state, t_metrics = tree_step(state, tokens, targets)
-    p_state, p_metrics = psum_step(state, tokens, targets)
+    # the module's one state: every step takes a copy
+    t_state, t_metrics = tree_step(own_copy(state), tokens, targets)
+    p_state, p_metrics = psum_step(own_copy(state), tokens, targets)
     jax.block_until_ready((t_state, p_state))
     t_loss, p_loss = float(t_metrics["loss"]), float(p_metrics["loss"])
     assert np.isfinite(t_loss)

@@ -34,6 +34,7 @@ import pytest
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
+from conftest import own_copy
 from flextree_tpu.models.transformer import TransformerConfig
 from flextree_tpu.parallel.overlap import (
     OverlapPlan,
@@ -42,7 +43,8 @@ from flextree_tpu.parallel.overlap import (
 )
 from flextree_tpu.parallel.train import (
     TrainConfig,
-    adamw_apply,
+    guarded_adamw,
+    jit_step,
     init_train_state,
     make_mesh_nd,
     make_train_step,
@@ -266,9 +268,11 @@ def run_steps(mesh_shape, train_cfg, model=MODEL):
     )
     out = {}
     out["prod"], _ = make_train_step(mesh, model, train_cfg)(
-        state, toks, tgts
+        own_copy(state), toks, tgts
     )
-    out["ovl"], _ = make_train_step(mesh, model, cfg_ovl)(state, toks, tgts)
+    out["ovl"], _ = make_train_step(mesh, model, cfg_ovl)(
+        own_copy(state), toks, tgts
+    )
     out["twin"], _ = make_train_step(
         mesh, model, cfg_ovl, serialize_overlap=True
     )(state, toks, tgts)
@@ -335,9 +339,11 @@ class TestBitwiseIdentityFamilies:
             tc = TrainConfig(codec=codec)
             tc_ovl = TrainConfig(codec=codec, overlap=True)
             state = init_pipeline_train_state(jax.random.PRNGKey(0), cfg, tc)
-            prod, _ = make_pipeline_train_step(mesh, cfg, tc)(state, toks, tgts)
+            prod, _ = make_pipeline_train_step(mesh, cfg, tc)(
+                own_copy(state), toks, tgts
+            )
             ovl, _ = make_pipeline_train_step(mesh, cfg, tc_ovl)(
-                state, toks, tgts
+                own_copy(state), toks, tgts
             )
             twin, _ = make_pipeline_train_step(
                 mesh, cfg, tc_ovl, serialize_overlap=True
@@ -366,10 +372,10 @@ class TestBitwiseIdentityFamilies:
             tc_ovl = TrainConfig(codec=codec, overlap=True)
             state = init_moe_train_state(jax.random.PRNGKey(0), cfg, tc)
             prod, m_prod = make_moe_train_step(mesh, cfg, tc)(
-                state, toks, tgts
+                own_copy(state), toks, tgts
             )
             ovl, m_ovl = make_moe_train_step(mesh, cfg, tc_ovl)(
-                state, toks, tgts
+                own_copy(state), toks, tgts
             )
             twin, _ = make_moe_train_step(
                 mesh, cfg, tc_ovl, serialize_overlap=True
@@ -395,7 +401,8 @@ def test_overlap_false_compiles_the_historical_program():
     """``overlap=False`` must be byte-for-byte the historical step: the
     same program as a replica of the pre-overlap device_step built from
     the public train.py pieces (value_and_grad + sync_with_feedback +
-    adamw).  If this fails, the refactor changed the default path."""
+    the guarded adamw, the state donated).  If this fails, the refactor
+    changed the default path."""
     mesh = make_mesh_nd(8, (2, 2, 2), ("dp", "sp", "tp"))
     train_cfg = TrainConfig(overlap=False)
     sspecs = state_specs(MODEL, "tp", train_cfg)
@@ -429,17 +436,16 @@ def test_overlap_false_compiles_the_historical_program():
         )
         metrics = {"loss": global_loss}
         grads = maybe_clip_grads(grads, sspecs["params"], train_cfg, metrics)
-        new_state = adamw_apply(state, grads, train_cfg)
-        if new_ef is not None:
-            new_state["ef"] = new_ef
+        new_state = guarded_adamw(state, grads, new_ef, train_cfg, metrics)
         return new_state, metrics
 
-    replica = jax.jit(
+    replica = jit_step(
         jax.shard_map(
             device_step, mesh=mesh, in_specs=(sspecs, data_spec, data_spec),
             out_specs=(sspecs, metric_specs(train_cfg, {"loss": P()})),
             check_vma=False,
-        )
+        ),
+        mesh, sspecs,
     )
     production = make_train_step(mesh, MODEL, train_cfg)
 

@@ -11,6 +11,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from conftest import own_copy
+
 pytestmark = pytest.mark.slow  # multi-minute train-step tests (fast subset: -m 'not slow')
 
 from flextree_tpu.models.transformer import TransformerConfig, init_params
@@ -111,7 +113,7 @@ def test_pipeline_with_tree_grad_topo():
     mesh = make_mesh_4d(8, (4, 2, 1, 1))
     state = init_pipeline_train_state(jax.random.PRNGKey(0), cfg)
     flat_s, flat_m = make_pipeline_train_step(mesh, cfg, n_microbatches=2)(
-        state, tokens, targets
+        own_copy(state), tokens, targets
     )
     tree_s, tree_m = make_pipeline_train_step(
         mesh, cfg, TrainConfig(grad_topo="2,2"), n_microbatches=2

@@ -662,6 +662,51 @@ def test_paged_kernel_share_is_the_share_of_layers_that_run_the_kernel():
     assert S.count_ratio_p50(_ctx(bare, None), **meta["args"]) is None
 
 
+def test_lagged_fetch_share_is_the_share_of_verdicts_read_one_step_late():
+    """``loop.lagged_fetch_share`` as its metric file reads it: the two
+    counts every ``ft.loop.guard_fetch`` span carries."""
+    meta = _metric_file("loop.lagged_fetch_share")
+    assert meta["reader"] == "spans:count_ratio_p50"
+
+    def steps(lagged):
+        host = [E("bench_window", 0, 1000)] + [
+            E("ft.loop.guard_fetch", 100 * i, 50, {"lagged": lag, "steps": 1})
+            for i, lag in enumerate(lagged)
+        ]
+        return S.count_ratio_p50(_ctx(host, None), **meta["args"])
+
+    # a window of lagged fetches and the drain before fit returns
+    assert steps([1] * 9 + [0]) == pytest.approx(100.0)
+    assert steps([0] * 4) == pytest.approx(0.0)  # a step the host guards
+    # a parent commit's span carries no counts: left out, no error
+    bare = [E("bench_window", 0, 1000), E("ft.loop.guard_fetch", 100, 50)]
+    assert S.count_ratio_p50(_ctx(bare, None), **meta["args"]) is None
+
+
+@pytest.mark.parametrize("verdict,lagged", [(True, [1, 1, 0]), (False, [0, 0, 0])])
+def test_guard_fetch_says_whether_it_lagged(verdict, lagged):
+    """The counts ``fit`` opens ``ft.loop.guard_fetch`` with: a step that
+    reports ``applied`` is read one step late (and last, at the drain, on
+    time); a step that does not is fetched before the next dispatch."""
+    from flextree_tpu.parallel.loop import FitConfig, fit
+
+    class Data:
+        def batch_at(self, step):
+            t = np.full((2, 4), float(step + 1))
+            return t, t
+
+    def step_fn(state, tokens, targets):
+        s = int(np.asarray(state["step"]))
+        metrics = {"loss": 0.5, **({"applied": True} if verdict else {})}
+        return {"step": np.int64(s + 1), "w": np.asarray(state["w"]) - 1.0}, metrics
+
+    with flight_recorder(None) as rec:
+        fit({"step": np.int64(0), "w": np.zeros(2)}, step_fn, Data(),
+            FitConfig(num_steps=3, log_every=0, prefetch=0))
+    fetches = [e for e in _spans(rec) if e["name"] == "ft.loop.guard_fetch"]
+    assert [(e["lagged"], e["steps"]) for e in fetches] == [(n, 1) for n in lagged]
+
+
 @pytest.mark.filterwarnings("ignore:builtin type:DeprecationWarning")
 def test_device_pick_share_reads_the_engines_own_spans(tmp_path):
     """The same reader over a real profile of a mixed batch: the counts
@@ -726,4 +771,4 @@ def test_new_metric_files_name_readers_that_exist():
         fn = getattr(S, meta["reader"].split(":")[1])
         inspect.signature(fn).bind(None, **meta.get("args", {}))
         assert entry["source"] in ("program_span", "program_counter", "device_trace")
-    assert seen == 26  # PR 32: attn.mla_proj_share, attn.mla_core_share
+    assert seen == 27  # PR 33: loop.lagged_fetch_share

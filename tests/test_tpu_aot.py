@@ -373,3 +373,85 @@ def test_dp4_step_syncs_large_leaves_in_their_own_shape(v5e, tpu_lowering):
     assert re.search(r"= f32\[\d+,\d+\]\S* reduce-scatter\(", hlo)
     # the three norm scales share one flat bucket
     assert "_dp_3leaves_6144B" in hlo
+    _assert_state_updated_in_place(hlo, state)
+
+
+# the benchmark's training configuration (benchmarks/configs/pythia-1.4b.json
+# at its depth of 7, B4 x T2048 a chip, flash): its state is 5.09 GiB
+PYTHIA_1_4B = TransformerConfig(
+    vocab_size=50304, d_model=2048, n_heads=16, n_layers=7, d_ff=8192,
+    dtype=jnp.bfloat16, attn_impl="flash",
+)
+# arguments + results + temporaries of the PARENT's program (f249152: no
+# donation, so no result takes an argument's buffer), by this same analysis
+# (my AOT compiles of the parent, PR 33): 5.089 + 5.089 + 2.938 and + 3.052
+PARENT_STEP_GIB = {(1, 1, 1): 13.116, (4, 1, 1): 13.230}
+
+
+def _assert_state_updated_in_place(hlo, state):
+    """Every state leaf's result takes its argument's buffer, and no
+    operation of a matrix's size stands in the entry computation but the
+    fusions that write the update, the collectives and the moves between
+    memories: no select and no copy of a state leaf outside them."""
+    import re
+
+    header = hlo.split("\n", 1)[0]
+    aliased = re.findall(
+        r"\{(\d+)\}: \((\d+), \{\}, (?:may|must)-alias\)", header)
+    n_leaves = len(jax.tree.leaves(state))
+    assert len(aliased) == n_leaves, (len(aliased), n_leaves)
+    assert all(out == arg for out, arg in aliased)
+    entry = hlo[hlo.index("\nENTRY "):]
+    entry = entry[:entry.index("\n}\n")]
+    shapes = {x.shape for x in jax.tree.leaves(state) if x.ndim == 2}
+    for rows, cols in shapes:
+        for op in ("select", "copy", "multiply", "add", "subtract"):
+            assert not re.search(
+                rf"= f32\[{rows},{cols}\]\S* {op}\(", entry
+            ), (rows, cols, op)
+
+
+@pytest.mark.parametrize("shape", list(PARENT_STEP_GIB))
+def test_the_benchmark_step_updates_its_state_in_place(v5e, tpu_lowering, shape):
+    """The one-chip and the dp4 step of the training cells, at the cells'
+    own size, compiled for the chip: the donated state is updated in
+    place (every leaf aliased, the guard's select inside the update's
+    fusions), and the program needs at least 1 GiB less than the
+    parent's, by the compiler's own count (it needs about 3 less)."""
+    from flextree_tpu.parallel.train import (
+        TrainConfig,
+        init_train_state,
+        make_train_step,
+        state_specs,
+    )
+
+    mesh = _mesh(v5e, shape)
+    tc = TrainConfig()
+    shardings = jax.tree.map(
+        lambda spec: NamedSharding(mesh, spec),
+        state_specs(PYTHIA_1_4B, train_cfg=tc, mesh=mesh),
+        is_leaf=lambda x: isinstance(x, P),
+    )
+    state = _on(
+        jax.eval_shape(
+            lambda k: init_train_state(k, PYTHIA_1_4B, tc),
+            jax.random.PRNGKey(0),
+        ),
+        shardings,
+    )
+    tok = jax.ShapeDtypeStruct(
+        (4 * shape[0], 2048), jnp.int32,
+        sharding=NamedSharding(mesh, P("dp", "sp")),
+    )
+    compiled = _compile(make_train_step(mesh, PYTHIA_1_4B, tc), state, tok, tok)
+    _assert_state_updated_in_place(compiled.as_text(), state)
+    mem = compiled.memory_analysis()
+    state_bytes = sum(
+        x.size * x.dtype.itemsize for x in jax.tree.leaves(state))
+    # (the scalar step pads to a tile)
+    assert state_bytes <= mem.alias_size_in_bytes < state_bytes + 4096
+    per_chip = (
+        mem.argument_size_in_bytes + mem.output_size_in_bytes
+        + mem.temp_size_in_bytes - mem.alias_size_in_bytes
+    ) / 2**30
+    assert per_chip <= PARENT_STEP_GIB[shape] - 1.0, f"{per_chip:.3f} GiB"

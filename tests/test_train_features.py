@@ -11,6 +11,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from conftest import own_copy
+
 from flextree_tpu.models.transformer import TransformerConfig
 from flextree_tpu.parallel.train import (
     TrainConfig,
@@ -95,7 +97,7 @@ def test_clipped_train_step_matches_single_device(shape):
     state = init_train_state(jax.random.PRNGKey(0), cfg)
     tokens, targets = _batch(cfg, b=8)
     s8, m8 = make_train_step(make_mesh_3d(8, shape), cfg, tcfg)(
-        state, tokens, targets
+        own_copy(state), tokens, targets
     )
     s1, m1 = make_train_step(make_mesh_3d(1, (1, 1, 1)), cfg, tcfg)(
         state, tokens, targets
@@ -157,7 +159,7 @@ def test_clipped_pipeline_step_matches_single_device():
     tokens, targets = _batch(cfg, b=8)
     s8, m8 = make_pipeline_train_step(
         make_mesh_4d(8, (1, 2, 2, 2)), cfg, tcfg, n_microbatches=2
-    )(state, tokens, targets)
+    )(own_copy(state), tokens, targets)
     s1, m1 = make_pipeline_train_step(
         make_mesh_4d(1, (1, 1, 1, 1)), cfg, tcfg, n_microbatches=2
     )(state, tokens, targets)
@@ -195,7 +197,7 @@ def test_clipped_moe_step_matches_single_device():
     tokens, targets = _batch(cfg, b=8)
     s8, m8 = make_moe_train_step(
         make_mesh_moe(8, (1, 2, 2, 2)), cfg, tcfg
-    )(state, tokens, targets)
+    )(own_copy(state), tokens, targets)
     s1, m1 = make_moe_train_step(
         make_mesh_moe(1, (1, 1, 1, 1)), cfg, tcfg
     )(state, tokens, targets)
@@ -225,9 +227,9 @@ def test_warmup_cosine_through_train_step():
         mesh, cfg,
         TrainConfig(lr=1e-2, schedule="warmup_cosine", warmup_steps=10,
                     total_steps=100),
-    )(state, tokens, targets)
+    )(own_copy(state), tokens, targets)
     s_c, _ = make_train_step(mesh, cfg, TrainConfig(lr=1e-2))(
-        state, tokens, targets
+        own_copy(state), tokens, targets
     )
     d_w = sum(
         float(jnp.abs(a - b).sum())

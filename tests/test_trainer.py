@@ -10,6 +10,8 @@ import jax
 import numpy as np
 import pytest
 
+from conftest import own_copy
+
 pytestmark = pytest.mark.slow  # multi-minute train-step tests (fast subset: -m 'not slow')
 
 from flextree_tpu.data import LMDataset, prefetch, synthetic_tokens
@@ -114,11 +116,13 @@ def test_fit_runs_and_loss_decreases(tmp_path):
 def test_fit_resume_is_exact(tmp_path):
     cfg, mesh, step, state, ds = _setup()
 
-    straight = fit(state, step, ds, FitConfig(num_steps=8, log_every=4))
+    straight = fit(
+        own_copy(state), step, ds, FitConfig(num_steps=8, log_every=4)
+    )
 
     ck = str(tmp_path / "ck")
     half = fit(
-        state, step, ds,
+        own_copy(state), step, ds,
         FitConfig(num_steps=4, ckpt_dir=ck, ckpt_every=4, log_every=4),
     )
     assert half.steps_run == 4
@@ -166,7 +170,7 @@ def test_multistage_grad_sync_matches_psum(tree_topo):
     step_psum = make_train_step(mesh, cfg, TrainConfig(lr=3e-3, grad_topo="psum"))
     step_tree = make_train_step(mesh, cfg, TrainConfig(lr=3e-3, grad_topo=tree_topo))
 
-    s_psum, m_psum = step_psum(state, tokens, targets)
+    s_psum, m_psum = step_psum(own_copy(state), tokens, targets)
     s_tree, m_tree = step_tree(state, tokens, targets)
     assert np.isclose(float(m_psum["loss"]), float(m_tree["loss"]), rtol=1e-6)
     for a, b in zip(_leaves(s_psum["params"]), _leaves(s_tree["params"])):

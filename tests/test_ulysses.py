@@ -185,6 +185,7 @@ def test_forward_unknown_sp_impl_raises():
 
 
 def test_train_step_ulysses_matches_single_device():
+    from conftest import own_copy
     from flextree_tpu.parallel.train import (
         init_train_state,
         make_mesh_3d,
@@ -198,7 +199,9 @@ def test_train_step_ulysses_matches_single_device():
     rng = np.random.default_rng(1)
     tokens = jnp.asarray(rng.integers(0, cfg.vocab_size, (4, 32)), jnp.int32)
     targets = jnp.asarray(rng.integers(0, cfg.vocab_size, (4, 32)), jnp.int32)
-    s8, m8 = make_train_step(make_mesh_3d(8, (2, 2, 2)), cfg)(state, tokens, targets)
+    s8, m8 = make_train_step(make_mesh_3d(8, (2, 2, 2)), cfg)(
+        own_copy(state), tokens, targets
+    )
     s1, m1 = make_train_step(make_mesh_3d(1, (1, 1, 1)), cfg)(state, tokens, targets)
     np.testing.assert_allclose(float(m8["loss"]), float(m1["loss"]), rtol=1e-5)
     for a, b in zip(

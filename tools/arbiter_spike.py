@@ -286,7 +286,7 @@ class TrainWorlds:
             jax.random.PRNGKey(0), self.model_cfg, self.base_tc, mesh=mesh
         )
         tok, tgt = _LMData().batch_at(0)
-        step_fn(state, tok, tgt)
+        state, _ = step_fn(state, tok, tgt)  # the step donates its state
         unpack(jax.device_get(pack(state)))
         # warming appends to the step trace; the run's trace starts clean
         self.step_trace.clear()

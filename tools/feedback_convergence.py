@@ -129,9 +129,15 @@ def main(argv=None) -> int:
         TrainConfig,
         init_train_state,
         make_mesh_nd,
-        make_train_step,
         state_specs,
     )
+    from flextree_tpu.parallel.train import keeping_state
+    from flextree_tpu.parallel.train import make_train_step as _built_step
+
+    def make_train_step(*a, **kw):
+        # a built step donates its state; this harness reuses ONE state
+        return keeping_state(_built_step(*a, **kw))
+
     from flextree_tpu.planner import (
         LinkParams,
         TpuCostParams,

@@ -115,7 +115,7 @@ def make_nosync_train_step(mesh, model_cfg, train_cfg, axis_names=("dp", "sp", "
 
     from flextree_tpu.models.transformer import cross_entropy_loss, forward
     from flextree_tpu.parallel.train import (
-        adamw_apply,
+        guarded_adamw,
         maybe_clip_grads,
         metric_specs,
         state_specs,
@@ -147,7 +147,7 @@ def make_nosync_train_step(mesh, model_cfg, train_cfg, axis_names=("dp", "sp", "
         # the SYNC is elided), and it also keeps the metrics pytree
         # matching metric_specs when clipping is configured
         grads = maybe_clip_grads(grads, sspecs["params"], train_cfg, metrics)
-        new_state = adamw_apply(state, grads, train_cfg)
+        new_state = guarded_adamw(state, grads, None, train_cfg, metrics)
         return new_state, metrics
 
     mspec = metric_specs(train_cfg, {"loss": P()})
@@ -196,9 +196,15 @@ def main(argv=None) -> int:
         TrainConfig,
         init_train_state,
         make_mesh_nd,
-        make_train_step,
         state_specs,
     )
+    from flextree_tpu.parallel.train import keeping_state
+    from flextree_tpu.parallel.train import make_train_step as _built_step
+
+    def make_train_step(*a, **kw):
+        # a built step donates its state; this harness reuses ONE state
+        return keeping_state(_built_step(*a, **kw))
+
     from flextree_tpu.planner import (
         LinkParams,
         TpuCostParams,
