@@ -5,11 +5,24 @@ the serving path chooses a model (``ServingEngine.from_config``, ``python
 
 :data:`BLOCKS` is the one table of what a block brings: its configuration
 object, seeded parameters, the two walks over its layers behind the
-signatures the engine calls, and the layout of what it caches a position.
-The blocks themselves are ``models.transformer`` (``gpt_neox``: the dense
-block at those widths, see ``benchmarks/configs/pythia-*.json`` for what
-it departs in), ``models.laguna`` (``laguna``) and
-``models.pangu_ultra_moe`` (``pangu_ultra_moe``).
+signatures the engine calls, and the layout of what it keeps.  The four
+blocks are ``models.transformer`` (``gpt_neox``: the dense block at those
+widths, see ``benchmarks/configs/pythia-*.json`` for what it departs in),
+``models.laguna`` (``laguna``), ``models.pangu_ultra_moe``
+(``pangu_ultra_moe``) and ``models.kimi_linear`` (``kimi_linear``).
+
+**The layout is a layer's** (:func:`pool_layout`, one entry a layer):
+``position`` names the parts the layer caches a POSITION and the shape of
+one position's row: these are paged, ``(num_blocks, block_size, *row)``,
+an array a layer that caches the part (:func:`position_parts`);
+``slot`` names the parts the layer holds a SLOT, whatever the length of
+the sequence in it, with their shape and dtype: these are the engine's
+state, ``(slots, *shape)``, an array a layer that holds the part
+(:func:`slot_parts`).  The dense and the Laguna block cache ``k`` and
+``v`` of ``(K/V heads, head_dim)`` in every layer, openPangu one ``ckv``
+row in every layer, none of the three anything a slot; a Kimi-Linear MLA
+layer caches one ``ckv`` row and a KDA layer NOTHING a position, but a
+float32 state ``s`` and its convolution's last inputs ``conv`` a slot.
 """
 
 from __future__ import annotations
@@ -21,13 +34,13 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.paged_attention import runs_kernel
-from . import laguna, pangu_ultra_moe as pangu
+from . import kimi_linear as kimi, laguna, pangu_ultra_moe as pangu
 from .generate import paged_decode_dense, prefill_dense
 from .transformer import TransformerConfig, init_params
 
 __all__ = [
     "Block", "BLOCKS", "block_of", "config_from_dict", "init_model_params",
-    "pool_layout",
+    "pool_layout", "position_parts", "slot_parts",
 ]
 
 
@@ -42,9 +55,12 @@ class Block:
     # (params, pools, tables, lengths, tokens, cfg, fused) -> (logits,
     # pools[, what its routers did])
     decode_step: Callable
-    # cfg -> {part: shape of one cached position of one layer}: the pools,
-    # a prefill's cache, a swap and a migration payload hold these parts,
-    # in this order
+    # cfg -> a layer at a time, {"position": {part: shape of one cached
+    # position}, "slot": {part: (shape, dtype name) of what a slot holds,
+    # whatever its sequence's length}}: the pools, a prefill's cache, a
+    # swap and a migration payload hold the position parts of the layers
+    # that have them, in blocks of positions; the engine's state, the same
+    # four and the decode program hold the slot parts, an array a slot
     pool_layout: Callable
     # (cfg, pcfg) -> (attention layers of the fused decode program, those
     # of them that run the Pallas kernel): fixed by the backend and the
@@ -63,17 +79,17 @@ def _dense_from_dict(c: dict) -> TransformerConfig:
     )
 
 
-def _kv_heads(cfg) -> dict:
-    """K and V, a row a K/V head."""
+def _kv_heads(cfg) -> tuple:
+    """K and V, a row a K/V head, in every layer; nothing a slot."""
     row = (cfg.n_kv_heads, cfg.head_dim)
-    return {"k": row, "v": row}
+    return ({"position": {"k": row, "v": row}, "slot": {}},) * cfg.n_layers
 
 
 def _kv_kernel_layers(cfg, pcfg) -> tuple:
     """A K and a V pool: a layer runs the kernel if its own count of query
     heads lets it (``layer_heads``: Laguna's window and full layers)."""
     heads = getattr(cfg, "layer_heads", None) or (cfg.n_heads,) * cfg.n_layers
-    row = _kv_heads(cfg)["k"]
+    row = (cfg.n_kv_heads, cfg.head_dim)
     pool = jax.ShapeDtypeStruct(
         (pcfg.num_blocks, pcfg.block_size, *row), cfg.dtype
     )
@@ -98,6 +114,11 @@ BLOCKS = {
         pangu.PanguConfig, pangu.config_from_dict, pangu.init_params,
         pangu.prefill, pangu.paged_decode_step, pangu.pool_layout,
         pangu.kernel_layers,
+    ),
+    "kimi_linear": Block(
+        kimi.KimiLinearConfig, kimi.config_from_dict, kimi.init_params,
+        kimi.prefill, kimi.paged_decode_step, kimi.pool_layout,
+        kimi.kernel_layers,
     ),
 }
 
@@ -129,6 +150,38 @@ def init_model_params(key, cfg):
     return block_of(cfg).init_params(key, cfg)
 
 
-def pool_layout(cfg) -> dict:
-    """``{part: shape of one cached position of one layer}`` for ``cfg``."""
+def pool_layout(cfg) -> tuple:
+    """What ``cfg``'s block keeps, a layer at a time: ``{"position":
+    {part: shape of one cached position}, "slot": {part: (shape, dtype
+    name)}}`` (:class:`Block`)."""
     return block_of(cfg).pool_layout(cfg)
+
+
+def _parts(cfg, kind: str) -> dict:
+    """``{part: (what the layout states of it, layers that hold it)}``; a
+    part is the same in every layer that holds it."""
+    parts: dict = {}
+    for layer in pool_layout(cfg):
+        for part, what in layer[kind].items():
+            seen, count = parts.get(part, (what, 0))
+            if seen != what:
+                raise ValueError(
+                    f"part {part!r} is {seen} in one layer and {what} in "
+                    f"another"
+                )
+            parts[part] = (what, count + 1)
+    return parts
+
+
+def position_parts(cfg) -> dict:
+    """``{part: (row shape, layers that cache it)}``: what the paged pools
+    hold, ``{part: [(num_blocks, block_size, *row) a layer that caches
+    it]}``, in the compute dtype."""
+    return _parts(cfg, "position")
+
+
+def slot_parts(cfg) -> dict:
+    """``{part: ((shape, dtype name), layers that hold it)}``: what the
+    engine's state holds, ``{part: [(slots, *shape) a layer that holds
+    it]}``.  Empty for a block that keeps nothing a slot."""
+    return _parts(cfg, "slot")

@@ -9,6 +9,11 @@ description of the block (:func:`_latent_inputs`, :func:`_after_attention`,
 :func:`head_logits`).  The table in ``models.configs`` hands a
 :class:`PanguConfig` to them, which is how the one ``ServingEngine`` runs
 this model through the programs, pool functions and round of the others.
+The latent attention of a layer (:func:`latent_prefill`,
+:func:`latent_decode`) and its FFN (:func:`ffn_layer`) take any
+configuration that has their sizes: ``models.kimi_linear`` runs the same
+two, with uncompressed queries (``q_rank`` None) and no rotary (``rope``
+false).
 
 The equations, ``H`` heads of ``d_nope + d_rope`` (queries and keys) and
 ``d_v`` (values); every norm is RMSNorm with a learned scale, no bias
@@ -77,6 +82,9 @@ __all__ = [
     "pool_layout",
     "kernel_layers",
     "blocked_causal_attention",
+    "latent_prefill",
+    "latent_decode",
+    "ffn_layer",
     "head_logits",
     "prefill",
     "paged_decode_step",
@@ -90,7 +98,7 @@ class PanguConfig:
     vocab_size: int
     d_model: int
     n_heads: int
-    q_rank: int  # the queries' compressed width
+    q_rank: int | None  # the queries' compressed width (None: uncompressed)
     kv_rank: int  # the cached row's compressed part
     d_nope: int  # a head's unrotated query/key width
     d_rope: int  # the rotary key, shared by all heads
@@ -107,6 +115,7 @@ class PanguConfig:
     rope_theta: float
     norm_topk: bool = True
     rms_eps: float = 1e-5
+    rope: bool = True  # rotate the queries' and the row's last d_rope
     dtype: Any = jnp.bfloat16  # compute and the cached rows
     param_dtype: Any = jnp.bfloat16  # how the weights are held
     # prefill attention: rows of queries whose scores exist at once, and
@@ -169,10 +178,12 @@ class PanguConfig:
         )
 
 
-def pool_layout(cfg: PanguConfig) -> dict:
-    """What the block caches a position a layer: one row that is key and
-    value at once, and no heads axis."""
-    return {"ckv": (cfg.pool_row,)}
+def pool_layout(cfg: PanguConfig) -> tuple:
+    """What the block keeps, a layer at a time: one cached row a position
+    that is key and value at once, no heads axis; nothing a slot."""
+    return (
+        {"position": {"ckv": (cfg.pool_row,)}, "slot": {}},
+    ) * cfg.n_layers
 
 
 def kernel_layers(cfg: PanguConfig, pcfg) -> tuple:
@@ -282,25 +293,22 @@ def _leaf_shapes(cfg: PanguConfig) -> dict:
     return leaves
 
 
-def init_params(key, cfg: PanguConfig) -> dict:
-    """The parameter tree, made leaf by leaf in ``param_dtype`` (each
-    matrix one jitted call that draws, scales and rounds it, so no float32
-    copy of the tree exists at any moment).  The norms' scales start at 1
-    as published checkpoints' do not: a seeded tree's are drawn near 1, so
-    that a norm left out or misplaced moves the result."""
-    dt = cfg.param_dtype
+def seeded_tree(key, leaves: dict, norms: list, n_layers: int, dtype,
+                spare: int = 0) -> tuple:
+    """``(params, put, spare keys)``: a parameter tree with every matrix
+    of ``leaves`` (``{path: (shape, std)}``) drawn about 0 and every norm
+    scale of ``norms`` (``[(path, width)]``) about 1 with a spread of 0.1,
+    leaf by leaf in ``dtype`` (each one jitted call that draws, scales and
+    rounds it, so no float32 copy of the tree exists at any moment).
+    ``put(path, value)`` sets further leaves, for which ``spare`` more keys
+    are split off."""
 
     def draw(k, shape, std, mean):
-        return (mean + jax.random.normal(k, shape, jnp.float32) * std).astype(dt)
+        return (mean + jax.random.normal(k, shape, jnp.float32) * std).astype(dtype)
 
     draw = jax.jit(draw, static_argnums=(1, 2, 3))
-    leaves = _leaf_shapes(cfg)
-    norms = [(("ln_f",), cfg.d_model)] + [
-        (("layers", i, name), getattr(cfg, width))
-        for i in range(cfg.n_layers) for name, width in _NORMS
-    ]
-    keys = jax.random.split(key, len(leaves) + len(norms))
-    params = {"layers": [{} for _ in range(cfg.n_layers)]}
+    keys = jax.random.split(key, len(leaves) + len(norms) + spare)
+    params = {"layers": [{} for _ in range(n_layers)]}
 
     def put(path, value):
         node = params
@@ -312,38 +320,59 @@ def init_params(key, cfg: PanguConfig) -> dict:
         put(path, draw(k, shape, std, 0.0))
     for k, (path, width) in zip(keys[len(leaves):], norms):
         put(path, draw(k, (width,), 0.1, 1.0))
-    return params
+    return params, put, keys[len(leaves) + len(norms):]
+
+
+def init_params(key, cfg: PanguConfig) -> dict:
+    """The parameter tree, made leaf by leaf in ``param_dtype``
+    (:func:`seeded_tree`).  The norms' scales start at 1 as published
+    checkpoints' do not: a seeded tree's are drawn near 1, so that a norm
+    left out or misplaced moves the result."""
+    norms = [(("ln_f",), cfg.d_model)] + [
+        (("layers", i, name), getattr(cfg, width))
+        for i in range(cfg.n_layers) for name, width in _NORMS
+    ]
+    return seeded_tree(
+        key, _leaf_shapes(cfg), norms, cfg.n_layers, cfg.param_dtype
+    )[0]
 
 
 # ------------------------------------------------------------------ block
 
 
-def _latent_inputs(layer, a, positions, cfg: PanguConfig):
+def _latent_inputs(layer, a, positions, cfg):
     """``(q_nope, q_rope, row)`` of one layer for normed inputs ``a``
     (B, T, d): queries (B, T, H, d_nope) and (B, T, H, d_rope), the latter
     rotated at ``positions``, and the row to cache (B, T, kv_rank +
     d_rope): the normed compressed vector and the rotated shared key.
     The two inner norms sit between the ``ft_mla_proj`` stretches (a
-    scope never holds another)."""
+    scope never holds another).  ``cfg`` is any configuration with the
+    latent attention's sizes (``models.kimi_linear``'s too): where its
+    ``q_rank`` is None the queries are ``a W_q`` with no compression and
+    no inner norm, and where its ``rope`` is false nothing is rotated (the
+    row's last ``d_rope`` numbers are a plain shared key part)."""
     b, t, _ = a.shape
+    turn = (
+        (lambda x: apply_rope(x, positions, cfg.rope_theta)) if cfg.rope
+        else (lambda x: x)
+    )
     with jax.named_scope("ft_mla_proj"):
-        cq = a @ layer["wq_a"]
+        cq = a @ (layer["wq"] if cfg.q_rank is None else layer["wq_a"])
         ckr = a @ layer["wkv_a"]
-    cq = rms_norm(cq, layer["ln_q"], cfg.rms_eps)
+    if cfg.q_rank is not None:
+        cq = rms_norm(cq, layer["ln_q"], cfg.rms_eps)
     c = rms_norm(ckr[..., : cfg.kv_rank], layer["ln_kv"], cfg.rms_eps)
     with jax.named_scope("ft_mla_proj"):
-        q = (cq @ layer["wq_b"]).reshape(
+        q = (cq if cfg.q_rank is None else cq @ layer["wq_b"]).reshape(
             b, t, cfg.n_heads, cfg.d_nope + cfg.d_rope
         )
         q_nope, q_rope = q[..., : cfg.d_nope], q[..., cfg.d_nope :]
-        q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
-        kr = apply_rope(
-            ckr[..., cfg.kv_rank :][:, :, None, :], positions, cfg.rope_theta
-        )[:, :, 0]
+        q_rope = turn(q_rope)
+        kr = turn(ckr[..., cfg.kv_rank :][:, :, None, :])[:, :, 0]
         return q_nope, q_rope, jnp.concatenate([c, kr], axis=-1)
 
 
-def _split_kvb(layer, cfg: PanguConfig):
+def _split_kvb(layer, cfg):
     """``W_kvb`` by head: ``(W_uk, W_uv)``, (kv_rank, H, d_nope) and
     (kv_rank, H, d_v)."""
     w = layer["wkv_b"].reshape(cfg.kv_rank, cfg.n_heads, cfg.d_nope + cfg.d_v)
@@ -393,7 +422,7 @@ def blocked_causal_attention(q_nope, q_rope, k_nope, kr, v, scale: float,
     return jnp.concatenate(outs, axis=1)
 
 
-def _prefill_core(q_nope, q_rope, k_nope, kr, v, cfg: PanguConfig):
+def _prefill_core(q_nope, q_rope, k_nope, kr, v, cfg):
     """The prefill's causal attention, expanded form.  Which
     implementation runs is decided from what can be observed, as
     ``ops.paged_attention`` decides: on a TPU the flash kernel
@@ -428,43 +457,94 @@ def _in_row_blocks(fn, flat, rows: int):
     return jax.tree.map(lambda a: a.reshape(n, *a.shape[2:]), out)
 
 
-def _after_attention(layer, x, attn, cfg: PanguConfig, i: int, rows=None):
-    """The residual after layer ``i``, given the attention's output
-    ``attn`` (B, T, d) before its sandwich norm; and what the layer's
-    router did (``None`` for a dense layer).  ``rows`` (B*T,) bool: rows
-    whose picks are dispatched and counted (a decode round's inactive
-    slots are not)."""
-    b, t, d = x.shape
-    x = x + rms_norm(attn, layer["ln_post_attn"], cfg.rms_eps)
-    m = rms_norm(x, layer["ln_pre_mlp"], cfg.rms_eps).reshape(b * t, d)
+def latent_prefill(layer, a, positions, cfg, max_len: int):
+    """One layer's latent attention over a whole prompt, expanded form:
+    normed inputs ``a`` (B, T, d) -> ``(attn, row)``, the output (B, T, d)
+    after ``W_o`` and the rows to cache (B, max_len, kv_rank + d_rope),
+    zeros past the prompt."""
+    b, t, _ = a.shape
+    q_nope, q_rope, row = _latent_inputs(layer, a, positions, cfg)
+    with jax.named_scope("ft_mla_proj"):
+        w_uk, w_uv = _split_kvb(layer, cfg)
+        c = row[..., : cfg.kv_rank]
+        k_nope = jnp.einsum("btr,rhn->bthn", c, w_uk)
+        v = jnp.einsum("btr,rhv->bthv", c, w_uv)
+        padded = jnp.pad(row, ((0, 0), (0, max_len - t), (0, 0)))
+    with jax.named_scope("ft_mla_core"):
+        o = _prefill_core(
+            q_nope, q_rope, k_nope, row[..., cfg.kv_rank :], v, cfg
+        )
+    with jax.named_scope("ft_mla_proj"):
+        return o.reshape(b, t, -1) @ layer["wo"], padded
+
+
+def latent_decode(layer, a, positions, pool, tables, lengths, cfg,
+                  fused: bool):
+    """One layer's latent attention for one token a slot, absorbed form,
+    over the paged latent ``pool`` (N, bs, kv_rank + d_rope): normed
+    inputs ``a`` (S, 1, d) -> ``(attn, pool)``, the output (S, 1, d) and
+    the pool with each slot's new row written at position ``lengths``."""
+    s = a.shape[0]
+    bs = pool.shape[1]
+    attend = paged_attention_latent if fused else paged_attention_latent_gather
+    q_nope, q_rope, row = _latent_inputs(layer, a, positions, cfg)
+    with jax.named_scope("ft_mla_proj"):
+        w_uk, w_uv = _split_kvb(layer, cfg)
+        q_lat = jnp.einsum("shn,rhn->shr", q_nope[:, 0], w_uk)
+        q = jnp.concatenate([q_lat, q_rope[:, 0]], axis=-1)
+    with jax.named_scope("ft_mla_core"):
+        o_lat = attend(
+            q, row[:, 0], pool, tables, lengths,
+            value_dim=cfg.kv_rank, scale=cfg.softmax_scale,
+        )
+    with jax.named_scope("ft_mla_proj"):
+        blk = tables[jnp.arange(s), lengths // bs]
+        pool = pool.at[blk, lengths % bs].set(row[:, 0])
+        o = jnp.einsum("shr,rhv->shv", o_lat, w_uv)
+        return (o.reshape(s, -1) @ layer["wo"])[:, None], pool
+
+
+def ffn_layer(layer, m, cfg, i: int, rows=None):
+    """Layer ``i``'s FFN on normed rows ``m`` (N, d): gated SiLU where
+    the layer is dense, else ``moe.expert_layer`` with sigmoid scores; and
+    what the layer's router did (``None`` for a dense layer).  A prompt
+    (``rows`` None) goes ``cfg.ffn_rows`` rows at a time; ``rows`` (N,)
+    bool: rows whose picks are dispatched and counted (a decode round's
+    inactive slots are not)."""
+    routed = dict(
+        top_k=cfg.top_k, scale=cfg.routed_scale, normalize=cfg.norm_topk,
+        held=cfg.experts_held, score="sigmoid",
+    )
     if cfg.is_dense(i):
         with jax.named_scope("ft_mlp"):
             y = _in_row_blocks(
                 lambda r: gated_ffn(layer["mlp"], r), m, cfg.ffn_rows
             )
-        moe = None
-    elif rows is None:
-        def experts(r):
-            y, moe = expert_layer(
-                layer, r, top_k=cfg.top_k, scale=cfg.routed_scale,
-                normalize=cfg.norm_topk, held=cfg.experts_held,
-                score="sigmoid",
-            )
-            return y, moe["scores"], moe["choices"]
+        return y, None
+    if rows is not None:
+        return expert_layer(layer, m, rows=rows, **routed)
 
-        y, scores, choices = _in_row_blocks(experts, m, cfg.ffn_rows)
-        moe = {"scores": scores, "choices": choices}
-    else:
-        y, moe = expert_layer(
-            layer, m, top_k=cfg.top_k, scale=cfg.routed_scale,
-            normalize=cfg.norm_topk, held=cfg.experts_held, score="sigmoid",
-            rows=rows,
-        )
+    def experts(r):
+        y, moe = expert_layer(layer, r, **routed)
+        return y, moe["scores"], moe["choices"]
+
+    y, scores, choices = _in_row_blocks(experts, m, cfg.ffn_rows)
+    return y, {"scores": scores, "choices": choices}
+
+
+def _after_attention(layer, x, attn, cfg: PanguConfig, i: int, rows=None):
+    """The residual after layer ``i``, given the attention's output
+    ``attn`` (B, T, d) before its sandwich norm; and what the layer's
+    router did (:func:`ffn_layer`)."""
+    b, t, d = x.shape
+    x = x + rms_norm(attn, layer["ln_post_attn"], cfg.rms_eps)
+    m = rms_norm(x, layer["ln_pre_mlp"], cfg.rms_eps).reshape(b * t, d)
+    y, moe = ffn_layer(layer, m, cfg, i, rows)
     y = rms_norm(y.reshape(b, t, d), layer["ln_post_mlp"], cfg.rms_eps)
     return x + y, moe
 
 
-def head_logits(params, x, cfg: PanguConfig):
+def head_logits(params, x, cfg):
     """Final norm and the untied head on (..., d): float32 logits from a
     product in the held type with float32 accumulation."""
     h = rms_norm(x, params["ln_f"], cfg.rms_eps)
@@ -472,7 +552,7 @@ def head_logits(params, x, cfg: PanguConfig):
         return jnp.dot(h, params["head"], preferred_element_type=jnp.float32)
 
 
-def _embed(params, tokens, cfg: PanguConfig):
+def _embed(params, tokens, cfg):
     with jax.named_scope("ft_embed"):
         return params["embed"][tokens].astype(cfg.dtype)
 
@@ -495,19 +575,8 @@ def prefill(params, tokens, cfg: PanguConfig, max_len: int):
     rows, moes = [], []
     for i, layer in enumerate(params["layers"]):
         a = rms_norm(x, layer["ln_in"], cfg.rms_eps)
-        q_nope, q_rope, row = _latent_inputs(layer, a, positions, cfg)
-        with jax.named_scope("ft_mla_proj"):
-            w_uk, w_uv = _split_kvb(layer, cfg)
-            c = row[..., : cfg.kv_rank]
-            k_nope = jnp.einsum("btr,rhn->bthn", c, w_uk)
-            v = jnp.einsum("btr,rhv->bthv", c, w_uv)
-            rows.append(jnp.pad(row, ((0, 0), (0, max_len - t), (0, 0))))
-        with jax.named_scope("ft_mla_core"):
-            o = _prefill_core(
-                q_nope, q_rope, k_nope, row[..., cfg.kv_rank :], v, cfg
-            )
-        with jax.named_scope("ft_mla_proj"):
-            attn = o.reshape(b, t, -1) @ layer["wo"]
+        attn, row = latent_prefill(layer, a, positions, cfg, max_len)
+        rows.append(row)
         x, moe = _after_attention(layer, x, attn, cfg, i)
         if moe is not None:
             moes.append(moe)
@@ -528,31 +597,16 @@ def paged_decode_step(params, pools, tables, lengths, tokens,
     layers' ``scores`` (L_s, S, E) and ``choices`` (L_s, S, k), and
     ``counts``, int32 in the order of ``moe.MOE_COUNTS``, over the sparse
     layers and the ACTIVE slots (``lengths > 0``)."""
-    s = tokens.shape[0]
     positions = lengths[:, None].astype(jnp.int32)
-    bs = pools["ckv"][0].shape[1]
-    blk = tables[jnp.arange(s), lengths // bs]
-    off = lengths % bs
     active = lengths > 0
-    attend = paged_attention_latent if fused else paged_attention_latent_gather
     x = _embed(params, tokens[:, None], cfg)
     new_rows, moes = [], []
     for i, (layer, pool) in enumerate(zip(params["layers"], pools["ckv"])):
         a = rms_norm(x, layer["ln_in"], cfg.rms_eps)
-        q_nope, q_rope, row = _latent_inputs(layer, a, positions, cfg)
-        with jax.named_scope("ft_mla_proj"):
-            w_uk, w_uv = _split_kvb(layer, cfg)
-            q_lat = jnp.einsum("shn,rhn->shr", q_nope[:, 0], w_uk)
-            q = jnp.concatenate([q_lat, q_rope[:, 0]], axis=-1)
-        with jax.named_scope("ft_mla_core"):
-            o_lat = attend(
-                q, row[:, 0], pool, tables, lengths,
-                value_dim=cfg.kv_rank, scale=cfg.softmax_scale,
-            )
-        with jax.named_scope("ft_mla_proj"):
-            new_rows.append(pool.at[blk, off].set(row[:, 0]))
-            o = jnp.einsum("shr,rhv->shv", o_lat, w_uv)
-            attn = (o.reshape(s, -1) @ layer["wo"])[:, None]
+        attn, pool = latent_decode(
+            layer, a, positions, pool, tables, lengths, cfg, fused
+        )
+        new_rows.append(pool)
         x, moe = _after_attention(layer, x, attn, cfg, i, rows=active)
         if moe is not None:
             moes.append(moe)

@@ -67,6 +67,7 @@ from .kv_cache import (
     PagedCacheConfig,
     gather_seq,
     init_pools,
+    init_state,
     make_paged_decode_fn,
     paged_decode_step,
     write_prefill,
@@ -78,6 +79,7 @@ from .migration import (
     migration_error_bound,
     pack_kv,
     unpack_kv,
+    unpack_state,
 )
 from .pool import PoolConfig, ReplicaFailed, ReplicaPool
 from .prefix_index import PrefixIndex, PrefixIndexError
@@ -97,6 +99,7 @@ __all__ = [
     "CacheExhausted",
     "PagedCacheConfig",
     "init_pools",
+    "init_state",
     "write_prefill",
     "write_prefill_at",
     "write_swapped",
@@ -130,5 +133,6 @@ __all__ = [
     "MigrationError",
     "pack_kv",
     "unpack_kv",
+    "unpack_state",
     "migration_error_bound",
 ]

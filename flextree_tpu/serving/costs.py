@@ -25,7 +25,7 @@ from __future__ import annotations
 
 import math
 
-from ..models.configs import pool_layout
+from ..models.configs import pool_layout, position_parts, slot_parts
 
 __all__ = [
     "decode_round_flops",
@@ -33,6 +33,7 @@ __all__ = [
     "predict_decode_round_us",
     "predict_prefill_us",
     "cache_bytes_per_position",
+    "state_bytes_per_slot",
     "kv_migration_elems",
     "predict_migration_us",
     "plan_migration",
@@ -69,8 +70,24 @@ def cache_bytes_per_position(cfg) -> int:
         itemsize = np.dtype(cfg.dtype).itemsize
     except TypeError:
         itemsize = 4
-    per_layer = sum(math.prod(row) for row in pool_layout(cfg).values())
-    return per_layer * itemsize * cfg.n_layers
+    return itemsize * sum(
+        math.prod(row)
+        for layer in pool_layout(cfg) for row in layer["position"].values()
+    )
+
+
+def state_bytes_per_slot(cfg) -> int:
+    """Bytes the layers hold a SLOT, whatever its sequence's length, over
+    all the layers (a recurrent layer's state): what ``init_state``
+    allocates a slot, ``engine.report()["state_bytes_per_slot"]``, and
+    what a swap or a migration moves besides the cached positions.  0 for
+    a block that keeps nothing a slot."""
+    import jax.numpy as jnp
+
+    return sum(
+        math.prod(shape) * jnp.dtype(dtype).itemsize * layers
+        for (shape, dtype), layers in slot_parts(cfg).values()
+    )
 
 
 def decode_round_bytes(cfg, pcfg, n_active: int, frontier_blocks: int) -> float:
@@ -145,11 +162,12 @@ def kv_migration_elems(cfg, pcfg, prompt_len: int) -> list:
     ships, a tensor a part of the pool's layout (K and V, or one latent
     row): the block footprint of the prompt (``blocks_for``, whole blocks
     — migration ships the tail block too) × block positions × the part's
-    numbers a position.  One sequence ships ``n_layers`` times these."""
+    numbers a position.  One sequence ships ``n_layers`` times these (every
+    layer of the three blocks that migrate today caches every part)."""
     n_blocks = pcfg.blocks_for(max(int(prompt_len), 1))
     return [
         n_blocks * pcfg.block_size * math.prod(row)
-        for row in pool_layout(cfg).values()
+        for row, _ in position_parts(cfg).values()
     ]
 
 

@@ -54,13 +54,18 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..models.configs import config_from_dict, init_model_params, pool_layout
+from ..models.configs import (
+    config_from_dict,
+    init_model_params,
+    position_parts,
+    slot_parts,
+)
 from ..models.generate import prefill, prefill_suffix, sample_token
 from ..models.moe import MOE_COUNTS
 from ..models.transformer import TransformerConfig
 from ..obs import MetricsRegistry, current_recorder, record_event, span
 from .batcher import BatcherConfig, ContinuousBatcher, Request, SeqState
-from .costs import cache_bytes_per_position
+from .costs import cache_bytes_per_position, state_bytes_per_slot
 from .kv_cache import (
     CacheExhausted,
     PagedCacheConfig,
@@ -68,13 +73,16 @@ from .kv_cache import (
     export_blocks,
     gather_seq,
     init_pools,
+    init_state,
     make_paged_decode_fn,
+    read_state,
     write_imported,
     write_prefill,
     write_prefill_at,
+    write_state,
     write_swapped,
 )
-from .migration import MigrationError, pack_kv, unpack_kv
+from .migration import MigrationError, pack_kv, unpack_kv, unpack_state
 
 # cache-occupancy histogram buckets: fractions of the allocatable pool in
 # use, observed once per scheduling round (engine.report() embeds it)
@@ -190,11 +198,24 @@ class ServingEngine:
                 f"the prefix cache is not implemented for "
                 f"{type(cfg).__name__}: build the engine with "
                 f"BatcherConfig(prefix_cache=False)"
+                + (
+                    " (its layers hold a state a slot, and a prefix's "
+                    "state is a snapshot nobody has taken)"
+                    if slot_parts(cfg) else ""
+                )
             )
         self.pools = init_pools(cfg, pcfg)
-        # what one cached position takes over all the layers
+        # what the layers hold a slot and not a position (a recurrent
+        # layer's state); {} for a block that holds nothing so
+        self.state = init_state(cfg, self.bcfg.slots)
+        # what one cached position takes over all the layers, what a slot
+        # holds whatever its length, and the layers that hold such a state
         # (ft.engine.decode_dispatch, report())
         self.cache_bytes_per_position = cache_bytes_per_position(cfg)
+        self.state_bytes_per_slot = state_bytes_per_slot(cfg)
+        self.state_layers = max(
+            (layers for _, layers in slot_parts(cfg).values()), default=0
+        )
         # donation keeps steady-state decode allocation-free: the pool
         # scatter aliases in place instead of copying the whole pool every
         # round.  XLA:TPU aliases every donated pool buffer (AOT compile
@@ -239,6 +260,7 @@ class ServingEngine:
 
         self._hit_prefill = jax.jit(_hit, static_argnums=(4,))
         self._write = jax.jit(write_prefill, donate_argnums=(0,))
+        self._write_state = jax.jit(write_state, donate_argnums=(0,))
         # the suffix scatter never touches blocks below start_block — the
         # shared cached blocks stay byte-identical through a hit
         self._write_at = jax.jit(
@@ -330,6 +352,7 @@ class ServingEngine:
                     "ft.engine.prefill", rid=state.rid,
                     prompt_len=state.request.prompt_len,
                     cached_tokens=state.cached_tokens,
+                    state_bytes=self.state_bytes_per_slot,
                 ):
                     self._prefill_slot(slot, state)
             with span("ft.engine.grow"):
@@ -344,11 +367,13 @@ class ServingEngine:
                     attn_layers=self.attn_layers,
                     attn_kernel_layers=self.attn_kernel_layers,
                     cache_bytes_per_position=self.cache_bytes_per_position,
+                    state_bytes_per_slot=self.state_bytes_per_slot,
+                    state_layers=self.state_layers,
                 ):
                     # a model with routed experts hands out a third
                     # result, what its routers did this round
-                    logits, self.pools, *routed = self._decode(
-                        self.params, self.pools, tables, lengths, tokens
+                    logits, *routed = self._decode_round(
+                        tables, lengths, tokens
                     )
                     ids = self._greedy_ids(logits)
                     counts = routed[0]["counts"] if routed else None
@@ -397,7 +422,9 @@ class ServingEngine:
                 "ft.engine.bookkeeping", round=self.steps,
                 decoded=len(active), admitted=len(admitted),
                 finished=len(finished), blocks_in_use=total - free,
-                blocks_total=total, **moe_ids,
+                blocks_total=total,
+                state_slots_live=self.batcher.num_active if self.state else 0,
+                **moe_ids,
             ):
                 self.steps += 1
                 m = self.metrics
@@ -435,6 +462,32 @@ class ServingEngine:
 
     # ---- internals ---------------------------------------------------------
 
+    def _decode_round(self, tables, lengths, tokens) -> tuple:
+        """Dispatch the decode program over the pools (and the state,
+        where the block holds one: a sixth argument, donated like the
+        pools, and the program's last result) and keep what it hands back.
+        Returns ``(logits[, what the routers did])``."""
+        carried = (self.state,) if self.state else ()
+        logits, self.pools, *rest = self._decode(
+            self.params, self.pools, tables, lengths, tokens, *carried
+        )
+        if carried:
+            self.state = rest.pop()
+        return (logits, *rest)
+
+    def _write_slot(self, slot: int, cache: dict, block_ids) -> None:
+        """Put a prefill's ``cache`` in place: its rows into ``block_ids``
+        and, where the block holds a state a slot, its final state into
+        ``slot``'s (replacing whatever the slot's last sequence left)."""
+        self.pools = self._write(
+            self.pools, cache, np.asarray(block_ids, np.int32)
+        )
+        if self.state:
+            self.state = self._write_state(
+                self.state, cache["state"], np.int32(slot)
+            )
+            self.metrics.counter("serve.state_resets").inc()
+
     def _grow_with_preemption(self) -> int:
         """On-demand growth with the exhaustion → preempt loop: keep
         evicting the newest resident sequence until every survivor's next
@@ -461,10 +514,18 @@ class ServingEngine:
         if mode == "swap":
             # host copies of the written positions — np.asarray moves the
             # bytes off-device NOW, before the freed blocks are rewritten
-            view = gather_seq(self.pools, state.block_ids, length=state.length)
-            kv = jax.tree.map(np.asarray, view)
+            # (and of the slot's state, whole, whatever the length)
+            kv = jax.tree.map(np.asarray, {
+                "rows": gather_seq(
+                    self.pools, state.block_ids, length=state.length
+                ),
+                "state": read_state(self.state, slot),
+            })
             swapped = sum(a.nbytes for a in jax.tree.leaves(kv))
             self.metrics.counter("serve.swap_out_bytes").inc(swapped)
+            self.metrics.counter("serve.state_swap_bytes").inc(
+                sum(a.nbytes for a in jax.tree.leaves(kv["state"]))
+            )
             self.metrics.counter("serve.swap_outs").inc()
             record_event(
                 "serve_swap_out", rid=state.rid, length=state.length,
@@ -493,10 +554,14 @@ class ServingEngine:
                 full[: a.shape[0]] = a
                 return jnp.asarray(full)
 
-            padded = jax.tree.map(pad, kv)
+            padded = jax.tree.map(pad, kv["rows"])
             self.pools = self._write_back(
                 self.pools, padded, np.asarray(state.block_ids, np.int32)
             )
+            if self.state:
+                self.state = self._write_state(
+                    self.state, kv["state"], np.int32(slot)
+                )
         else:
             # recompute: replay the tokens whose K/V were dropped (prompt
             # + already-written decode tokens) through prefill
@@ -508,9 +573,7 @@ class ServingEngine:
                 ),
             ])
             _, cache = self._prefill(self.params, written[None])
-            self.pools = self._write(
-                self.pools, cache, np.asarray(state.block_ids, np.int32)
-            )
+            self._write_slot(slot, cache, state.block_ids)
         if req.temperature > 0:
             # same derivation as _prefill_slot: the schedule is a pure
             # function of the seed, indexed by len(generated) — resume
@@ -637,7 +700,13 @@ class ServingEngine:
         )
         first_token = int(np.asarray(self._greedy_ids(logits))[0])
         kv = jax.tree.map(np.asarray, export_blocks(self.pools, blocks))
-        meta, blob = pack_kv(kv, codec=codec)
+        # no slot was taken: what the sequence carries a slot goes out as
+        # the prefill left it, one array a layer
+        carried = {
+            part: [np.asarray(a[0]) for a in layers]
+            for part, layers in cache.get("state", {}).items()
+        }
+        meta, blob = pack_kv(kv, codec=codec, state=carried)
         self._exported[req.rid] = blocks
         now = _now()
         self.metrics.counter("serve.migration_exports").inc()
@@ -654,6 +723,20 @@ class ServingEngine:
             "blob": blob,
             "ttft_s": now - req.arrival_s,
             "prefill_s": now - t0,
+        }
+
+    def _payload_geometry(self) -> dict:
+        """What a migration payload has to state to land here: the pool's
+        block size, the rows' layout and the layers that cache them, the
+        state's layout and the layers that hold it (``models.configs.
+        position_parts``, ``slot_parts``)."""
+        rows, held = position_parts(self.cfg), slot_parts(self.cfg)
+        return {
+            "block_size": self.pcfg.block_size,
+            "layout": {k: list(row) for k, (row, _) in rows.items()},
+            "n_layers": max((n for _, n in rows.values()), default=0),
+            "state": {k: list(shape) for k, ((shape, _), _) in held.items()},
+            "state_layers": self.state_layers,
         }
 
     def release_exported(self, rid: int, acked: bool) -> bool:
@@ -698,18 +781,18 @@ class ServingEngine:
                 f"blocks, pool holds {self.pcfg.num_blocks - 1}"
             )
         kv = unpack_kv(meta, blob)  # CRC + per-tensor verification
-        layout = {k: list(v) for k, v in pool_layout(self.cfg).items()}
-        if (
-            int(meta["block_size"]) != self.pcfg.block_size
-            or meta["layout"] != layout
-            or int(meta["n_layers"]) != self.cfg.n_layers
-        ):
+        carried = unpack_state(meta, blob)
+        stated = meta.get("state") or {"layout": {}, "n_layers": 0}
+        shipped = {
+            "block_size": int(meta["block_size"]), "layout": meta["layout"],
+            "n_layers": int(meta["n_layers"]),
+            "state": stated["layout"], "state_layers": int(stated["n_layers"]),
+        }
+        if shipped != self._payload_geometry():
             raise MigrationError(
-                f"request {req.rid}: payload geometry "
-                f"(bs={meta['block_size']}, layout={meta['layout']}, "
-                f"L={meta['n_layers']}) does not match this replica's "
-                f"model (bs={self.pcfg.block_size}, layout={layout}, "
-                f"L={self.cfg.n_layers})"
+                f"request {req.rid}: payload geometry (block size, layout "
+                f"and layers of the rows, of the state) {shipped} does not "
+                f"match this replica's model {self._payload_geometry()}"
             )
         n_mig = int(meta["n_blocks"])
         if n_mig != self.pcfg.blocks_for(req.prompt_len):
@@ -731,6 +814,11 @@ class ServingEngine:
         self.pools = self._write_import(
             self.pools, kv_dev, np.asarray(state.block_ids[:n_mig], np.int32)
         )
+        if self.state:
+            self.state = self._write_state(
+                self.state, jax.tree.map(lambda a: a[None], carried),
+                np.int32(slot),
+            )
         if self.batcher.prefix_index is not None:
             # mid-stream arrival of already-full blocks: the prompt's
             # FULL blocks are shareable the moment they land, so the
@@ -893,7 +981,7 @@ class ServingEngine:
         prompt = np.asarray(req.prompt, np.int32)
         c = state.cached_tokens
         with span("ft.engine.prefill_dispatch"):
-            logits = self._dispatch_prefill(state, prompt, c)
+            logits = self._dispatch_prefill(slot, state, prompt, c)
             ids = self._greedy_ids(logits)
         if self.batcher.prefix_index is not None:
             self._note_prefix_admission(c > 0, t0)
@@ -916,7 +1004,7 @@ class ServingEngine:
         )
         self._record_prefill(req, slot, c, now - t0)
 
-    def _dispatch_prefill(self, state: SeqState, prompt, c: int):
+    def _dispatch_prefill(self, slot: int, state: SeqState, prompt, c: int):
         """Dispatch the prompt's prefill (the suffix alone on a
         prefix-cache hit) and the scatter of its K/V into the sequence's
         blocks; returns the logits, still on the device."""
@@ -960,9 +1048,7 @@ class ServingEngine:
             )
         else:
             logits, cache = self._prefill(self.params, prompt[None])
-            self.pools = self._write(
-                self.pools, cache, np.asarray(state.block_ids, np.int32)
-            )
+            self._write_slot(slot, cache, state.block_ids)
             if self.batcher.prefix_index is not None:
                 self.metrics.counter("serve.prefix_misses").inc()
         return logits
@@ -1016,6 +1102,8 @@ class ServingEngine:
             "attn_layers": self.attn_layers,
             "attn_kernel_layers": self.attn_kernel_layers,
             "cache_bytes_per_position": self.cache_bytes_per_position,
+            "state_bytes_per_slot": self.state_bytes_per_slot,
+            "state_layers": self.state_layers,
             **self.metrics.snapshot(),
         }
 
@@ -1049,37 +1137,34 @@ class ServingEngine:
         hit was supposed to shrink.  Each bucket also warms the offset
         scatter for every remaining-block count it can need."""
         S, P = self.bcfg.slots, self.pcfg.blocks_per_seq
+        # Every program is warmed on the engine's OWN pools and state,
+        # donated and kept as the engine keeps them in a round (no second
+        # pool is ever allocated: at 128 slots a linear-attention model's
+        # pools and state are a third of the chip).  What the warm-up
+        # writes is invisible: an all-inactive decode round and every pool
+        # write land in the null block alone (block id 0, n times over:
+        # the same compiled scatter as n real blocks), and the state write
+        # puts slot 0's state back where it came from.
+        def null(n):
+            return np.zeros((n,), np.int32)
+
         # the greedy pick is warmed on both of its shapes: the decode
         # round's (S, V) logits here and a prefill's (1, V) below
-        jax.block_until_ready(
-            self._greedy_ids(
-                self._decode(
-                    self.params,
-                    init_pools(self.cfg, self.pcfg),
-                    np.zeros((S, P), np.int32),
-                    np.zeros((S,), np.int32),
-                    np.zeros((S,), np.int32),
-                )[0]
-            )
+        logits, *_ = self._decode_round(
+            np.zeros((S, P), np.int32), null(S), null(S)
         )
+        jax.block_until_ready(self._greedy_ids(logits))
         cache = None
         for t in sorted(set(int(t) for t in prompt_lens)):
             logits, cache = self._prefill(
                 self.params, np.zeros((1, t), np.int32)
             )
             jax.block_until_ready(self._greedy_ids(logits))
-        for n in sorted(set(int(n) for n in block_counts)):
-            if cache is None:
-                _, cache = self._prefill(
-                    self.params, np.zeros((1, 1), np.int32)
-                )
-            jax.block_until_ready(
-                self._write(
-                    init_pools(self.cfg, self.pcfg),
-                    cache,
-                    np.arange(1, n + 1, dtype=np.int32),
-                )
+        if self.state:
+            self.state = self._write_state(
+                self.state, read_state(self.state, np.int32(0)), np.int32(0)
             )
+        counts = set(int(n) for n in block_counts)
         if self.batcher.ondemand:
             # on-demand writes use block counts the caller's reservation
             # math never names: admission scatters blocks_for(prompt)
@@ -1087,39 +1172,24 @@ class ServingEngine:
             # the prefill write AND the swap-in scatter for every count,
             # or the compile lands inside the TTFT / preemption stall it
             # was supposed to end
-            bs = self.pcfg.block_size
-            if cache is None:
-                _, cache = self._prefill(
-                    self.params, np.zeros((1, 1), np.int32)
-                )
-            for n in range(1, P + 1):
-                jax.block_until_ready(
-                    self._write(
-                        init_pools(self.cfg, self.pcfg),
-                        cache,
-                        np.arange(1, n + 1, dtype=np.int32),
-                    )
-                )
-                jax.block_until_ready(
-                    self._write_back(
-                        init_pools(self.cfg, self.pcfg),
-                        self._zero_rows((n * bs,)),
-                        np.arange(1, n + 1, dtype=np.int32),
-                    )
+            counts |= set(range(1, P + 1))
+        bs = self.pcfg.block_size
+        if counts and cache is None:
+            _, cache = self._prefill(self.params, np.zeros((1, 1), np.int32))
+        for n in sorted(counts):
+            self.pools = self._write(self.pools, cache, null(n))
+            if self.batcher.ondemand:
+                self.pools = self._write_back(
+                    self.pools, self._zero_rows((n * bs,)), null(n)
                 )
         # migrated-KV import scatter: one compile per inbound block
         # count — an unwarmed one stalls the decode replica's engine
         # loop mid-handoff, landing inside the very inter-token p99 the
         # disaggregation exists to protect
         for n in sorted(set(int(n) for n in import_counts)):
-            jax.block_until_ready(
-                self._write_import(
-                    init_pools(self.cfg, self.pcfg),
-                    self._zero_rows((n, self.pcfg.block_size)),
-                    np.arange(1, n + 1, dtype=np.int32),
-                )
+            self.pools = self._write_import(
+                self.pools, self._zero_rows((n, bs)), null(n)
             )
-        bs = self.pcfg.block_size
         for c, s in sorted(set((int(c), int(s)) for c, s in suffix_buckets)):
             if c < 1 or s < 1:
                 # c need NOT be block-aligned: the COW case caches
@@ -1130,17 +1200,10 @@ class ServingEngine:
                 )
             nc = -(-c // bs)  # chain blocks covering the cached prefix
             _, cache = self._hit_prefill(
-                self.params, np.zeros((1, s), np.int32),
-                init_pools(self.cfg, self.pcfg),
-                np.arange(1, nc + 1, dtype=np.int32), c,
+                self.params, np.zeros((1, s), np.int32), self.pools,
+                null(nc), c,
             )
             sb = c // bs
             for n in range(1, P - sb + 1):
-                jax.block_until_ready(
-                    self._write_at(
-                        init_pools(self.cfg, self.pcfg),
-                        cache,
-                        np.arange(1, n + 1, dtype=np.int32),
-                        sb,
-                    )
-                )
+                self.pools = self._write_at(self.pools, cache, null(n), sb)
+        jax.block_until_ready((self.pools, self.state))

@@ -8,11 +8,22 @@ paged layout breaks the coupling the way vLLM's PagedAttention does:
 - the pool is per layer ``(num_blocks, block_size, *row)`` — one static
   shape for the whole server lifetime, so the decode step stays ONE
   compiled program regardless of which sequences are resident.  What a
-  ``row`` (one cached position) is comes from the configuration
-  (``models.configs.pool_layout``): K and V of ``(H, Dh)`` each for the
-  dense and the Laguna block, ONE array of ``(kv_rank + d_rope,)`` for
-  latent attention.  The pools are ``{part: [array a layer]}``, and every
-  function below works on whatever parts they name;
+  ``row`` (one cached position) is comes from the configuration, a layer
+  at a time (``models.configs.pool_layout``: the parts a layer caches a
+  POSITION, and the parts it holds a SLOT): K and V of ``(H, Dh)`` each
+  in every layer of the dense and the Laguna block, ONE array of
+  ``(kv_rank + d_rope,)`` for latent attention, and nothing at all under a
+  linear-attention layer.  The pools are ``{part: [array a layer that
+  caches it]}``, and every pool function below works on whatever parts
+  and however many layers they name;
+- what a layer holds a slot (a recurrent layer's state: one array of a
+  fixed size a sequence, whatever its length) is the STATE, ``{part:
+  [(slots, *shape) a layer that holds it]}`` (:func:`init_state`),
+  beside the pools and never in blocks: the prefill hands a sequence's
+  out whole and :func:`write_state` puts it in the slot's place, the
+  decode program takes the state and returns it with the active slots'
+  updated in place, a swap and a migration carry a slot's whole
+  (:func:`read_state`).  ``{}`` for a block that keeps nothing a slot;
 - each sequence owns a **block table** (a row of block ids): block
   ``p`` of the table holds cache positions ``p*block_size ..``; tables
   are plain int32 inputs to the jitted step, so the host can remap them
@@ -58,7 +69,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from ..models.configs import block_of, pool_layout
+from ..models.configs import block_of, position_parts, slot_parts
 
 __all__ = [
     "NULL_BLOCK",
@@ -66,6 +77,9 @@ __all__ = [
     "PagedCacheConfig",
     "BlockAllocator",
     "init_pools",
+    "init_state",
+    "write_state",
+    "read_state",
     "write_prefill",
     "write_prefill_at",
     "write_swapped",
@@ -243,17 +257,51 @@ class BlockAllocator:
 
 
 def init_pools(cfg, pcfg: PagedCacheConfig) -> dict:
-    """``{part: [(num_blocks, block_size, *row) a layer]}``, zeros in the
-    compute dtype: the parts and their rows are the configuration's
-    (``models.configs.pool_layout``) — K and V of ``(Hkv, Dh)`` for the
-    dense block (``Hkv`` its ``n_heads``) and the Laguna block, one
-    ``ckv`` of ``(kv_rank + d_rope,)`` for latent attention."""
+    """``{part: [(num_blocks, block_size, *row) a layer that caches it]}``,
+    zeros in the compute dtype: the parts, their rows and their layers are
+    the configuration's (``models.configs.position_parts``) — K and V of
+    ``(Hkv, Dh)`` in every layer of the dense block (``Hkv`` its
+    ``n_heads``) and the Laguna block, one ``ckv`` of ``(kv_rank +
+    d_rope,)`` under each latent-attention layer."""
     return {
         part: [
             jnp.zeros((pcfg.num_blocks, pcfg.block_size, *row), cfg.dtype)
-            for _ in range(cfg.n_layers)
+            for _ in range(layers)
         ]
-        for part, row in pool_layout(cfg).items()
+        for part, (row, layers) in position_parts(cfg).items()
+    }
+
+
+def init_state(cfg, slots: int) -> dict:
+    """``{part: [(slots, *shape) a layer that holds it]}``, zeros in each
+    part's own dtype (``models.configs.slot_parts``): what the layers hold
+    a slot and not a position.  ``{}`` for a block that holds nothing so."""
+    return {
+        part: [jnp.zeros((slots, *shape), dtype) for _ in range(layers)]
+        for part, ((shape, dtype), layers) in slot_parts(cfg).items()
+    }
+
+
+def write_state(state: dict, carried: dict, slot) -> dict:
+    """Put one sequence's state in ``slot``'s place: ``carried`` is per
+    part and layer ``(1, *shape)`` (a prefill's ``cache["state"]``, a
+    swapped or a migrated sequence's), and replaces the slot's whole.  A
+    slot's state is never cleared on its own: whoever admits a sequence
+    writes what that sequence carries (a prefill's starts from zeros)."""
+    return {
+        part: [
+            a.at[slot].set(c[0].astype(a.dtype))
+            for a, c in zip(layers, carried[part])
+        ]
+        for part, layers in state.items()
+    }
+
+
+def read_state(state: dict, slot) -> dict:
+    """One slot's state, per part and layer ``(1, *shape)``: what
+    :func:`write_state` takes back."""
+    return {
+        part: [a[slot][None] for a in layers] for part, layers in state.items()
     }
 
 
@@ -358,7 +406,7 @@ def decode_attention_layers(cfg, pcfg: PagedCacheConfig,
 
 
 def paged_decode_step(params, pools, tables, lengths, tokens, cfg,
-                      fused: bool = False, impl: str = "jnp"):
+                      fused: bool = False, impl: str = "jnp", state=None):
     """One decode step for S slots over the paged pool.
 
     ``tables`` (S, P) int32 block tables, ``lengths`` (S,) int32 cache
@@ -367,7 +415,9 @@ def paged_decode_step(params, pools, tables, lengths, tokens, cfg,
     vocab) f32 next-position logits and the pool with each slot's new row
     scattered at ``(tables[s, lengths[s]//bs], lengths[s] % bs)`` — and,
     from a block with routed experts, a third result: what its routers
-    did.
+    did.  A block that holds something a slot takes it as ``state``
+    (:func:`init_state`) and returns it last, the active slots' updated and
+    every other slot's as it was.
 
     Inactive slots are driven with table rows of all-NULL_BLOCK and
     length 0: their writes land in the null block and their logits are
@@ -388,9 +438,11 @@ def paged_decode_step(params, pools, tables, lengths, tokens, cfg,
     the benchmark's traffic files still carry the key (ROADMAP D3).
     """
     check_decode_impl(impl)
-    return block_of(cfg).decode_step(
-        params, pools, tables, lengths, tokens, cfg, fused
-    )
+    step = block_of(cfg).decode_step
+    if state:
+        return step(params, pools, tables, lengths, tokens, cfg, fused,
+                    state=state)
+    return step(params, pools, tables, lengths, tokens, cfg, fused)
 
 
 def make_paged_decode_fn(cfg, donate: bool = True,
@@ -399,11 +451,25 @@ def make_paged_decode_fn(cfg, donate: bool = True,
     pool is dead the moment the new one exists — donation keeps steady-
     state decode allocation-free).  ``fused=`` selects the attention
     path (see :func:`paged_decode_step`); ``impl`` is checked here, at
-    construction, and selects nothing."""
+    construction, and selects nothing.  For a block that holds something a
+    slot the program takes the state as a sixth argument, donated too, and
+    returns it last."""
     check_decode_impl(impl)
+    if not slot_parts(cfg):
+        return jax.jit(
+            partial(paged_decode_step, cfg=cfg, fused=fused),
+            donate_argnums=(1,) if donate else (),
+        )
+
+    def paged_decode_step_with_state(params, pools, tables, lengths, tokens,
+                                     state):
+        return paged_decode_step(
+            params, pools, tables, lengths, tokens, cfg, fused, state=state
+        )
+
     return jax.jit(
-        partial(paged_decode_step, cfg=cfg, fused=fused),
-        donate_argnums=(1,) if donate else (),
+        paged_decode_step_with_state,
+        donate_argnums=(1, 5) if donate else (),
     )
 
 

@@ -28,7 +28,11 @@ Dh)``, or one latent ``ckv`` row) and bytes:
 
 Tensor order on the wire is fixed (layer-major, the parts in the order
 the payload's ``layout`` states them: K before V) and the meta states the
-layout, which the receiver holds against its own model's; the meta dict travels in the
+layout, which the receiver holds against its own model's.  A model whose
+layers keep something a SLOT and not a position (a recurrent state:
+``models.configs.slot_parts``) ships it behind the rows, in the same blob,
+under the same codec and CRCs, stated under ``meta["state"]``
+(:func:`unpack_state`).  The meta dict travels in the
 RPC JSON body, the blob rides base64-chunked frames (``rpc.chunk_blob``).
 """
 
@@ -44,6 +48,7 @@ __all__ = [
     "MigrationError",
     "pack_kv",
     "unpack_kv",
+    "unpack_state",
     "migration_error_bound",
 ]
 
@@ -71,35 +76,20 @@ def _tensors(kv: dict):
             yield layer, part, layers[layer]
 
 
-def pack_kv(kv: dict, *, codec: str = "f32") -> tuple[dict, bytes]:
-    """Pack block-shaped cache rows into ``(meta, blob)`` for the wire.
-
-    ``kv`` is ``export_blocks`` output: per part and layer ``(n, bs,
-    *row)``.  ``meta`` declares the geometry (``layout``: each part's
-    row shape), codec, and per-tensor byte spans + CRCs; ``blob`` is the
-    concatenated tensor payload in fixed order.
-    The f32 codec emits each tensor's float32 bytes verbatim (bitwise);
-    int8 emits ``encode_int8``'s (q, scales) pair per tensor, flattened,
-    with the tensor's amax recorded so the receiver can state the
-    documented error bound without re-deriving it.
-    """
-    c = get_codec(codec)
-    if c.name not in ("f32", "int8"):
-        raise MigrationError(
-            f"codec {c.name!r} is not a migration codec (f32 | int8)"
-        )
-    n, bs = np.asarray(next(iter(kv.values()))[0]).shape[:2]
+def _encode(tree: dict, lead: tuple, c) -> tuple:
+    """``(layout, entries, payloads)`` of ``{part: [array a layer]}``
+    whose arrays are ``(*lead, *layout[part])``."""
     layout = {
-        part: [int(x) for x in np.asarray(layers[0]).shape[2:]]
-        for part, layers in kv.items()
+        part: [int(x) for x in np.asarray(layers[0]).shape[len(lead):]]
+        for part, layers in tree.items()
     }
-    tensors, parts = [], []
-    for layer, part, arr in _tensors(kv):
+    entries, payloads = [], []
+    for layer, part, arr in _tensors(tree):
         a = np.ascontiguousarray(np.asarray(arr, dtype=np.float32))
-        if a.shape != (n, bs, *layout[part]):
+        if a.shape != (*lead, *layout[part]):
             raise MigrationError(
                 f"layer {layer} {part} shaped {a.shape}, expected "
-                f"{(n, bs, *layout[part])}"
+                f"{(*lead, *layout[part])}"
             )
         if c.name == "f32":
             payload = a.tobytes()
@@ -119,9 +109,38 @@ def pack_kv(kv: dict, *, codec: str = "f32") -> tuple[dict, bytes]:
                 "amax": float(np.max(np.abs(flat))) if flat.size else 0.0,
             }
         entry["crc32"] = _crc(payload)
-        tensors.append(entry)
-        parts.append(payload)
-    blob = b"".join(parts)
+        entries.append(entry)
+        payloads.append(payload)
+    return layout, entries, payloads
+
+
+def pack_kv(kv: dict, *, codec: str = "f32",
+            state: dict | None = None) -> tuple[dict, bytes]:
+    """Pack block-shaped cache rows into ``(meta, blob)`` for the wire.
+
+    ``kv`` is ``export_blocks`` output: per part and layer ``(n, bs,
+    *row)``.  ``meta`` declares the geometry (``layout``: each part's
+    row shape), codec, and per-tensor byte spans + CRCs; ``blob`` is the
+    concatenated tensor payload in fixed order.
+    The f32 codec emits each tensor's float32 bytes verbatim (bitwise);
+    int8 emits ``encode_int8``'s (q, scales) pair per tensor, flattened,
+    with the tensor's amax recorded so the receiver can state the
+    documented error bound without re-deriving it.
+
+    ``state``: what the sequence carries a SLOT and not a position
+    (``{part: [array a layer that holds it]}``, a recurrent layer's state;
+    ``models.configs.slot_parts``), shipped behind the rows in the same
+    blob and stated under ``meta["state"]`` (its own ``layout``,
+    ``n_layers``, ``nbytes`` and ``tensors``); None or empty for a model
+    that keeps nothing a slot, whose ``meta`` then has no such key.
+    """
+    c = get_codec(codec)
+    if c.name not in ("f32", "int8"):
+        raise MigrationError(
+            f"codec {c.name!r} is not a migration codec (f32 | int8)"
+        )
+    n, bs = np.asarray(next(iter(kv.values()))[0]).shape[:2]
+    layout, tensors, parts = _encode(kv, (int(n), int(bs)), c)
     meta = {
         "codec": c.name,
         "codec_block": c.block,
@@ -129,40 +148,27 @@ def pack_kv(kv: dict, *, codec: str = "f32") -> tuple[dict, bytes]:
         "block_size": int(bs),
         "layout": layout,
         "n_layers": len(next(iter(kv.values()))),
-        "nbytes": len(blob),
-        "crc32": _crc(blob),
         "tensors": tensors,
     }
+    if state:
+        s_layout, s_tensors, s_parts = _encode(state, (), c)
+        meta["state"] = {
+            "layout": s_layout,
+            "n_layers": len(next(iter(state.values()))),
+            "nbytes": sum(len(p) for p in s_parts),
+            "tensors": s_tensors,
+        }
+        parts += s_parts
+    blob = b"".join(parts)
+    meta["nbytes"] = len(blob)
+    meta["crc32"] = _crc(blob)
     return meta, blob
 
 
-def unpack_kv(meta: dict, blob: bytes) -> dict:
-    """Verify and decode a migration payload back to block-shaped rows.
-
-    Refuses loudly (:class:`MigrationError`) on: whole-blob CRC or byte
-    count drift, per-tensor CRC drift, tensor count vs declared layers
-    and parts, byte spans that do not reconstruct the declared geometry,
-    unknown codec.  On success returns ``{part: [np (n, bs, *row) f32 a
-    layer]}`` ready for ``kv_cache.write_imported``.
-    """
-    try:
-        codec = get_codec(meta["codec"])
-        n = int(meta["n_blocks"])
-        bs = int(meta["block_size"])
-        layout = {
-            str(part): tuple(int(x) for x in row)
-            for part, row in meta["layout"].items()
-        }
-        layers = int(meta["n_layers"])
-        tensors = list(meta["tensors"])
-    except (KeyError, TypeError, ValueError, AttributeError) as e:
-        raise MigrationError(f"malformed migration meta: {e}") from None
-    if len(blob) != int(meta.get("nbytes", -1)):
-        raise MigrationError(
-            f"payload is {len(blob)} bytes, meta declares {meta.get('nbytes')}"
-        )
-    if _crc(blob) != int(meta.get("crc32", -1)):
-        raise MigrationError("payload CRC mismatch — corrupt migration blob")
+def _decode(tensors, layout: dict, layers: int, lead: tuple, codec,
+            blob: bytes) -> dict:
+    """The tensors ``_encode`` wrote, verified one by one: ``{part: [np
+    (*lead, *layout[part]) f32 a layer]}``; ``blob`` holds exactly them."""
     if len(tensors) != len(layout) * layers:
         raise MigrationError(
             f"{len(tensors)} tensors declared for {layers} layers "
@@ -180,7 +186,7 @@ def unpack_kv(meta: dict, blob: bytes) -> dict:
             raise MigrationError(f"tensor entry {i} addresses {part}@{layer}")
         if out[part][layer] is not None:
             raise MigrationError(f"duplicate tensor {part}@{layer}")
-        shape = (n, bs, *layout[part])
+        shape = (*lead, *layout[part])
         count = int(np.prod(shape))
         payload = blob[off : off + nbytes]
         if len(payload) != nbytes:
@@ -228,6 +234,66 @@ def unpack_kv(meta: dict, blob: bytes) -> dict:
             f"{len(blob) - off} trailing bytes after the declared tensors"
         )
     return out
+
+
+def _checked(meta: dict, blob: bytes) -> tuple:
+    """The payload whole: ``(codec, bytes of the rows' tensors)`` once the
+    blob's length and CRC are the meta's."""
+    try:
+        codec = get_codec(meta["codec"])
+        state_bytes = int((meta.get("state") or {}).get("nbytes", 0))
+    except (KeyError, TypeError, ValueError, AttributeError) as e:
+        raise MigrationError(f"malformed migration meta: {e}") from None
+    if len(blob) != int(meta.get("nbytes", -1)):
+        raise MigrationError(
+            f"payload is {len(blob)} bytes, meta declares {meta.get('nbytes')}"
+        )
+    if _crc(blob) != int(meta.get("crc32", -1)):
+        raise MigrationError("payload CRC mismatch — corrupt migration blob")
+    if not 0 <= state_bytes <= len(blob):
+        raise MigrationError(f"state of {state_bytes} bytes in a {len(blob)}-byte payload")
+    return codec, len(blob) - state_bytes
+
+
+def _geometry(stated: dict) -> tuple:
+    try:
+        layout = {
+            str(part): tuple(int(x) for x in row)
+            for part, row in stated["layout"].items()
+        }
+        return layout, int(stated["n_layers"]), list(stated["tensors"])
+    except (KeyError, TypeError, ValueError, AttributeError) as e:
+        raise MigrationError(f"malformed migration meta: {e}") from None
+
+
+def unpack_kv(meta: dict, blob: bytes) -> dict:
+    """Verify and decode a migration payload back to block-shaped rows.
+
+    Refuses loudly (:class:`MigrationError`) on: whole-blob CRC or byte
+    count drift, per-tensor CRC drift, tensor count vs declared layers
+    and parts, byte spans that do not reconstruct the declared geometry,
+    unknown codec.  On success returns ``{part: [np (n, bs, *row) f32 a
+    layer]}`` ready for ``kv_cache.write_imported``.  What the payload
+    carries a slot is :func:`unpack_state`'s.
+    """
+    codec, rows_bytes = _checked(meta, blob)
+    layout, layers, tensors = _geometry(meta)
+    try:
+        lead = (int(meta["n_blocks"]), int(meta["block_size"]))
+    except (KeyError, TypeError, ValueError) as e:
+        raise MigrationError(f"malformed migration meta: {e}") from None
+    return _decode(tensors, layout, layers, lead, codec, blob[:rows_bytes])
+
+
+def unpack_state(meta: dict, blob: bytes) -> dict:
+    """The slot parts of a payload, verified as :func:`unpack_kv` verifies
+    the rows: ``{part: [np (*shape) f32 a layer that holds it]}`` ready
+    for ``kv_cache.write_state``; ``{}`` where the payload states none."""
+    if not meta.get("state"):
+        return {}
+    codec, rows_bytes = _checked(meta, blob)
+    layout, layers, tensors = _geometry(meta["state"])
+    return _decode(tensors, layout, layers, (), codec, blob[rows_bytes:])
 
 
 def migration_error_bound(meta: dict) -> float:

@@ -585,7 +585,8 @@ def test_the_configuration_file_keeps_every_published_width():
     count = sum(math.prod(a.shape) for a in jax.tree.leaves(shapes))
     assert 4.918e9 < count < 4.920e9
     assert all(a.dtype == jnp.bfloat16 for a in jax.tree.leaves(shapes))
-    assert pool_layout(cfg) == {"ckv": (576,)}
+    assert pool_layout(cfg) == (
+        {"position": {"ckv": (576,)}, "slot": {}},) * cfg.n_layers
     # 1,699 M of them a decoded token multiplies with, with 0.5 local picks
     active = C.other_params(c) + 4 * C.expected_local_picks(c) * C.expert_params(c)
     assert C.expected_local_picks(c) == 0.5
@@ -604,7 +605,7 @@ def test_what_the_block_does_not_implement_is_refused(key, value, match):
 
 
 def test_the_table_of_blocks_names_every_model_type():
-    assert set(BLOCKS) == {"gpt_neox", "laguna", "pangu_ultra_moe"}
+    assert set(BLOCKS) == {"gpt_neox", "laguna", "pangu_ultra_moe", "kimi_linear"}
     assert BLOCKS["pangu_ultra_moe"].config_type is pangu.PanguConfig
     with pytest.raises(ValueError, match="model_type"):
         config_from_dict({"model_type": "no_such_block"})
@@ -756,8 +757,8 @@ def test_load_cell_finds_the_new_cell():
     assert counts == [9, 9, 9, 9, 12, 12, 12, 12, 16] and len(cards) == 100
     # the new cell joins the old lists at their end and nowhere else
     bench = harness.load_benchmark()
-    assert bench["workloads"][-1]["name"] == CELL
-    assert bench["configs"][-1]["name"] == "openpangu-ultra-moe-718b"
+    assert bench["workloads"][4]["name"] == CELL
+    assert bench["configs"][3]["name"] == "openpangu-ultra-moe-718b"
 
 
 @pytest.mark.parametrize("seed", [1, 2147483999, 3100000932])

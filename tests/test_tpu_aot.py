@@ -455,3 +455,105 @@ def test_the_benchmark_step_updates_its_state_in_place(v5e, tpu_lowering, shape)
         + mem.temp_size_in_bytes - mem.alias_size_in_bytes
     ) / 2**30
     assert per_chip <= PARENT_STEP_GIB[shape] - 1.0, f"{per_chip:.3f} GiB"
+
+
+# ---------------------------------------------------- the state-holding cell
+
+
+def _kimi_cell():
+    """(configuration object, pool shape) of
+    ``kimi-linear-48b-a3b.gen-closed-c128``, from the benchmark's files."""
+    import json
+    import os
+
+    from flextree_tpu.models.configs import config_from_dict
+    from flextree_tpu.serving.kv_cache import PagedCacheConfig
+
+    root = os.path.join(os.path.dirname(os.path.dirname(__file__)), "benchmarks")
+    with open(os.path.join(root, "configs", "kimi-linear-48b-a3b.json")) as f:
+        cfg = config_from_dict(json.load(f))
+    with open(os.path.join(root, "traffic", "gen-closed-c128.json")) as f:
+        t = json.load(f)
+    return cfg, t, PagedCacheConfig(
+        t["num_blocks"], t["block_size"], t["blocks_per_seq"])
+
+
+def test_the_latent_kernel_takes_32_heads_a_slot_over_128_slots(v5e, tpu_lowering):
+    """The state cell's MLA layers: 128 slots x 32 heads over 1,025 blocks
+    of 656: one Mosaic kernel, no loop, no copy of the pool (XLA:TPU keeps
+    it row-major: no other axis pads less than the 576-wide one), and
+    ``kernel_layers`` says so for all 3.  2,049 blocks of 320 would be
+    laid out with the BLOCKS axis minor (2,049 -> 2,176 pads 6%, 576 ->
+    640 11%) and copied whole, twice a layer a round."""
+    import re
+
+    from flextree_tpu.models.configs import block_of
+
+    cfg, t, pcfg = _kimi_cell()
+    shape = dict(s=t["slots"], h=cfg.n_heads, r=cfg.pool_row,
+                 bs=pcfg.block_size, p=pcfg.blocks_per_seq, n=pcfg.num_blocks)
+    hlo = _compile(
+        lambda *a: paged_attention_latent(
+            *a, value_dim=cfg.kv_rank, scale=cfg.softmax_scale),
+        *_latent_avals(v5e[0], **shape),
+    ).as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 1
+    assert " while(" not in hlo
+    assert not re.findall(
+        rf"= bf16\[{shape['n']},{shape['bs']},576\]\S* copy\(", hlo)
+    assert block_of(cfg).kernel_layers(cfg, pcfg) == (3, 3)
+
+
+def test_the_state_cells_decode_program_updates_pools_and_state_in_place(
+    v5e, tpu_lowering
+):
+    """The fused decode program at the cell's size: every latent pool and
+    every slot's state aliased to its result (5.19 GB donated, none
+    copied), the latent kernel once an MLA layer, and the whole program
+    inside the chip's memory beside nothing else."""
+    import re
+
+    from flextree_tpu.models import kimi_linear as kimi
+    from flextree_tpu.serving.kv_cache import (
+        init_pools, init_state, make_paged_decode_fn,
+    )
+
+    cfg, t, pcfg = _kimi_cell()
+    slots = t["slots"]
+    one = NamedSharding(_mesh(v5e[:1], (1, 1, 1)), P())
+    params = _on(jax.eval_shape(
+        lambda k: kimi.init_params(k, cfg), jax.random.PRNGKey(0)), one)
+    pools = _on(jax.eval_shape(lambda: init_pools(cfg, pcfg)), one)
+    state = _on(jax.eval_shape(lambda: init_state(cfg, slots)), one)
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one)  # noqa: E731
+    compiled = _compile(
+        make_paged_decode_fn(cfg, donate=True, fused=True), params, pools,
+        i32(slots, pcfg.blocks_per_seq), i32(slots), i32(slots), state)
+    hlo = compiled.as_text()
+    assert hlo.count("paged_latent_attention") >= 3
+    assert not re.findall(r"= bf16\[1025,656,576\]\S* copy\(", hlo)
+    mem = compiled.memory_analysis()
+    carried = sum(
+        x.size * x.dtype.itemsize for x in jax.tree.leaves((pools, state)))
+    assert carried <= mem.alias_size_in_bytes < 1.1 * carried
+    state_bytes = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(state))
+    assert state_bytes == slots * 21_708_800
+    whole = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert whole < 14.5e9, f"{whole / 1e9:.2f} GB"
+
+
+def test_the_chunked_scan_compiles_at_the_longest_prompt(v5e, tpu_lowering):
+    """One KDA layer's recurrence over 4,096 tokens, 32 heads of 128, in
+    chunks of 64: the pairwise decays of a sub-chunk (a (.., 16, 16, 128)
+    array a sub-chunk, 1.07 GB whole) stay inside their fusions, so the
+    scan's temporaries are a fraction of that."""
+    from flextree_tpu.ops.linear_attention import delta_rule_chunked
+
+    one = NamedSharding(_mesh(v5e[:1], (1, 1, 1)), P())
+    a = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32, sharding=one)  # noqa: E731
+    wide = a(1, 4096, 32, 128)
+    compiled = _compile(
+        lambda *x: delta_rule_chunked(*x, chunk=64, sub=16),
+        wide, wide, wide, wide, a(1, 4096, 32), a(1, 32, 128, 128))
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.9e9
