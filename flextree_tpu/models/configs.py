@@ -66,6 +66,9 @@ class Block:
     # of them that run the Pallas kernel): fixed by the backend and the
     # shapes (``pcfg``: the pool's ``num_blocks`` and ``block_size``)
     kernel_layers: Callable
+    # cfg -> (layers of the decode program that hold a state a slot, those
+    # of them whose update runs the Pallas kernel), fixed likewise
+    state_kernel_layers: Callable = lambda cfg: (0, 0)
 
 
 def _dense_from_dict(c: dict) -> TransformerConfig:
@@ -118,7 +121,7 @@ BLOCKS = {
     "kimi_linear": Block(
         kimi.KimiLinearConfig, kimi.config_from_dict, kimi.init_params,
         kimi.prefill, kimi.paged_decode_step, kimi.pool_layout,
-        kimi.kernel_layers,
+        kimi.kernel_layers, kimi.state_kernel_layers,
     ),
 }
 

@@ -38,8 +38,9 @@ Scopes (``jax.named_scope``, never one inside another): ``ft_embed``,
 ``ft_norm`` (``rms_norm``'s own), ``ft_kda_proj`` (the projections, the
 convolution, both gates, beta, the output norm, gate and ``W_o``),
 ``ft_kda_core`` (the recurrence: the chunked scan, or the state's read,
-update, write and readout), ``ft_mla_proj``, ``ft_mla_core``, ``ft_mlp``,
-``ft_moe_router``, ``ft_moe_experts``, ``ft_moe_shared``, ``ft_head``.
+update, write and readout: on a TPU the kernel ``kda_state_update``),
+``ft_mla_proj``, ``ft_mla_core``, ``ft_mlp``, ``ft_moe_router``,
+``ft_moe_experts``, ``ft_moe_shared``, ``ft_head``.
 """
 
 from __future__ import annotations
@@ -55,6 +56,7 @@ from ..ops.linear_attention import (
     causal_conv,
     delta_rule_chunked,
     delta_rule_step,
+    runs_step_kernel,
 )
 from ..ops.paged_attention import runs_latent_kernel
 from .moe import round_counts, stack_router
@@ -74,6 +76,7 @@ __all__ = [
     "init_params",
     "pool_layout",
     "kernel_layers",
+    "state_kernel_layers",
     "kda_prefill",
     "kda_decode",
     "prefill",
@@ -207,6 +210,17 @@ def kernel_layers(cfg: KimiLinearConfig, pcfg) -> tuple:
         (pcfg.num_blocks, pcfg.block_size, cfg.pool_row), cfg.dtype
     )
     return n_mla, n_mla * runs_latent_kernel(q, pool, cfg.kv_rank)
+
+
+def state_kernel_layers(cfg: KimiLinearConfig) -> tuple:
+    """``(layers of the decode program that hold a state a slot, those of
+    them whose one-token update runs the Pallas kernel)``: the KDA layers,
+    every one alike."""
+    n_kda = sum(cfg.kda)
+    state = jax.ShapeDtypeStruct(
+        (1, cfg.kda_heads, cfg.kda_dim, cfg.kda_dim), jnp.float32
+    )
+    return n_kda, n_kda * runs_step_kernel(state)
 
 
 def config_from_dict(c: dict) -> KimiLinearConfig:

@@ -7,8 +7,15 @@ a CHANNEL of the key) and write strength ``beta``, in float32::
 
     S <- Diag(exp(g)) S;  u = beta (v - S^T k);  S <- S + k u^T;  o = S^T q
 
-:func:`delta_rule_step` is that, one token a slot, for the decode round:
-the state read twice and written once, no product on the MXU.
+:func:`delta_rule_step` is that, one token a slot, for the decode round,
+no product on the MXU.  ``u`` needs the whole reduction ``S^T k`` over
+``d_k`` before any element of the state can change, so the least is one
+read and one write of the state by a program that holds a head's tile on
+chip meanwhile: the Pallas kernel ``kda_state_update``, which a TPU runs
+at shapes its tiling admits (:func:`runs_step_kernel`), in place in the
+donated state.  The ``jnp`` body (the CPU backend, narrower heads) is the
+same arithmetic as XLA fuses it: on a TPU two fusions, the state read
+twice and written once (PERF.md section 6, PR 35).
 
 :func:`delta_rule_chunked` is the same recurrence over a whole prompt in
 chunks of ``chunk`` tokens (the WY form): with ``G`` the running sum of
@@ -49,26 +56,154 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["delta_rule_step", "delta_rule_chunked", "causal_conv"]
+from ..utils import backend
+
+__all__ = [
+    "delta_rule_step", "delta_rule_chunked", "causal_conv",
+    "step_kernel_admits", "runs_step_kernel",
+]
 
 _HIGHEST = lax.Precision.HIGHEST
 
+# heads of one slot a grid step of the update's kernel: the most whose
+# state block stays at or under this many bytes (in and out, each
+# double-buffered: four such blocks in VMEM).  On the v5e, 32 heads of 128
+# x 128: 8 a step 1.01 ms a layer (the 0.35 us a step shows), 16 and 32
+# alike 0.92 (576 GB/s, what a DMA-only copy with the same blocks reads:
+# PERF.md section 6, PR 35), so the smaller of the two
+_STEP_BLOCK_BYTES = 1 << 20
 
-def delta_rule_step(q, k, v, g, beta, state, active=None):
-    """One token a slot.  ``q``, ``k``, ``g`` (S, H, d_k), ``v`` (S, H,
-    d_v), ``beta`` (S, H), ``state`` (S, H, d_k, d_v), all float32;
-    ``active`` (S,) bool: a slot that is not keeps its state bit for bit.
-    Returns ``(o, state)``: (S, H, d_v) and the updated state."""
+
+def step_kernel_admits(state) -> bool:
+    """Whether Mosaic takes the update's kernel at this state's shape
+    (S, H, d_k, d_v): float32, a head's state whole 128-lane tiles both
+    ways (``d_v`` lies on the lanes; the ``(heads, d_k)`` row blocks of
+    ``q``, ``k`` and the decay are transposed in the kernel), and a head
+    count whose groups are whole sublane tiles."""
+    _, h, dk, dv = state.shape
+    return (
+        state.dtype == jnp.float32 and dk % 128 == 0 and dv % 128 == 0
+        and h % 8 == 0
+    )
+
+
+def runs_step_kernel(state) -> bool:
+    """Whether :func:`delta_rule_step` runs the Pallas kernel on
+    ``state`` in this process: a TPU to lower for, and a shape the
+    kernel's tiling admits."""
+    return backend.kernel_platform() == "tpu" and step_kernel_admits(state)
+
+
+def _heads_a_step(h: int, dk: int, dv: int) -> int:
+    """Heads a grid step holds: the most (a multiple of 8 that divides
+    ``h``) whose float32 block fits :data:`_STEP_BLOCK_BYTES`."""
+    fits = [
+        hb for hb in range(8, h + 1, 8)
+        if h % hb == 0 and hb * dk * dv * 4 <= _STEP_BLOCK_BYTES
+    ]
+    return max(fits, default=8)
+
+
+def _step_kernel(act_ref, q_ref, k_ref, g_ref, v_ref, beta_ref, s_ref,
+                 o_ref, s_out_ref):
+    """Grid (slot, group of heads).  A step holds the group's state, ``hb``
+    tiles of (d_k, d_v) with ``d_v`` on lanes, brought in once by the
+    pipeline and written back once to where it came from.  Both
+    reductions run over ``d_k``, the sublanes, so ``k``, ``q`` and the
+    decay are needed as COLUMNS: the three (hb, d_k) row blocks are
+    transposed here, once a step."""
+    keep = act_ref[pl.program_id(0)] != 0
+    q, k, v = q_ref[0], k_ref[0], v_ref[0]  # (hb, d_k) x2, (hb, d_v)
+    beta = beta_ref[0]  # (hb, 1)
+    q_cols, k_cols, decay = q.T, k.T, jnp.exp(g_ref[0]).T  # (d_k, hb)
+    kq = (k * q).sum(axis=-1, keepdims=True)  # (hb, 1)
+    for h in range(q.shape[0]):
+        s = s_ref[0, h]  # (d_k, d_v)
+        k_col = k_cols[:, h : h + 1]
+        s1 = s * decay[:, h : h + 1]
+        read = (s1 * k_col).sum(axis=0, keepdims=True)  # (1, d_v)
+        seen = (s1 * q_cols[:, h : h + 1]).sum(axis=0, keepdims=True)
+        u = (v[h : h + 1] - read) * beta[h : h + 1]
+        o_ref[0, h : h + 1] = seen + kq[h : h + 1] * u
+        s_out_ref[0, h] = jnp.where(keep, s1 + k_col * u, s)
+
+
+@functools.partial(jax.jit, static_argnames=("heads", "interpret"))
+def _step_pallas(q, k, v, g, beta, state, active, *, heads=None,
+                 interpret=False):
+    s, h, dk, dv = state.shape
+    hb = heads or _heads_a_step(h, dk, dv)
+    q, k, g, v, beta = (
+        x.astype(jnp.float32) for x in (q, k, g, v, beta[..., None])
+    )
+    row = lambda w: pl.BlockSpec((1, hb, w), lambda i, j, *_: (i, j, 0))  # noqa: E731
+    tile = pl.BlockSpec((1, hb, dk, dv), lambda i, j, *_: (i, j, 0, 0))
+    return pl.pallas_call(
+        _step_kernel,
+        out_shape=(
+            jax.ShapeDtypeStruct((s, h, dv), jnp.float32),
+            jax.ShapeDtypeStruct(state.shape, jnp.float32),
+        ),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(s, h // hb),
+            in_specs=[row(dk), row(dk), row(dk), row(dv), row(1), tile],
+            out_specs=(row(dv), tile),
+        ),
+        # the state is updated where it lies: operand 6 (the prefetched
+        # ``active`` counts) is result 1
+        input_output_aliases={6: 1},
+        # four blocks in the pipeline, and room for the unrolled loop's
+        # temporaries where one head's state outgrows the block limit
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+            vmem_limit_bytes=6 * hb * dk * dv * 4 + (8 << 20),
+        ),
+        name="kda_state_update",
+        interpret=interpret,
+    )(active.astype(jnp.int32), q, k, g, v, beta, state)
+
+
+def _step_jnp(q, k, v, g, beta, state, active):
     s1 = state * jnp.exp(g)[..., None]
     read = (s1 * k[..., None]).sum(axis=-2)
     seen = (s1 * q[..., None]).sum(axis=-2)
     u = (v - read) * beta[..., None]
     o = seen + (k * q).sum(axis=-1, keepdims=True) * u
     s2 = s1 + k[..., None] * u[..., None, :]
-    if active is not None:
-        s2 = jnp.where(active[:, None, None, None], s2, state)
+    s2 = jnp.where(active[:, None, None, None], s2, state)
     return o, s2
+
+
+def delta_rule_step(q, k, v, g, beta, state, active=None, *,
+                    impl: str | None = None):
+    """One token a slot.  ``q``, ``k``, ``g`` (S, H, d_k), ``v`` (S, H,
+    d_v), ``beta`` (S, H), ``state`` (S, H, d_k, d_v), all float32;
+    ``active`` (S,) bool: a slot that is not keeps its state bit for bit
+    (None: every slot is).  Returns ``(o, state)``: (S, H, d_v) and the
+    updated state.
+
+    One algorithm, two lowerings, chosen from what can be observed
+    (:func:`runs_step_kernel`): on a TPU at shapes its tiling admits the
+    Pallas kernel ``kda_state_update`` (``_step_kernel``), which holds a
+    group of heads' state in VMEM and so reads it once and writes it
+    once, in place; elsewhere (the CPU backend, heads that are no whole
+    lane tiles) the ``jnp`` body, which XLA:TPU makes two fusions of: the
+    state read twice.  ``impl`` (``"pallas"`` / ``"jnp"``) forces one, for
+    the tests."""
+    if impl not in (None, "jnp", "pallas"):
+        raise ValueError(f"unknown delta-rule step impl {impl!r}")
+    if active is None:
+        active = jnp.ones((state.shape[0],), bool)
+    if impl == "pallas" or (impl is None and runs_step_kernel(state)):
+        return _step_pallas(
+            q, k, v, g, beta, state, active,
+            interpret=backend.pallas_interpret(),
+        )
+    return _step_jnp(q, k, v, g, beta, state, active)
 
 
 def _unit_lower_inverse(m):

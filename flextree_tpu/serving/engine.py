@@ -55,6 +55,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..models.configs import (
+    block_of,
     config_from_dict,
     init_model_params,
     position_parts,
@@ -216,6 +217,9 @@ class ServingEngine:
         self.state_layers = max(
             (layers for _, layers in slot_parts(cfg).values()), default=0
         )
+        # those of them whose update runs the Pallas kernel, fixed when the
+        # decode program is built, as ``attn_kernel_layers`` below
+        self.state_kernel_layers = block_of(cfg).state_kernel_layers(cfg)[1]
         # donation keeps steady-state decode allocation-free: the pool
         # scatter aliases in place instead of copying the whole pool every
         # round.  XLA:TPU aliases every donated pool buffer (AOT compile
@@ -369,6 +373,7 @@ class ServingEngine:
                     cache_bytes_per_position=self.cache_bytes_per_position,
                     state_bytes_per_slot=self.state_bytes_per_slot,
                     state_layers=self.state_layers,
+                    state_kernel_layers=self.state_kernel_layers,
                 ):
                     # a model with routed experts hands out a third
                     # result, what its routers did this round
@@ -1104,6 +1109,7 @@ class ServingEngine:
             "cache_bytes_per_position": self.cache_bytes_per_position,
             "state_bytes_per_slot": self.state_bytes_per_slot,
             "state_layers": self.state_layers,
+            "state_kernel_layers": self.state_kernel_layers,
             **self.metrics.snapshot(),
         }
 

@@ -29,7 +29,8 @@ from flextree_tpu.models.configs import (
 from flextree_tpu.models.moe import gated_ffn
 from flextree_tpu.obs import flight_recorder
 from flextree_tpu.ops.linear_attention import (
-    causal_conv, delta_rule_chunked, delta_rule_step,
+    _heads_a_step, _step_pallas, causal_conv, delta_rule_chunked,
+    delta_rule_step, runs_step_kernel, step_kernel_admits,
 )
 from flextree_tpu.serving import (
     BatcherConfig, PagedCacheConfig, Request, ServingEngine, costs,
@@ -140,6 +141,87 @@ def test_the_one_token_update_is_the_recurrence_and_skips_inactive_slots():
     want_o, want_s = ref.delta_rule(q, k, v, g, beta, s0)
     np.testing.assert_allclose(o[0], want_o[-1], atol=1e-5)
     np.testing.assert_allclose(state[0], want_s, atol=1e-5)
+
+
+def _step_inputs(slots, h, dk=128, dv=128, gate=0.3, seed=0):
+    """One token a slot at a head size the update's kernel admits."""
+    q, k, v, g, beta, s0 = _recurrence_inputs(slots, gate, seed, h, dk, dv)
+    state = s0[None] * jnp.arange(1.0, slots + 1.0)[:, None, None, None]
+    return tuple(x.astype(jnp.float32) for x in (q, k, v, g, beta, state))
+
+
+@pytest.mark.parametrize("h,heads", [
+    (8, None), (16, 8), (24, None), (16, None), (24, 24),
+], ids=["8", "16-by-8", "24-by-8", "16", "24"])
+def test_the_update_kernel_is_the_jnp_body(h, heads):
+    """The Pallas kernel under the interpreter against the ``jnp`` body,
+    heads of 128 x 128: head counts the 1 MB block limit takes whole (8,
+    16), one it must split (24: 8 a step, the only multiple of 8 that
+    divides it and fits), and forced groups (16 by 8, 24 whole).  An
+    inactive slot's state comes back bit for bit."""
+    assert _heads_a_step(h, 128, 128) == {8: 8, 16: 16, 24: 8}[h]
+    assert _heads_a_step(32, 128, 128) == 16 and _heads_a_step(8, 256, 256) == 8
+    q, k, v, g, beta, state = _step_inputs(3, h)
+    active = jnp.asarray([True, False, True])
+    want_o, want_s = delta_rule_step(q, k, v, g, beta, state, active, impl="jnp")
+    o, s = _step_pallas(
+        q, k, v, g, beta, state, active, heads=heads, interpret=True)
+    np.testing.assert_allclose(o, want_o, atol=2e-6 * float(jnp.abs(want_o).max()))
+    np.testing.assert_allclose(s, want_s, atol=2e-6 * float(jnp.abs(want_s).max()))
+    assert np.asarray(s[1]).tobytes() == np.asarray(state[1]).tobytes()
+    assert np.asarray(s[0]).tobytes() != np.asarray(state[0]).tobytes()
+
+
+@pytest.mark.parametrize("impl", ["jnp", "pallas"])
+def test_a_one_token_decay_that_underflows_gives_zeros_not_nans(impl):
+    """``g`` near -200 a channel: ``exp(g)`` is 0 in float32, the old
+    state is gone, and what is left is the token's own write."""
+    q, k, v, g, beta, state = _step_inputs(2, 8, gate=250.0)
+    o, s = delta_rule_step(q, k, v, g, beta, state, impl=impl)
+    assert bool(jnp.isfinite(o).all()) and bool(jnp.isfinite(s).all())
+    u = v * beta[..., None]
+    np.testing.assert_allclose(s, k[..., None] * u[..., None, :], atol=1e-6)
+    np.testing.assert_allclose(
+        o, (k * q).sum(-1, keepdims=True) * u, atol=1e-6)
+
+
+@pytest.mark.parametrize("shape,dtype,admitted", [
+    ((128, 32, 128, 128), "float32", True),   # the benchmark's cell
+    ((3, 8, 128, 256), "float32", True),
+    ((3, 4, 16, 16), "float32", False),       # the tests' block
+    ((3, 8, 8, 8), "float32", False),
+    ((3, 8, 128, 64), "float32", False),
+    ((3, 4, 128, 128), "float32", False),     # no whole sublane tile of heads
+    ((3, 8, 128, 128), "bfloat16", False),    # the state is float32
+])
+def test_the_update_kernel_runs_on_a_tpu_at_shapes_its_tiling_admits(
+    monkeypatch, shape, dtype, admitted
+):
+    from flextree_tpu.utils import backend
+
+    state = jax.ShapeDtypeStruct(shape, jnp.dtype(dtype))
+    assert step_kernel_admits(state) == admitted
+    assert not runs_step_kernel(state)  # the CPU: the jnp body, whatever
+    monkeypatch.setattr(backend, "kernel_platform", lambda: "tpu")
+    assert runs_step_kernel(state) == admitted
+
+
+def test_a_shape_the_update_kernel_refuses_walks_the_jnp_body_without_a_raise(
+    monkeypatch
+):
+    """8-wide heads where the chip would run the kernel: no Mosaic
+    lowering is tried (on this CPU it would raise), the result is the
+    ``jnp`` body's; and an unknown ``impl`` is refused."""
+    from flextree_tpu.utils import backend
+
+    q, k, v, g, beta, state = _step_inputs(2, 3, dk=8, dv=8)
+    want = delta_rule_step(q, k, v, g, beta, state, impl="jnp")
+    monkeypatch.setattr(backend, "kernel_platform", lambda: "tpu")
+    got = delta_rule_step(q, k, v, g, beta, state)
+    for a, b in zip(got, want):
+        assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
+    with pytest.raises(ValueError, match="impl"):
+        delta_rule_step(q, k, v, g, beta, state, impl="mosaic")
 
 
 def test_the_convolution_carries_its_last_inputs():
@@ -607,6 +689,8 @@ def test_spans_and_the_report_carry_the_states_numbers():
     per_slot = costs.state_bytes_per_slot(eng.cfg)
     for e in named("ft.engine.decode_dispatch"):
         assert e["state_bytes_per_slot"] == per_slot and e["state_layers"] == 6
+        # 16-wide heads on the CPU: the jnp body in every layer, and said so
+        assert e["state_kernel_layers"] == 0 == eng.report()["state_kernel_layers"]
         assert e["cache_bytes_per_position"] == 2 * 24 * 4 and e["attn_layers"] == 2
     assert [e["state_slots_live"] for e in named("ft.engine.bookkeeping")] == [3, 3]
     assert [e["state_bytes"] for e in named("ft.engine.prefill")] == [per_slot] * 3
@@ -619,7 +703,26 @@ def test_spans_and_the_report_carry_the_states_numbers():
         init_params(jax.random.PRNGKey(0), dense_cfg), dense_cfg, PCFG,
         BatcherConfig(slots=2))
     assert dense.report()["state_bytes_per_slot"] == 0 == dense.report()["state_layers"]
+    assert dense.report()["state_kernel_layers"] == 0
     assert dense.state == {}
+
+
+@pytest.mark.parametrize("platform,dim,took", [
+    ("tpu", 128, 6), ("tpu", 16, 0), ("cpu", 128, 0),
+], ids=["tpu-128", "tpu-16", "cpu-128"])
+def test_the_block_says_which_state_layers_run_the_kernel(
+    monkeypatch, platform, dim, took
+):
+    """``Block.state_kernel_layers``: fixed by the backend and the shapes
+    alone, (0, 0) for a block that holds no state."""
+    from flextree_tpu.utils import backend
+
+    monkeypatch.setattr(backend, "kernel_platform", lambda: platform)
+    lin = dict(tiny()["linear_attn_config"], head_dim=dim, num_heads=8)
+    cfg = config_from_dict(tiny(linear_attn_config=lin))
+    assert BLOCKS["kimi_linear"].state_kernel_layers(cfg) == (6, took)
+    for name in ("gpt_neox", "laguna", "pangu_ultra_moe"):
+        assert BLOCKS[name].state_kernel_layers(None) == (0, 0)
 
 
 NEW_SCOPES = ["ft_kda_proj", "ft_kda_core"]
@@ -689,7 +792,8 @@ def test_load_cell_finds_the_new_cell():
     per_layer = {m["name"] for m in cell.per_layer}
     assert {"attn.kda_proj_share", "attn.kda_core_share",
             "kernels.kda_decode_roofline", "kernels.kda_prefill_roofline",
-            "kv_cache.state_bytes_share", "attn.mla_proj_share",
+            "kv_cache.state_bytes_share", "kernels.kda_kernel_share",
+            "attn.mla_proj_share",
             "attn.mla_core_share", "engine.prefill_time_share",
             "kernels.paged_kernel_share", "moe.experts_share",
             "moe.router_share", "moe.local_pick_share",
@@ -728,10 +832,10 @@ def test_load_cell_finds_the_new_cell():
     for m in bench["end_to_end"] + bench["per_layer"]:
         if CELL in m.get("workloads", ()):
             assert m["workloads"][-1] == CELL
-    assert [m["name"] for m in bench["per_layer"][-5:]] == [
+    assert [m["name"] for m in bench["per_layer"][-6:]] == [
         "attn.kda_proj_share", "attn.kda_core_share",
         "kernels.kda_decode_roofline", "kernels.kda_prefill_roofline",
-        "kv_cache.state_bytes_share"]
+        "kv_cache.state_bytes_share", "kernels.kda_kernel_share"]  # PR 35's
 
 
 @pytest.mark.parametrize("seed", [1, 2147483999, 3100000932])
@@ -795,7 +899,8 @@ def test_run_py_rehearses_the_new_cell():
     assert all(m["value"] is None for m in line["metrics"].values())
     assert {"moe.local_pick_share", "moe.experts_hit_share",
             "kernels.paged_kernel_share", "engine.prefill_time_share",
-            "kv_cache.state_bytes_share"} <= set(line["metrics"])
+            "kv_cache.state_bytes_share",
+            "kernels.kda_kernel_share"} <= set(line["metrics"])
 
 
 # ------------------------------------------------ the counts, worked by hand
@@ -901,6 +1006,33 @@ def test_the_prefill_roofline_is_the_prompts_flops_over_traced_time():
     at_peak = K.prefill_roofline(_trace_ctx(prefill_ns=least_ns), **meta["args"])
     assert at_peak == pytest.approx(100.0) and at_peak <= 100.0 + 1e-9
     assert K.prefill_roofline(_trace_ctx(prefill_ns=0.0), **meta["args"]) is None
+
+
+@pytest.mark.parametrize("stated,want", [
+    ({"state_layers": 10, "state_kernel_layers": 10}, 100.0),  # the cell on a TPU
+    ({"state_layers": 10, "state_kernel_layers": 0}, 0.0),  # the jnp body, counted
+    ({"state_layers": 0, "state_kernel_layers": 0}, None),  # a block with no state
+    ({"state_layers": 10}, None),  # a parent commit's span: nothing to read
+], ids=["all", "none", "no-state", "parent"])
+def test_the_kernel_share_is_the_state_layers_that_run_the_kernel(stated, want):
+    from benchmarks.lib import xplane as X
+    from benchmarks.readers import spans as S
+
+    meta = _metric("kernels.kda_kernel_share")
+    assert meta["reader"] == "spans:count_ratio_p50"
+    host = [X.Event("bench_window", 0, 1000)] + [
+        X.Event("ft.engine.decode_dispatch", 100 * i, 50, dict(stated))
+        for i in range(3)
+    ]
+    trace = X.Trace([X.Plane("/host:CPU", [X.Line("python3", host)])])
+    ctx = harness.ReaderContext(
+        types.SimpleNamespace(name="toy"),
+        harness.Run(True, 0, 0, {}, {}, 0.0, None), {}, trace, (0.0, 1000.0))
+    got = S.count_ratio_p50(ctx, **meta["args"])
+    assert got == (want if want is None else pytest.approx(want))
+    entry, = [m for m in harness.load_benchmark()["per_layer"]
+              if m["name"] == "kernels.kda_kernel_share"]
+    assert entry["workloads"] == [CELL] and entry["source"] == "program_counter"
 
 
 def test_the_state_bytes_share_and_the_scope_shares_read_what_the_program_states():

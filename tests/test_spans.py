@@ -771,4 +771,4 @@ def test_new_metric_files_name_readers_that_exist():
         fn = getattr(S, meta["reader"].split(":")[1])
         inspect.signature(fn).bind(None, **meta.get("args", {}))
         assert entry["source"] in ("program_span", "program_counter", "device_trace")
-    assert seen == 29  # PR 34: attn.kda_proj_share, attn.kda_core_share
+    assert seen == 30  # PR 35: kernels.kda_kernel_share

@@ -509,8 +509,9 @@ def test_the_state_cells_decode_program_updates_pools_and_state_in_place(
 ):
     """The fused decode program at the cell's size: every latent pool and
     every slot's state aliased to its result (5.19 GB donated, none
-    copied), the latent kernel once an MLA layer, and the whole program
-    inside the chip's memory beside nothing else."""
+    copied), the latent kernel once an MLA layer and the state's update
+    kernel once a KDA layer (nothing else reads or writes a state), and
+    the whole program inside the chip's memory beside nothing else."""
     import re
 
     from flextree_tpu.models import kimi_linear as kimi
@@ -532,6 +533,11 @@ def test_the_state_cells_decode_program_updates_pools_and_state_in_place(
     hlo = compiled.as_text()
     assert hlo.count("paged_latent_attention") >= 3
     assert not re.findall(r"= bf16\[1025,656,576\]\S* copy\(", hlo)
+    calls = re.findall(
+        r"^\s*(?:ROOT )?%?(\w+?)[.\d]* = .*custom-call\(.*tpu_custom_call",
+        hlo, re.M)
+    assert sorted(calls) == ["kda_state_update"] * 10 + ["paged_latent_attention"] * 3
+    assert _ops_on_a_state(hlo) == ["custom-call"] * 10
     mem = compiled.memory_analysis()
     carried = sum(
         x.size * x.dtype.itemsize for x in jax.tree.leaves((pools, state)))
@@ -541,6 +547,57 @@ def test_the_state_cells_decode_program_updates_pools_and_state_in_place(
     whole = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert whole < 14.5e9, f"{whole / 1e9:.2f} GB"
+
+
+def _ops_on_a_state(hlo: str) -> list:
+    """The operation of every instruction of ``hlo`` that takes or makes a
+    whole ``f32[128,32,128,128]`` (parameters and tuple plumbing aside)."""
+    import re
+
+    ops = re.findall(
+        r"^\s*(?:ROOT )?\S+ = (?=.*f32\[128,32,128,128\]).*?\s([\w-]+)\(",
+        hlo, re.M)
+    return [op for op in ops
+            if op not in ("parameter", "get-tuple-element", "tuple", "bitcast")]
+
+
+def test_the_state_update_is_one_mosaic_kernel_that_reads_the_state_once(
+    v5e, tpu_lowering
+):
+    """The KDA decode update at the state cell's shape, 128 slots x 32
+    heads of 128 x 128 float32: ONE Mosaic kernel, the donated state
+    updated where it lies (268 MB aliased, no temporary), no copy of it
+    round the call (the kernel states the array's own row-major layout)
+    and no fusion that reads it a second time; the block says so for all
+    10 KDA layers."""
+    from flextree_tpu.models.configs import block_of
+    from flextree_tpu.ops.linear_attention import delta_rule_step
+
+    cfg, t, _ = _kimi_cell()
+    s, h, d = t["slots"], cfg.kda_heads, cfg.kda_dim
+    one = NamedSharding(_mesh(v5e[:1], (1, 1, 1)), P())
+    a = lambda *shape, dtype=jnp.float32: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dtype, sharding=one)
+    row = a(s, h, d)
+    compiled = _compile(
+        jax.jit(delta_rule_step, donate_argnums=(5,)),
+        row, row, row, row, a(s, h), a(s, h, d, d), a(s, dtype=jnp.bool_))
+    hlo = compiled.as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 1
+    assert "kda_state_update" in hlo
+    assert _ops_on_a_state(hlo) == ["custom-call"]
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == s * h * d * d * 4
+    assert mem.temp_size_in_bytes < 1 << 20
+    assert block_of(cfg).state_kernel_layers(cfg) == (10, 10)
+
+
+def test_on_the_cpu_no_state_layer_runs_the_update_kernel():
+    """The same block where the tests run: the ``jnp`` body in all 10."""
+    from flextree_tpu.models.configs import block_of
+
+    cfg, _, _ = _kimi_cell()
+    assert block_of(cfg).state_kernel_layers(cfg) == (10, 0)
 
 
 def test_the_chunked_scan_compiles_at_the_longest_prompt(v5e, tpu_lowering):
