@@ -100,9 +100,8 @@ _PLAN_KINDS = frozenset({"bucket_planned", "bucket_fired", "collective"})
 #: comes from the feedback prober's timed collectives (planner/
 #: feedback.py) AND from the per-step span clock (obs/stepclock.py:
 #: ``per_step: true``, host-timed steps apportioned over the compile-time
-#: plan); ``serve_round_measured`` is the serving engine's decode round
-#: against the paged-decode cost estimate (serving/costs.py).
-_MEASURED_KINDS = frozenset({"bucket_measured", "serve_round_measured"})
+#: plan).
+_MEASURED_KINDS = frozenset({"bucket_measured"})
 
 #: whole-step measured spans (obs/stepclock.py): duration is the step's
 #: host wall time, args carry the comm/floor split and the plan signature
@@ -274,11 +273,7 @@ def merge_events(events, dumps: dict[int, dict] | None = None) -> dict:
             trace.append(
                 {
                     "name": str(args.get("name", kind)),
-                    "cat": (
-                        "serve-measured"
-                        if kind == "serve_round_measured"
-                        else "comm-measured"
-                    ),
+                    "cat": "comm-measured",
                     "ph": "X",
                     **common,
                     "dur": round(dur, 1),

@@ -3,22 +3,22 @@
 The training stack prices every collective before it runs and PR 12
 closed the loop on the residuals; serving had measured histograms
 (round/TTFT) but no predictions to hold them against.  This module
-supplies the predicted half so the engine can emit
-``serve_round_measured`` spans — measured decode round (and prefill)
-time beside a cost estimate priced from the SAME calibratable constants
-the rest of the planner uses (``TpuCostParams.bwd_GFLOPs`` as the
-achievable compute throughput, ``reduce_bw_GBps`` as the HBM-bound
-byte-stream rate).
+supplies the predicted half — a decode round's and a prefill's cost
+estimate, priced from the SAME calibratable constants the rest of the
+planner uses (``TpuCostParams.bwd_GFLOPs`` as the achievable compute
+throughput, ``reduce_bw_GBps`` as the HBM-bound byte-stream rate).  The
+engine puts the prefill's beside its measured time on the
+``serve_prefill`` event, for a recorder alone; migration's cost follows
+the round's decomposition.
 
 The estimate is deliberately first-order: dense projection FLOPs per
 decoded token plus the attention walk's K/V byte traffic over the batch
 causal frontier (the paged pools are read once per round up to the
 frontier — exactly the quantity the fused kernel's win shrinks with).
-It does not model dispatch overlap or sampling-host
-time; that is what the residual loop is FOR — drift between this
-estimate and the measured rounds is the serving-side feedback signal,
-per-phase attributable like the training residuals (compute-bound vs
-byte-bound terms are separate fields of the prediction).
+It does not model dispatch overlap or sampling-host time, and it prices
+a DENSE block (routed experts, latent rows and a recurrent state are not
+in it); compute-bound and byte-bound terms are separate fields of the
+prediction.
 """
 
 from __future__ import annotations

@@ -89,11 +89,6 @@ from .migration import MigrationError, pack_kv, unpack_kv, unpack_state
 # use, observed once per scheduling round (engine.report() embeds it)
 _OCCUPANCY_BUCKETS = tuple(round(0.1 * i, 1) for i in range(1, 11))
 
-# relative-residual buckets for the serving feedback loop: |predicted -
-# measured| / measured of each decode round vs the paged-decode cost
-# estimate (serving/costs.py) — ratio-scaled, not ms-scaled
-_RESIDUAL_BUCKETS = (0.05, 0.1, 0.2, 0.5, 1.0, 2.0, 5.0, 10.0)
-
 # migration payload size buckets (bytes on the wire, power-of-4-ish):
 # tiny bench models ship KB, production shapes ship MB — one histogram
 # covers both
@@ -365,9 +360,8 @@ class ServingEngine:
             if active:
                 with span("ft.batcher.batch_arrays"):
                     tables, lengths, tokens, _ = self.batcher.batch_arrays()
-                t_dec = _now()
                 with span(
-                    "ft.engine.decode_dispatch",
+                    "ft.engine.decode_dispatch", round=self.steps,
                     attn_layers=self.attn_layers,
                     attn_kernel_layers=self.attn_kernel_layers,
                     cache_bytes_per_position=self.cache_bytes_per_position,
@@ -387,7 +381,7 @@ class ServingEngine:
                     self.batcher.slots[slot].request.temperature > 0
                     for slot in active
                 )
-                with span("ft.engine.decode_fetch"):
+                with span("ft.engine.decode_fetch", round=self.steps):
                     # host fetch = the step boundary; the logits stay on
                     # the device and are dropped with the round.  The
                     # routers' counts come back beside the ids, in the
@@ -399,7 +393,6 @@ class ServingEngine:
                         moe_ids = {
                             k: int(v) for k, v in zip(MOE_COUNTS, counts)
                         }
-                decode_s = _now() - t_dec
                 now = _now()
                 with span(
                     "ft.engine.sample", on_device=len(active) - sampled,
@@ -411,9 +404,6 @@ class ServingEngine:
                 self.decode_steps += 1
                 self.metrics.counter("serve.decode_tokens").inc(len(active))
                 record_event("serve_decode", n_active=len(active))
-                self._round_feedback(
-                    len(active), int(np.asarray(lengths).max()), decode_s
-                )
             with span("ft.engine.retire"):
                 finished = self.batcher.retire_ready()
                 for slot, state in finished:
@@ -592,39 +582,6 @@ class ServingEngine:
             mode="swap" if kv is not None else "recompute",
             length=state.length, blocks=n,
         )
-
-    def _round_feedback(
-        self, n_active: int, max_len: int, measured_s: float
-    ) -> None:
-        """The serving-side feedback sample: one decode round's measured
-        time against the paged-decode cost estimate (serving/costs.py),
-        observed into the ``serve.round_residual`` histogram (the drift
-        signal ``engine.report()`` exposes) and emitted as a
-        ``serve_round_measured`` span — the serving twin of the training
-        stack's ``bucket_measured`` events, rendered beside its
-        prediction in the merged timeline."""
-        from .costs import predict_decode_round_us
-
-        pred = predict_decode_round_us(
-            self.cfg, self.pcfg, n_active, max_len, self._cost_params()
-        )
-        measured_us = float(measured_s) * 1e6
-        predicted_us = pred["predicted_us"]
-        rel = abs(predicted_us - measured_us) / max(measured_us, 1e-9)
-        self.metrics.histogram(
-            "serve.round_residual", buckets=_RESIDUAL_BUCKETS
-        ).observe(rel)
-        if current_recorder() is not None:
-            record_event(
-                "serve_round_measured",
-                round=self.decode_steps,
-                n_active=int(n_active),
-                max_len=int(max_len),
-                measured_us=round(measured_us, 3),
-                predicted_us=round(predicted_us, 3),
-                compute_us=round(pred["compute_us"], 3),
-                bytes_us=round(pred["bytes_us"], 3),
-            )
 
     def _cost_params(self):
         params = getattr(self, "_cost_params_cache", None)
@@ -985,7 +942,7 @@ class ServingEngine:
         req = state.request
         prompt = np.asarray(req.prompt, np.int32)
         c = state.cached_tokens
-        with span("ft.engine.prefill_dispatch"):
+        with span("ft.engine.prefill_dispatch", rid=req.rid):
             logits = self._dispatch_prefill(slot, state, prompt, c)
             ids = self._greedy_ids(logits)
         if self.batcher.prefix_index is not None:

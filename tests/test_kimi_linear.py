@@ -832,10 +832,13 @@ def test_load_cell_finds_the_new_cell():
     for m in bench["end_to_end"] + bench["per_layer"]:
         if CELL in m.get("workloads", ()):
             assert m["workloads"][-1] == CELL
-    assert [m["name"] for m in bench["per_layer"][-6:]] == [
+    names = [m["name"] for m in bench["per_layer"]]
+    first = names.index("attn.kda_proj_share")
+    assert names[first:first + 6] == [
         "attn.kda_proj_share", "attn.kda_core_share",
         "kernels.kda_decode_roofline", "kernels.kda_prefill_roofline",
         "kv_cache.state_bytes_share", "kernels.kda_kernel_share"]  # PR 35's
+    assert first == 49  # and later PRs append after them
 
 
 @pytest.mark.parametrize("seed", [1, 2147483999, 3100000932])

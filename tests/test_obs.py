@@ -791,19 +791,6 @@ class TestStepMeasuredTimeline:
         assert isinstance(meas[0]["args"]["predicted"], dict)
         assert step[0]["dur"] == pytest.approx(2000.0)
 
-    def test_serve_round_measured_renders_as_span(self):
-        events = [
-            {"ts": 1.0, "rank": 0, "seq": 0, "kind": "serve_round_measured",
-             "round": 4, "n_active": 3, "max_len": 40,
-             "measured_us": 900.0, "predicted_us": 700.0,
-             "compute_us": 600.0, "bytes_us": 100.0},
-        ]
-        doc = merge_events(events)
-        assert validate_trace(doc) == []
-        spans = [e for e in doc["traceEvents"]
-                 if e.get("cat") == "serve-measured"]
-        assert len(spans) == 1 and spans[0]["dur"] == pytest.approx(900.0)
-
     def test_residual_pairs_tags_step_source_and_breakdown(self):
         prov = _prov(4096)
         events = [

@@ -1073,34 +1073,3 @@ def test_decode_cost_estimate_is_positive_and_split(model):
     longer = predict_decode_round_us(cfg, pcfg, 3, 48)
     assert longer["predicted_us"] > pred["predicted_us"]
     assert predict_prefill_us(cfg, 16) > predict_prefill_us(cfg, 4)
-
-
-def test_engine_emits_serve_round_measured_spans(model, tmp_path):
-    from flextree_tpu.obs import flight_recorder
-    from flextree_tpu.obs.timeline import read_dir
-
-    cfg, params = model
-    eng = ServingEngine(params, cfg, _pcfg(), BatcherConfig(slots=2))
-    rng = np.random.default_rng(5)
-    with flight_recorder(tmp_path, rank=0):
-        assert eng.submit(
-            Request(rid=1, prompt=_prompt(rng, 6), max_new_tokens=4)
-        )
-        eng.run_until_idle()
-    events, _ = read_dir(str(tmp_path))
-    rounds = [e for e in events if e["kind"] == "serve_round_measured"]
-    assert rounds, "decode rounds left no measured spans"
-    for ev in rounds:
-        assert ev["measured_us"] > 0
-        assert ev["predicted_us"] > 0
-        assert ev["predicted_us"] == pytest.approx(
-            ev["compute_us"] + ev["bytes_us"], rel=1e-3
-        )
-        assert ev["n_active"] >= 1
-    prefills = [e for e in events if e["kind"] == "serve_prefill"]
-    assert prefills and all(
-        e["predicted_us"] > 0 and e["measured_us"] > 0 for e in prefills
-    )
-    # the residual instrument is a view over the same rounds
-    snap = eng.report()
-    assert snap["histograms"]["serve.round_residual"]["count"] == len(rounds)

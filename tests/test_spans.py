@@ -35,6 +35,10 @@ ROUND_SPANS = [
     "ft.engine.decode_fetch", "ft.engine.sample", "ft.engine.retire",
     "ft.engine.bookkeeping",
 ]
+ROUND_ID_SPANS = [  # the spans of a decode round that carry its ``round``
+    "ft.engine.round", "ft.engine.decode_dispatch", "ft.engine.decode_fetch",
+    "ft.engine.bookkeeping",
+]
 STEP_SPANS = [
     "ft.loop.step", "ft.loop.data_wait", "ft.loop.dispatch",
     "ft.loop.guard_fetch", "ft.loop.bookkeeping",
@@ -221,6 +225,10 @@ def test_one_engine_round_emits_exactly_its_spans():
     assert by_name["ft.engine.prefill_dispatch"]["parent"] == "ft.engine.prefill"
     assert by_name["ft.engine.prefill_fetch_sample"]["parent"] == "ft.engine.prefill"
     assert by_name["ft.engine.round"]["round"] == 0
+    # one id joins the round's dispatch, fetch, books and the round itself
+    # (benchmarks/readers/chain.py joins them by it, never by a stamp)
+    assert [by_name[n]["round"] for n in ROUND_ID_SPANS] == [0] * 4
+    assert by_name["ft.engine.prefill_dispatch"]["rid"] == 11
     prefill = by_name["ft.engine.prefill"]
     assert (prefill["rid"], prefill["prompt_len"], prefill["cached_tokens"]) == (11, 6, 0)
     book = by_name["ft.engine.bookkeeping"]
@@ -246,6 +254,12 @@ def test_one_engine_round_emits_exactly_its_spans():
         "ft.engine.decode_dispatch", "ft.engine.decode_fetch",
         "ft.engine.sample", "ft.engine.retire", "ft.engine.bookkeeping",
     ]
+    # and the next round counts on, on all four
+    with flight_recorder(None) as rec:
+        assert eng.step()["decoded"] == 1
+    by_name = {e["name"]: e for e in _spans(rec)}
+    assert [by_name[n]["round"] for n in ROUND_ID_SPANS] == [1] * 4
+    assert "ft.engine.prefill_dispatch" not in by_name
 
 
 def test_a_round_samples_under_one_span_and_later_rounds_count_on():
@@ -321,7 +335,74 @@ def test_prefill_prediction_is_built_only_for_a_recorder(monkeypatch):
     assert calls == [1]
     [ev] = [e for e in rec.events if e["kind"] == "serve_prefill"]
     assert ev["rid"] == 2 and ev["predicted_us"] > 0 and ev["measured_us"] > 0
-    assert [e for e in rec.events if e["kind"] == "serve_round_measured"]
+
+
+def test_a_decode_round_prices_nothing_and_reports_no_residual(monkeypatch):
+    """Between ``ft.engine.sample`` and ``ft.engine.retire`` the device
+    waits: the round's prediction, its histogram and its event went (PR
+    36); neither the report nor a recorder sees them, recorder or not."""
+    from flextree_tpu.serving import costs
+
+    calls = []
+    monkeypatch.setattr(
+        costs, "predict_decode_round_us", lambda *a, **k: calls.append(1))
+    eng = _engine()
+    assert eng.submit(_request(1, 6, 3))
+    eng.step()
+    with flight_recorder(None) as rec:
+        eng.run_until_idle()
+    assert calls == []
+    kinds = {e["kind"] for e in rec.events}
+    assert "serve_decode" in kinds
+    assert not [k for k in kinds if k.endswith("_measured")]
+    histograms = eng.report()["histograms"]
+    assert "serve.cache_occupancy" in histograms
+    assert not [h for h in histograms if "residual" in h]
+
+
+def _block_config(block):
+    if block == "dense":
+        return None
+    from tests.test_kimi_linear import tiny as kimi_tiny
+    from tests.test_laguna import tiny as laguna_tiny
+    from tests.test_pangu_ultra_moe import tiny as pangu_tiny
+
+    return {"laguna": laguna_tiny, "pangu": pangu_tiny,
+            "kimi": kimi_tiny}[block]()
+
+
+@pytest.mark.parametrize("block", ["dense", "laguna", "pangu", "kimi"])
+def test_every_blocks_decode_program_is_named_paged_decode(block):
+    """A profile names a program after the function ``jax.jit`` was
+    handed (``jit_<name>``): a ``functools.partial`` has none and read
+    ``jit__unknown``.  Every block's decode program holds ``paged_decode``,
+    where the metric files and ``readers/chain.py`` find it, and the pick
+    and prefill programs keep the names they are found by."""
+    import re
+
+    from flextree_tpu.serving import (
+        BatcherConfig, PagedCacheConfig, ServingEngine,
+    )
+
+    config = _block_config(block)
+    if config is None:
+        eng = _engine()
+    else:
+        eng = ServingEngine.from_config(
+            config, PagedCacheConfig(num_blocks=40, block_size=4,
+                                     blocks_per_seq=8),
+            BatcherConfig(slots=2), seed=3)
+    tables, lengths, tokens, _ = eng.batcher.batch_arrays()
+    carried = (eng.state,) if eng.state else ()
+    text = eng._decode.lower(
+        eng.params, eng.pools, tables, lengths, tokens, *carried).as_text()
+    [name] = re.findall(r"module @(\w+)", text)
+    assert name.startswith("jit_") and "paged_decode" in name
+    assert "unknown" not in name
+    assert bool(carried) == (block == "kimi")
+    logits = jax.ShapeDtypeStruct((2, 128), jnp.float32)
+    assert "module @jit_greedy_ids" in eng._greedy_ids.lower(logits).as_text()
+    assert eng._prefill.__name__ == "prefill_program"
 
 
 # ---------------------------------------------------------- the train loop
@@ -433,10 +514,12 @@ def test_phase_scopes_never_nest(lowered_step_paths):
 E = X.Event
 
 
-def _ctx(host_events, ops, window=(0.0, 1000.0), trace_dir=None):
+def _ctx(host_events, ops, window=(0.0, 1000.0), trace_dir=None,
+         modules=()):
     planes = [X.Plane("/host:CPU", [X.Line("python3", host_events)])]
     if ops is not None:
-        planes.append(X.Plane("/device:TPU:0", [X.Line("XLA Ops", ops)]))
+        planes.append(X.Plane("/device:TPU:0", [
+            X.Line("XLA Ops", ops), X.Line("XLA Modules", list(modules))]))
     run = Run(True, 0, 0, {}, {}, 0.0, trace_dir)
     cell = types.SimpleNamespace(name="toy")
     return ReaderContext(cell, run, {}, X.Trace(planes), window)
@@ -738,6 +821,212 @@ def test_device_pick_share_reads_the_engines_own_spans(tmp_path):
     assert shares == [0.5, 1.0, 1.0]
 
 
+# ------------------------------------------- the chain between two rounds
+#
+# Six decode rounds, every stamp a whole number of nanoseconds (so that a
+# shifted plane's differences are exact).  Round k's decode program starts
+# LAUNCH[k] after its dispatch opens; its pick program ends 15.05 ms later
+# and its fetch closes RETURN[k] after that; HOST_GAP[k] later round k+1's
+# dispatch opens, OUTSIDE[k] of it outside any round.  Rounds 2 and 5 admit
+# a request first, so the pairs are (0,1), (2,3), (3,4).
+
+MS = 1_000_000
+LAUNCH = [400_000, 500_000, 450_000, 600_000, 420_000, 480_000]
+RETURN = [700_000, 650_000, 800_000, 750_000, 900_000, 720_000]
+HOST_GAP = [900_000, 5_000_000, 1_100_000, 1_000_000, 6_000_000]
+OUTSIDE = [200_000, 300_000, 250_000, 150_000, 300_000]
+ADMITS = (2, 5)  # the rounds that open with a prefill
+PROGRAM_NS = 15_050_000  # decode program's first operation -> pick's last
+
+
+def _chain_ctx(host_late=0, host_prefills=True, device_prefills=True,
+               drop_host=(), drop_device=(), ids=True, decode="paged_decode"):
+    """The six rounds as a profile would hold them, the host plane's stamps
+    ``host_late`` ns later than true time.  ``drop_host`` / ``drop_device``:
+    rounds missing from one plane; ``ids=False``: a parent commit's spans."""
+    rid = (lambda k: {"round": k + 100}) if ids else (lambda k: {})
+    host, ops, modules = [], [], []
+    dispatch = 1 * MS  # round 0's dispatch opens, true time
+    ends = []
+    for k in range(6):
+        start = dispatch + LAUNCH[k]  # the decode program's first operation
+        pick_end = start + PROGRAM_NS
+        fetch_close = pick_end + RETURN[k]
+        ends.append(fetch_close)
+        pre = 0 if k == 0 else (HOST_GAP[k - 1] - OUTSIDE[k - 1]) // 2
+        post = 50_000 if k == 5 else HOST_GAP[k] - OUTSIDE[k] - (
+            HOST_GAP[k] - OUTSIDE[k]) // 2
+        if k not in drop_host:
+            host += [
+                E("ft.engine.round", dispatch - pre,
+                  fetch_close + post - (dispatch - pre), rid(k)),
+                E("ft.batcher.batch_arrays", dispatch - 60_000, 50_000),
+                E("ft.engine.decode_dispatch", dispatch, 620_000, rid(k)),
+                E("ft.engine.decode_fetch", dispatch + 650_000,
+                  fetch_close - dispatch - 650_000, rid(k)),
+                E("ft.engine.sample", fetch_close + 10_000, 100_000),
+                E("ft.engine.bookkeeping", fetch_close + post - 40_000,
+                  30_000, rid(k)),
+            ]
+            if k in ADMITS and host_prefills:
+                host.append(E("ft.engine.prefill", dispatch - pre + 20_000,
+                              pre - 100_000, {"rid": k}))
+        if k not in drop_device:
+            if k in ADMITS and device_prefills:
+                at = dispatch - pre + 200_000
+                modules += [E("jit_prefill_program(7)", at, 900_000),
+                            E("jit_greedy_ids(9)", at + 1 * MS, 20_000)]
+                ops += [E("%fusion.7 = f32[4]{0} fusion(", at + 900, 890_000),
+                        E("%fusion.9 = s32[1]{0} fusion(", at + 1 * MS + 900,
+                          10_000)]
+            modules += [
+                E(f"jit_{decode}_program(3)", start - 900, 14_999_800),
+                E("jit_greedy_ids(5)", start + 15_000_000 - 900, 52_000),
+            ]
+            ops += [  # back to back but for 2 us before the pick's
+                E("%fusion.1 = f32[4]{0} fusion(", start, 10 * MS),
+                E("%while.2 = f32[4]{0} while(", start + 10 * MS, 4_998_000),
+                E("%fusion.3 = f32[4]{0} fusion(", start + 10_200_000,
+                  100_000),
+                E("%fusion.5 = s32[2]{0} fusion(", start + 15_000_000,
+                  50_000),
+            ]
+        if k < 5:
+            dispatch = fetch_close + HOST_GAP[k]
+    window = (0 + host_late, ends[-1] + 100_000 + host_late)
+    host = [E("bench_window", 0, ends[-1] + 100_000)] + host
+    host = [E(e.name, e.start_ns + host_late, e.dur_ns, e.stats) for e in host]
+    return _ctx(host, ops, window, modules=modules)
+
+
+def _chain_metric(ctx, name):
+    """A chain metric as its metric file reads it, in ns (whole numbers in
+    the hand-made trace, so equalities below are exact)."""
+    from benchmarks.readers import chain
+
+    meta = _metric_file(name)
+    module, fn = meta["reader"].split(":")
+    assert module == "chain"
+    value = getattr(chain, fn)(ctx, **meta["args"])
+    return None if value is None else round(value * 1e6, 3)
+
+
+GAP_METRICS = ["engine.gap_device_ms_p50", "engine.gap_host_ms_p50",
+               "engine.gap_crossing_ms_p50", "engine.gap_outside_ms_p50"]
+CLOCK_METRICS = ["trace.launch_min_ms", "trace.return_min_ms"]
+# worked by hand over the pairs (0,1), (2,3), (3,4): the host's gaps
+# 0.9, 1.1, 1.0; the crossings RETURN[n] + LAUNCH[n+1] = 1.2, 1.4, 1.17;
+# the device's their sums 2.1, 2.5, 2.17: medians taken pair by pair, so
+# the crossing's is NOT the device's less the host's
+WRITTEN = {
+    "engine.gap_device_ms_p50": 2_170_000,
+    "engine.gap_host_ms_p50": 1_000_000,
+    "engine.gap_crossing_ms_p50": 1_200_000,
+    "engine.gap_outside_ms_p50": 200_000,
+    "trace.launch_min_ms": 400_000,  # round 0
+    "trace.return_min_ms": 650_000,  # round 1
+}
+
+
+@pytest.mark.parametrize("name", GAP_METRICS + CLOCK_METRICS)
+def test_chain_metric_reads_the_written_value(name):
+    assert _chain_metric(_chain_ctx(), name) == WRITTEN[name]
+
+
+@pytest.mark.parametrize("seen_by", ["host", "device"])
+def test_a_pair_with_a_prefill_is_skipped_whichever_plane_shows_it(seen_by):
+    """The prefill span on the host, or any program between the pick and
+    the next decode program on the device: either takes the pair out."""
+    from benchmarks.readers import chain
+
+    ctx = _chain_ctx(host_prefills=seen_by == "host",
+                     device_prefills=seen_by == "device")
+    ids = [(a[0].round_id, b[0].round_id) for a, b in chain.pairs(ctx)]
+    assert ids == [(100, 101), (102, 103), (103, 104)]
+    assert [_chain_metric(ctx, n) for n in GAP_METRICS] \
+        == [WRITTEN[n] for n in GAP_METRICS]
+    # with no prefill on either plane all five gaps count: the medians move
+    bare = _chain_ctx(host_prefills=False, device_prefills=False)
+    assert len(chain.pairs(bare)) == 5
+    assert _chain_metric(bare, "engine.gap_host_ms_p50") == 1_100_000
+
+
+@pytest.mark.parametrize("shift_ms", [-1.5, -0.5, 0.5, 1.5])
+def test_a_shift_of_the_host_plane_moves_only_what_crosses_the_clocks(shift_ms):
+    """The test the reader exists for.  The profiler joins the host's and
+    the device's clocks once a session, to about a millisecond: with the
+    host plane recorded ``shift`` later, every ``engine.gap_*`` reads the
+    same to the nanosecond (each is a difference on ONE clock, or a
+    difference of two such differences), the two ``trace.*`` readings move
+    by exactly the shift, in opposite directions, their sum unmoved, and
+    the old reading (chip 0's idle time laid over ``ft.engine.decode_fetch``,
+    ``engine.idle_fetch_ms``) moves with the clocks, on the same code."""
+    shift = int(shift_ms * MS)
+    base, moved = _chain_ctx(), _chain_ctx(host_late=shift)
+    for name in GAP_METRICS:
+        assert _chain_metric(moved, name) == _chain_metric(base, name) \
+            == WRITTEN[name]
+    launch = _chain_metric(moved, "trace.launch_min_ms")
+    back = _chain_metric(moved, "trace.return_min_ms")
+    assert launch == WRITTEN["trace.launch_min_ms"] - shift
+    assert back == WRITTEN["trace.return_min_ms"] + shift
+    # a floor under the crossing, whatever the offset
+    assert launch + back == 1_050_000 <= WRITTEN["engine.gap_crossing_ms_p50"]
+    # one of them negative: the clocks are shown apart, by at least that
+    assert (launch < 0) == (shift > 400_000)
+    assert (back < 0) == (shift < -650_000)
+    old = _metric_file("engine.idle_fetch_ms")
+    assert old["reader"] == "spans:idle_ms_per"
+    before = S.idle_ms_per(base, **old["args"])
+    after = S.idle_ms_per(moved, **old["args"])
+    # on true clocks a round's fetch covers the 2 us before its pick
+    # program and RETURN[k] of the gap after it; on shifted ones, what the
+    # shift lays under it
+    assert before * 6 * 1e6 == pytest.approx(sum(RETURN) + 6 * 2_000)
+    assert abs(after - before) > 0.3 * abs(shift_ms)
+
+
+@pytest.mark.parametrize("planes", [
+    {"drop_device": (4, 5)}, {"drop_host": (0, 1)}, {"drop_device": (0,)},
+    {"drop_device": range(6)}, {"drop_host": range(6)}, {"ids": False},
+    {"decode": "unknown"},
+])
+def test_rounds_that_do_not_join_give_nothing(planes):
+    """Counts that differ by more than the one round an edge of the profile
+    may cut (and a cut the causal order rules out: the device's FIRST round
+    missing while the host holds its dispatch), a plane with no round at
+    all, a parent commit's spans without the id, a decode program no name
+    finds: every chain metric is None, none guesses."""
+    ctx = _chain_ctx(**planes)
+    if planes == {"drop_device": (0,)}:
+        # joined, by the rule, one round off: the clock readings show it
+        # (a fetch that closed a round before its pick program ended),
+        # which is what they are for
+        assert _chain_metric(ctx, "trace.return_min_ms") < -10 * MS
+        return
+    assert [_chain_metric(ctx, n) for n in GAP_METRICS + CLOCK_METRICS] \
+        == [None] * 6
+
+
+@pytest.mark.parametrize("planes,pairs", [
+    ({"drop_host": (0,)}, [(102, 103), (103, 104)]),
+    ({"drop_device": (5,)}, [(100, 101), (102, 103), (103, 104)]),
+])
+def test_a_round_cut_by_an_edge_of_the_profile_is_dropped(planes, pairs):
+    """A dispatch comes before its program: the device's first round may
+    lack its dispatch (it opened before the profile did), the host's last
+    its program.  One such round is dropped there and the rest join."""
+    from benchmarks.readers import chain
+
+    ctx = _chain_ctx(**planes)
+    assert [(a[0].round_id, b[0].round_id)
+            for a, b in chain.pairs(ctx)] == pairs
+    for (ha, da), (hb, db) in chain.pairs(ctx):
+        n = ha.round_id - 100
+        assert db.decode_start - da.pick_end \
+            == HOST_GAP[n] + RETURN[n] + LAUNCH[n + 1]
+
+
 def test_readers_return_none_where_there_is_nothing_to_read():
     host, ops = _round_trace()
     # a rehearsal: host spans, no device plane
@@ -762,13 +1051,36 @@ def test_new_metric_files_name_readers_that_exist():
     from benchmarks.lib import harness
 
     bench = harness.load_benchmark()
-    seen = 0
+    from benchmarks.readers import chain
+
+    modules = {"spans": S, "chain": chain}
+    seen = {"spans": 0, "chain": 0}
     for entry in bench["per_layer"]:
         meta = _metric_file(entry["name"])
-        if not meta["reader"].startswith("spans:"):
+        module, name = meta["reader"].split(":")
+        if module not in modules:
             continue
-        seen += 1
-        fn = getattr(S, meta["reader"].split(":")[1])
+        seen[module] += 1
+        fn = getattr(modules[module], name)
         inspect.signature(fn).bind(None, **meta.get("args", {}))
         assert entry["source"] in ("program_span", "program_counter", "device_trace")
-    assert seen == 30  # PR 35: kernels.kda_kernel_share
+    # PR 36: four more medians of a span's duration, and the chain's six
+    assert seen == {"spans": 34, "chain": 6}
+
+
+def test_the_chains_entries_are_the_serving_cells_and_move_the_rate():
+    from benchmarks.lib import harness
+
+    bench = harness.load_benchmark()
+    serving = [w["name"] for w in bench["workloads"]
+               if "closed" in w["traffic"]]
+    assert len(serving) == 4
+    first = [e["name"] for e in bench["per_layer"]].index(GAP_METRICS[0])
+    added = bench["per_layer"][first:first + 10]
+    assert [e["name"] for e in added] == GAP_METRICS + [
+        "engine.dispatch_ms_p50", "engine.retire_ms_p50",
+        "engine.bookkeeping_ms_p50", "batcher.batch_arrays_ms_p50",
+    ] + CLOCK_METRICS
+    for e in added:
+        assert (e["workloads"], e["moves"], e["unit"], e["better"]) \
+            == (serving, "serve_tokens_per_s", "ms", "lower")
