@@ -36,6 +36,7 @@ import jax.numpy as jnp
 from ..ops.paged_attention import runs_kernel
 from . import kimi_linear as kimi, laguna, pangu_ultra_moe as pangu
 from .generate import paged_decode_dense, prefill_dense
+from .moe import expert_kernel_layers
 from .transformer import TransformerConfig, init_params
 
 __all__ = [
@@ -69,6 +70,9 @@ class Block:
     # cfg -> (layers of the decode program that hold a state a slot, those
     # of them whose update runs the Pallas kernel), fixed likewise
     state_kernel_layers: Callable = lambda cfg: (0, 0)
+    # (cfg, slots) -> (expert layers of the decode program, those of them
+    # whose grouped products run the Pallas kernel), fixed likewise
+    expert_kernel_layers: Callable = lambda cfg, slots: (0, 0)
 
 
 def _dense_from_dict(c: dict) -> TransformerConfig:
@@ -111,17 +115,18 @@ BLOCKS = {
     "laguna": Block(
         laguna.LagunaConfig, laguna.config_from_dict, laguna.init_params,
         laguna.prefill, laguna.paged_decode_step, _kv_heads,
-        _kv_kernel_layers,
+        _kv_kernel_layers, expert_kernel_layers=expert_kernel_layers,
     ),
     "pangu_ultra_moe": Block(
         pangu.PanguConfig, pangu.config_from_dict, pangu.init_params,
         pangu.prefill, pangu.paged_decode_step, pangu.pool_layout,
-        pangu.kernel_layers,
+        pangu.kernel_layers, expert_kernel_layers=expert_kernel_layers,
     ),
     "kimi_linear": Block(
         kimi.KimiLinearConfig, kimi.config_from_dict, kimi.init_params,
         kimi.prefill, kimi.paged_decode_step, kimi.pool_layout,
         kimi.kernel_layers, kimi.state_kernel_layers,
+        expert_kernel_layers=expert_kernel_layers,
     ),
 }
 

@@ -161,6 +161,10 @@ class LagunaConfig:
     def n_held(self) -> int:
         return self.experts_held[1] - self.experts_held[0]
 
+    @property
+    def n_sparse(self) -> int:
+        return sum(m == SPARSE for m in self.mlp_types)
+
     def rope(self, i: int) -> RopeSpec:
         return self.rope_full if self.layer_types[i] == FULL else self.rope_window
 

@@ -26,8 +26,9 @@ The framework's second model family (next to the dense
   layer and weighted into the training loss by ``router_aux_weight``.
 - **Dropless share** (the SERVED path; ``models.laguna``):
   :func:`route_topk_normalized` + :func:`dropless_experts` route over all
-  the experts, sort the picks by expert and run one grouped product
-  (``lax.ragged_dot``) over the experts HELD here.  No capacity, no
+  the experts, sort the picks by expert and run grouped products over
+  the experts HELD here (``lax.ragged_dot``; in a decode round on a TPU
+  the Pallas kernel of ``ops.grouped_matmul``).  No capacity, no
   one-hot over (tokens, experts, slots), no dropped pick whatever the
   skew; picks of experts held elsewhere keep their weight and add
   nothing, and on one chip there is no exchange.
@@ -48,6 +49,9 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
+from ..ops.grouped_matmul import (
+    group_visits, grouped_matmul, runs_grouped_kernel,
+)
 from ..parallel.allreduce import allreduce
 from .transformer import (
     TransformerConfig,
@@ -71,6 +75,7 @@ __all__ = [
     "ROUTER_SCORES",
     "route_topk_normalized",
     "dropless_experts",
+    "expert_kernel_layers",
     "gated_ffn",
     "expert_layer",
     "stack_router",
@@ -353,7 +358,8 @@ def route_topk_normalized(h, router_w, k: int, scale: float = 1.0,
     return scores, choices.astype(jnp.int32), top * scale
 
 
-def dropless_experts(h, choices, weights, experts, held, rows=None):
+def dropless_experts(h, choices, weights, experts, held, rows=None, *,
+                     impl: str | None = None):
     """The part of a routed layer's output that the experts held here
     give.  ``h`` (N, d); ``choices`` (N, k) expert ids over ALL the
     experts; ``weights`` (N, k) f32; ``experts`` the stacked gated-SiLU
@@ -362,11 +368,24 @@ def dropless_experts(h, choices, weights, experts, held, rows=None):
     picks are dispatched at all (default: every row).
 
     Picks are sorted by expert (absent experts' picks last), each held
-    expert's rows are multiplied by its own matrices in one grouped
-    product, and the results return to their tokens weighted.  Every
-    local pick is computed, however many land on one expert.  Returns
-    ``(out, sizes)``: (N, d) float32 and the (n_held,) int32 picks each
-    held expert got."""
+    expert's rows are multiplied by its own matrices in grouped products,
+    and the results return to their tokens weighted.  Every local pick is
+    computed, however many land on one expert.  Returns ``(out, sizes)``:
+    (N, d) float32 and the (n_held,) int32 picks each held expert got.
+
+    One algorithm, two lowerings of its three grouped products, chosen
+    from the backend and the static shapes
+    (``ops.grouped_matmul.runs_grouped_kernel``): where an expert gets a
+    handful of rows (a decode round on a TPU) the Pallas kernel
+    ``moe_grouped_matmul`` in row tiles of 32, gate, up and the
+    activation one call and down another; elsewhere (a prefill's thousands
+    of rows an expert, the CPU backend, shapes that are no whole tiles)
+    ``lax.ragged_dot``.  The arithmetic is one: operands in the held type,
+    f32 accumulation, the activation cast to the held type before the
+    down product.  ``impl`` (``"pallas"`` / ``"ragged"``) forces one, for
+    the tests."""
+    if impl not in (None, "pallas", "ragged"):
+        raise ValueError(f"unknown grouped-product impl {impl!r}")
     lo, hi = held
     n_held = hi - lo
     n, k = choices.shape
@@ -378,15 +397,43 @@ def dropless_experts(h, choices, weights, experts, held, rows=None):
     sizes = jnp.zeros((n_held + 1,), jnp.int32).at[key].add(1)[:n_held]
     xs = h[order // k]  # (N*k, d): row j is the token of sorted pick j
     f32 = jnp.float32
-    gate = lax.ragged_dot(xs, experts["w_gate"], sizes, preferred_element_type=f32)
-    up = lax.ragged_dot(xs, experts["w_up"], sizes, preferred_element_type=f32)
-    act = (jax.nn.silu(gate) * up).astype(h.dtype)
-    ys = lax.ragged_dot(act, experts["w_down"], sizes, preferred_element_type=f32)
+    if impl == "pallas" or (
+        impl is None and runs_grouped_kernel(xs, experts["w_gate"])
+    ):
+        visits = group_visits(sizes, n * k)
+        act = grouped_matmul(
+            xs, experts["w_up"], sizes, experts["w_gate"], visits=visits
+        )
+        ys = grouped_matmul(act, experts["w_down"], sizes, visits=visits)
+    else:
+        gate = lax.ragged_dot(
+            xs, experts["w_gate"], sizes, preferred_element_type=f32
+        )
+        up = lax.ragged_dot(xs, experts["w_up"], sizes, preferred_element_type=f32)
+        act = (jax.nn.silu(gate) * up).astype(h.dtype)
+        ys = lax.ragged_dot(
+            act, experts["w_down"], sizes, preferred_element_type=f32
+        )
     # back to pick order; rows past the held experts' groups were never
     # computed, so they are selected away, not multiplied by zero
     per_pick = ys[jnp.argsort(order)].reshape(n, k, -1)
     per_pick = jnp.where(local[..., None], per_pick, 0.0)
     return jnp.einsum("nkd,nk->nd", per_pick, weights.astype(f32)), sizes
+
+
+def expert_kernel_layers(cfg, slots: int) -> tuple:
+    """``(expert layers of a decode program over ``slots`` slots, those of
+    them whose grouped products run the Pallas kernel)``, every one alike:
+    fixed by the backend and the static shapes, by the function that
+    chooses the lowering in :func:`dropless_experts`."""
+    lo, hi = cfg.experts_held
+    took = runs_grouped_kernel(
+        jax.ShapeDtypeStruct((slots * cfg.top_k, cfg.d_model), cfg.dtype),
+        jax.ShapeDtypeStruct(
+            (hi - lo, cfg.d_model, cfg.d_expert), cfg.param_dtype
+        ),
+    )
+    return cfg.n_sparse, cfg.n_sparse * took
 
 
 def gated_ffn(w, h):

@@ -215,6 +215,11 @@ class ServingEngine:
         # those of them whose update runs the Pallas kernel, fixed when the
         # decode program is built, as ``attn_kernel_layers`` below
         self.state_kernel_layers = block_of(cfg).state_kernel_layers(cfg)[1]
+        # the decode program's expert layers and those of them whose
+        # grouped products run the Pallas kernel, fixed likewise
+        self.expert_layers, self.expert_kernel_layers = block_of(
+            cfg
+        ).expert_kernel_layers(cfg, self.bcfg.slots)
         # donation keeps steady-state decode allocation-free: the pool
         # scatter aliases in place instead of copying the whole pool every
         # round.  XLA:TPU aliases every donated pool buffer (AOT compile
@@ -368,6 +373,8 @@ class ServingEngine:
                     state_bytes_per_slot=self.state_bytes_per_slot,
                     state_layers=self.state_layers,
                     state_kernel_layers=self.state_kernel_layers,
+                    expert_layers=self.expert_layers,
+                    expert_kernel_layers=self.expert_kernel_layers,
                 ):
                     # a model with routed experts hands out a third
                     # result, what its routers did this round
@@ -1067,6 +1074,8 @@ class ServingEngine:
             "state_bytes_per_slot": self.state_bytes_per_slot,
             "state_layers": self.state_layers,
             "state_kernel_layers": self.state_kernel_layers,
+            "expert_layers": self.expert_layers,
+            "expert_kernel_layers": self.expert_kernel_layers,
             **self.metrics.snapshot(),
         }
 

@@ -692,6 +692,9 @@ def test_spans_and_the_report_carry_the_states_numbers():
         # 16-wide heads on the CPU: the jnp body in every layer, and said so
         assert e["state_kernel_layers"] == 0 == eng.report()["state_kernel_layers"]
         assert e["cache_bytes_per_position"] == 2 * 24 * 4 and e["attn_layers"] == 2
+        # and lax.ragged_dot in every expert layer, said likewise (PR 37)
+        assert e["expert_layers"] == eng.cfg.n_sparse == eng.report()["expert_layers"]
+        assert e["expert_kernel_layers"] == 0 == eng.report()["expert_kernel_layers"]
     assert [e["state_slots_live"] for e in named("ft.engine.bookkeeping")] == [3, 3]
     assert [e["state_bytes"] for e in named("ft.engine.prefill")] == [per_slot] * 3
     # a block that keeps no state says 0
@@ -704,6 +707,7 @@ def test_spans_and_the_report_carry_the_states_numbers():
         BatcherConfig(slots=2))
     assert dense.report()["state_bytes_per_slot"] == 0 == dense.report()["state_layers"]
     assert dense.report()["state_kernel_layers"] == 0
+    assert dense.report()["expert_layers"] == 0 == dense.report()["expert_kernel_layers"]
     assert dense.state == {}
 
 

@@ -1064,8 +1064,9 @@ def test_new_metric_files_name_readers_that_exist():
         fn = getattr(modules[module], name)
         inspect.signature(fn).bind(None, **meta.get("args", {}))
         assert entry["source"] in ("program_span", "program_counter", "device_trace")
-    # PR 36: four more medians of a span's duration, and the chain's six
-    assert seen == {"spans": 34, "chain": 6}
+    # PR 36: four more medians of a span's duration, and the chain's six;
+    # PR 37: the expert layers that run the grouped kernel
+    assert seen == {"spans": 35, "chain": 6}
 
 
 def test_the_chains_entries_are_the_serving_cells_and_move_the_rate():

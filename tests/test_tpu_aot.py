@@ -536,7 +536,14 @@ def test_the_state_cells_decode_program_updates_pools_and_state_in_place(
     calls = re.findall(
         r"^\s*(?:ROOT )?%?(\w+?)[.\d]* = .*custom-call\(.*tpu_custom_call",
         hlo, re.M)
-    assert sorted(calls) == ["kda_state_update"] * 10 + ["paged_latent_attention"] * 3
+    # and the grouped kernel twice an expert layer (gate, up and the
+    # activation one call, down the other): none of XLA's own grouped
+    # products, whose 512-row tile holds an expert's four or five rows
+    assert sorted(calls) == (
+        ["kda_state_update"] * 10 + ["moe_grouped_matmul"] * 24
+        + ["paged_latent_attention"] * 3
+    )
+    assert "ragged-dot-none" not in hlo
     assert _ops_on_a_state(hlo) == ["custom-call"] * 10
     mem = compiled.memory_analysis()
     carried = sum(
@@ -598,6 +605,75 @@ def test_on_the_cpu_no_state_layer_runs_the_update_kernel():
 
     cfg, _, _ = _kimi_cell()
     assert block_of(cfg).state_kernel_layers(cfg) == (10, 0)
+
+
+def test_the_state_cells_prefill_program_keeps_xlas_grouped_product(
+    v5e, tpu_lowering
+):
+    """A 512-token prompt is 4,096 sorted picks over 32 experts, 128 an
+    expert by the shapes: XLA's 512-row tile is right there, so the
+    prefill program holds ``lax.ragged_dot`` as XLA:TPU renames it, three
+    a sparse layer, and no grouped kernel of the repo's."""
+    import re
+
+    from flextree_tpu.models import kimi_linear as kimi
+
+    cfg, _, pcfg = _kimi_cell()
+    one = NamedSharding(_mesh(v5e[:1], (1, 1, 1)), P())
+    params = _on(jax.eval_shape(
+        lambda k: kimi.init_params(k, cfg), jax.random.PRNGKey(0)), one)
+    hlo = _compile(
+        lambda p, tok: kimi.prefill(p, tok, cfg, max_len=pcfg.max_len),
+        params, jax.ShapeDtypeStruct((1, 512), jnp.int32, sharding=one),
+    ).as_text()
+    assert "moe_grouped_matmul" not in hlo
+    products = re.findall(r"^\s*%?ragged-dot-none\S* = ", hlo, re.M)
+    assert len(products) == 3 * cfg.n_sparse == 36
+
+
+#: (sorted picks, experts held, hidden, expert width) of a decode round in
+#: the three catalog cells
+CELL_EXPERTS = {
+    "state": (1024, 32, 2304, 1024),
+    "laguna": (640, 128, 3072, 1024),
+    "latent": (256, 16, 7680, 2048),
+}
+
+
+@pytest.mark.parametrize("product", ["inner", "down"])
+@pytest.mark.parametrize("cell", list(CELL_EXPERTS))
+def test_the_grouped_kernel_compiles_at_a_cells_decode_shape(
+    v5e, tpu_lowering, cell, product
+):
+    """Each cell's two calls: gate, up and the activation over the whole
+    hidden width (7,680 in the latent cell: slabs of 512 columns, four
+    blocks of 7.9 MB in VMEM), and the product back; ONE Mosaic kernel
+    each, whose grid's length comes from the sizes."""
+    from flextree_tpu.ops.grouped_matmul import (
+        grouped_matmul, runs_grouped_kernel,
+    )
+
+    m, g, d, f = CELL_EXPERTS[cell]
+    k, n = (d, f) if product == "inner" else (f, d)
+    one = NamedSharding(_mesh(v5e[:1], (1, 1, 1)), P())
+    a = lambda *s, dt=jnp.bfloat16: jax.ShapeDtypeStruct(s, dt, sharding=one)  # noqa: E731
+    xs, w = a(m, k), a(g, k, n)
+    assert runs_grouped_kernel(xs, w)
+    if product == "inner":
+        fn = lambda xs, w, wg, sizes: grouped_matmul(xs, w, sizes, wg)  # noqa: E731
+    else:
+        fn = lambda xs, w, wg, sizes: grouped_matmul(xs, w, sizes)  # noqa: E731
+    hlo = _compile(fn, xs, w, w, a(g, dt=jnp.int32)).as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 1
+    assert "moe_grouped_matmul" in hlo and "ragged-dot" not in hlo
+
+
+def test_on_the_cpu_no_expert_layer_runs_the_grouped_kernel():
+    """The same block where the tests run: ``lax.ragged_dot`` in all 12."""
+    from flextree_tpu.models.configs import block_of
+
+    cfg, t, _ = _kimi_cell()
+    assert block_of(cfg).expert_kernel_layers(cfg, t["slots"]) == (12, 0)
 
 
 def test_the_chunked_scan_compiles_at_the_longest_prompt(v5e, tpu_lowering):
