@@ -1,11 +1,13 @@
 """Model substrate the collectives serve: dense transformer LM + MoE LM,
-and the four served blocks that ``models.configs.BLOCKS`` chooses among by
+and the five served blocks that ``models.configs.BLOCKS`` chooses among by
 ``model_type``: ``transformer`` (dense), ``laguna`` (window + full
 grouped-query attention, softmax-routed experts), ``pangu_ultra_moe``
-(latent attention, sigmoid-routed experts, sandwich norms) and
+(latent attention, sigmoid-routed experts, sandwich norms),
 ``kimi_linear`` (delta-rule linear-attention layers that hold a recurrent
 state a slot, beside NoPE latent-attention layers that cache a row a
-position).  What each keeps is its layout, a layer at a time: parts a
+position) and ``olmo_hybrid`` (delta-rule layers with a decay a head and
+keys narrower than values, beside full-attention layers that cache plain K
+and V; a sublayer's output is normed).  What each keeps is its layout, a layer at a time: parts a
 position, paged in blocks, and parts a slot, one array a sequence
 (``models.configs.pool_layout``)."""
 

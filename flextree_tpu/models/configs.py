@@ -5,11 +5,12 @@ the serving path chooses a model (``ServingEngine.from_config``, ``python
 
 :data:`BLOCKS` is the one table of what a block brings: its configuration
 object, seeded parameters, the two walks over its layers behind the
-signatures the engine calls, and the layout of what it keeps.  The four
+signatures the engine calls, and the layout of what it keeps.  The five
 blocks are ``models.transformer`` (``gpt_neox``: the dense block at those
 widths, see ``benchmarks/configs/pythia-*.json`` for what it departs in),
 ``models.laguna`` (``laguna``), ``models.pangu_ultra_moe``
-(``pangu_ultra_moe``) and ``models.kimi_linear`` (``kimi_linear``).
+(``pangu_ultra_moe``), ``models.kimi_linear`` (``kimi_linear``) and
+``models.olmo_hybrid`` (``olmo_hybrid``).
 
 **The layout is a layer's** (:func:`pool_layout`, one entry a layer):
 ``position`` names the parts the layer caches a POSITION and the shape of
@@ -22,7 +23,9 @@ state, ``(slots, *shape)``, an array a layer that holds the part
 ``v`` of ``(K/V heads, head_dim)`` in every layer, openPangu one ``ckv``
 row in every layer, none of the three anything a slot; a Kimi-Linear MLA
 layer caches one ``ckv`` row and a KDA layer NOTHING a position, but a
-float32 state ``s`` and its convolution's last inputs ``conv`` a slot.
+float32 state ``s`` and its convolution's last inputs ``conv`` a slot; an
+Olmo-Hybrid linear layer holds the same two parts a slot (keys narrower
+than values) beside full layers that cache PLAIN ``k`` and ``v``.
 """
 
 from __future__ import annotations
@@ -34,7 +37,12 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.paged_attention import runs_kernel
-from . import kimi_linear as kimi, laguna, pangu_ultra_moe as pangu
+from . import (
+    kimi_linear as kimi,
+    laguna,
+    olmo_hybrid as olmo,
+    pangu_ultra_moe as pangu,
+)
 from .generate import paged_decode_dense, prefill_dense
 from .moe import expert_kernel_layers
 from .transformer import TransformerConfig, init_params
@@ -127,6 +135,11 @@ BLOCKS = {
         kimi.prefill, kimi.paged_decode_step, kimi.pool_layout,
         kimi.kernel_layers, kimi.state_kernel_layers,
         expert_kernel_layers=expert_kernel_layers,
+    ),
+    "olmo_hybrid": Block(
+        olmo.OlmoHybridConfig, olmo.config_from_dict, olmo.init_params,
+        olmo.prefill, olmo.paged_decode_step, olmo.pool_layout,
+        olmo.kernel_layers, olmo.state_kernel_layers,
     ),
 }
 

@@ -33,7 +33,11 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..ops.paged_attention import paged_attention, paged_attention_gather
+from ..ops.paged_attention import (
+    paged_attention,
+    paged_attention_gather,
+    put_rows,
+)
 from .transformer import (
     TransformerConfig,
     apply_rope,
@@ -300,8 +304,8 @@ def paged_decode_dense(params, pools, tables, lengths, tokens,
         x = x + o
         x = mlp_block(layer, x, cfg)
         # scatter the appended K/V back into each row's current block
-        new_k.append(pk.at[blk, off].set(k[:, 0]))
-        new_v.append(pv.at[blk, off].set(v[:, 0]))
+        new_k.append(put_rows(pk, blk, off, k[:, 0]))
+        new_v.append(put_rows(pv, blk, off, v[:, 0]))
     logits = final_logits(params["embed"], params["ln_f"], x)
     return logits[:, 0], {"k": new_k, "v": new_v}
 

@@ -53,7 +53,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..ops.paged_attention import paged_attention, paged_attention_gather
+from ..ops.paged_attention import (
+    paged_attention,
+    paged_attention_gather,
+    put_rows,
+)
 from .generate import cached_attention
 from .moe import expert_layer, gated_ffn, round_counts, stack_router
 from .transformer import apply_rope, rms_norm
@@ -462,8 +466,8 @@ def paged_decode_step(params, pools, tables, lengths, tokens,
                 window=cfg.window if cfg.layer_types[i] == WINDOW else None,
             )[:, None]
             x = _attention_output(layer, x, attn, gate)
-            new_k.append(pk.at[blk, off].set(k[:, 0]))
-            new_v.append(pv.at[blk, off].set(v[:, 0]))
+            new_k.append(put_rows(pk, blk, off, k[:, 0]))
+            new_v.append(put_rows(pv, blk, off, v[:, 0]))
         x, moe = _ffn(layer, x, cfg, i, rows=active)
         if moe is not None:
             moes.append(moe)

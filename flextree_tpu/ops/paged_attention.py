@@ -79,6 +79,10 @@ __all__ = [
     "latent_kernel_admits",
     "runs_kernel",
     "runs_latent_kernel",
+    "pool_relayouts",
+    "take_blocks",
+    "put_blocks",
+    "put_rows",
 ]
 
 _NEG_INF = -1e30
@@ -141,6 +145,62 @@ def paged_attention_gather(q, k_new, v_new, k_pool, v_pool, tables, lengths,
 
 
 # ------------------------------------------------------------ jnp streaming
+
+
+def pool_relayouts(pool) -> bool:
+    """Whether XLA:TPU holds this K/V pool (N, bs, Hkv, D) in another order
+    than it is indexed in.  The device keeps an array with the axis that
+    pads least to the sublane tile next to the lanes: for a head count that
+    is no multiple of 8 (30 -> 32) that is the block-size or the blocks
+    axis, not the heads', and a gather or a scatter over such a pool first
+    copies it WHOLE into row-major order and back (AOT compile for the v5e
+    at (529, 256, 30, 128): 4.4 GB of copies a round, 5.6 GB of
+    temporaries; PERF.md section 6, PR 38).  ``dynamic_slice`` and
+    ``dynamic_update_slice`` take the pool as it lies."""
+    return (
+        backend.kernel_platform() == "tpu" and pool.ndim == 4
+        and pool.shape[2] % 8 != 0
+    )
+
+
+def take_blocks(pool, idx):
+    """``pool[idx]`` for block ids ``idx`` of any (static) shape: one
+    gather, or where that would copy the pool whole
+    (:func:`pool_relayouts`) one ``dynamic_slice`` a block."""
+    if not pool_relayouts(pool):
+        return pool[idx]
+    flat = idx.reshape(-1)
+    blocks = jnp.stack([
+        lax.dynamic_index_in_dim(pool, flat[i], 0, keepdims=False)
+        for i in range(flat.shape[0])
+    ])
+    return blocks.reshape(*idx.shape, *pool.shape[1:])
+
+
+def put_blocks(pool, idx, blocks):
+    """``pool.at[idx].set(blocks)`` for (n,) block ids and (n, bs, Hkv, D)
+    ``blocks``: one scatter, or where that would copy the pool whole one
+    ``dynamic_update_slice`` a block."""
+    if not pool_relayouts(pool):
+        return pool.at[idx].set(blocks)
+    for i in range(idx.shape[0]):
+        pool = lax.dynamic_update_slice_in_dim(pool, blocks[i][None], idx[i], 0)
+    return pool
+
+
+def put_rows(pool, blk, off, rows):
+    """``pool.at[blk, off].set(rows)``: each slot's new row (S, Hkv, D) at
+    its block and offset; one scatter, or where that would copy the pool
+    whole one ``dynamic_update_slice`` a slot (slots that share a place,
+    the inactive ones' null block, keep the last)."""
+    if not pool_relayouts(pool):
+        return pool.at[blk, off].set(rows)
+    zero = jnp.zeros((), blk.dtype)
+    for i in range(rows.shape[0]):
+        pool = lax.dynamic_update_slice(
+            pool, rows[i][None, None], (blk[i], off[i].astype(blk.dtype), zero, zero)
+        )
+    return pool
 
 
 def _stream_jnp(q, k_new, v_new, k_pool, v_pool, tables, lengths, scale,
@@ -215,8 +275,8 @@ def _stream_jnp(q, k_new, v_new, k_pool, v_pool, tables, lengths, scale,
     def body(i, carry):
         m, l, acc = carry
         tb = lax.dynamic_slice_in_dim(tables, i * cb, cb, axis=1)  # (S, cb)
-        kb = k_pool[tb].reshape(s, cb * bs, hkv, d)
-        vb = v_pool[tb].reshape(s, cb * bs, hkv, d)
+        kb = take_blocks(k_pool, tb).reshape(s, cb * bs, hkv, d)
+        vb = take_blocks(v_pool, tb).reshape(s, cb * bs, hkv, d)
         # einsum in the compute dtype then f32, mirroring cached_attention
         sc = scores(kb).astype(jnp.float32) * scale
         kpos = i * cb * bs + jnp.arange(cb * bs)

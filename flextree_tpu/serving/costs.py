@@ -162,8 +162,10 @@ def kv_migration_elems(cfg, pcfg, prompt_len: int) -> list:
     ships, a tensor a part of the pool's layout (K and V, or one latent
     row): the block footprint of the prompt (``blocks_for``, whole blocks
     — migration ships the tail block too) × block positions × the part's
-    numbers a position.  One sequence ships ``n_layers`` times these (every
-    layer of the three blocks that migrate today caches every part)."""
+    numbers a position.  One sequence ships each as often as there are
+    layers that cache the part (``position_parts``: every layer of the
+    dense, Laguna and openPangu blocks, the latent or full layers alone of
+    a block whose other layers hold a state a slot)."""
     n_blocks = pcfg.blocks_for(max(int(prompt_len), 1))
     return [
         n_blocks * pcfg.block_size * math.prod(row)
@@ -186,14 +188,25 @@ def predict_migration_us(cfg, pcfg, prompt_len: int, codec="f32",
     if params is None:
         params = default_params()
     c = get_codec(codec)
-    elems = kv_migration_elems(cfg, pcfg, prompt_len)
-    bytes_on_wire = cfg.n_layers * sum(c.wire_bytes(e) for e in elems)
+    # (how often, f32 elements) of every tensor shipped: a part's rows a
+    # layer that caches it, and, under the same codec, what the sequence
+    # holds a slot, a part and layer at a time as the rows are
+    tensors = [
+        (layers, e) for (_, layers), e in zip(
+            position_parts(cfg).values(),
+            kv_migration_elems(cfg, pcfg, prompt_len),
+        )
+    ] + [
+        (layers, math.prod(shape))
+        for (shape, _), layers in slot_parts(cfg).values()
+    ]
+    bytes_on_wire = sum(n * c.wire_bytes(e) for n, e in tensors)
     wire_us = params.dcn.latency_us + bytes_on_wire / (
         max(params.dcn.bandwidth_GBps, 1e-6) * 1e3
     )
     codec_us = 0.0
     if c.hop_cost:
-        codec_us = 2.0 * (cfg.n_layers * sum(elems) * 4) / (
+        codec_us = 2.0 * (sum(n * e for n, e in tensors) * 4) / (
             max(params.codec_bw_GBps, 1e-6) * 1e3
         )
     return {

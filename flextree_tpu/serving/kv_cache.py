@@ -69,6 +69,7 @@ import jax
 import jax.numpy as jnp
 
 from ..models.configs import block_of, position_parts, slot_parts
+from ..ops.paged_attention import put_blocks, take_blocks
 
 __all__ = [
     "NULL_BLOCK",
@@ -351,8 +352,8 @@ def write_prefill_at(pools: dict, cache: dict, block_ids,
                 f"prefill cache holds {c.shape[1]} positions, blocks "
                 f"{start_block}..{start_block + n} need {s0 + n * bs}"
             )
-        return pool.at[idx].set(
-            c[0, s0 : s0 + n * bs].reshape(n, bs, *pool.shape[2:])
+        return put_blocks(
+            pool, idx, c[0, s0 : s0 + n * bs].reshape(n, bs, *pool.shape[2:])
         )
 
     return _map_pools(scatter, pools, cache)
@@ -381,7 +382,7 @@ def write_swapped(pools: dict, kv: dict, block_ids) -> dict:
                 f"swapped rows hold {a.shape[0]} positions, "
                 f"{n} blocks need {n * bs}"
             )
-        return pool.at[idx].set(a.reshape(n, bs, *pool.shape[2:]))
+        return put_blocks(pool, idx, a.reshape(n, bs, *pool.shape[2:]))
 
     return _map_pools(scatter, pools, kv)
 
@@ -493,7 +494,10 @@ def export_blocks(pools: dict, block_ids) -> dict:
     transfer itself.
     """
     idx = jnp.asarray(block_ids, jnp.int32)
-    return {part: [p[idx] for p in layers] for part, layers in pools.items()}
+    return {
+        part: [take_blocks(p, idx) for p in layers]
+        for part, layers in pools.items()
+    }
 
 
 def write_imported(pools: dict, kv: dict, block_ids) -> dict:
@@ -518,7 +522,7 @@ def write_imported(pools: dict, kv: dict, block_ids) -> dict:
                 f"imported rows shaped {tuple(a.shape)}, "
                 f"{n} blocks of {tuple(pool.shape[1:])} expected"
             )
-        return pool.at[idx].set(a)
+        return put_blocks(pool, idx, a)
 
     return _map_pools(scatter, pools, kv)
 
@@ -528,6 +532,9 @@ def gather_seq(pools: dict, block_ids, length: int | None = None) -> dict:
     ``(n_blocks*bs, *row)``, truncated to ``length`` if given."""
     idx = jnp.asarray(block_ids, jnp.int32)
     return {
-        part: [p[idx].reshape(-1, *p.shape[2:])[:length] for p in layers]
+        part: [
+            take_blocks(p, idx).reshape(-1, *p.shape[2:])[:length]
+            for p in layers
+        ]
         for part, layers in pools.items()
     }

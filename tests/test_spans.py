@@ -365,13 +365,68 @@ def _block_config(block):
         return None
     from tests.test_kimi_linear import tiny as kimi_tiny
     from tests.test_laguna import tiny as laguna_tiny
+    from tests.test_olmo_hybrid import tiny as olmo_tiny
     from tests.test_pangu_ultra_moe import tiny as pangu_tiny
 
     return {"laguna": laguna_tiny, "pangu": pangu_tiny,
-            "kimi": kimi_tiny}[block]()
+            "kimi": kimi_tiny, "olmo": olmo_tiny}[block]()
 
 
-@pytest.mark.parametrize("block", ["dense", "laguna", "pangu", "kimi"])
+#: what ``ft.engine.decode_dispatch`` and ``engine.report()`` say of each
+#: block's decode program on the CPU (no Pallas kernel runs there):
+#: (attention layers that read a paged pool, layers that hold a state a
+#: slot, parts a position, parts a slot)
+BLOCK_COUNTS = {
+    "dense": (2, 0, {"k", "v"}, set()),
+    "laguna": (None, 0, {"k", "v"}, set()),
+    "pangu": (None, 0, {"ckv"}, set()),
+    "kimi": (2, 6, {"ckv"}, {"s", "conv"}),
+    "olmo": (2, 6, {"k", "v"}, {"s", "conv"}),
+}
+
+
+@pytest.mark.parametrize("block", list(BLOCK_COUNTS))
+def test_every_blocks_dispatch_span_counts_its_layers_and_what_it_keeps(block):
+    """One round of each block under a recorder: the dispatch span carries
+    the program's own counts (attention layers and state layers, none of
+    either on a Pallas kernel on the CPU; the bytes a slot and a position
+    hold), the report says the same, and the engine holds the parts the
+    block's layout names: a state beside plain K and V rows in the
+    Olmo-Hybrid block, beside one latent row in the Kimi-Linear one."""
+    from flextree_tpu.serving import (
+        BatcherConfig, PagedCacheConfig, ServingEngine, costs,
+    )
+
+    config = _block_config(block)
+    if config is None:
+        eng = _engine()
+    else:
+        eng = ServingEngine.from_config(
+            config, PagedCacheConfig(num_blocks=40, block_size=4,
+                                     blocks_per_seq=8),
+            BatcherConfig(slots=2), seed=3)
+    attn, held, paged, slot = BLOCK_COUNTS[block]
+    assert set(eng.pools) == paged and set(eng.state) == slot
+    assert eng.submit(_request(1, 5, 3))
+    with flight_recorder(None) as rec:
+        eng.step()
+    [dispatch] = [e for e in _spans(rec)
+                  if e["name"] == "ft.engine.decode_dispatch"]
+    report = eng.report()
+    for key in ("attn_layers", "attn_kernel_layers", "state_layers",
+                "state_kernel_layers", "state_bytes_per_slot",
+                "cache_bytes_per_position"):
+        assert dispatch[key] == report[key], key
+    assert dispatch["attn_layers"] == (attn or eng.cfg.n_layers)
+    assert dispatch["state_layers"] == held
+    assert dispatch["attn_kernel_layers"] == dispatch["state_kernel_layers"] == 0
+    assert dispatch["state_bytes_per_slot"] == costs.state_bytes_per_slot(eng.cfg)
+    assert (dispatch["state_bytes_per_slot"] > 0) == bool(slot)
+    assert dispatch["cache_bytes_per_position"] == \
+        costs.cache_bytes_per_position(eng.cfg) > 0
+
+
+@pytest.mark.parametrize("block", ["dense", "laguna", "pangu", "kimi", "olmo"])
 def test_every_blocks_decode_program_is_named_paged_decode(block):
     """A profile names a program after the function ``jax.jit`` was
     handed (``jit_<name>``): a ``functools.partial`` has none and read
@@ -399,7 +454,7 @@ def test_every_blocks_decode_program_is_named_paged_decode(block):
     [name] = re.findall(r"module @(\w+)", text)
     assert name.startswith("jit_") and "paged_decode" in name
     assert "unknown" not in name
-    assert bool(carried) == (block == "kimi")
+    assert bool(carried) == (block in ("kimi", "olmo"))
     logits = jax.ShapeDtypeStruct((2, 128), jnp.float32)
     assert "module @jit_greedy_ids" in eng._greedy_ids.lower(logits).as_text()
     assert eng._prefill.__name__ == "prefill_program"
@@ -1066,7 +1121,8 @@ def test_new_metric_files_name_readers_that_exist():
         assert entry["source"] in ("program_span", "program_counter", "device_trace")
     # PR 36: four more medians of a span's duration, and the chain's six;
     # PR 37: the expert layers that run the grouped kernel
-    assert seen == {"spans": 35, "chain": 6}
+    # PR 38: two more scope shares (`attn.gdn_proj_share`, `attn.gdn_core_share`)
+    assert seen == {"spans": 37, "chain": 6}
 
 
 def test_the_chains_entries_are_the_serving_cells_and_move_the_rate():
@@ -1075,7 +1131,7 @@ def test_the_chains_entries_are_the_serving_cells_and_move_the_rate():
     bench = harness.load_benchmark()
     serving = [w["name"] for w in bench["workloads"]
                if "closed" in w["traffic"]]
-    assert len(serving) == 4
+    assert len(serving) == 5  # PR 38: the hybrid cell joins every list
     first = [e["name"] for e in bench["per_layer"]].index(GAP_METRICS[0])
     added = bench["per_layer"][first:first + 10]
     assert [e["name"] for e in added] == GAP_METRICS + [

@@ -690,3 +690,129 @@ def test_the_chunked_scan_compiles_at_the_longest_prompt(v5e, tpu_lowering):
         lambda *x: delta_rule_chunked(*x, chunk=64, sub=16),
         wide, wide, wide, wide, a(1, 4096, 32), a(1, 32, 128, 128))
     assert compiled.memory_analysis().temp_size_in_bytes < 0.9e9
+
+
+# ------------------------------------------- the state beside K and V rows
+
+
+def _olmo_cell():
+    """(configuration object, traffic, pool shape) of
+    ``olmo-hybrid-7b.longdoc-closed-c8``, from the benchmark's files."""
+    import json
+    import os
+
+    from flextree_tpu.models.configs import config_from_dict
+    from flextree_tpu.serving.kv_cache import PagedCacheConfig
+
+    root = os.path.join(os.path.dirname(os.path.dirname(__file__)), "benchmarks")
+    with open(os.path.join(root, "configs", "olmo-hybrid-7b.json")) as f:
+        cfg = config_from_dict(json.load(f))
+    with open(os.path.join(root, "traffic", "longdoc-closed-c8.json")) as f:
+        t = json.load(f)
+    return cfg, t, PagedCacheConfig(
+        t["num_blocks"], t["block_size"], t["blocks_per_seq"])
+
+
+def test_the_scan_with_a_decay_a_head_compiles_at_the_longest_prompt(
+    v5e, tpu_lowering
+):
+    """One linear layer's recurrence over 16,384 tokens, 30 heads with keys
+    of 96 and values of 192, in chunks of 64, 4,096 tokens a pass as the
+    block runs a long prompt: with one decay a head the pairwise decays are
+    a (64, 64) matrix a chunk (0.03 GB a pass), where the channel form's
+    (.., 16, 16, 96) array a sub-chunk would be 3.02 GB a prompt before its
+    products; the scan's temporaries stay far under that (all 16,384
+    tokens in one pass: 2.27 GB)."""
+    from flextree_tpu.ops.linear_attention import delta_rule_chunked
+
+    one = NamedSharding(_mesh(v5e[:1], (1, 1, 1)), P())
+    a = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32, sharding=one)  # noqa: E731
+    t, h, dk, dv = 4096, 30, 96, 192
+    keys = a(1, t, h, dk)
+    compiled = _compile(
+        lambda *x: delta_rule_chunked(*x, chunk=64),
+        keys, keys, a(1, t, h, dv), a(1, t, h), a(1, t, h), a(1, h, dk, dv))
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 0.7e9, f"{temp / 1e9:.2f} GB"
+
+
+def test_the_hybrid_cells_decode_program_walks_the_loop_and_updates_in_place(
+    v5e, tpu_lowering
+):
+    """The fused decode program at the cell's size.  30 K/V heads divide no
+    1,024 rows and heads of (96, 192) are no lane tiles, so neither Pallas
+    kernel admits: the program holds no Mosaic call at all, the two full
+    layers walk the table in the loop, the six linear layers run the
+    ``jnp`` update, and the block says so; every K and V pool and every
+    slot's state is aliased to its result and NONE is copied whole: the
+    v5e keeps a (529, 256, 30, 128) pool with the block-size axis next to
+    the lanes (30 heads would pad to 32), a gather or a scatter over it
+    would first copy it into row-major order and back (5.56 GB of
+    temporaries, 14.7 GB the program), and ``take_blocks`` / ``put_rows``
+    read and write it as it lies (temporaries of 15 MB)."""
+    import re
+
+    from flextree_tpu.models import olmo_hybrid as olmo
+    from flextree_tpu.models.configs import block_of
+    from flextree_tpu.serving.kv_cache import (
+        init_pools, init_state, make_paged_decode_fn,
+    )
+
+    cfg, t, pcfg = _olmo_cell()
+    slots = t["slots"]
+    assert block_of(cfg).kernel_layers(cfg, pcfg) == (2, 0)
+    assert block_of(cfg).state_kernel_layers(cfg) == (6, 0)
+    one = NamedSharding(_mesh(v5e[:1], (1, 1, 1)), P())
+    params = _on(jax.eval_shape(
+        lambda k: olmo.init_params(k, cfg), jax.random.PRNGKey(0)), one)
+    pools = _on(jax.eval_shape(lambda: init_pools(cfg, pcfg)), one)
+    state = _on(jax.eval_shape(lambda: init_state(cfg, slots)), one)
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one)  # noqa: E731
+    compiled = _compile(
+        make_paged_decode_fn(cfg, donate=True, fused=True), params, pools,
+        i32(slots, pcfg.blocks_per_seq), i32(slots), i32(slots), state)
+    hlo = compiled.as_text()
+    assert 'custom_call_target="tpu_custom_call"' not in hlo
+    assert hlo.count(" while(") >= 2
+    mem = compiled.memory_analysis()
+    carried = sum(
+        x.size * x.dtype.itemsize for x in jax.tree.leaves((pools, state)))
+    assert carried == 4 * 529 * 256 * 30 * 128 * 2 + slots * 13_685_760
+    assert carried <= mem.alias_size_in_bytes < 1.01 * carried
+    assert not re.findall(r"= bf16\[529,256,30,128\]\S* copy\(", hlo)
+    assert mem.temp_size_in_bytes < 0.1e9
+    whole = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert whole < 9.5e9, f"{whole / 1e9:.2f} GB"
+
+
+def test_the_hybrid_cells_longest_prefill_fits_beside_what_is_resident(
+    v5e, tpu_lowering
+):
+    """The 16,384-token prefill program (six chunked scans over 256 chunks,
+    two flash forwards over 30 heads) by the compiler's own memory
+    analysis, plus what the engine keeps resident meanwhile (pools and
+    state): under the chip's 16 GB.  This is where
+    a prompt's temporaries show before a chip run does."""
+    from flextree_tpu.models import olmo_hybrid as olmo
+    from flextree_tpu.serving.kv_cache import init_pools, init_state
+
+    cfg, t, pcfg = _olmo_cell()
+    one = NamedSharding(_mesh(v5e[:1], (1, 1, 1)), P())
+    params = _on(jax.eval_shape(
+        lambda k: olmo.init_params(k, cfg), jax.random.PRNGKey(0)), one)
+    compiled = _compile(
+        lambda p, tok: olmo.prefill(p, tok, cfg, max_len=pcfg.max_len),
+        params, jax.ShapeDtypeStruct((1, 16384), jnp.int32, sharding=one))
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 2
+    mem = compiled.memory_analysis()
+    program = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+               + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    pools = jax.eval_shape(lambda: init_pools(cfg, pcfg))
+    state = jax.eval_shape(lambda: init_state(cfg, t["slots"]))
+    resident = sum(
+        x.size * x.dtype.itemsize for x in jax.tree.leaves((pools, state)))
+    print(f"prefill program {program / 1e9:.2f} GB, temporaries "
+          f"{mem.temp_size_in_bytes / 1e9:.2f} GB")
+    assert program + resident < 14.5e9, (
+        f"{program / 1e9:.2f} GB program + {resident / 1e9:.2f} GB resident")
