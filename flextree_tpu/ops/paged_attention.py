@@ -21,9 +21,18 @@ from what it can observe (the backend it compiles for, and the shapes):
   are scalar-prefetched into SMEM, the pools stay in HBM, and every slot
   walks ITS OWN live blocks (for a window layer those that meet the
   window; none for an empty slot), each brought in once by its own DMA,
-  three chunks in flight across slot boundaries.  On the v5e it reads
-  the dense cell's live K/V at 725 GB/s and the Laguna cell's at 370 to
-  470 (PERF.md §6, PR 31; ROADMAP S1).
+  three chunks in flight across slot boundaries.  It reads a pool as the
+  device holds it, through a view that is a bitcast: ``(N, bs * Hkv,
+  D)``, a block's rows (position, head), where the K/V heads are a
+  multiple of 8; where they are not (30) XLA:TPU keeps the block-size
+  axis next to the lanes (:func:`pool_relayouts`) and the kernel takes
+  the pool TRANSPOSED, ``(N, Hkv * bs, D)``, a block's rows (head,
+  position), a few whole heads of one block a compute step.  One kernel
+  body serves both: only the two tables that say which query heads a row
+  serves and where it stands know the order.  On the v5e it reads the
+  dense cell's live K/V at 725 GB/s, the hybrid cell's 30-head pools at
+  728, and the Laguna cell's at 370 to 470 (PERF.md §6, PR 31, PR 39;
+  ROADMAP S1).
 - the ``fori_loop`` (``_stream_jnp``), everywhere else: the CPU backend
   (tier 1, the chaos drivers, an RPC replica on the CPU) and shapes the
   kernel refuses.  Its trip count is the *runtime* block frontier of the
@@ -156,7 +165,12 @@ def pool_relayouts(pool) -> bool:
     copies it WHOLE into row-major order and back (AOT compile for the v5e
     at (529, 256, 30, 128): 4.4 GB of copies a round, 5.6 GB of
     temporaries; PERF.md section 6, PR 38).  ``dynamic_slice`` and
-    ``dynamic_update_slice`` take the pool as it lies."""
+    ``dynamic_update_slice`` take the pool as it lies, and so does the
+    decode kernel: at a block size that is a whole number of sublane
+    tiles the device's order is ``transpose(pool, (0, 2, 1, 3))``
+    row-major, so that view (and its ``(N, Hkv * bs, D)`` reshape) is a
+    bitcast where the row-major one would be the copy
+    (``tests/test_tpu_aot.py`` holds both at the hybrid cell's shape)."""
     return (
         backend.kernel_platform() == "tpu" and pool.ndim == 4
         and pool.shape[2] % 8 != 0
@@ -315,9 +329,11 @@ def _stream_jnp(q, k_new, v_new, k_pool, v_pool, tables, lengths, scale,
 
 #: Rows of K (or V) one compute step of the kernel holds: a chunk of
 #: ``_CHUNK_ROWS // (bs * Hkv)`` blocks, 256 KB of bf16 at D = 128 in
-#: either cell (2 dense blocks of 16 x 32 rows, 8 Laguna blocks of 16 x 8).
-#: A step is a serial chain (product, softmax, product, rescale), so it
-#: has to be long enough to hide under its own DMAs.
+#: the two older cells (2 dense blocks of 16 x 32 rows, 8 Laguna blocks of
+#: 16 x 8); of a block longer than that, read head-major, the heads that
+#: come nearest (``_chunk_heads``: 5 of the hybrid cell's 30 heads of 256
+#: rows, 328 KB).  A step is a serial chain (product, softmax, product,
+#: rescale), so it has to be long enough to hide under its own DMAs.
 _CHUNK_ROWS = 1024
 #: Chunks of K and of V in VMEM at once; all but the one being read are
 #: in flight.  1.5 MB in flight covers the HBM's bandwidth x latency.
@@ -329,19 +345,42 @@ def _kernel_chunk(bs: int, hkv: int, p: int) -> int:
     return max(1, min(_CHUNK_ROWS // (bs * hkv), p))
 
 
+def _chunk_heads(bs: int, hkv: int) -> int:
+    """K/V heads of ONE block a compute step of the kernel takes where it
+    reads the block head-major, a head's ``bs`` rows together: the heads
+    over the whole chunks a block holds, rounded up, so every step is at
+    least a chunk and under two (30 heads of 256 rows hold 7 chunks: 5
+    heads a step, six steps a block); all the heads of a block that holds
+    under two."""
+    return -(-hkv // max(1, bs * hkv // _CHUNK_ROWS))
+
+
 def kernel_admits(q, k_pool) -> bool:
     """Whether Mosaic's tiling takes these shapes: a head dimension that
-    fills the 128 lanes, and blocks of whole tiles whose scores fill the
-    lanes too, a whole number of them a chunk.  Anything else (the CPU
-    tests' toy head dimensions) walks the table in ``_stream_jnp``."""
+    fills the 128 lanes, and chunks of whole tiles whose scores fill the
+    lanes too.  A pool the device holds as it is indexed (a K/V head count
+    that is a multiple of 8: :func:`pool_relayouts`) is read (position,
+    head): blocks of whole tiles, a whole number of them a chunk, whole
+    sublane tiles of query heads.  Any other head count is read (head,
+    position), as the v5e holds such a pool: a head's rows of a block have
+    to fill the lanes of the scores by themselves (a block size that is a
+    multiple of 128) and fit a chunk; the query heads are padded to whole
+    tiles.  Anything else (the CPU tests' toy head dimensions) walks the
+    table in ``_stream_jnp``."""
     bs, hkv, d = k_pool.shape[1:]
     rows = bs * hkv
+    if hkv % 8:
+        tiles = bs % 128 == 0 and bs <= _CHUNK_ROWS
+    else:
+        tiles = (
+            bs % 8 == 0
+            and rows % 128 == 0
+            and _CHUNK_ROWS % rows == 0
+            and q.shape[1] % 8 == 0
+        )
     return (
-        d % 128 == 0
-        and bs % 8 == 0
-        and rows % 128 == 0
-        and _CHUNK_ROWS % rows == 0
-        and q.shape[1] % 8 == 0
+        tiles
+        and d % 128 == 0
         and k_pool.dtype in (jnp.bfloat16, jnp.float32)
         and q.dtype == k_pool.dtype
     )
@@ -369,44 +408,63 @@ def _weighted_values(pr, v):
 
 def _decode_kernel(len_ref, tab_ref, q_ref, kn_ref, vn_ref, match_ref,
                    colpos_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sems, st,
-                   *, bs, cb, p, n_slots, window, scale, grouped):
+                   *, bs, cb, sub, p, n_slots, window, scale, grouped):
     """One grid step = one slot.  The slot walks ITS OWN live blocks (for
     a window layer those that meet the window; none for an empty slot),
-    ``cb`` a compute step, each block brought from the pool in HBM by its
-    own DMA, and folds them into the online softmax; then the new token.
+    each brought from the pool in HBM by its own DMAs, and folds them into
+    the online softmax; then the new token.  A compute step takes ``cb``
+    blocks, or one of the ``sub`` parts of ONE block (a block longer than
+    a chunk: never both).
 
     The DMAs run ahead of the compute ACROSS slots: ``st`` (SMEM) holds
-    the walk's issue cursor (slot, column, chunks issued) and the count
-    of chunks consumed, so the first chunk of a slot is already in VMEM
-    when its grid step starts.
+    the walk's issue cursor (slot, part of a table column, chunks issued)
+    and the count of chunks consumed, so the first chunk of a slot is
+    already in VMEM when its grid step starts.
 
-    A chunk is ``cb * bs * Hkv`` rows ``(position, K/V head)`` of K and
-    of V, as the pool holds them.  Every query head is multiplied with
-    every row (one MXU product, ``(H, D) x (rows, D)``), and ``match``
-    keeps, for query head ``j``, the rows of K/V head ``j // (H // Hkv)``;
-    the other products are masked like a position past ``length``.  The
-    pool's layout puts a position's heads side by side, and the MXU is
-    idle in a decode round: wasting its products costs less than moving
-    the rows.
+    A chunk is rows of K and of V as the pool's view holds them: (position,
+    K/V head) of whole blocks, or (K/V head, position) of some heads of
+    one.  Every query head is multiplied with every row (one MXU product,
+    ``(H, D) x (rows, D)``), and ``match`` keeps, for query head ``j``,
+    the rows of K/V head ``j // (H // Hkv)``; the other products are
+    masked like a position past ``length``, so a head outside the chunk
+    passes through the step unchanged.  Only ``match`` and ``colpos`` (a
+    row's position in the chunk) know the order.  The MXU is idle in a
+    decode round: wasting its products costs less than moving the rows.
     """
     s = pl.program_id(0)
     nbuf = kbuf.shape[0]
     rows = k_hbm.shape[1]  # of one block
+    piece = kbuf.shape[1] // cb  # rows one DMA brings
     f32 = jnp.float32
 
     def walk(slot):
-        """First and end column of the slot's walk."""
+        """First and end part of the slot's walk (a table column, where a
+        block has one part)."""
         length = len_ref[slot]
         end = (length + (bs - 1)) // bs
-        if window is None:
-            return jnp.int32(0), end
-        return jnp.maximum(length - (window - 1), 0) // bs, end
+        first = jnp.int32(0)
+        if window is not None:
+            first = jnp.maximum(length - (window - 1), 0) // bs
+        return (first, end) if sub == 1 else (first * sub, end * sub)
 
-    def copies(blk, b, j):
-        dst = pl.ds(j * rows, rows)
+    def place(u):
+        """Table column and part of the block of the walk's part ``u``."""
+        if sub == 1:
+            return u, 0
+        return lax.div(u, jnp.int32(sub)), lax.rem(u, jnp.int32(sub))
+
+    def copies(blk, b, j, part):
+        k_src, v_src = k_hbm.at[blk], v_hbm.at[blk]
+        if sub > 1:
+            # where the parts do not divide a block its last part starts
+            # early: all DMAs are one size, and ``match`` drops the rows
+            # the part before it has served
+            src = pl.ds(jnp.minimum(part * piece, rows - piece), piece)
+            k_src, v_src = k_src.at[src], v_src.at[src]
+        dst = pl.ds(j * piece, piece)
         return (
-            pltpu.make_async_copy(k_hbm.at[blk], kbuf.at[b, dst], sems.at[0, b]),
-            pltpu.make_async_copy(v_hbm.at[blk], vbuf.at[b, dst], sems.at[1, b]),
+            pltpu.make_async_copy(k_src, kbuf.at[b, dst], sems.at[0, b]),
+            pltpu.make_async_copy(v_src, vbuf.at[b, dst], sems.at[1, b]),
         )
 
     def issue():
@@ -414,27 +472,28 @@ def _decode_kernel(len_ref, tab_ref, q_ref, kn_ref, vn_ref, match_ref,
         last = n_slots - 1
 
         def exhausted(c):
-            slot, col = c
-            return (slot < n_slots) & (col >= walk(jnp.minimum(slot, last))[1])
+            slot, u = c
+            return (slot < n_slots) & (u >= walk(jnp.minimum(slot, last))[1])
 
         def next_slot(c):
             slot = c[0] + 1
             return slot, walk(jnp.minimum(slot, last))[0]
 
-        slot, col = lax.while_loop(exhausted, next_slot, (st[0], st[1]))
+        slot, u = lax.while_loop(exhausted, next_slot, (st[0], st[1]))
         st[0] = slot
-        st[1] = col
+        st[1] = u
 
         @pl.when(slot < n_slots)
         def _():
             end = walk(slot)[1]
             b = lax.rem(st[2], jnp.int32(nbuf))
             for j in range(cb):
-                @pl.when(col + j < end)
+                @pl.when(u + j < end)
                 def _():
-                    for c in copies(tab_ref[slot * p + col + j], b, j):
+                    col, part = place(u + j)
+                    for c in copies(tab_ref[slot * p + col], b, j, part):
                         c.start()
-            st[1] = col + cb
+            st[1] = u + cb
             st[2] = st[2] + 1
 
     @pl.when(s == 0)
@@ -457,8 +516,9 @@ def _decode_kernel(len_ref, tab_ref, q_ref, kn_ref, vn_ref, match_ref,
     done = st[3]
     q = q_ref[0]  # (H, D)
     h, d = q.shape
-    match = match_ref[...] != 0  # (H, cb * rows)
-    colpos = colpos_ref[...]  # (1, cb * rows): position inside the chunk
+    # (sub * H, chunk rows): a part of a block has its own heads
+    match = match_ref[...] != 0 if sub == 1 else None
+    colpos = colpos_ref[...]  # (1, chunk rows): position inside the chunk
     precision = lax.Precision.HIGHEST if q.dtype == f32 else None
 
     def step(i, carry):
@@ -466,25 +526,29 @@ def _decode_kernel(len_ref, tab_ref, q_ref, kn_ref, vn_ref, match_ref,
         # into the buffer the step before this one read
         issue()
         b = lax.rem(done + i, jnp.int32(nbuf))
-        col0 = first + i * cb
+        u0 = first + i * cb
         for j in range(cb):
-            @pl.when(col0 + j < end)
+            @pl.when(u0 + j < end)
             def _():
-                for c in copies(0, b, j):
+                for c in copies(0, b, j, 0):
                     c.wait()
         sc = lax.dot_general(
             q, kbuf[b], (((1,), (1,)), ((), ())),
             preferred_element_type=f32, precision=precision,
-        )  # (H, cb * rows)
+        )  # (H, chunk rows)
         if not grouped:
             # the dense model rounds its scores to the compute type, as
             # cached_attention does
             sc = sc.astype(q.dtype).astype(f32)
+        col0, part = place(u0)
         kpos = col0 * bs + colpos
         seen = kpos < length
         if window is not None:
             seen = seen & (kpos > length - window)
-        valid = match & seen
+        mine = match
+        if sub > 1:
+            mine = match_ref[pl.ds(pl.multiple_of(part * h, 8), h), :] != 0
+        valid = mine & seen
         sc = jnp.where(valid, sc * scale, _NEG_INF)
         m_new = jnp.maximum(m, sc.max(axis=-1, keepdims=True))
         pr = jnp.where(valid, jnp.exp(sc - m_new), 0.0)
@@ -518,12 +582,18 @@ def _decode_kernel(len_ref, tab_ref, q_ref, kn_ref, vn_ref, match_ref,
 # calls eight times), not eight: lowering is paid by every process before
 # the persistent cache is asked, 0.6 s a kernel in the dense cell's set-up
 @functools.partial(
-    jax.jit, static_argnames=("scale", "window", "cb", "nbuf", "interpret")
+    jax.jit,
+    static_argnames=("scale", "window", "cb", "heads", "nbuf", "interpret"),
 )
 def _stream_kernel(q, k_new, v_new, k_pool, v_pool, tables, lengths, *,
-                   scale, window, cb, nbuf, interpret):
+                   scale, window, cb, heads, nbuf, interpret):
     """``cb`` blocks a compute step, ``nbuf`` chunks of K and of V in
-    VMEM."""
+    VMEM.  ``heads`` is the order the pools are read in, which the entry
+    takes from :func:`pool_relayouts`: ``None``, a block's rows (position,
+    K/V head) as a row-major pool holds them; a number, its rows (K/V
+    head, position) as the v5e holds a pool whose heads fill no sublane
+    tile, that many heads of ONE block a step (``cb`` is 1 where they are
+    not all of them).  Both views are bitcasts of what the device holds."""
     s, h, d = q.shape
     n, bs, hkv = k_pool.shape[:3]
     p = tables.shape[1]
@@ -532,32 +602,59 @@ def _stream_kernel(q, k_new, v_new, k_pool, v_pool, tables, lengths, *,
     if g > 1:
         k_new = jnp.repeat(k_new, g, axis=1)
         v_new = jnp.repeat(v_new, g, axis=1)
-    # the chunk's rows are (position, K/V head), a position's heads side
-    # by side: which query heads a row serves, and its position
-    row = np.arange(cb * rows)
-    match = (row[None, :] % hkv == (np.arange(h) // g)[:, None])
-    colpos = (row // hkv)[None, :]
-    slot_block = pl.BlockSpec((1, h, d), lambda i, *_: (i, 0, 0))
+    hp = -(-h // 8) * 8  # whole sublane tiles of query heads
+    if hp != h:
+        # rows of zeros that no K/V row matches: they see the new token's
+        # row alone, which is zeros too
+        q, k_new, v_new = (
+            jnp.pad(x, ((0, 0), (0, hp - h), (0, 0))) for x in (q, k_new, v_new)
+        )
+    head_major = heads is not None
+    if head_major:
+        k_pool, v_pool = (
+            jnp.transpose(x, (0, 2, 1, 3)) for x in (k_pool, v_pool)
+        )
+    else:
+        heads = hkv
+    sub = -(-hkv // heads)  # compute steps a block
+    if sub > 1 and cb > 1:
+        raise ValueError(f"{cb} blocks a step, each in {sub} parts")
+    piece = heads * bs  # rows one DMA brings: a block, or a part of one
+    # which query heads a row of the chunk serves, and its position in the
+    # chunk, from the order: the row's place in its DMA (``at``), its block
+    # in the chunk, and the part of the block the DMA brought
+    row = np.arange(cb * piece)
+    at = row % piece
+    head, pos = (at // bs, at % bs) if head_major else (at % hkv, at // hkv)
+    colpos = row // piece * bs + pos
+    # where the parts do not divide the heads the last one starts early,
+    # and serves only the heads the part before it has not
+    part = np.arange(sub)[:, None]
+    head = np.minimum(part * heads, hkv - heads) + head  # (sub, chunk rows)
+    match = (head >= part * heads)[:, None, :] & (
+        head[:, None, :] == (np.arange(hp) // g)[None, :, None]
+    )
+    slot_block = pl.BlockSpec((1, hp, d), lambda i, *_: (i, 0, 0))
     whole = lambda *shape: pl.BlockSpec(shape, lambda i, *_: (0,) * len(shape))  # noqa: E731
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(
-            _decode_kernel, bs=bs, cb=cb, p=p, n_slots=s, window=window,
-            scale=scale, grouped=g > 1,
+            _decode_kernel, bs=bs, cb=cb, sub=sub, p=p, n_slots=s,
+            window=window, scale=scale, grouped=g > 1,
         ),
-        out_shape=jax.ShapeDtypeStruct((s, h, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((s, hp, d), q.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(s,),
             in_specs=[
                 slot_block, slot_block, slot_block,
-                whole(h, cb * rows), whole(1, cb * rows),
+                whole(sub * hp, cb * piece), whole(1, cb * piece),
                 pl.BlockSpec(memory_space=pl.ANY),
                 pl.BlockSpec(memory_space=pl.ANY),
             ],
             out_specs=slot_block,
             scratch_shapes=[
-                pltpu.VMEM((nbuf, cb * rows, d), k_pool.dtype),
-                pltpu.VMEM((nbuf, cb * rows, d), v_pool.dtype),
+                pltpu.VMEM((nbuf, cb * piece, d), k_pool.dtype),
+                pltpu.VMEM((nbuf, cb * piece, d), v_pool.dtype),
                 pltpu.SemaphoreType.DMA((2, nbuf)),
                 pltpu.SMEM((4,), jnp.int32),
             ],
@@ -569,9 +666,11 @@ def _stream_kernel(q, k_new, v_new, k_pool, v_pool, tables, lengths, *,
         interpret=interpret,
     )(
         lengths, tables.reshape(-1), q, k_new, v_new,
-        jnp.asarray(match, jnp.int32), jnp.asarray(colpos, jnp.int32),
+        jnp.asarray(match.reshape(sub * hp, -1), jnp.int32),
+        jnp.asarray(colpos[None, :], jnp.int32),
         k_pool.reshape(n, rows, d), v_pool.reshape(n, rows, d),
     )
+    return out if hp == h else out[:, :h]
 
 
 # ------------------------------------------------------------------- entry
@@ -636,10 +735,12 @@ def paged_attention(
     tables = jnp.asarray(tables, jnp.int32)
     lengths = jnp.asarray(lengths, jnp.int32)
     if impl == "pallas" or (impl is None and runs_kernel(q, k_pool)):
+        bs, hkv = k_pool.shape[1:3]
         return _stream_kernel(
             q, k_new, v_new, k_pool, v_pool, tables, lengths,
             scale=float(scale), window=window,
-            cb=_kernel_chunk(*k_pool.shape[1:3], tables.shape[1]),
+            cb=_kernel_chunk(bs, hkv, tables.shape[1]),
+            heads=_chunk_heads(bs, hkv) if pool_relayouts(k_pool) else None,
             nbuf=_CHUNKS_IN_VMEM,
             interpret=backend.pallas_interpret(interpret),
         )

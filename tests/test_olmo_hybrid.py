@@ -328,6 +328,29 @@ def test_requests_through_the_engine_follow_the_reference_greedily():
         assert np.array_equal(np.argmax(np.asarray(want), -1), tokens)
 
 
+def test_the_full_layers_decode_through_the_kernel_head_major(monkeypatch):
+    """What the cell's decode program runs on a TPU, here under the
+    interpreter: both full layers walk their pools in the paged kernel,
+    which reads the 3 K/V heads' blocks (head, position), as the v5e holds
+    a pool whose heads fill no sublane tile, and the round's rows go in by
+    ``put_rows``' slices; every request returns the tokens the loop
+    returns."""
+    from functools import partial
+
+    config = tiny()
+    prompts = _prompts(3, length=11)
+    want = [_alone(config, p, 6) for p in prompts]
+    monkeypatch.setattr(pa, "pool_relayouts", lambda pool: pool.ndim == 4)
+    monkeypatch.setattr(
+        olmo, "paged_attention", partial(pa.paged_attention, impl="pallas"))
+    eng = engine(config, slots=3)
+    for i, p in enumerate(prompts):
+        assert eng.submit(Request(rid=i, prompt=p, max_new_tokens=6))
+    eng.run_until_idle()
+    for i, tokens in enumerate(want):
+        assert np.array_equal(eng.completed[i].tokens, tokens)
+
+
 # ----------------------------------------- (c) a slot's state, admission
 
 
@@ -592,28 +615,39 @@ def test_spans_and_the_report_carry_the_states_and_the_kernels_numbers():
         assert e["state_kernel_layers"] == 0 == eng.report()["state_kernel_layers"]
         assert e["cache_bytes_per_position"] == 2 * 2 * 3 * 16 * 4
         assert e["attn_layers"] == 2 == eng.report()["attn_layers"]
+        # this engine runs on the CPU, where every full layer walks the
+        # loop (and heads of 16 fill no lanes): on a TPU at the published
+        # heads and a block of 256 the same two keys say 2 of 2 (below)
         assert e["attn_kernel_layers"] == 0 == eng.report()["attn_kernel_layers"]
     assert [e["state_slots_live"] for e in named("ft.engine.bookkeeping")] == [3, 3]
     assert [e["state_bytes"] for e in named("ft.engine.prefill")] == [per_slot] * 3
 
 
 @pytest.mark.parametrize("platform", ["tpu", "cpu"])
-def test_the_block_says_that_neither_kernel_admits_the_published_shapes(
+def test_the_block_says_which_kernel_admits_the_published_shapes(
     monkeypatch, platform
 ):
-    """30 K/V heads divide no 1,024 rows whatever the block size, and heads
-    of (96, 192) over 30 are no lane tiles: 0 of 2 and 0 of 6, on a TPU
-    too; a head count and widths the kernels admit are counted."""
+    """30 K/V heads fill no sublane tile, so on a TPU the paged kernel
+    reads their pools (head, position), as the v5e holds them, a few
+    heads of ONE block a step.  It admits a block whose head tile, ``bs``
+    rows of 128, fills the lanes of the scores by itself and fits a chunk:
+    a block size that is a multiple of 128 up to 1,024 (the cell's 256: 2
+    of 2); at 16 or 32 positions a head's rows are an eighth or a quarter
+    of the lanes, at 192 a tile and a half, at 2,048 two chunks: the loop,
+    0 of 2, as everywhere on the CPU.  Heads of (96, 192) over 30 are no
+    lane tiles: the state kernel 0 of 6 on both; a head count and widths
+    both kernels admit are counted."""
     monkeypatch.setattr(backend, "kernel_platform", lambda: platform)
+    took = platform == "tpu"
     cfg = config_from_dict(PUBLISHED)
     block = BLOCKS["olmo_hybrid"]
-    for bs in (16, 32, 256):
+    for bs, admitted in ((16, False), (32, False), (128, True), (192, False),
+                         (256, True), (1024, True), (2048, False)):
         pcfg = PagedCacheConfig(num_blocks=529, block_size=bs, blocks_per_seq=66)
-        assert block.kernel_layers(cfg, pcfg) == (2, 0)
+        assert block.kernel_layers(cfg, pcfg) == (2, 2 * (admitted and took)), bs
     assert block.state_kernel_layers(cfg) == (6, 0)
     lanes = dataclasses.replace(
         cfg, gdn_heads=32, gdn_dk=128, gdn_dv=256, n_heads=32, n_kv_heads=32)
-    took = platform == "tpu"
     assert block.state_kernel_layers(lanes) == (6, 6 * took)
     assert block.kernel_layers(
         lanes, PagedCacheConfig(529, 16, 66)) == (2, 2 * took)
@@ -863,7 +897,9 @@ def _metric(name):
 def _trace_ctx(decode_ns=(0.0, 0.0), prefill_ns=0.0, window=1e9, device=True):
     """A made-up window: two decode rounds of 8 slots over 70,000 live
     positions whose program ran ``decode_ns`` each, one prefill of 16,384
-    and one of 2,048 tokens whose programs ran ``prefill_ns`` in all."""
+    and one of 2,048 tokens whose programs ran ``prefill_ns`` in all; the
+    rounds state what the cell's engine states on a TPU (both full layers
+    in the paged kernel, no linear layer in the state kernel)."""
     from benchmarks.lib import xplane as X
     from benchmarks.lib.harness import ReaderContext
     from benchmarks.lib.peaks import Peaks
@@ -871,7 +907,7 @@ def _trace_ctx(decode_ns=(0.0, 0.0), prefill_ns=0.0, window=1e9, device=True):
     E = X.Event
     stated = {"state_bytes_per_slot": 13_685_760, "cache_bytes_per_position": 30_720,
               "state_layers": 6, "state_kernel_layers": 0, "attn_layers": 2,
-              "attn_kernel_layers": 0}
+              "attn_kernel_layers": 2}
     host = [
         E("bench_window", 0, window),
         E("ft.engine.prefill", 0.10 * window, 0.15 * window, {"prompt_len": 16384}),
@@ -946,5 +982,6 @@ def test_the_shares_read_what_the_program_states():
     for name, want in (("attn.gdn_core_share", 25.0), ("attn.gdn_proj_share", 50.0),
                        ("attn.full_share", 25.0)):
         assert S.scope_share(ctx, **_metric(name)["args"]) == pytest.approx(want)
-    for name in ("kernels.kda_kernel_share", "kernels.paged_kernel_share"):
-        assert S.count_ratio_p50(ctx, **_metric(name)["args"]) == 0.0
+    for name, want in (("kernels.kda_kernel_share", 0.0),
+                       ("kernels.paged_kernel_share", 100.0)):
+        assert S.count_ratio_p50(ctx, **_metric(name)["args"]) == want

@@ -188,16 +188,46 @@ def _grouped_case(lengths, g, hkv=2, d=16, bs=8, p=7, seed=3, chain=None):
 #: off (3, 17, 41) a block boundary; one slot in the table's last block
 RAGGED = (0, 3, 8, 0, 17, 41, 16, 48, 55, 0)
 
+#: the order the kernel reads a block's rows in, as (K/V heads, rows a
+#: chunk).  "rows": (position, head), the pool as it is indexed.  The
+#: others: (head, position), as the v5e holds a pool whose K/V heads fill
+#: no sublane tile, a block of 8 positions in several chunks: 6 heads in
+#: parts of 3 and 3; 10 heads in parts of 4, 4 and 2, where the last part
+#: starts two heads early and serves only its own two
+ORDERS = {"rows": (2, None), "heads-6": (6, 24), "heads-10": (10, 24),
+          "heads-30": (30, 1024)}
 
+
+@pytest.fixture
+def read_as(monkeypatch):
+    """``read_as(order)``: the K/V head count of the order, after making
+    the kernel read in it.  The interpreter has no device layout, so what
+    the entry observes (``pool_relayouts``) is replaced."""
+    def force(order):
+        hkv, chunk_rows = ORDERS[order]
+        if chunk_rows is not None:
+            monkeypatch.setattr(
+                paged_attention_mod, "pool_relayouts", lambda pool: True)
+            monkeypatch.setattr(paged_attention_mod, "_CHUNK_ROWS", chunk_rows)
+        return hkv
+
+    return force
+
+
+@pytest.mark.parametrize("order", ["rows", "heads-6", "heads-10"])
 @pytest.mark.parametrize("window", [None, 5, 24, 200],
                          ids=["full", "w5", "w24", "wider-than-any-row"])
 @pytest.mark.parametrize("g", [1, 6, 9])
-def test_kernel_grouped_queries_and_windows(g, window):
+def test_kernel_grouped_queries_and_windows(g, window, order, read_as):
     """What the old kernel refused: 6 and 9 queries a K/V head, a window
     narrower and wider than the longest row, empty slots, a slot at the
     table's last block, lengths on and off a block boundary, in one
-    batch; every slot walks its own blocks."""
-    args = _grouped_case(RAGGED, g)
+    batch; every slot walks its own blocks.  And what the kernel after it
+    refused: a K/V head count that is a multiple neither of 8 nor of a
+    chunk's heads, read head-major, a block in several compute steps."""
+    hkv = read_as(order)
+    assert order == "rows" or paged_attention_mod._chunk_heads(8, hkv) in (3, 4)
+    args = _grouped_case(RAGGED, g, hkv=hkv)
     ref = paged_attention_gather(*args, window=window)
     out = paged_attention(*args, impl="pallas", window=window)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
@@ -207,14 +237,17 @@ def test_kernel_grouped_queries_and_windows(g, window):
                                atol=FUSED_DECODE_ATOL, rtol=0)
 
 
+@pytest.mark.parametrize("order", ["rows", "heads-10"])
 @pytest.mark.parametrize("window", [None, 12])
-def test_kernel_reads_only_its_own_live_blocks_bitwise(window):
+def test_kernel_reads_only_its_own_live_blocks_bitwise(window, order, read_as):
     """No slot pays for another: blocks a slot holds past its length, the
     blocks behind its window, the null block and every block of the pool
     that no table names can hold anything — the output does not move by
-    a bit, because the kernel never brings them in."""
+    a bit, because the kernel never brings them in; nor does it for what
+    the unwritten tail of a slot's last block holds, which it does bring
+    in and masks."""
     lengths = (0, 3, 8, 17, 41, 30)
-    args = _grouped_case(lengths, 3, chain=(0, 2, 3))
+    args = _grouped_case(lengths, 3, hkv=read_as(order), chain=(0, 2, 3))
     q, kn, vn, kp, vp, tables, lens = args
     tab = np.asarray(tables)
     live = set()
@@ -224,8 +257,12 @@ def test_kernel_reads_only_its_own_live_blocks_bitwise(window):
     dead = np.array(sorted(set(range(kp.shape[0])) - live))
     assert NULL_BLOCK in dead and len(dead) > len(live)
     a = paged_attention(*args, impl="pallas", window=window)
-    b = paged_attention(q, kn, vn, kp.at[dead].set(1e30),
-                        vp.at[dead].set(jnp.nan), tables, lens,
+    kp, vp = kp.at[dead].set(1e30), vp.at[dead].set(jnp.nan)
+    for i, length in enumerate(lengths):
+        if length % 8:  # positions length.. of the block the write lands in
+            kp = kp.at[tab[i, length // 8], length % 8:].set(1e30)
+            vp = vp.at[tab[i, length // 8], length % 8:].set(1e30)
+    b = paged_attention(q, kn, vn, kp, vp, tables, lens,
                         impl="pallas", window=window)
     np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     np.testing.assert_allclose(
@@ -233,19 +270,52 @@ def test_kernel_reads_only_its_own_live_blocks_bitwise(window):
         atol=FUSED_DECODE_ATOL, rtol=0)
 
 
-def test_kernel_chunks_several_blocks_a_step(monkeypatch):
+@pytest.mark.parametrize("order", ["rows", "heads-6"])
+def test_kernel_chunks_several_blocks_a_step(monkeypatch, order, read_as):
     """The cells' shapes put 2 and 8 blocks in a compute step and keep 4
     chunks in VMEM; the toy shapes put the whole row in one.  Shrink the
     chunk so that a row is several steps with a ragged last one, and the
-    ring of buffers wraps inside a row and across rows."""
-    args = _grouped_case(RAGGED, 6)
+    ring of buffers wraps inside a row and across rows.  Head-major, 48
+    rows a block: a block in three steps, in one, and two blocks a step."""
+    args = _grouped_case(RAGGED, 6, hkv=read_as(order))
     ref = paged_attention_gather(*args, window=24)
-    for rows, nbuf in ((32, 2), (48, 3), (16, 4)):
+    sizes = {"rows": ((32, 2), (48, 3), (16, 4)),
+             "heads-6": ((16, 2), (48, 3), (96, 4))}[order]
+    for rows, nbuf in sizes:
         monkeypatch.setattr(paged_attention_mod, "_CHUNK_ROWS", rows)
         monkeypatch.setattr(paged_attention_mod, "_CHUNKS_IN_VMEM", nbuf)
         out = paged_attention(*args, impl="pallas", window=24)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=FUSED_DECODE_ATOL, rtol=0)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_kernel_walks_thirty_heads_of_128_in_parts_of_a_block(dtype, read_as):
+    """The hybrid cell's heads at the module's own chunk: 30 K/V heads of
+    128 under 30 query heads (padded to 32 rows for the kernel, dropped
+    after), a block of 128 positions = 3,840 rows read head-major in three
+    parts of 10 heads; an empty slot, a short one, one block exactly and
+    one long among them; the null block and every unwritten tail hold
+    1e30."""
+    bs, hkv, lengths = 128, read_as("heads-30"), (0, 3, 128, 300)
+    assert paged_attention_mod._chunk_heads(bs, hkv) == 10
+    rng = np.random.default_rng(7)
+    f = lambda *shape: jnp.asarray(rng.standard_normal(shape), dtype)  # noqa: E731
+    q, kn, vn = f(4, hkv, 128), f(4, hkv, 128), f(4, hkv, 128)
+    kp, vp = np.array(f(8, bs, hkv, 128)), np.array(f(8, bs, hkv, 128))
+    tables = np.zeros((4, 3), np.int32)
+    tables[1, :1], tables[2, :2], tables[3, :3] = [5], [2, 7], [6, 1, 3]
+    for pool in (kp, vp):
+        pool[NULL_BLOCK] = 1e30
+        for i, length in enumerate(lengths):
+            pool[tables[i, length // bs], length % bs:] = 1e30
+    args = (q, kn, vn, jnp.asarray(kp), jnp.asarray(vp), jnp.asarray(tables),
+            jnp.asarray(lengths, jnp.int32))
+    ref = paged_attention_gather(*args).astype(jnp.float32)
+    out = paged_attention(*args, impl="pallas").astype(jnp.float32)
+    tol = FUSED_DECODE_ATOL if dtype == jnp.float32 else 0.05
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=tol,
+                               rtol=0)
 
 
 def test_shape_validation_is_loud():
@@ -423,7 +493,18 @@ def test_on_a_tpu_the_eligible_shapes_take_the_kernel_in_every_layer(
     ((4, 32, 128), jnp.bfloat16, False),   # a block of 4 positions
     ((8, 8, 128), jnp.bfloat16, False),    # a block of 64 rows: half a tile of scores
     ((16, 128, 128), jnp.bfloat16, False), # a block past the chunk
-], ids=["dense", "laguna", "f32", "d64", "bs4", "rows64", "rows2048"])
+    # K/V heads that fill no sublane tile: read (head, position), as the
+    # v5e holds such a pool, any number of heads in parts of a block
+    ((256, 30, 128), jnp.bfloat16, True),  # the hybrid cell
+    ((128, 6, 128), jnp.float32, True),
+    ((1024, 7, 128), jnp.bfloat16, True),  # a head's rows: a whole chunk
+    ((16, 30, 128), jnp.bfloat16, False),  # a head's 16 rows: an eighth of the lanes
+    ((192, 30, 128), jnp.bfloat16, False), # a tile and a half of scores
+    ((2048, 30, 128), jnp.bfloat16, False),# a head's rows past the chunk
+    ((256, 30, 64), jnp.bfloat16, False),
+], ids=["dense", "laguna", "f32", "d64", "bs4", "rows64", "rows2048",
+        "hybrid", "heads6-f32", "heads7-bs1024", "heads30-bs16",
+        "heads30-bs192", "heads30-bs2048", "heads30-d64"])
 def test_kernel_admits_what_its_tiling_takes(shape, dtype, admitted, monkeypatch):
     pool = jax.ShapeDtypeStruct((9, *shape), dtype)
     q = jax.ShapeDtypeStruct((2, 2 * shape[1] if shape[1] == 8 else shape[1],

@@ -21,7 +21,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from flextree_tpu.models.transformer import TransformerConfig, init_params
 from flextree_tpu.ops.paged_attention import (
-    paged_attention, paged_attention_latent,
+    kernel_admits, paged_attention, paged_attention_latent,
 )
 from flextree_tpu.ops.pallas_attention import flash_attention, kvgrid_tiles
 from flextree_tpu.utils import backend
@@ -130,13 +130,14 @@ def _paged_avals(dev, s, h, hkv, d, bs, p, n, dtype=jnp.bfloat16):
             a(n, bs, hkv, d), a(s, p, dt=jnp.int32), a(s, dt=jnp.int32))
 
 
-#: the two serving cells' decode shapes (S, H, Hkv, D, bs, P, N), window
+#: the three serving cells' decode shapes (S, H, Hkv, D, bs, P, N), window
 CELL_DECODE = {
     "dense": ((32, 32, 32, 128, 16, 48, 1537), None),
     "laguna-48-full": ((64, 48, 8, 128, 16, 96, 6145), None),
     "laguna-48-window": ((64, 48, 8, 128, 16, 96, 6145), 512),
     "laguna-72-full": ((64, 72, 8, 128, 16, 96, 6145), None),
     "laguna-72-window": ((64, 72, 8, 128, 16, 96, 6145), 512),
+    "hybrid": ((8, 30, 30, 128, 256, 66, 529), None),
 }
 
 
@@ -144,11 +145,15 @@ CELL_DECODE = {
 def test_paged_decode_is_one_mosaic_kernel_that_copies_no_pool(
     v5e, tpu_lowering, cell
 ):
-    """Mosaic takes the kernel at both cells' exact shapes (grouped
+    """Mosaic takes the kernel at the cells' exact shapes (grouped
     queries and a window among them), one ``tpu_custom_call`` a call; the
     pools go in as they are (the ``(N, bs * Hkv, D)`` view is a bitcast
     under the (8, 128)(2, 1) tiling, not a 201 MB copy); and nothing is
-    left of the loop over table columns."""
+    left of the loop over table columns.  The hybrid cell's 30 K/V heads
+    fill no sublane tile, so the v5e holds its pools with the block-size
+    axis next to the lanes (``{3,1,2,0}``): the kernel takes them
+    transposed, ``(N, Hkv * bs, D)``, which is that very order and a
+    bitcast again, where the row-major view would copy 1.04 GB a pool."""
     import re
 
     shape, window = CELL_DECODE[cell]
@@ -156,6 +161,36 @@ def test_paged_decode_is_one_mosaic_kernel_that_copies_no_pool(
         lambda *a: paged_attention(*a, window=window),
         *_paged_avals(v5e[0], *shape),
     ).as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 1
+    n = shape[-1]
+    assert not re.search(rf"= \w+\[{n},[\d,]*\]\S* copy\(", hlo)
+    assert len(re.findall(rf"= bf16\[{n},\d+,128\]\S* bitcast\(", hlo)) == 2
+    assert " while(" not in hlo
+
+
+@pytest.mark.parametrize("shape,window", [
+    ((4, 12, 6, 128, 128, 8, 33), None),      # grouped queries, a block a step
+    ((4, 12, 6, 128, 128, 8, 33), 200),
+    ((8, 30, 30, 128, 128, 66, 512), None),   # as many blocks as sublanes like
+    ((8, 7, 7, 128, 1024, 17, 133), None),    # ONE head a step, seven a block
+    ((8, 30, 30, 128, 1024, 17, 133), None),
+], ids=["grouped-6", "grouped-6-window", "bs128", "heads7-bs1024", "bs1024"])
+def test_what_the_kernel_admits_head_major_mosaic_takes_as_it_lies(
+    v5e, tpu_lowering, shape, window
+):
+    """``kernel_admits`` promises for every K/V head count that fills no
+    sublane tile what the hybrid cell's case above shows for 30 at a block
+    of 256: Mosaic takes the kernel (aligned slices of the view, VMEM for
+    four chunks of under two times 1,024 rows and the ``match`` table),
+    and the transposed view is the order the v5e chose for the pool, a
+    bitcast, also where the blocks axis would pad as little as the
+    block-size axis."""
+    import re
+
+    avals = _paged_avals(v5e[0], *shape)
+    assert kernel_admits(avals[0], avals[3])
+    hlo = _compile(
+        lambda *a: paged_attention(*a, window=window), *avals).as_text()
     assert hlo.count('custom_call_target="tpu_custom_call"') == 1
     n = shape[-1]
     assert not re.search(rf"= \w+\[{n},[\d,]*\]\S* copy\(", hlo)
@@ -736,20 +771,20 @@ def test_the_scan_with_a_decay_a_head_compiles_at_the_longest_prompt(
     assert temp < 0.7e9, f"{temp / 1e9:.2f} GB"
 
 
-def test_the_hybrid_cells_decode_program_walks_the_loop_and_updates_in_place(
+def test_the_hybrid_cells_decode_program_walks_its_pools_in_the_kernel_in_place(
     v5e, tpu_lowering
 ):
-    """The fused decode program at the cell's size.  30 K/V heads divide no
-    1,024 rows and heads of (96, 192) are no lane tiles, so neither Pallas
-    kernel admits: the program holds no Mosaic call at all, the two full
-    layers walk the table in the loop, the six linear layers run the
-    ``jnp`` update, and the block says so; every K and V pool and every
-    slot's state is aliased to its result and NONE is copied whole: the
-    v5e keeps a (529, 256, 30, 128) pool with the block-size axis next to
-    the lanes (30 heads would pad to 32), a gather or a scatter over it
-    would first copy it into row-major order and back (5.56 GB of
-    temporaries, 14.7 GB the program), and ``take_blocks`` / ``put_rows``
-    read and write it as it lies (temporaries of 15 MB)."""
+    """The fused decode program at the cell's size.  The two full layers
+    walk their 30-head pools in the paged Mosaic kernel (two
+    ``tpu_custom_call``s, nothing left of the loop over table columns),
+    heads of (96, 192) are no lane tiles, so the six linear layers run
+    the ``jnp`` update, and the block says both; every K and V pool and
+    every slot's state is aliased to its result and NONE is copied whole:
+    the v5e keeps a (529, 256, 30, 128) pool with the block-size axis next
+    to the lanes (30 heads would pad to 32), a gather, a scatter or a
+    row-major view of it would first copy it into row-major order (5.56
+    GB of temporaries, 14.7 GB the program); ``put_rows`` writes it as it
+    lies and the kernel reads it transposed, which is how it lies."""
     import re
 
     from flextree_tpu.models import olmo_hybrid as olmo
@@ -760,7 +795,7 @@ def test_the_hybrid_cells_decode_program_walks_the_loop_and_updates_in_place(
 
     cfg, t, pcfg = _olmo_cell()
     slots = t["slots"]
-    assert block_of(cfg).kernel_layers(cfg, pcfg) == (2, 0)
+    assert block_of(cfg).kernel_layers(cfg, pcfg) == (2, 2)
     assert block_of(cfg).state_kernel_layers(cfg) == (6, 0)
     one = NamedSharding(_mesh(v5e[:1], (1, 1, 1)), P())
     params = _on(jax.eval_shape(
@@ -772,14 +807,14 @@ def test_the_hybrid_cells_decode_program_walks_the_loop_and_updates_in_place(
         make_paged_decode_fn(cfg, donate=True, fused=True), params, pools,
         i32(slots, pcfg.blocks_per_seq), i32(slots), i32(slots), state)
     hlo = compiled.as_text()
-    assert 'custom_call_target="tpu_custom_call"' not in hlo
-    assert hlo.count(" while(") >= 2
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 2
+    assert " while(" not in hlo
     mem = compiled.memory_analysis()
     carried = sum(
         x.size * x.dtype.itemsize for x in jax.tree.leaves((pools, state)))
     assert carried == 4 * 529 * 256 * 30 * 128 * 2 + slots * 13_685_760
     assert carried <= mem.alias_size_in_bytes < 1.01 * carried
-    assert not re.findall(r"= bf16\[529,256,30,128\]\S* copy\(", hlo)
+    assert not re.findall(r"= bf16\[529,[\d,]*\]\S* copy\(", hlo)
     assert mem.temp_size_in_bytes < 0.1e9
     whole = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
