@@ -1,5 +1,5 @@
 """Model substrate the collectives serve: dense transformer LM + MoE LM,
-and the five served blocks that ``models.configs.BLOCKS`` chooses among by
+and the six served blocks that ``models.configs.BLOCKS`` chooses among by
 ``model_type``: ``transformer`` (dense), ``laguna`` (window + full
 grouped-query attention, softmax-routed experts), ``pangu_ultra_moe``
 (latent attention, sigmoid-routed experts, sandwich norms),
@@ -7,7 +7,9 @@ grouped-query attention, softmax-routed experts), ``pangu_ultra_moe``
 state a slot, beside NoPE latent-attention layers that cache a row a
 position) and ``olmo_hybrid`` (delta-rule layers with a decay a head and
 keys narrower than values, beside full-attention layers that cache plain K
-and V; a sublayer's output is normed).  What each keeps is its layout, a layer at a time: parts a
+and V; a sublayer's output is normed) and ``sdar_moe`` (grouped-query
+attention and softmax-routed experts that generate by diffusion over
+blocks: a pass computes a block of positions a slot).  What each keeps is its layout, a layer at a time: parts a
 position, paged in blocks, and parts a slot, one array a sequence
 (``models.configs.pool_layout``)."""
 

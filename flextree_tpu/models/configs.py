@@ -5,12 +5,14 @@ the serving path chooses a model (``ServingEngine.from_config``, ``python
 
 :data:`BLOCKS` is the one table of what a block brings: its configuration
 object, seeded parameters, the two walks over its layers behind the
-signatures the engine calls, and the layout of what it keeps.  The five
+signatures the engine calls, and the layout of what it keeps.  The six
 blocks are ``models.transformer`` (``gpt_neox``: the dense block at those
 widths, see ``benchmarks/configs/pythia-*.json`` for what it departs in),
 ``models.laguna`` (``laguna``), ``models.pangu_ultra_moe``
-(``pangu_ultra_moe``), ``models.kimi_linear`` (``kimi_linear``) and
-``models.olmo_hybrid`` (``olmo_hybrid``).
+(``pangu_ultra_moe``), ``models.kimi_linear`` (``kimi_linear``),
+``models.olmo_hybrid`` (``olmo_hybrid``) and ``models.sdar_moe``
+(``sdar_moe``: generation by diffusion over blocks, a pass of B positions a
+slot, :attr:`Block.block_len`).
 
 **The layout is a layer's** (:func:`pool_layout`, one entry a layer):
 ``position`` names the parts the layer caches a POSITION and the shape of
@@ -42,6 +44,7 @@ from . import (
     laguna,
     olmo_hybrid as olmo,
     pangu_ultra_moe as pangu,
+    sdar_moe as sdar,
 )
 from .generate import paged_decode_dense, prefill_dense
 from .moe import expert_kernel_layers
@@ -81,6 +84,11 @@ class Block:
     # (cfg, slots) -> (expert layers of the decode program, those of them
     # whose grouped products run the Pallas kernel), fixed likewise
     expert_kernel_layers: Callable = lambda cfg, slots: (0, 0)
+    # cfg -> positions a pass of the decode program computes a slot: 1, a
+    # token a slot a round; B > 1, an open block of a model that generates
+    # by diffusion over blocks, whose ``decode_step`` then takes (S, B)
+    # tokens and an ``active`` (S,) mask (``models.sdar_moe.block_pass``)
+    block_len: Callable = lambda cfg: 1
 
 
 def _dense_from_dict(c: dict) -> TransformerConfig:
@@ -140,6 +148,12 @@ BLOCKS = {
         olmo.OlmoHybridConfig, olmo.config_from_dict, olmo.init_params,
         olmo.prefill, olmo.paged_decode_step, olmo.pool_layout,
         olmo.kernel_layers, olmo.state_kernel_layers,
+    ),
+    "sdar_moe": Block(
+        sdar.SdarMoeConfig, sdar.config_from_dict, sdar.init_params,
+        sdar.prefill, sdar.block_pass, sdar.pool_layout, sdar.kernel_layers,
+        expert_kernel_layers=sdar.expert_kernel_layers,
+        block_len=lambda cfg: cfg.block_len,
     ),
 }
 

@@ -449,12 +449,13 @@ def expert_layer(layer, flat, *, top_k: int, scale: float, normalize: bool,
     """A served expert layer on normed rows ``flat`` (N, d): the router
     over ALL the experts (``layer["router"]``), the part of the routed sum
     that the experts ``held`` here give (``layer["experts"]``), plus the
-    shared expert (``layer["shared"]``), ungated.  Returns ``(y, moe)``:
-    (N, d) in ``flat``'s dtype, and what the router did: ``scores`` (N, E)
-    f32, ``choices`` (N, k) int32, ``sizes`` (n_held,) picks of each held
-    expert.  ``rows`` (N,) bool: rows whose picks are dispatched and
-    counted (a decode round's inactive slots are not).  Scopes:
-    ``ft_moe_router``, ``ft_moe_experts``, ``ft_moe_shared``."""
+    shared expert (``layer["shared"]``), ungated, where the layer has one.
+    Returns ``(y, moe)``: (N, d) in ``flat``'s dtype, and what the router
+    did: ``scores`` (N, E) f32, ``choices`` (N, k) int32, ``sizes``
+    (n_held,) picks of each held expert.  ``rows`` (N,) bool: rows whose
+    picks are dispatched and counted (a decode round's inactive slots are
+    not).  Scopes: ``ft_moe_router``, ``ft_moe_experts``,
+    ``ft_moe_shared`` (none without a shared expert)."""
     with jax.named_scope("ft_moe_router"):
         scores, choices, weights = route_topk_normalized(
             flat, layer["router"], top_k, scale, normalize, score
@@ -463,6 +464,10 @@ def expert_layer(layer, flat, *, top_k: int, scale: float, normalize: bool,
         routed, sizes = dropless_experts(
             flat, choices, weights, layer["experts"], held, rows
         )
+        if "shared" not in layer:
+            return routed.astype(flat.dtype), {
+                "scores": scores, "choices": choices, "sizes": sizes,
+            }
     with jax.named_scope("ft_moe_shared"):
         shared = gated_ffn(layer["shared"], flat)
         y = (routed + shared.astype(jnp.float32)).astype(flat.dtype)

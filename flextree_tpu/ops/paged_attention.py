@@ -23,9 +23,10 @@ from what it can observe (the backend it compiles for, and the shapes):
   window; none for an empty slot), each brought in once by its own DMA,
   three chunks in flight across slot boundaries.  It reads a pool as the
   device holds it, through a view that is a bitcast: ``(N, bs * Hkv,
-  D)``, a block's rows (position, head), where the K/V heads are a
-  multiple of 8; where they are not (30) XLA:TPU keeps the block-size
-  axis next to the lanes (:func:`pool_relayouts`) and the kernel takes
+  D)``, a block's rows (position, head), where the K/V heads fill a
+  sublane tile (a multiple of 8, or 1, 2, 4); where they do not (30)
+  XLA:TPU keeps the block-size axis next to the lanes
+  (:func:`pool_relayouts`) and the kernel takes
   the pool TRANSPOSED, ``(N, Hkv * bs, D)``, a block's rows (head,
   position), a few whole heads of one block a compute step.  One kernel
   body serves both: only the two tables that say which query heads a row
@@ -86,6 +87,7 @@ __all__ = [
     "paged_attention_latent",
     "paged_attention_latent_gather",
     "latent_kernel_admits",
+    "heads_fill_a_tile",
     "runs_kernel",
     "runs_latent_kernel",
     "pool_relayouts",
@@ -156,21 +158,52 @@ def paged_attention_gather(q, k_new, v_new, k_pool, v_pool, tables, lengths,
 # ------------------------------------------------------------ jnp streaming
 
 
+def heads_fill_a_tile(hkv: int) -> bool:
+    """Whether the v5e tiles a pool's (K/V head, head dim) rows as they
+    are indexed: a head count that is whole 8-row sublane tiles, or one
+    that a smaller tile the device takes for it holds whole (1, 2, 4: in
+    the AOT compile for the v5e a (1025, 128, 4, 128) bf16 pool is
+    ``{3,2,1,0:T(4,128)(2,1)}``, a 2-head one ``T(2,128)``, row-major, and
+    a 1-head one's heads axis orders nothing; ``tests/test_tpu_aot.py``
+    holds all three).  Any other count (6, 7, 30) pads to no tile of its
+    own, and the device puts another axis next to the lanes
+    (:func:`pool_relayouts`)."""
+    return hkv % 8 == 0 or 8 % hkv == 0
+
+
 def pool_relayouts(pool) -> bool:
     """Whether XLA:TPU holds this K/V pool (N, bs, Hkv, D) in another order
     than it is indexed in.  The device keeps an array with the axis that
-    pads least to the sublane tile next to the lanes: for a head count that
-    is no multiple of 8 (30 -> 32) that is the block-size or the blocks
-    axis, not the heads', and a gather or a scatter over such a pool first
-    copies it WHOLE into row-major order and back (AOT compile for the v5e
-    at (529, 256, 30, 128): 4.4 GB of copies a round, 5.6 GB of
-    temporaries; PERF.md section 6, PR 38).  ``dynamic_slice`` and
-    ``dynamic_update_slice`` take the pool as it lies, and so does the
-    decode kernel: at a block size that is a whole number of sublane
-    tiles the device's order is ``transpose(pool, (0, 2, 1, 3))``
-    row-major, so that view (and its ``(N, Hkv * bs, D)`` reshape) is a
-    bitcast where the row-major one would be the copy
-    (``tests/test_tpu_aot.py`` holds both at the hybrid cell's shape)."""
+    pads least to the sublane tile next to the lanes: for a head count
+    whose rows fill no tile (:func:`heads_fill_a_tile`; 30 -> 32) that is
+    the block-size or the blocks axis, not the heads', and a gather or a
+    scatter over such a pool first copies it WHOLE into row-major order
+    and back (AOT compile for the v5e at (529, 256, 30, 128): 4.4 GB of
+    copies a round, 5.6 GB of temporaries; PERF.md section 6).
+    ``dynamic_slice`` and ``dynamic_update_slice`` take the pool as it
+    lies, and so does the decode kernel: at a block size that is a whole
+    number of sublane tiles the device's order is ``transpose(pool, (0,
+    2, 1, 3))`` row-major, so that view (and its ``(N, Hkv * bs, D)``
+    reshape) is a bitcast where the row-major one would be the copy
+    (``tests/test_tpu_aot.py`` holds both at the hybrid cell's shape).
+    Four heads are held row-major in ``T(4,128)`` tiles: taken head-major
+    they cost two whole-pool copies a call (277 MB of temporaries at
+    (1057, 128, 4, 128)), taken as they lie one Mosaic call and none."""
+    return (
+        backend.kernel_platform() == "tpu" and pool.ndim == 4
+        and not heads_fill_a_tile(pool.shape[2])
+    )
+
+
+def _blocks_copy_pool(pool) -> bool:
+    """Whether a gather or a scatter of WHOLE blocks would first copy this
+    pool on a TPU: for any K/V head count that is no multiple of 8.  A pool
+    held head-major (:func:`pool_relayouts`) is copied into row-major order
+    and back; one held row-major in a smaller tile (4 heads, ``T(4,128)``)
+    is copied into ``T(8,128)`` tiles and back, as the block scatter wants
+    them (24 whole-pool copies in the 4-head cell's prefill write, by the
+    compile for the v5e).  A row scatter (:func:`put_rows`) takes such a
+    row-major pool as it lies."""
     return (
         backend.kernel_platform() == "tpu" and pool.ndim == 4
         and pool.shape[2] % 8 != 0
@@ -180,8 +213,8 @@ def pool_relayouts(pool) -> bool:
 def take_blocks(pool, idx):
     """``pool[idx]`` for block ids ``idx`` of any (static) shape: one
     gather, or where that would copy the pool whole
-    (:func:`pool_relayouts`) one ``dynamic_slice`` a block."""
-    if not pool_relayouts(pool):
+    (:func:`_blocks_copy_pool`) one ``dynamic_slice`` a block."""
+    if not _blocks_copy_pool(pool):
         return pool[idx]
     flat = idx.reshape(-1)
     blocks = jnp.stack([
@@ -193,9 +226,9 @@ def take_blocks(pool, idx):
 
 def put_blocks(pool, idx, blocks):
     """``pool.at[idx].set(blocks)`` for (n,) block ids and (n, bs, Hkv, D)
-    ``blocks``: one scatter, or where that would copy the pool whole one
-    ``dynamic_update_slice`` a block."""
-    if not pool_relayouts(pool):
+    ``blocks``: one scatter, or where that would copy the pool whole
+    (:func:`_blocks_copy_pool`) one ``dynamic_update_slice`` a block."""
+    if not _blocks_copy_pool(pool):
         return pool.at[idx].set(blocks)
     for i in range(idx.shape[0]):
         pool = lax.dynamic_update_slice_in_dim(pool, blocks[i][None], idx[i], 0)
@@ -205,8 +238,9 @@ def put_blocks(pool, idx, blocks):
 def put_rows(pool, blk, off, rows):
     """``pool.at[blk, off].set(rows)``: each slot's new row (S, Hkv, D) at
     its block and offset; one scatter, or where that would copy the pool
-    whole one ``dynamic_update_slice`` a slot (slots that share a place,
-    the inactive ones' null block, keep the last)."""
+    whole (a pool held head-major: :func:`pool_relayouts`) one
+    ``dynamic_update_slice`` a slot (slots that share a place, the inactive
+    ones' null block, keep the last)."""
     if not pool_relayouts(pool):
         return pool.at[blk, off].set(rows)
     zero = jnp.zeros((), blk.dtype)
@@ -359,17 +393,17 @@ def kernel_admits(q, k_pool) -> bool:
     """Whether Mosaic's tiling takes these shapes: a head dimension that
     fills the 128 lanes, and chunks of whole tiles whose scores fill the
     lanes too.  A pool the device holds as it is indexed (a K/V head count
-    that is a multiple of 8: :func:`pool_relayouts`) is read (position,
-    head): blocks of whole tiles, a whole number of them a chunk, whole
-    sublane tiles of query heads.  Any other head count is read (head,
-    position), as the v5e holds such a pool: a head's rows of a block have
-    to fill the lanes of the scores by themselves (a block size that is a
-    multiple of 128) and fit a chunk; the query heads are padded to whole
-    tiles.  Anything else (the CPU tests' toy head dimensions) walks the
-    table in ``_stream_jnp``."""
+    whose rows fill a tile: :func:`heads_fill_a_tile`, 4 among them) is
+    read (position, head): blocks of whole tiles, a whole number of them a
+    chunk, whole sublane tiles of query heads.  Any other head count is
+    read (head, position), as the v5e holds such a pool: a head's rows of
+    a block have to fill the lanes of the scores by themselves (a block
+    size that is a multiple of 128) and fit a chunk; the query heads are
+    padded to whole tiles.  Anything else (the CPU tests' toy head
+    dimensions) walks the table in ``_stream_jnp``."""
     bs, hkv, d = k_pool.shape[1:]
     rows = bs * hkv
-    if hkv % 8:
+    if not heads_fill_a_tile(hkv):
         tiles = bs % 128 == 0 and bs <= _CHUNK_ROWS
     else:
         tiles = (

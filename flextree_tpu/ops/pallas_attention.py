@@ -262,9 +262,19 @@ def _flash_kernel_kvgrid(
     scale: float,
     q_offset: int,
     k_offset: int,
+    block_len: int = 1,
 ):
     """The "kvgrid" forward: k/v-major tiles are a GRID dimension, not a
     ``fori_loop``.
+
+    ``block_len`` B (a power of two) makes the causal mask block-causal:
+    query position ``i`` sees key position ``j`` iff ``j // B <= i // B``
+    (absolute positions, blocks aligned on 0, B, 2B, ...), that is ``j <=
+    i | (B - 1)``; B = 1 is the causal mask itself, and the tile-skip
+    bounds follow from the same comparison.  (At B = 1 Mosaic's
+    canonicalization folds the ``| 0`` away: in the AOT compile for the
+    v5e the causal kernel's final LLO is the one the plain causal
+    expressions gave.)
 
     The softmax carry (m, l, acc) lives in VMEM scratch across the
     ``arbitrary``-semantics kv axis, each grid step's inner walk over
@@ -294,14 +304,16 @@ def _flash_kernel_kvgrid(
         acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
 
     if causal:
-        # exclusive bound of visible local k positions for this q tile
-        hi = q_offset + (i + 1) * block_q - k_offset
+        # exclusive bound of visible local k positions for this q tile (a
+        # row sees to the end of its block)
+        hi = ((q_offset + (i + 1) * block_q - 1) | (block_len - 1)) \
+            + 1 - k_offset
         run = (j * block_k_major) < hi
         # last kv-major tile with any visible position — where the output
         # is finalized (0 when nothing is visible: zero acc, l=0 path)
         j_last = jnp.clip(-(-hi // block_k_major) - 1, 0, n_j - 1)
         # fully-visible prefix (min over the tile's rows), for mask skipping
-        lo_vis = q_offset + i * block_q - k_offset + 1
+        lo_vis = ((q_offset + i * block_q) | (block_len - 1)) + 1 - k_offset
     else:
         run = True
         j_last = n_j - 1
@@ -342,13 +354,14 @@ def _flash_kernel_kvgrid(
                 )
                 valid = kpos < t_kv_valid
                 if causal:
-                    qpos = (
-                        q_offset - k_offset + i * block_q
+                    # the last position of the row's block, absolute
+                    qend = (
+                        q_offset + i * block_q
                         + lax.broadcasted_iota(
                             jnp.int32, (block_q, block_k), 0
                         )
-                    )
-                    valid = valid & (qpos >= kpos)
+                    ) | (block_len - 1)
+                    valid = valid & (qend >= k_offset + kpos)
                 return _kv_update(m, l, acc, s, vb, valid)
 
             def unmasked(op):
@@ -480,6 +493,7 @@ def _flash_fwd_impl(
     q, k, v, causal, scale, q_offset, k_offset, block_q, block_k, interpret,
     emit_lse: bool = False,
     variant: str | None = None,
+    block_len: int = 1,
 ):
     """(B, Tq, H, D) x (B, Tk, H, D)^2 -> fused attention out, plus the
     per-row logsumexp (B*H, Tq_pad) when ``emit_lse`` (else None) — the
@@ -487,12 +501,20 @@ def _flash_fwd_impl(
 
     ``variant``: "loop" (carry-serialized fori_loop), "pipelined"
     (software-pipelined fori_loop), or "kvgrid" (k/v walk as a grid axis
-    with VMEM scratch carry — see ``_flash_kernel_kvgrid``).
+    with VMEM scratch carry — see ``_flash_kernel_kvgrid``, which alone
+    takes a block-causal ``block_len``).
     """
     if variant is None:
         variant = DEFAULT_FWD_VARIANT
     if variant not in ("loop", "pipelined", "kvgrid"):
         raise ValueError(f"unknown flash variant {variant!r}")
+    if block_len != 1 and (
+        variant != "kvgrid" or not causal or block_len & (block_len - 1)
+    ):
+        raise ValueError(
+            f"a block-causal mask (block_len {block_len}) is the causal "
+            f"kvgrid forward's, over blocks of a power of two"
+        )
     b, tq, h, d = q.shape
     tk = k.shape[1]
     # values may be narrower or wider than queries and keys (latent
@@ -545,6 +567,7 @@ def _flash_fwd_impl(
                 scale=scale,
                 q_offset=q_offset,
                 k_offset=k_offset,
+                block_len=block_len,
             ),
             out_shape=tuple(out_shape),
             grid=(b * h, tq_pad // bq, tk_pad // bkM),
@@ -1003,10 +1026,16 @@ def flash_attention(
     interpret: bool | None = None,
     return_lse: bool = False,
     variant: str | None = None,
+    block_len: int = 1,
 ):
     """Fused attention on (B, Tq, H, D) queries / (B, Tk, H, D) keys-values
     (values may have a width of their own under ``variant="kvgrid"``,
     forward only: the output then has theirs).
+
+    ``block_len`` B > 1 makes the causal mask block-causal (``j // B <= i
+    // B`` on absolute positions: bidirectional inside a block of B, causal
+    across blocks), in the ``kvgrid`` forward, forward only: a served
+    prefill's, which takes no gradient.  B = 1 is the causal mask.
 
     Same contract as ``attention_reference`` (output for the local queries
     in ``q``'s dtype) plus global ``q_offset``/``k_offset`` positions for
@@ -1044,6 +1073,15 @@ def flash_attention(
         raise ValueError(f"k/v shapes differ: {k.shape} vs {v.shape}")
     if scale is None:
         scale = 1.0 / (q.shape[-1] ** 0.5)
+    if block_len != 1:
+        if return_lse:
+            raise ValueError("a block-causal forward hands out no logsumexp")
+        out, _ = _flash_fwd_impl(
+            q, k, v, causal, float(scale), int(q_offset), int(k_offset),
+            int(block_q), int(block_k), interpret, variant=variant,
+            block_len=int(block_len),
+        )
+        return out
     core = _flash_attention_lse_core if return_lse else _flash_attention_core
     return core(
         q, k, v, causal, float(scale), int(q_offset), int(k_offset),

@@ -59,6 +59,16 @@ full prompt blocks into the index and RELEASES everything it held;
 pool pressure evicts idle index entries before blocking admission.
 Still a pure host-side state machine: the index stores token ids and
 block ids, never tensors.
+
+**Diffusion over blocks** (``ContinuousBatcher(..., diffusion=
+BlockDiffusion(...))``, for a model whose pass computes B positions a
+slot): a resident sequence's ``length`` counts its COMMITTED positions, a
+multiple of B, and it holds an :class:`OpenBlock` (the prompt's tail, then
+masked places) at its phase; ``block_arrays`` is a pass's view of the
+slots and ``record_block_pass`` what a pass did: reveals put in place, a
+fully revealed block's tokens delivered at one stamp, a commit pass that
+advances ``length`` by B and opens the next block.  A request's
+``max_new_tokens`` must be a multiple of B, and it must be greedy.
 """
 
 from __future__ import annotations
@@ -74,6 +84,8 @@ from .prefix_index import PrefixIndex
 __all__ = [
     "Request",
     "SeqState",
+    "OpenBlock",
+    "BlockDiffusion",
     "PreemptedSeq",
     "BatcherConfig",
     "ContinuousBatcher",
@@ -99,6 +111,41 @@ class Request:
     @property
     def prompt_len(self) -> int:
         return int(np.asarray(self.prompt).shape[0])
+
+
+@dataclasses.dataclass(frozen=True)
+class BlockDiffusion:
+    """How a model that generates by diffusion over blocks makes its
+    tokens: blocks of ``length`` positions aligned on absolute positions,
+    ``steps`` denoising passes a block, then one commit pass; an
+    unrevealed place holds ``mask_id``."""
+
+    length: int
+    steps: int
+    mask_id: int
+
+    def reveals(self, masked: int) -> tuple:
+        """Places each denoising pass reveals of a block that opens with
+        ``masked`` of them masked: as even as whole places allow, the
+        earlier passes taking the rest (the published schedule's
+        ``get_num_transfer_tokens``)."""
+        base, rest = divmod(masked, self.steps)
+        return tuple(base + (j < rest) for j in range(self.steps))
+
+
+@dataclasses.dataclass
+class OpenBlock:
+    """A slot's open block: its token ids as they stand (``mask_id`` where
+    masked), which places are still masked, how many leading places are the
+    prompt's tail (fixed, never delivered), what each denoising pass
+    reveals, and the phase: denoising pass ``phase``, or at ``phase ==
+    len(reveals)`` the commit pass, whose K/V are the ones kept."""
+
+    tokens: list
+    masked: list
+    fixed: int
+    reveals: tuple
+    phase: int = 0
 
 
 @dataclasses.dataclass
@@ -129,6 +176,9 @@ class SeqState:
     # decode-SLO quantity the disagg bench holds p99 floors against,
     # which the mean ``per_token_s`` on CompletedRequest cannot carry
     token_times: list = dataclasses.field(default_factory=list)
+    # a diffusion block engine's open block (``length`` then counts the
+    # COMMITTED positions, a multiple of the block length)
+    block: OpenBlock | None = None
 
     @property
     def rid(self) -> int:
@@ -181,9 +231,15 @@ class BatcherConfig:
 
 
 class ContinuousBatcher:
-    def __init__(self, pcfg: PagedCacheConfig, bcfg: BatcherConfig):
+    """``diffusion``: how a model that generates by diffusion over blocks
+    makes its tokens (:class:`BlockDiffusion`), or None for one token a
+    slot a round."""
+
+    def __init__(self, pcfg: PagedCacheConfig, bcfg: BatcherConfig,
+                 diffusion: BlockDiffusion | None = None):
         self.pcfg = pcfg
         self.bcfg = bcfg
+        self.diffusion = diffusion
         self.allocator = BlockAllocator(pcfg.num_blocks)
         self.prefix_index = (
             PrefixIndex(pcfg.block_size, self.allocator)
@@ -225,6 +281,10 @@ class ContinuousBatcher:
                  f"{self.pcfg.num_blocks - 1}")
             )
             return False
+        refused = self._diffusion_refuses(request)
+        if refused:
+            self.rejected.append((request.rid, refused))
+            return False
         if request.temperature > 0 and request.seed is None:
             # reject BEFORE admission: discovered mid-prefill this would
             # wedge the slot (blocks reserved, no sampler key)
@@ -234,6 +294,21 @@ class ContinuousBatcher:
             return False
         self.queue.append(request)
         return True
+
+    def _diffusion_refuses(self, request: Request) -> str | None:
+        """Why a diffusion block engine cannot serve ``request``, or None."""
+        diff = self.diffusion
+        if diff is None:
+            return None
+        if request.max_new_tokens % diff.length:
+            return (
+                f"max_new_tokens {request.max_new_tokens} is no multiple of "
+                f"the block length {diff.length}: a diffusion block "
+                f"delivers its tokens a whole block at a time"
+            )
+        if request.temperature > 0:
+            return "a diffusion block reveals greedily (temperature 0)"
+        return None
 
     # ---- admission ---------------------------------------------------------
 
@@ -361,10 +436,27 @@ class ContinuousBatcher:
                 shared_blocks=len(shared),
                 cow_src=cow_src,
             )
+            if self.diffusion is not None:
+                # the prompt's whole blocks are committed by the prefill;
+                # its tail opens the first block
+                tail = req.prompt_len % self.diffusion.length
+                state.length -= tail
+                self._open_block(state, np.asarray(req.prompt)[state.length:])
             slot = free_slots[0]
             self.slots[slot] = state
             admitted.append((slot, state))
         return admitted
+
+    def _open_block(self, s: SeqState, tail=()) -> None:
+        """Open ``s``'s next block at its committed length: ``tail`` (the
+        prompt's last tokens, fixed) then masked places."""
+        diff = self.diffusion
+        n = diff.length - len(tail)
+        s.block = OpenBlock(
+            tokens=[int(t) for t in tail] + [diff.mask_id] * n,
+            masked=[False] * len(tail) + [True] * n,
+            fixed=len(tail), reveals=diff.reveals(n),
+        )
 
     def admit_migrated(self, request: Request, first_token: int,
                        now_s: float = 0.0):
@@ -542,6 +634,80 @@ class ContinuousBatcher:
         s.generated.append(int(token))
         s.token_times.append(now_s)
         self._maybe_finish(s, now_s)
+
+    def block_arrays(self):
+        """A diffusion pass's view of the slots: (tables (S, P), lengths
+        (S,) COMMITTED positions, tokens (S, B) the open blocks as they
+        stand, masked (S, B), reveal (S,) places this pass reveals (0 in a
+        commit pass), active (S,)); inactive rows are all-NULL_BLOCK tables
+        at length 0 with nothing masked."""
+        S, P = self.bcfg.slots, self.pcfg.blocks_per_seq
+        b = self.diffusion.length
+        tables = np.full((S, P), NULL_BLOCK, np.int32)
+        lengths = np.zeros((S,), np.int32)
+        tokens = np.zeros((S, b), np.int32)
+        masked = np.zeros((S, b), bool)
+        reveal = np.zeros((S,), np.int32)
+        active = np.zeros((S,), bool)
+        for i, s in enumerate(self.slots):
+            if s is None or s.done:
+                continue
+            blk = s.block
+            tables[i, : len(s.block_ids)] = s.block_ids
+            lengths[i] = s.length
+            tokens[i] = blk.tokens
+            masked[i] = blk.masked
+            if blk.phase < len(blk.reveals):
+                reveal[i] = blk.reveals[blk.phase]
+            active[i] = True
+        return tables, lengths, tokens, masked, reveal, active
+
+    def record_block_pass(self, slots, ids, revealed, now_s: float) -> dict:
+        """What one pass did to the ``slots`` that ran it: ``ids`` (S, B)
+        the greedy tokens, ``revealed`` (S, B) the places this pass
+        revealed.  A denoising pass puts the revealed ids in place; the one
+        that reveals a block's last masked place delivers the block's
+        generated tokens in order, at one stamp (``now_s``), up to the
+        request's ``max_new_tokens`` or a stop token.  A commit pass (the
+        next one) advances the committed length by the block and opens the
+        next block.  Returns the counts ``revealed``, ``committed`` (commit
+        passes), ``emitted`` (tokens delivered), ``blocks`` (blocks
+        delivered) and ``first`` (slots whose first token this was)."""
+        counts = {"revealed": 0, "committed": 0, "emitted": 0, "blocks": 0,
+                  "first": []}
+        b = self.diffusion.length
+        for slot in slots:
+            s = self.slots[slot]
+            blk = s.block
+            if blk.phase == len(blk.reveals):
+                s.length += b
+                counts["committed"] += 1
+                self._open_block(s)
+                continue
+            for j in np.flatnonzero(revealed[slot]):
+                blk.tokens[j] = int(ids[slot][j])
+                blk.masked[j] = False
+                counts["revealed"] += 1
+            blk.phase += 1
+            if blk.phase < len(blk.reveals):
+                continue
+            if any(blk.masked):
+                raise RuntimeError(
+                    f"request {s.rid}: a block left masked after its "
+                    f"{len(blk.reveals)} denoising passes"
+                )
+            if not s.generated:
+                s.first_token_s = now_s
+                counts["first"].append(slot)
+            counts["blocks"] += 1
+            for tok in blk.tokens[blk.fixed:]:
+                s.generated.append(tok)
+                s.token_times.append(now_s)
+                counts["emitted"] += 1
+                self._maybe_finish(s, now_s)
+                if s.done:
+                    break
+        return counts
 
     def _maybe_finish(self, s: SeqState, now_s: float) -> None:
         hit_stop = s.generated[-1] in s.request.stop_tokens

@@ -40,6 +40,14 @@ under the same presplit key schedule ``generate`` uses, so a sampled
 request through the engine reproduces ``generate(..., key=PRNGKey(seed))``
 exactly.
 
+For a model that generates by diffusion over blocks (``Block.block_len``
+B > 1) step 4 is one PASS of the open blocks over every slot, each at its
+own phase (a denoising pass, or the commit pass that keeps a clean block's
+K/V), and the reveal on the device (:func:`unmask_greedy_ids`); a slot
+delivers a whole block of tokens at one stamp or none, and ``step()``
+reports ``emitted``, the tokens delivered (``decoded`` for the other
+blocks).
+
 Timestamps come from the module-level ``_now`` (monotonic), injectable
 for tests the same way ``runtime.supervisor._wall`` is.
 """
@@ -65,7 +73,13 @@ from ..models.generate import prefill, prefill_suffix, sample_token
 from ..models.moe import MOE_COUNTS
 from ..models.transformer import TransformerConfig
 from ..obs import MetricsRegistry, current_recorder, record_event, span
-from .batcher import BatcherConfig, ContinuousBatcher, Request, SeqState
+from .batcher import (
+    BatcherConfig,
+    BlockDiffusion,
+    ContinuousBatcher,
+    Request,
+    SeqState,
+)
 from .costs import cache_bytes_per_position, state_bytes_per_slot
 from .kv_cache import (
     CacheExhausted,
@@ -110,6 +124,32 @@ def greedy_ids(logits):
     program shows in a profile as ``jit_greedy_ids``, apart from the
     decode program the benchmark finds by name."""
     return sample_token(logits)
+
+
+def unmask_greedy_ids(logits, masked, reveal):
+    """A diffusion pass's pick: (S, B, V) logits, (S, B) bool ``masked``
+    places, (S,) int32 ``reveal`` counts -> ``(ids, revealed)``: (S, B)
+    int32 the greedy token at every place (``greedy_ids``'s first index of
+    the maximum), and (S, B) bool the ``reveal`` masked places of each
+    slot whose token is the most probable, by its softmax probability in
+    float32 over the whole vocabulary, ties to the lower place.  Only the
+    ids and the mask cross to the host, never a logits row.  A named
+    ``def`` (``jit_unmask_greedy_ids`` in a profile: the pick program the
+    benchmark finds by ``greedy_ids``), scope ``ft_unmask``."""
+    with jax.named_scope("ft_unmask"):
+        ids = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        top = jnp.max(logits, axis=-1, keepdims=True)
+        conf = 1.0 / jnp.sum(jnp.exp(logits - top), axis=-1)
+        conf = jnp.where(masked, conf, -1.0)
+        place = jnp.arange(masked.shape[1])
+        # place j is ahead of place i: more probable, or as probable and
+        # lower; a place's rank is how many are ahead of it
+        ahead = (conf[:, None, :] > conf[:, :, None]) | (
+            (conf[:, None, :] == conf[:, :, None])
+            & (place[None, None, :] < place[None, :, None])
+        )
+        rank = ahead.sum(axis=-1)
+        return ids, masked & (rank < reveal[:, None])
 
 
 @dataclasses.dataclass(frozen=True)
@@ -185,7 +225,25 @@ class ServingEngine:
         self.metrics.windowed_histogram(
             "serve.ttft_ms", interval_s=self.slo_window_s / 10.0, intervals=10
         )
-        self.batcher = ContinuousBatcher(pcfg, self.bcfg)
+        # positions a pass computes a slot: 1, or a diffusion block's B
+        self.block_len = block_of(cfg).block_len(cfg)
+        diffusion = None
+        if self.block_len > 1:
+            if pcfg.block_size % self.block_len:
+                raise ValueError(
+                    f"a pool block of {pcfg.block_size} positions holds no "
+                    f"whole number of diffusion blocks of {self.block_len}"
+                )
+            if self.bcfg.admission != "reserve":
+                raise NotImplementedError(
+                    "preemption of a slot with an open diffusion block (its "
+                    "block and phase) is not implemented: build the engine "
+                    "with BatcherConfig(admission='reserve')"
+                )
+            diffusion = BlockDiffusion(
+                self.block_len, cfg.denoise_steps, cfg.mask_id
+            )
+        self.batcher = ContinuousBatcher(pcfg, self.bcfg, diffusion)
         if self.batcher.prefix_index is not None and not isinstance(
             cfg, TransformerConfig
         ):
@@ -245,6 +303,8 @@ class ServingEngine:
         # not (S, V) logits cross to the host: two shapes for the server
         # lifetime, the decode round's (S, V) and a prefill's (1, V)
         self._greedy_ids = jax.jit(greedy_ids)
+        # a diffusion pass's pick: the reveal, on the device
+        self._unmask = jax.jit(unmask_greedy_ids)
         # suffix-only prefill for prefix-cache hits, fused with the block
         # gather into ONE program: one compile per (chain_len, cached_len,
         # suffix_len) bucket — the prefix shapes carry the offset, so
@@ -362,19 +422,17 @@ class ServingEngine:
             with span("ft.engine.grow"):
                 preempted = self._grow_with_preemption()
             active = self.batcher.active_slots()
-            if active:
+            emitted = 0
+            block_counts: dict = {}
+            if active and self.block_len > 1:
+                moe_ids, block_counts = self._block_round(active)
+                emitted = block_counts["emitted"]
+            elif active:
                 with span("ft.batcher.batch_arrays"):
                     tables, lengths, tokens, _ = self.batcher.batch_arrays()
                 with span(
                     "ft.engine.decode_dispatch", round=self.steps,
-                    attn_layers=self.attn_layers,
-                    attn_kernel_layers=self.attn_kernel_layers,
-                    cache_bytes_per_position=self.cache_bytes_per_position,
-                    state_bytes_per_slot=self.state_bytes_per_slot,
-                    state_layers=self.state_layers,
-                    state_kernel_layers=self.state_kernel_layers,
-                    expert_layers=self.expert_layers,
-                    expert_kernel_layers=self.expert_kernel_layers,
+                    **self._program_counts(),
                 ):
                     # a model with routed experts hands out a third
                     # result, what its routers did this round
@@ -411,6 +469,7 @@ class ServingEngine:
                 self.decode_steps += 1
                 self.metrics.counter("serve.decode_tokens").inc(len(active))
                 record_event("serve_decode", n_active=len(active))
+                emitted = len(active)
             with span("ft.engine.retire"):
                 finished = self.batcher.retire_ready()
                 for slot, state in finished:
@@ -426,7 +485,7 @@ class ServingEngine:
                 finished=len(finished), blocks_in_use=total - free,
                 blocks_total=total,
                 state_slots_live=self.batcher.num_active if self.state else 0,
-                **moe_ids,
+                **moe_ids, **block_counts,
             ):
                 self.steps += 1
                 m = self.metrics
@@ -453,6 +512,9 @@ class ServingEngine:
             "preempted": preempted,
             "decoded": len(active),
             "finished": len(finished),
+            # tokens delivered to requests this round: ``decoded`` for a
+            # block of one token a slot, a diffusion block's whole blocks
+            "emitted": emitted,
         }
 
     def run_until_idle(self, max_steps: int = 100_000) -> None:
@@ -463,6 +525,69 @@ class ServingEngine:
         raise RuntimeError(f"engine not idle after {max_steps} steps")
 
     # ---- internals ---------------------------------------------------------
+
+    def _program_counts(self) -> dict:
+        """What the decode program is, fixed when it is built: the layers
+        of each kind and those that run a Pallas kernel, and what the pools
+        and the state hold (``ft.engine.decode_dispatch``, ``report()``)."""
+        return {
+            "attn_layers": self.attn_layers,
+            "attn_kernel_layers": self.attn_kernel_layers,
+            "cache_bytes_per_position": self.cache_bytes_per_position,
+            "state_bytes_per_slot": self.state_bytes_per_slot,
+            "state_layers": self.state_layers,
+            "state_kernel_layers": self.state_kernel_layers,
+            "expert_layers": self.expert_layers,
+            "expert_kernel_layers": self.expert_kernel_layers,
+        }
+
+    def _block_round(self, active) -> tuple:
+        """One pass of a diffusion block over every active slot, each at
+        its own phase (a denoising pass of its open block, or the commit
+        pass of a block whose last place the pass before revealed), and the
+        reveal on the device.  Returns ``(what the routers counted, the
+        round's counts: revealed, committed, emitted)``."""
+        b, steps = self.block_len, self.batcher.diffusion.steps
+        with span("ft.batcher.batch_arrays"):
+            tables, lengths, tokens, masked, reveal, act = (
+                self.batcher.block_arrays()
+            )
+        with span(
+            "ft.engine.decode_dispatch", round=self.steps,
+            **self._program_counts(), block_len=b,
+            query_rows=self.bcfg.slots * b, denoise_steps=steps,
+        ):
+            logits, self.pools, routed = self._decode(
+                self.params, self.pools, tables, lengths, tokens, act
+            )
+            ids, revealed = self._unmask(logits, masked, reveal)
+        with span("ft.engine.decode_fetch", round=self.steps):
+            # (S, B) ids and the revealed places, and the routers' counts:
+            # no logits row crosses
+            ids, revealed, counts = jax.device_get(
+                (ids, revealed, routed["counts"])
+            )
+        now = _now()
+        with span(
+            "ft.engine.sample", on_device=len(active), rows_fetched=0,
+            active=len(active),
+        ):
+            done = self.batcher.record_block_pass(active, ids, revealed, now)
+        for slot in done["first"]:
+            self.metrics.histogram("serve.ttft_ms").observe(
+                (now - self.batcher.slots[slot].request.arrival_s) * 1e3
+            )
+        self.decode_steps += 1
+        m = self.metrics
+        m.counter("serve.decode_tokens").inc(done["emitted"])
+        m.counter("serve.tokens_revealed").inc(done["revealed"])
+        m.counter("serve.commit_passes").inc(done["committed"])
+        m.counter("serve.blocks_committed").inc(done["blocks"])
+        record_event("serve_decode", n_active=len(active))
+        return (
+            {k: int(v) for k, v in zip(MOE_COUNTS, counts)},
+            {k: done[k] for k in ("revealed", "committed", "emitted")},
+        )
 
     def _decode_round(self, tables, lengths, tokens) -> tuple:
         """Dispatch the decode program over the pools (and the state,
@@ -637,6 +762,7 @@ class ServingEngine:
         back to the front door); raises :class:`MigrationError` for a
         request that could NEVER migrate (oversized, sampled)."""
         req = request
+        self._refuse_diffusion_migration(req)
         if req.temperature > 0:
             raise MigrationError(
                 f"request {req.rid}: migration is greedy-only "
@@ -694,6 +820,14 @@ class ServingEngine:
             "prefill_s": now - t0,
         }
 
+    def _refuse_diffusion_migration(self, req: Request) -> None:
+        if self.block_len > 1:
+            raise MigrationError(
+                f"request {req.rid}: migration of a slot with an open "
+                f"diffusion block is not implemented (its block and phase "
+                f"are no part of the payload)"
+            )
+
     def _payload_geometry(self) -> dict:
         """What a migration payload has to state to land here: the pool's
         block size, the rows' layout and the layers that cache them, the
@@ -738,6 +872,7 @@ class ServingEngine:
         prompt_len, first token recorded, decode continues from the
         imported blocks on the next :meth:`step`."""
         req = request
+        self._refuse_diffusion_migration(req)
         total = req.prompt_len + req.max_new_tokens
         if req.prompt_len < 1 or total > self.pcfg.max_len:
             raise MigrationError(
@@ -949,6 +1084,21 @@ class ServingEngine:
         req = state.request
         prompt = np.asarray(req.prompt, np.int32)
         c = state.cached_tokens
+        if self.block_len > 1:
+            # the prompt's whole blocks, committed; its tail is in the
+            # first open block, and the first token comes with that block
+            if state.length:
+                with span("ft.engine.prefill_dispatch", rid=req.rid):
+                    _, cache = self._prefill(
+                        self.params, prompt[None, : state.length]
+                    )
+                    self._write_slot(slot, cache, state.block_ids)
+                # waited for here, as the other blocks wait for a first
+                # token's fetch: ``ft.engine.prefill`` spans the prefill's
+                # device time, not its dispatch alone
+                jax.block_until_ready(self.pools)
+            self._record_prefill(req, slot, c, _now() - t0)
+            return
         with span("ft.engine.prefill_dispatch", rid=req.rid):
             logits = self._dispatch_prefill(slot, state, prompt, c)
             ids = self._greedy_ids(logits)
@@ -1068,14 +1218,12 @@ class ServingEngine:
             "steps": self.steps,
             "decode_steps": self.decode_steps,
             "completed": len(self.completed),
-            "attn_layers": self.attn_layers,
-            "attn_kernel_layers": self.attn_kernel_layers,
-            "cache_bytes_per_position": self.cache_bytes_per_position,
-            "state_bytes_per_slot": self.state_bytes_per_slot,
-            "state_layers": self.state_layers,
-            "state_kernel_layers": self.state_kernel_layers,
-            "expert_layers": self.expert_layers,
-            "expert_kernel_layers": self.expert_kernel_layers,
+            **self._program_counts(),
+            "block_len": self.block_len,
+            "query_rows": self.bcfg.slots * self.block_len,
+            "denoise_steps": (
+                self.batcher.diffusion.steps if self.batcher.diffusion else 1
+            ),
             **self.metrics.snapshot(),
         }
 
@@ -1122,16 +1270,28 @@ class ServingEngine:
 
         # the greedy pick is warmed on both of its shapes: the decode
         # round's (S, V) logits here and a prefill's (1, V) below
-        logits, *_ = self._decode_round(
-            np.zeros((S, P), np.int32), null(S), null(S)
-        )
-        jax.block_until_ready(self._greedy_ids(logits))
+        b = self.block_len
+        if b > 1:
+            logits, self.pools, _ = self._decode(
+                self.params, self.pools, np.zeros((S, P), np.int32), null(S),
+                np.zeros((S, b), np.int32), np.zeros((S,), bool),
+            )
+            jax.block_until_ready(self._unmask(
+                logits, np.zeros((S, b), bool), null(S)
+            ))
+        else:
+            logits, *_ = self._decode_round(
+                np.zeros((S, P), np.int32), null(S), null(S)
+            )
+            jax.block_until_ready(self._greedy_ids(logits))
         cache = None
-        for t in sorted(set(int(t) for t in prompt_lens)):
+        # a diffusion block prefills a prompt's whole blocks only
+        for t in sorted(set(int(t) // b * b for t in prompt_lens) - {0}):
             logits, cache = self._prefill(
                 self.params, np.zeros((1, t), np.int32)
             )
-            jax.block_until_ready(self._greedy_ids(logits))
+            if logits is not None:
+                jax.block_until_ready(self._greedy_ids(logits))
         if self.state:
             self.state = self._write_state(
                 self.state, read_state(self.state, np.int32(0)), np.int32(0)
@@ -1147,7 +1307,7 @@ class ServingEngine:
             counts |= set(range(1, P + 1))
         bs = self.pcfg.block_size
         if counts and cache is None:
-            _, cache = self._prefill(self.params, np.zeros((1, 1), np.int32))
+            _, cache = self._prefill(self.params, np.zeros((1, b), np.int32))
         for n in sorted(counts):
             self.pools = self._write(self.pools, cache, null(n))
             if self.batcher.ondemand:

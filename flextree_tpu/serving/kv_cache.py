@@ -453,8 +453,22 @@ def make_paged_decode_fn(cfg, donate: bool = True,
     path (see :func:`paged_decode_step`); ``impl`` is checked here, at
     construction, and selects nothing.  For a block that holds something a
     slot the program takes the state as a sixth argument, donated too, and
-    returns it last."""
+    returns it last.  For a block that computes B > 1 positions a slot a
+    pass (``Block.block_len``: a diffusion block) the program is its pass,
+    ``(params, pools, tables, lengths, tokens (S, B), active (S,))``."""
     check_decode_impl(impl)
+    block = block_of(cfg)
+    if block.block_len(cfg) > 1:
+        def paged_decode_block_pass(params, pools, tables, lengths, tokens,
+                                    active):
+            return block.decode_step(
+                params, pools, tables, lengths, tokens, cfg, fused,
+                active=active,
+            )
+
+        return jax.jit(
+            paged_decode_block_pass, donate_argnums=(1,) if donate else ()
+        )
     if not slot_parts(cfg):
         # a named ``def`` (a ``partial`` has no name, and its program is
         # ``jit__unknown`` in a profile): every block's decode program

@@ -255,4 +255,5 @@ def test_the_kernel_share_is_the_expert_layers_that_run_the_kernel(stated, want)
               if m["name"] == "kernels.moe_kernel_share"]
     assert entry["source"] == "program_counter" and entry["workloads"] == [
         "laguna-s-2.1.chat-closed-c64", "openpangu-ultra-moe-718b.doc-closed-c32",
-        "kimi-linear-48b-a3b.gen-closed-c128"]
+        "kimi-linear-48b-a3b.gen-closed-c128",
+        "sdar-30b-a3b-chat.gen-block4-closed-c32"]

@@ -574,7 +574,7 @@ def test_what_the_block_does_not_implement_is_refused(over, match):
 def test_the_table_names_the_block_and_the_write_strength_follows_the_key():
     assert BLOCKS["olmo_hybrid"].config_type is olmo.OlmoHybridConfig
     assert isinstance(config_from_dict(tiny()), olmo.OlmoHybridConfig)
-    assert len(BLOCKS) == 5
+    assert len(BLOCKS) == 6
     assert config_from_dict(tiny()).beta_scale == 2.0
     assert config_from_dict(tiny(linear_allow_neg_eigval=False)).beta_scale == 1.0
 
@@ -752,14 +752,18 @@ def test_load_cell_finds_the_new_cell():
     assert t["check_blocks"] * t["block_size"] >= t["check_prompt"] + t["check_steps"]
     # the new entries join the old lists at their end and nowhere else
     assert all(len(e["why"]) <= 200 for e in bench["workloads"] + bench["configs"])
+    # (a later cell may follow it in a list, never come before it)
+    later = [w["name"] for w in bench["workloads"][7:]]
     for m in bench["end_to_end"] + bench["per_layer"]:
         if CELL in m.get("workloads", ()):
-            assert m["workloads"][-1] == CELL
+            after = m["workloads"][m["workloads"].index(CELL) + 1:]
+            assert all(w in later for w in after)
     names = [m["name"] for m in bench["per_layer"]]
-    assert names[-4:] == [
+    first = names.index("attn.gdn_proj_share")
+    assert names[first:first + 4] == [
         "attn.gdn_proj_share", "attn.gdn_core_share",
         "kernels.gdn_decode_roofline", "kernels.gdn_prefill_roofline"]
-    for m in bench["per_layer"][-4:]:
+    for m in bench["per_layer"][first:first + 4]:
         assert m["workloads"] == [CELL] and m["moves"] == "serve_tokens_per_s"
 
 

@@ -606,7 +606,8 @@ def test_what_the_block_does_not_implement_is_refused(key, value, match):
 
 def test_the_table_of_blocks_names_every_model_type():
     assert set(BLOCKS) == {
-        "gpt_neox", "laguna", "pangu_ultra_moe", "kimi_linear", "olmo_hybrid"}
+        "gpt_neox", "laguna", "pangu_ultra_moe", "kimi_linear", "olmo_hybrid",
+        "sdar_moe"}
     assert BLOCKS["pangu_ultra_moe"].config_type is pangu.PanguConfig
     with pytest.raises(ValueError, match="model_type"):
         config_from_dict({"model_type": "no_such_block"})

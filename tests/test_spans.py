@@ -1122,7 +1122,8 @@ def test_new_metric_files_name_readers_that_exist():
     # PR 36: four more medians of a span's duration, and the chain's six;
     # PR 37: the expert layers that run the grouped kernel
     # PR 38: two more scope shares (`attn.gdn_proj_share`, `attn.gdn_core_share`)
-    assert seen == {"spans": 37, "chain": 6}
+    # the diffusion cell's two more (`attn.block_share`, `engine.unmask_share`)
+    assert seen == {"spans": 39, "chain": 6}
 
 
 def test_the_chains_entries_are_the_serving_cells_and_move_the_rate():
@@ -1131,7 +1132,7 @@ def test_the_chains_entries_are_the_serving_cells_and_move_the_rate():
     bench = harness.load_benchmark()
     serving = [w["name"] for w in bench["workloads"]
                if "closed" in w["traffic"]]
-    assert len(serving) == 5  # PR 38: the hybrid cell joins every list
+    assert len(serving) == 6  # the diffusion block's cell joins every list
     first = [e["name"] for e in bench["per_layer"]].index(GAP_METRICS[0])
     added = bench["per_layer"][first:first + 10]
     assert [e["name"] for e in added] == GAP_METRICS + [

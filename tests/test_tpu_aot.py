@@ -851,3 +851,162 @@ def test_the_hybrid_cells_longest_prefill_fits_beside_what_is_resident(
           f"{mem.temp_size_in_bytes / 1e9:.2f} GB")
     assert program + resident < 14.5e9, (
         f"{program / 1e9:.2f} GB program + {resident / 1e9:.2f} GB resident")
+
+
+# ------------------------------------------------ the diffusion block's pass
+
+
+def _sdar_cell():
+    """(configuration object, traffic, pool config) of
+    ``sdar-30b-a3b-chat.gen-block4-closed-c32``, from the benchmark's
+    files."""
+    import json
+    import os
+
+    from flextree_tpu.models.configs import config_from_dict
+    from flextree_tpu.serving.kv_cache import PagedCacheConfig
+
+    root = os.path.join(os.path.dirname(os.path.dirname(__file__)), "benchmarks")
+    with open(os.path.join(root, "configs", "sdar-30b-a3b-chat.json")) as f:
+        cfg = config_from_dict(json.load(f))
+    with open(os.path.join(root, "traffic", "gen-block4-closed-c32.json")) as f:
+        t = json.load(f)
+    return cfg, t, PagedCacheConfig(
+        t["num_blocks"], t["block_size"], t["blocks_per_seq"])
+
+
+@pytest.mark.parametrize("hkv", [1, 2, 4])
+def test_a_pool_of_heads_that_fill_a_tile_is_read_as_it_lies_row_major(
+    v5e, tpu_lowering, hkv
+):
+    """1, 2 and 4 K/V heads fill a tile of their own: the v5e holds a
+    (1025, 128, Hkv, 128) bf16 pool row-major (``T(4,128)`` tiles for 4
+    heads, ``T(2,128)`` for 2; one head's axis is of size 1 and orders
+    nothing), so ``pool_relayouts`` is false and the kernel reads it
+    (position, head) through the ``(N, 128 * Hkv, 128)`` view, a bitcast:
+    one Mosaic call, no copy of the pool, nearly no temporaries (taken
+    head-major, as every head count that is no multiple of 8 was, 4 heads
+    cost two whole-pool copies a call).  The B = 4 positions of a slot's
+    open block fold into 32 x 4 = 128 query rows over the same walk."""
+    import re
+
+    from flextree_tpu.ops.paged_attention import pool_relayouts
+
+    avals = _paged_avals(v5e[0], 32, 128, hkv, 128, 128, 32, 1025)
+    assert not pool_relayouts(avals[3])
+    assert kernel_admits(avals[0], avals[3])
+    compiled = _compile(paged_attention, *avals)
+    hlo = compiled.as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 1
+    if hkv > 1:
+        assert re.search(
+            rf"bf16\[1025,128,{hkv},128\]\{{3,2,1,0:T\({hkv},128\)", hlo)
+    assert not re.search(r"= \w+\[1025,[\d,]*\]\S* copy\(", hlo)
+    assert len(re.findall(
+        rf"= bf16\[1025,{128 * hkv},128\]\S* bitcast\(", hlo)) == 2
+    assert compiled.memory_analysis().temp_size_in_bytes < 2e6
+
+
+def test_the_block_cells_pass_program_walks_in_the_kernel_in_place(
+    v5e, tpu_lowering
+):
+    """The pass program at the cell's size: 32 slots x 4 positions over six
+    layers.  Each layer's attention is ONE paged Mosaic call (the block's
+    rows written first, the 4 positions folded into 128 query rows), each
+    expert layer the grouped kernel (1,024 sorted picks over 128 experts of
+    2048 x 768: two Mosaic calls), as the block says; every pool is aliased
+    to its result and none is copied whole; the temporaries are the
+    logits' and the picks'."""
+    import re
+
+    from flextree_tpu.models import sdar_moe
+    from flextree_tpu.models.configs import block_of
+    from flextree_tpu.serving.kv_cache import init_pools, make_paged_decode_fn
+
+    cfg, t, pcfg = _sdar_cell()
+    slots, b = t["slots"], cfg.block_len
+    assert block_of(cfg).kernel_layers(cfg, pcfg) == (6, 6)
+    assert block_of(cfg).expert_kernel_layers(cfg, slots) == (6, 6)
+    one = NamedSharding(_mesh(v5e[:1], (1, 1, 1)), P())
+    params = _on(jax.eval_shape(
+        lambda k: sdar_moe.init_params(k, cfg), jax.random.PRNGKey(0)), one)
+    pools = _on(jax.eval_shape(lambda: init_pools(cfg, pcfg)), one)
+
+    def a(*s, dt=jnp.int32):
+        return jax.ShapeDtypeStruct(s, dt, sharding=one)
+
+    compiled = _compile(
+        make_paged_decode_fn(cfg, donate=True, fused=True), params, pools,
+        a(slots, pcfg.blocks_per_seq), a(slots), a(slots, b),
+        a(slots, dt=jnp.bool_))
+    hlo = compiled.as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 6 + 6 * 2
+    # nothing is left of the loop over table columns: the only loops are
+    # the grouped kernel's search for its visits
+    loops = [ln for ln in hlo.splitlines() if " while(" in ln]
+    assert loops and all("ft_moe_experts" in ln for ln in loops)
+    mem = compiled.memory_analysis()
+    carried = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(pools))
+    assert carried == 12 * 1025 * 128 * 4 * 128 * 2
+    assert carried <= mem.alias_size_in_bytes < 1.01 * carried
+    assert not re.findall(r"= bf16\[1025,[\d,]*\]\S* copy\(", hlo)
+    assert mem.temp_size_in_bytes < 0.3e9, f"{mem.temp_size_in_bytes / 1e9:.2f} GB"
+    whole = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert whole < 10.8e9, f"{whole / 1e9:.2f} GB"
+
+
+def test_the_block_cells_longest_prefill_is_block_causal_flash_beside_what_is_resident(
+    v5e, tpu_lowering
+):
+    """The 2,048-token prefill program: six block-causal flash forwards
+    (the ``kvgrid`` kernel with ``block_len`` 4, one Mosaic call a layer),
+    within the chip beside the weights and pools."""
+    from flextree_tpu.models import sdar_moe
+    from flextree_tpu.serving.kv_cache import init_pools
+
+    cfg, t, pcfg = _sdar_cell()
+    one = NamedSharding(_mesh(v5e[:1], (1, 1, 1)), P())
+    params = _on(jax.eval_shape(
+        lambda k: sdar_moe.init_params(k, cfg), jax.random.PRNGKey(0)), one)
+    compiled = _compile(
+        lambda p, tok: sdar_moe.prefill(p, tok, cfg, max_len=pcfg.max_len),
+        params, jax.ShapeDtypeStruct((1, 2048), jnp.int32, sharding=one))
+    kernels = [
+        ln for ln in compiled.as_text().splitlines()
+        if 'custom_call_target="tpu_custom_call"' in ln
+    ]
+    assert sum("ft_attn_block" in ln for ln in kernels) == 6
+    mem = compiled.memory_analysis()
+    program = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+               + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    pools = jax.eval_shape(lambda: init_pools(cfg, pcfg))
+    resident = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(pools))
+    print(f"prefill program {program / 1e9:.2f} GB, temporaries "
+          f"{mem.temp_size_in_bytes / 1e9:.2f} GB")
+    assert program + resident < 14.5e9, (
+        f"{program / 1e9:.2f} GB program + {resident / 1e9:.2f} GB resident")
+
+
+def test_the_block_cells_prefill_write_copies_no_pool(v5e, tpu_lowering):
+    """A prompt's K/V written into its blocks of the twelve 4-head pools:
+    a whole-block scatter would first copy each pool into ``T(8,128)``
+    tiles and back (24 whole-pool copies), so ``put_blocks`` writes a
+    block at a time into the pool as it lies; every pool aliased."""
+    import re
+
+    from flextree_tpu.serving.kv_cache import init_pools, write_prefill
+
+    cfg, t, pcfg = _sdar_cell()
+    one = NamedSharding(_mesh(v5e[:1], (1, 1, 1)), P())
+    pools = _on(jax.eval_shape(lambda: init_pools(cfg, pcfg)), one)
+    row = jax.ShapeDtypeStruct((1, pcfg.max_len, 4, 128), jnp.bfloat16, sharding=one)
+    cache = {part: [row] * 6 for part in ("k", "v")}
+    compiled = _compile(
+        jax.jit(write_prefill, donate_argnums=(0,)), pools, cache,
+        jax.ShapeDtypeStruct((16,), jnp.int32, sharding=one))
+    hlo = compiled.as_text()
+    assert not re.findall(r"= bf16\[1025,[\d,]*\]\S* copy\(", hlo)
+    mem = compiled.memory_analysis()
+    carried = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(pools))
+    assert carried <= mem.alias_size_in_bytes and mem.temp_size_in_bytes < 0.05e9
