@@ -599,10 +599,13 @@ class ContinuousBatcher:
             if s is not None and not s.done
         ]
 
-    def batch_arrays(self):
+    def batch_arrays(self, ahead: int = 0):
         """(tables (S, P), lengths (S,), tokens (S,), active (S,)) int32 /
         bool numpy views of the current slots — inactive rows are
-        all-NULL_BLOCK tables at length 0 with token 0 (masked no-ops)."""
+        all-NULL_BLOCK tables at length 0 with token 0 (masked no-ops).
+        ``ahead``: rounds dispatched whose tokens are not recorded yet; an
+        active row's length counts their positions (its token is then
+        stale: the round takes the last round's ids, on the device)."""
         S, P = self.bcfg.slots, self.pcfg.blocks_per_seq
         tables = np.full((S, P), NULL_BLOCK, np.int32)
         lengths = np.zeros((S,), np.int32)
@@ -612,10 +615,37 @@ class ContinuousBatcher:
             if s is None or s.done:
                 continue
             tables[i, : len(s.block_ids)] = s.block_ids
-            lengths[i] = s.length
+            lengths[i] = s.length + ahead
             tokens[i] = s.pending_token
             active[i] = True
         return tables, lengths, tokens, active
+
+    def continues(self, slots) -> bool:
+        """Whether the round after one that decodes ``slots``, whose tokens
+        are not recorded yet, is known now: one token a slot (no diffusion
+        block), nothing waiting to resume or to be admitted, and every one
+        of ``slots``, and no other, decodes again, greedily, one position
+        further in a block it already holds — none finishes with this
+        round's token (``max_new_tokens``; a stop token could not be
+        known before the token is)."""
+        if self.diffusion is not None or self.preempted:
+            return False
+        if self.queue and None in self.slots:
+            return False  # an admission would join the next round
+        if self.active_slots() != list(slots):
+            return False
+        bs = self.pcfg.block_size
+        for i in slots:
+            s = self.slots[i]
+            req = s.request
+            if (
+                req.temperature > 0
+                or req.stop_tokens
+                or len(s.generated) + 1 >= req.max_new_tokens
+                or (s.length + 1) // bs >= len(s.block_ids)
+            ):
+                return False
+        return True
 
     def record_first_token(self, slot: int, token: int, now_s: float) -> None:
         s = self.slots[slot]

@@ -31,6 +31,18 @@ against ``generate``.  ``step()`` is one scheduling round:
 5. **retire** — finished sequences (stop token or ``max_new_tokens``)
    free their blocks immediately and land in ``completed``.
 
+**Dispatch ahead.**  A step may dispatch the NEXT round before it fetches
+its own round's ids, when the host already knows that round: the same
+slots (none finishes with this round's token, none has a stop token, every
+one picks greedily, nothing waits to resume or to be admitted), one
+position further (each in a block it already holds), fed this round's
+picked ids as they lie on the device.  The step then fetches, records and
+retires while the device runs the next round, and the next step finds its
+round already dispatched (``ContinuousBatcher.continues``).  Whatever
+reads or moves a slot outside a round delivers the round in flight first
+(:meth:`ServingEngine.settle`); one that is dropped with its engine (a
+replica's drain) delivers nothing.
+
 Sampling is per request, and token ids, not logits, cross to the host:
 the greedy pick is :func:`greedy_ids` on the device (``generate``'s
 ``jnp.argmax`` on identical logits — the bench's floor), one ``(S,)``
@@ -150,6 +162,24 @@ def unmask_greedy_ids(logits, masked, reveal):
         )
         rank = ahead.sum(axis=-1)
         return ids, masked & (rank < reveal[:, None])
+
+
+@dataclasses.dataclass
+class _Round:
+    """A decode round of one token a slot from its dispatch to its
+    delivery: its id (the step that owns it), the slots it decodes, and
+    its ids (with the routers' counts, where the block routes) on the
+    device until it is delivered.  ``logits`` are kept only where a slot
+    samples on the host (``sampled`` of them)."""
+
+    rid: int
+    active: list
+    ids: object
+    counts: object
+    sampled: int
+    logits: object
+    moe: dict = dataclasses.field(default_factory=dict)
+    delivered: bool = False
 
 
 @dataclasses.dataclass(frozen=True)
@@ -343,6 +373,9 @@ class ServingEngine:
         self.completed: dict = {}
         self.steps = 0
         self.decode_steps = 0
+        # the round the last step dispatched ahead: the next step's own
+        self._ahead: _Round | None = None
+        self.metrics.counter("serve.rounds_ahead")
         # windowed prefix hit-rate over the SLO window (admissions only;
         # exported as a gauge so `obs metrics DIR --prom` carries it)
         self._prefix_window: deque = deque()
@@ -390,7 +423,13 @@ class ServingEngine:
         counters.  Growth (on-demand admission only) allocates each
         active sequence's next decode block; exhaustion preempts the
         newest resident sequence (swap-out or recompute per
-        ``BatcherConfig.preempt``) until the rest fit."""
+        ``BatcherConfig.preempt``) until the rest fit.
+
+        The step owns one decode round: the one the last step dispatched
+        ahead, or one it dispatches now over the active slots.  It may
+        dispatch the round after its own ahead (see the module's
+        docstring); every token of its own round is recorded when it
+        returns."""
         t0 = _now()
         moe_ids: dict = {}  # what the round's routers counted, if any
         with span("ft.engine.round", round=self.steps):
@@ -421,54 +460,30 @@ class ServingEngine:
                     self._prefill_slot(slot, state)
             with span("ft.engine.grow"):
                 preempted = self._grow_with_preemption()
-            active = self.batcher.active_slots()
-            emitted = 0
+            own, self._ahead = self._ahead, None
+            active = self.batcher.active_slots() if own is None else own.active
+            emitted = ahead = 0
             block_counts: dict = {}
-            if active and self.block_len > 1:
+            if own is None and active and self.block_len > 1:
                 moe_ids, block_counts = self._block_round(active)
                 emitted = block_counts["emitted"]
-            elif active:
+            elif own is None and active:
                 with span("ft.batcher.batch_arrays"):
                     tables, lengths, tokens, _ = self.batcher.batch_arrays()
-                with span(
-                    "ft.engine.decode_dispatch", round=self.steps,
-                    **self._program_counts(),
-                ):
-                    # a model with routed experts hands out a third
-                    # result, what its routers did this round
-                    logits, *routed = self._decode_round(
-                        tables, lengths, tokens
-                    )
-                    ids = self._greedy_ids(logits)
-                    counts = routed[0]["counts"] if routed else None
-                # counted while the device decodes
-                sampled = sum(
-                    self.batcher.slots[slot].request.temperature > 0
-                    for slot in active
+                own = self._dispatch_round(
+                    self.steps, active, tables, lengths, tokens, 0
                 )
-                with span("ft.engine.decode_fetch", round=self.steps):
-                    # host fetch = the step boundary; the logits stay on
-                    # the device and are dropped with the round.  The
-                    # routers' counts come back beside the ids, in the
-                    # same fetch
-                    if counts is None:
-                        ids = np.asarray(ids)
-                    else:
-                        ids, counts = jax.device_get((ids, counts))
-                        moe_ids = {
-                            k: int(v) for k, v in zip(MOE_COUNTS, counts)
-                        }
-                now = _now()
-                with span(
-                    "ft.engine.sample", on_device=len(active) - sampled,
-                    rows_fetched=sampled, active=len(active),
-                ):
-                    for slot in active:
-                        tok = self._pick(slot, ids, logits, slot)
-                        self.batcher.record_decode_token(slot, tok, now)
-                self.decode_steps += 1
-                self.metrics.counter("serve.decode_tokens").inc(len(active))
-                record_event("serve_decode", n_active=len(active))
+            if own is not None:
+                if not own.delivered and self.batcher.continues(active):
+                    # the next round is known before this one's ids reach
+                    # the host: its tokens are those ids, on the device
+                    tables, lengths, _, _ = self.batcher.batch_arrays(ahead=1)
+                    self._ahead = self._dispatch_round(
+                        self.steps + 1, active, tables, lengths, own.ids, 1
+                    )
+                    self.metrics.counter("serve.rounds_ahead").inc()
+                    ahead = len(active)
+                moe_ids = self._deliver(own)
                 emitted = len(active)
             with span("ft.engine.retire"):
                 finished = self.batcher.retire_ready()
@@ -481,7 +496,7 @@ class ServingEngine:
             # of the round, opened once its counts are known, carries them
             with span(
                 "ft.engine.bookkeeping", round=self.steps,
-                decoded=len(active), admitted=len(admitted),
+                decoded=len(active), ahead=ahead, admitted=len(admitted),
                 finished=len(finished), blocks_in_use=total - free,
                 blocks_total=total,
                 state_slots_live=self.batcher.num_active if self.state else 0,
@@ -589,6 +604,68 @@ class ServingEngine:
             {k: done[k] for k in ("revealed", "committed", "emitted")},
         )
 
+    def _dispatch_round(self, rid: int, active, tables, lengths, tokens,
+                        ahead: int) -> _Round:
+        """Dispatch round ``rid`` over the ``active`` slots and the pick of
+        its ids, and fetch nothing.  ``ahead``: 1 when the previous
+        round's ids are still on the device (``tokens`` are those ids)."""
+        with span(
+            "ft.engine.decode_dispatch", round=rid, active=len(active),
+            ahead=ahead, **self._program_counts(),
+        ):
+            # a model with routed experts hands out a third result, what
+            # its routers did this round
+            logits, *routed = self._decode_round(tables, lengths, tokens)
+            ids = self._greedy_ids(logits)
+        # counted while the device decodes
+        sampled = sum(
+            self.batcher.slots[slot].request.temperature > 0
+            for slot in active
+        )
+        return _Round(
+            rid, active, ids, routed[0]["counts"] if routed else None,
+            sampled, logits if sampled else None,
+        )
+
+    def _deliver(self, rnd: _Round) -> dict:
+        """Fetch a round's ids and record its tokens, once.  Returns what
+        its routers counted ({} for a block without experts)."""
+        if rnd.delivered:
+            return rnd.moe
+        active, sampled = rnd.active, rnd.sampled
+        with span("ft.engine.decode_fetch", round=rnd.rid):
+            # the logits stay on the device and are dropped with the
+            # round.  The routers' counts come back beside the ids, in the
+            # same fetch
+            if rnd.counts is None:
+                ids = np.asarray(rnd.ids)
+            else:
+                ids, counts = jax.device_get((rnd.ids, rnd.counts))
+                rnd.moe = {k: int(v) for k, v in zip(MOE_COUNTS, counts)}
+        now = _now()
+        with span(
+            "ft.engine.sample", on_device=len(active) - sampled,
+            rows_fetched=sampled, active=len(active),
+        ):
+            for slot in active:
+                tok = self._pick(slot, ids, rnd.logits, slot)
+                self.batcher.record_decode_token(slot, tok, now)
+        rnd.delivered, rnd.logits = True, None
+        self.decode_steps += 1
+        self.metrics.counter("serve.decode_tokens").inc(len(active))
+        record_event("serve_decode", n_active=len(active))
+        return rnd.moe
+
+    def settle(self) -> None:
+        """Deliver the round in flight, if there is one: fetch its ids and
+        record its tokens.  Whatever reads or moves a slot outside a round
+        calls it first (a preemption, a migration in or out, a prefix
+        handoff; a resume never meets a round in flight, since none is
+        dispatched ahead while a sequence waits to resume); the step that
+        owns the round still retires and counts it."""
+        if self._ahead is not None:
+            self._deliver(self._ahead)
+
     def _decode_round(self, tables, lengths, tokens) -> tuple:
         """Dispatch the decode program over the pools (and the state,
         where the block holds one: a sixth argument, donated like the
@@ -635,6 +712,7 @@ class ServingEngine:
                 preempted += 1
 
     def _preempt_slot(self, slot: int) -> None:
+        self.settle()
         state = self.batcher.slots[slot]
         mode = self.bcfg.preempt
         kv = None
@@ -763,6 +841,7 @@ class ServingEngine:
         request that could NEVER migrate (oversized, sampled)."""
         req = request
         self._refuse_diffusion_migration(req)
+        self.settle()
         if req.temperature > 0:
             raise MigrationError(
                 f"request {req.rid}: migration is greedy-only "
@@ -873,6 +952,7 @@ class ServingEngine:
         imported blocks on the next :meth:`step`."""
         req = request
         self._refuse_diffusion_migration(req)
+        self.settle()
         total = req.prompt_len + req.max_new_tokens
         if req.prompt_len < 1 or total > self.pcfg.max_len:
             raise MigrationError(
@@ -967,6 +1047,7 @@ class ServingEngine:
         idx = self.batcher.prefix_index
         if idx is None:
             return None
+        self.settle()
         entries = [
             {
                 "prefix": [int(t) for key in path for t in key],
@@ -998,6 +1079,7 @@ class ServingEngine:
         if idx is None:
             stats["refused"] = "prefix cache disabled"
             return stats
+        self.settle()
         bs = self.pcfg.block_size
         if (
             not isinstance(doc, dict)

@@ -620,7 +620,9 @@ def test_load_cell_finds_the_new_cell_and_its_entries_are_additions():
     for m in bench["end_to_end"] + bench["per_layer"]:
         if CELL in m.get("workloads", ()):
             assert m["workloads"][-1] == CELL
-    new = bench["per_layer"][-5:]
+    names = [m["name"] for m in bench["per_layer"]]
+    first = names.index("engine.tokens_per_slot_pass")
+    new = bench["per_layer"][first:first + 5]
     assert [m["name"] for m in new] == [
         "engine.tokens_per_slot_pass", "attn.block_share",
         "engine.unmask_share", "kernels.block_decode_roofline",

@@ -418,8 +418,10 @@ def test_benchmark_reference_check_runs_the_engines_own_programs():
     loop = serve_closed.ClosedLoop(engine, t, 4, cfg.vocab_size)
     loop.issue()
     loop.round()
+    # its own round, and the next where it was dispatched ahead
+    ahead = engine.report()["counters"]["serve.rounds_ahead"]
     assert calls == {"_prefill": 2, "_write": 2,
-                     "_decode": t["check_steps"] + 1}
+                     "_decode": t["check_steps"] + 1 + ahead}
     assert loop.rounds[-1][2:4] == (2, 1)  # a first token and a decoded one
 
 
@@ -728,6 +730,7 @@ def test_engine_midblock_swap_resume_is_bit_identical(model):
     req = Request(rid=0, prompt=_prompt(rng, 9), max_new_tokens=8)
     eng.submit(req)
     eng.step()  # prefill + first decode: length 9, mid-block
+    eng.settle()  # the round dispatched ahead, recorded before a read
     state = eng.batcher.slots[0]
     assert state.length % pcfg.block_size != 0
     saved = gather_seq(eng.pools, state.block_ids, length=state.length)

@@ -216,8 +216,16 @@ def test_one_engine_round_emits_exactly_its_spans():
         out = eng.step()
     assert out["admitted"] == 1 and out["decoded"] == 1
     spans = _spans(rec)
-    assert sorted(e["name"] for e in spans) == sorted(ROUND_SPANS)
+    # the request goes on, so the round after is dispatched before this
+    # one's ids are fetched: a second dispatch, of round 1
+    assert sorted(e["name"] for e in spans) == sorted(
+        ROUND_SPANS + ["ft.engine.decode_dispatch"])
+    first, ahead = [e for e in spans
+                    if e["name"] == "ft.engine.decode_dispatch"]
+    assert [(e["round"], e["ahead"], e["active"]) for e in (first, ahead)] \
+        == [(0, 0, 1), (1, 1, 1)]
     by_name = {e["name"]: e for e in spans}
+    by_name["ft.engine.decode_dispatch"] = first
     children = set(ROUND_SPANS) - {
         "ft.engine.round", "ft.engine.prefill_dispatch",
         "ft.engine.prefill_fetch_sample"}
@@ -236,6 +244,7 @@ def test_one_engine_round_emits_exactly_its_spans():
     assert book["blocks_total"] == total
     assert book["blocks_in_use"] == total - eng.batcher.allocator.num_free > 0
     assert (book["round"], book["decoded"], book["admitted"], book["finished"]) == (0, 1, 1, 0)
+    assert book["ahead"] == 1
     # the pick's counts, known before the span opens: one greedy slot
     # decoded, its id picked on the device, no logits row fetched
     sample = by_name["ft.engine.sample"]
@@ -251,14 +260,16 @@ def test_one_engine_round_emits_exactly_its_spans():
     assert order == [
         "ft.engine.resume", "ft.batcher.try_admit", "ft.engine.prefill",
         "ft.engine.grow", "ft.batcher.batch_arrays",
-        "ft.engine.decode_dispatch", "ft.engine.decode_fetch",
-        "ft.engine.sample", "ft.engine.retire", "ft.engine.bookkeeping",
+        "ft.engine.decode_dispatch", "ft.engine.decode_dispatch",
+        "ft.engine.decode_fetch", "ft.engine.sample", "ft.engine.retire",
+        "ft.engine.bookkeeping",
     ]
-    # and the next round counts on, on all four
+    # and the next round counts on, on all four: the step delivers round
+    # 1 and dispatches round 2 ahead
     with flight_recorder(None) as rec:
         assert eng.step()["decoded"] == 1
     by_name = {e["name"]: e for e in _spans(rec)}
-    assert [by_name[n]["round"] for n in ROUND_ID_SPANS] == [1] * 4
+    assert [by_name[n]["round"] for n in ROUND_ID_SPANS] == [1, 2, 1, 1]
     assert "ft.engine.prefill_dispatch" not in by_name
 
 
@@ -313,6 +324,40 @@ def test_a_sampled_slot_is_counted_as_a_fetched_row():
     # the counter adds the row its first token took in the prefill
     rows = eng.report()["counters"]["serve.logits_rows_fetched"]
     assert rows == 1 + sum(e["rows_fetched"] for e in samples) == 2
+
+
+def test_a_round_dispatched_ahead_opens_before_the_last_ones_fetch_closes():
+    """Two greedy requests of 4 tokens: rounds 0 and 1 leave both going on,
+    so each step dispatches the next round before it fetches its own ids.
+    That dispatch opens inside the step, after the step's own dispatch and
+    before its fetch closes; every span carries its own round's id;
+    ``active`` and ``ahead`` on the dispatch, and ``ahead`` (the slots whose
+    next round was already dispatched) on the books, say so."""
+    eng = _engine()
+    for rid in (1, 2):
+        assert eng.submit(_request(rid, 5, 4))
+    with flight_recorder(None) as rec:
+        eng.run_until_idle()
+    spans = _spans(rec)
+
+    def by_round(name):
+        return {e["round"]: e for e in spans if e["name"] == name}
+
+    rounds, dispatch, fetch, books = (by_round(n) for n in ROUND_ID_SPANS)
+    assert sorted(rounds) == sorted(dispatch) == sorted(fetch) \
+        == sorted(books) == [0, 1, 2]
+    for n in (0, 1):
+        # round n+1 is dispatched in step n, after round n was, before
+        # round n's fetch closes; its own fetch is the next step's
+        ahead = dispatch[n + 1]
+        assert dispatch[n]["end"] <= ahead["start"]
+        assert rounds[n]["start"] <= ahead["start"] < fetch[n]["end"] \
+            <= rounds[n]["end"] <= fetch[n + 1]["start"]
+    assert [(dispatch[n]["ahead"], dispatch[n]["active"]) for n in range(3)] \
+        == [(0, 2), (1, 2), (1, 2)]
+    assert [(books[n]["ahead"], books[n]["decoded"]) for n in range(3)] \
+        == [(2, 2), (2, 2), (0, 2)]
+    assert eng.report()["counters"]["serve.rounds_ahead"] == 2
 
 
 def test_prefill_prediction_is_built_only_for_a_recorder(monkeypatch):
@@ -410,13 +455,15 @@ def test_every_blocks_dispatch_span_counts_its_layers_and_what_it_keeps(block):
     assert eng.submit(_request(1, 5, 3))
     with flight_recorder(None) as rec:
         eng.step()
-    [dispatch] = [e for e in _spans(rec)
-                  if e["name"] == "ft.engine.decode_dispatch"]
+    # the round and the next, dispatched ahead: both say the same
+    dispatch, ahead = [e for e in _spans(rec)
+                       if e["name"] == "ft.engine.decode_dispatch"]
+    assert (dispatch["ahead"], ahead["ahead"]) == (0, 1)
     report = eng.report()
     for key in ("attn_layers", "attn_kernel_layers", "state_layers",
                 "state_kernel_layers", "state_bytes_per_slot",
                 "cache_bytes_per_position"):
-        assert dispatch[key] == report[key], key
+        assert dispatch[key] == ahead[key] == report[key], key
     assert dispatch["attn_layers"] == (attn or eng.cfg.n_layers)
     assert dispatch["state_layers"] == held
     assert dispatch["attn_kernel_layers"] == dispatch["state_kernel_layers"] == 0

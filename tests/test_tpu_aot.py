@@ -821,6 +821,69 @@ def test_the_hybrid_cells_decode_program_walks_its_pools_in_the_kernel_in_place(
     assert whole < 9.5e9, f"{whole / 1e9:.2f} GB"
 
 
+def test_a_round_fed_the_picks_ids_on_the_device_is_the_same_executable(
+    v5e, tpu_lowering
+):
+    """A round dispatched ahead takes the pick program's ids as they lie on
+    the chip, where any other round takes a host array: the decode program
+    compiled for the v5e for either ``tokens`` is one executable.  Printed
+    without source lines, the two optimized modules are the same
+    instruction for instruction, layout for layout; they differ only where
+    the abstract argument states its placement (the device array's
+    sharding, and that a host array's may be propagated), which at run
+    time an uncommitted array on the engine's one device does not state
+    (``tests/test_dispatch_ahead.py`` counts the compiles there)."""
+    import hashlib
+    import re
+
+    from jax._src.lib import xla_client
+
+    from flextree_tpu.serving.engine import greedy_ids
+    from flextree_tpu.serving.kv_cache import (
+        PagedCacheConfig, init_pools, make_paged_decode_fn,
+    )
+
+    one = NamedSharding(_mesh(v5e, (1, 1, 1)), P())
+    cfg = TransformerConfig(vocab_size=1024, d_model=256, n_heads=2,
+                            n_layers=2, d_ff=512, dtype=jnp.bfloat16)
+    pcfg = PagedCacheConfig(num_blocks=65, block_size=16, blocks_per_seq=8)
+    slots = 8
+    params = _on(jax.eval_shape(
+        lambda k: init_params(k, cfg), jax.random.PRNGKey(0)), one)
+    pools = _on(jax.eval_shape(lambda: init_pools(cfg, pcfg)), one)
+    tables = jax.ShapeDtypeStruct((slots, 8), jnp.int32, sharding=one)
+    lengths = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one)
+    decode = make_paged_decode_fn(cfg, donate=True, fused=True)
+    logits = jax.ShapeDtypeStruct((slots, cfg.vocab_size), jnp.float32,
+                                  sharding=one)
+    [ids] = jax.tree.leaves(jax.eval_shape(jax.jit(greedy_ids), logits))
+    host = jax.ShapeDtypeStruct((slots,), jnp.int32)  # a numpy array
+    device = jax.ShapeDtypeStruct(ids.shape, ids.dtype, sharding=one)
+    assert (ids.shape, ids.dtype) == (host.shape, host.dtype)
+
+    options = xla_client._xla.HloPrintOptions.short_parsable()
+    options.print_metadata = False  # the source lines of the two calls
+
+    def compiled(tokens):
+        exe = decode.trace(params, pools, tables, lengths, tokens).lower(
+            lowering_platforms=("tpu",)).compile()
+        [module] = exe.runtime_executable().hlo_modules()
+        return module.to_string(options).splitlines()
+
+    a, b = compiled(host), compiled(device)
+    placement = re.compile(
+        r"allow_spmd_sharding_propagation_to_parameters=\{[^}]*\}"
+        r"|, sharding=\{replicated\}, "
+        r'frontend_attributes=\{xla\.sdy\.sharding="[^"]*"\}')
+    differ = [i for i, (x, y) in enumerate(zip(a, b)) if x != y]
+    assert len(a) == len(b) > 1000 and len(differ) == 2
+    assert "tokens" in b[differ[1]] and "parameter(24)" in b[differ[1]]
+    print_a, print_b = (
+        hashlib.sha256("\n".join(placement.sub("", x) for x in m).encode())
+        .hexdigest() for m in (a, b))
+    assert print_a == print_b
+
+
 def test_the_hybrid_cells_longest_prefill_fits_beside_what_is_resident(
     v5e, tpu_lowering
 ):
