@@ -65,7 +65,9 @@ BlockDiffusion(...))``, for a model whose pass computes B positions a
 slot): a resident sequence's ``length`` counts its COMMITTED positions, a
 multiple of B, and it holds an :class:`OpenBlock` (the prompt's tail, then
 masked places) at its phase; ``block_arrays`` is a pass's view of the
-slots and ``record_block_pass`` what a pass did: reveals put in place, a
+slots (or of the pass after it, ``ahead=1``, where ``continues_block``
+says that pass is known before this one's reveal is recorded) and
+``record_block_pass`` what a pass did: reveals put in place, a
 fully revealed block's tokens delivered at one stamp, a commit pass that
 advances ``length`` by B and opens the next block.  A request's
 ``max_new_tokens`` must be a multiple of B, and it must be greedy.
@@ -622,12 +624,12 @@ class ContinuousBatcher:
 
     def continues(self, slots) -> bool:
         """Whether the round after one that decodes ``slots``, whose tokens
-        are not recorded yet, is known now: one token a slot (no diffusion
-        block), nothing waiting to resume or to be admitted, and every one
-        of ``slots``, and no other, decodes again, greedily, one position
-        further in a block it already holds — none finishes with this
-        round's token (``max_new_tokens``; a stop token could not be
-        known before the token is)."""
+        are not recorded yet, is known now: one token a slot (a diffusion
+        pass has :meth:`continues_block`), nothing waiting to resume or to
+        be admitted, and every one of ``slots``, and no other, decodes
+        again, greedily, one position further in a block it already holds —
+        none finishes with this round's token (``max_new_tokens``; a stop
+        token could not be known before the token is)."""
         if self.diffusion is not None or self.preempted:
             return False
         if self.queue and None in self.slots:
@@ -665,12 +667,17 @@ class ContinuousBatcher:
         s.token_times.append(now_s)
         self._maybe_finish(s, now_s)
 
-    def block_arrays(self):
+    def block_arrays(self, ahead: int = 0):
         """A diffusion pass's view of the slots: (tables (S, P), lengths
         (S,) COMMITTED positions, tokens (S, B) the open blocks as they
         stand, masked (S, B), reveal (S,) places this pass reveals (0 in a
-        commit pass), active (S,)); inactive rows are all-NULL_BLOCK tables
-        at length 0 with nothing masked."""
+        commit pass), commit (S,) the slots whose pass is a commit pass,
+        active (S,)); inactive rows are all-NULL_BLOCK tables at length 0
+        with nothing masked.  ``ahead``: 1 for the pass after one whose
+        reveal is not recorded yet, each slot one phase further (after a
+        commit pass, the first pass of a fresh block ``B`` positions
+        further); its tokens and masked places are that pass's fold, on
+        the device, and are None here."""
         S, P = self.bcfg.slots, self.pcfg.blocks_per_seq
         b = self.diffusion.length
         tables = np.full((S, P), NULL_BLOCK, np.int32)
@@ -678,19 +685,61 @@ class ContinuousBatcher:
         tokens = np.zeros((S, b), np.int32)
         masked = np.zeros((S, b), bool)
         reveal = np.zeros((S,), np.int32)
+        commit = np.zeros((S,), bool)
         active = np.zeros((S,), bool)
         for i, s in enumerate(self.slots):
             if s is None or s.done:
                 continue
             blk = s.block
+            length, phase, reveals = s.length, blk.phase, blk.reveals
+            if ahead and phase == len(reveals):  # a fresh block
+                length, phase = length + b, 0
+                reveals = self.diffusion.reveals(b)
+            elif ahead:
+                phase += 1
             tables[i, : len(s.block_ids)] = s.block_ids
-            lengths[i] = s.length
+            lengths[i] = length
             tokens[i] = blk.tokens
             masked[i] = blk.masked
-            if blk.phase < len(blk.reveals):
-                reveal[i] = blk.reveals[blk.phase]
+            commit[i] = phase == len(reveals)
+            if not commit[i]:
+                reveal[i] = reveals[phase]
             active[i] = True
-        return tables, lengths, tokens, masked, reveal, active
+        if ahead:
+            tokens = masked = None
+        return tables, lengths, tokens, masked, reveal, commit, active
+
+    def continues_block(self, slots) -> bool:
+        """Whether the diffusion pass after one that runs ``slots``, whose
+        reveal is not recorded yet, is known now but for its tokens and
+        masked places (which the device folds from that reveal): nothing
+        waits to resume or to be admitted, every one of ``slots``, and no
+        other, runs the next pass, no request has stop tokens (a stop could
+        not be known before its token is), none of ``slots`` has its last
+        block delivered by this pass, and each holds the blocks the next
+        pass writes (always under ``reserve``).  Each slot's phase follows
+        the static schedule, its reveals are fixed when its block opens,
+        and a commit pass advances its length by exactly ``B``."""
+        if self.diffusion is None or self.preempted:
+            return False
+        if self.queue and None in self.slots:
+            return False  # an admission would join the next pass
+        if self.active_slots() != list(slots):
+            return False
+        b, bs = self.diffusion.length, self.pcfg.block_size
+        for i in slots:
+            s = self.slots[i]
+            blk, req = s.block, s.request
+            commits = blk.phase == len(blk.reveals)
+            delivers = blk.phase == len(blk.reveals) - 1
+            if (
+                req.stop_tokens
+                or (delivers
+                    and len(s.generated) + b - blk.fixed >= req.max_new_tokens)
+                or s.length + b * commits + b > len(s.block_ids) * bs
+            ):
+                return False
+        return True
 
     def record_block_pass(self, slots, ids, revealed, now_s: float) -> dict:
         """What one pass did to the ``slots`` that ran it: ``ids`` (S, B)

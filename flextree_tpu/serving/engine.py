@@ -38,10 +38,16 @@ one picks greedily, nothing waits to resume or to be admitted), one
 position further (each in a block it already holds), fed this round's
 picked ids as they lie on the device.  The step then fetches, records and
 retires while the device runs the next round, and the next step finds its
-round already dispatched (``ContinuousBatcher.continues``).  Whatever
-reads or moves a slot outside a round delivers the round in flight first
-(:meth:`ServingEngine.settle`); one that is dropped with its engine (a
-replica's drain) delivers nothing.
+round already dispatched (``ContinuousBatcher.continues``).  A diffusion
+pass goes ahead the same way (``ContinuousBatcher.continues_block``): the
+same slots, none whose last block this pass delivers, no stop tokens,
+nothing waiting to resume or to be admitted; each slot one phase further
+by the static schedule (after a commit pass a fresh block, ``B``
+positions further), its open block this pass's reveal folded in by the
+pick program, on the device.  Whatever reads or moves a slot outside a
+round delivers the round in flight first (:meth:`ServingEngine.settle`);
+one that is dropped with its engine (a replica's drain) delivers
+nothing.
 
 Sampling is per request, and token ids, not logits, cross to the host:
 the greedy pick is :func:`greedy_ids` on the device (``generate``'s
@@ -138,7 +144,8 @@ def greedy_ids(logits):
     return sample_token(logits)
 
 
-def unmask_greedy_ids(logits, masked, reveal):
+def unmask_greedy_ids(logits, masked, reveal, tokens=None, commit=None,
+                      mask_id=0):
     """A diffusion pass's pick: (S, B, V) logits, (S, B) bool ``masked``
     places, (S,) int32 ``reveal`` counts -> ``(ids, revealed)``: (S, B)
     int32 the greedy token at every place (``greedy_ids``'s first index of
@@ -147,7 +154,15 @@ def unmask_greedy_ids(logits, masked, reveal):
     float32 over the whole vocabulary, ties to the lower place.  Only the
     ids and the mask cross to the host, never a logits row.  A named
     ``def`` (``jit_unmask_greedy_ids`` in a profile: the pick program the
-    benchmark finds by ``greedy_ids``), scope ``ft_unmask``."""
+    benchmark finds by ``greedy_ids``), scope ``ft_unmask``.
+
+    Given the open blocks' ``tokens`` (S, B) and ``commit`` (S,) bool, the
+    slots whose pass is a commit pass, it also folds the reveal into the
+    next pass's open blocks and returns ``(ids, revealed, tokens,
+    masked)``: the revealed ids put in place and their places unmasked, or
+    after a commit pass a fresh block, ``mask_id`` at every place and all
+    of them masked.  That is what ``record_block_pass`` leaves on the
+    host, and it never crosses."""
     with jax.named_scope("ft_unmask"):
         ids = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         top = jnp.max(logits, axis=-1, keepdims=True)
@@ -161,7 +176,40 @@ def unmask_greedy_ids(logits, masked, reveal):
             & (place[None, None, :] < place[None, :, None])
         )
         rank = ahead.sum(axis=-1)
-        return ids, masked & (rank < reveal[:, None])
+        revealed = masked & (rank < reveal[:, None])
+        if tokens is None:
+            return ids, revealed
+        fresh = commit[:, None]
+        return (
+            ids, revealed,
+            jnp.where(fresh, mask_id, jnp.where(revealed, ids, tokens)),
+            fresh | (masked & ~revealed),
+        )
+
+
+class _RevealView:
+    """``(logits, masked, reveal) -> (ids, revealed)``: the pick program
+    a diffusion pass runs, with no block to fold (no slot commits, tokens
+    of 0), and its first two results; ``lower`` lowers that program.  How
+    a caller outside the rounds takes the reveal alone, on the executable
+    the rounds run."""
+
+    def __init__(self, pick, mask_id: int):
+        self.pick, self.mask_id = pick, mask_id
+        self.__name__ = pick.__name__
+
+    def _args(self, logits, masked, reveal) -> tuple:
+        s, b = np.shape(masked)
+        return (logits, masked, reveal, np.zeros((s, b), np.int32),
+                np.zeros((s,), bool))
+
+    def __call__(self, logits, masked, reveal) -> tuple:
+        args = self._args(logits, masked, reveal)
+        return self.pick(*args, mask_id=self.mask_id)[:2]
+
+    def lower(self, logits, masked, reveal):
+        args = self._args(logits, masked, reveal)
+        return self.pick.lower(*args, mask_id=self.mask_id)
 
 
 @dataclasses.dataclass
@@ -179,6 +227,27 @@ class _Round:
     sampled: int
     logits: object
     moe: dict = dataclasses.field(default_factory=dict)
+    delivered: bool = False
+
+
+@dataclasses.dataclass
+class _Pass:
+    """A diffusion pass from its dispatch to its delivery: its id (the
+    step that owns it), the slots it runs, and on the device until it is
+    delivered its ids, revealed places and the routers' counts, with the
+    next pass's open blocks (``tokens``, ``masked``: the reveal folded in
+    by the pick).  Once delivered, what the routers counted (``moe``) and
+    the pass's counts (``blocks``: revealed, committed, emitted)."""
+
+    rid: int
+    active: list
+    ids: object
+    revealed: object
+    counts: object
+    tokens: object
+    masked: object
+    moe: dict = dataclasses.field(default_factory=dict)
+    blocks: dict = dataclasses.field(default_factory=dict)
     delivered: bool = False
 
 
@@ -333,8 +402,13 @@ class ServingEngine:
         # not (S, V) logits cross to the host: two shapes for the server
         # lifetime, the decode round's (S, V) and a prefill's (1, V)
         self._greedy_ids = jax.jit(greedy_ids)
-        # a diffusion pass's pick: the reveal, on the device
-        self._unmask = jax.jit(unmask_greedy_ids)
+        # a diffusion pass's pick: the reveal, on the device, folded there
+        # into the next pass's open blocks; the reveal alone is a view of
+        # the same program
+        self._pass_pick = jax.jit(unmask_greedy_ids, static_argnames="mask_id")
+        self._unmask = _RevealView(
+            self._pass_pick, diffusion.mask_id if diffusion else 0
+        )
         # suffix-only prefill for prefix-cache hits, fused with the block
         # gather into ONE program: one compile per (chain_len, cached_len,
         # suffix_len) bucket — the prefix shapes carry the offset, so
@@ -373,8 +447,9 @@ class ServingEngine:
         self.completed: dict = {}
         self.steps = 0
         self.decode_steps = 0
-        # the round the last step dispatched ahead: the next step's own
-        self._ahead: _Round | None = None
+        # the round (a diffusion pass) the last step dispatched ahead: the
+        # next step's own
+        self._ahead: _Round | _Pass | None = None
         self.metrics.counter("serve.rounds_ahead")
         # windowed prefix hit-rate over the SLO window (admissions only;
         # exported as a gauge so `obs metrics DIR --prom` carries it)
@@ -465,15 +540,28 @@ class ServingEngine:
             emitted = ahead = 0
             block_counts: dict = {}
             if own is None and active and self.block_len > 1:
-                moe_ids, block_counts = self._block_round(active)
-                emitted = block_counts["emitted"]
+                with span("ft.batcher.batch_arrays"):
+                    arrays = self.batcher.block_arrays()
+                own = self._dispatch_pass(self.steps, active, arrays)
             elif own is None and active:
                 with span("ft.batcher.batch_arrays"):
                     tables, lengths, tokens, _ = self.batcher.batch_arrays()
                 own = self._dispatch_round(
                     self.steps, active, tables, lengths, tokens, 0
                 )
-            if own is not None:
+            if own is not None and self.block_len > 1:
+                if not own.delivered and self.batcher.continues_block(active):
+                    # the next pass is known but for its open blocks, which
+                    # are this pass's reveal folded in, on the device
+                    self._ahead = self._dispatch_pass(
+                        self.steps + 1, active,
+                        self.batcher.block_arrays(ahead=1), own,
+                    )
+                    self.metrics.counter("serve.rounds_ahead").inc()
+                    ahead = len(active)
+                moe_ids, block_counts = self._deliver_pass(own)
+                emitted = block_counts["emitted"]
+            elif own is not None:
                 if not own.delivered and self.batcher.continues(active):
                     # the next round is known before this one's ids reach
                     # the host: its tokens are those ids, on the device
@@ -556,31 +644,46 @@ class ServingEngine:
             "expert_kernel_layers": self.expert_kernel_layers,
         }
 
-    def _block_round(self, active) -> tuple:
-        """One pass of a diffusion block over every active slot, each at
-        its own phase (a denoising pass of its open block, or the commit
-        pass of a block whose last place the pass before revealed), and the
-        reveal on the device.  Returns ``(what the routers counted, the
-        round's counts: revealed, committed, emitted)``."""
-        b, steps = self.block_len, self.batcher.diffusion.steps
-        with span("ft.batcher.batch_arrays"):
-            tables, lengths, tokens, masked, reveal, act = (
-                self.batcher.block_arrays()
-            )
+    def _dispatch_pass(self, rid: int, active, arrays,
+                       prev: _Pass | None = None) -> _Pass:
+        """Dispatch pass ``rid`` of the diffusion blocks over the
+        ``active`` slots (each at its own phase: a denoising pass of its
+        open block, or the commit pass of a block whose last place the pass
+        before revealed) and the pick of its reveal, and fetch nothing.
+        ``arrays``: ``block_arrays``'s; ``prev``: the pass in flight, whose
+        reveal is not fetched yet, where this pass goes ahead of it (its
+        open blocks are then ``prev``'s fold, on the device)."""
+        tables, lengths, tokens, masked, reveal, commit, act = arrays
+        if prev is not None:
+            tokens, masked = prev.tokens, prev.masked
         with span(
-            "ft.engine.decode_dispatch", round=self.steps,
-            **self._program_counts(), block_len=b,
-            query_rows=self.bcfg.slots * b, denoise_steps=steps,
+            "ft.engine.decode_dispatch", round=rid, active=len(active),
+            ahead=int(prev is not None), **self._program_counts(),
+            block_len=self.block_len,
+            query_rows=self.bcfg.slots * self.block_len,
+            denoise_steps=self.batcher.diffusion.steps,
         ):
             logits, self.pools, routed = self._decode(
                 self.params, self.pools, tables, lengths, tokens, act
             )
-            ids, revealed = self._unmask(logits, masked, reveal)
-        with span("ft.engine.decode_fetch", round=self.steps):
+            picked = self._pass_pick(
+                logits, masked, reveal, tokens, commit,
+                mask_id=self.batcher.diffusion.mask_id,
+            )
+        return _Pass(rid, active, *picked[:2], routed["counts"], *picked[2:])
+
+    def _deliver_pass(self, rnd: _Pass) -> tuple:
+        """Fetch a pass's ids and reveal and record what it did, once.
+        Returns ``(what the routers counted, the pass's counts: revealed,
+        committed, emitted)``."""
+        if rnd.delivered:
+            return rnd.moe, rnd.blocks
+        active = rnd.active
+        with span("ft.engine.decode_fetch", round=rnd.rid):
             # (S, B) ids and the revealed places, and the routers' counts:
             # no logits row crosses
             ids, revealed, counts = jax.device_get(
-                (ids, revealed, routed["counts"])
+                (rnd.ids, rnd.revealed, rnd.counts)
             )
         now = _now()
         with span(
@@ -599,10 +702,10 @@ class ServingEngine:
         m.counter("serve.commit_passes").inc(done["committed"])
         m.counter("serve.blocks_committed").inc(done["blocks"])
         record_event("serve_decode", n_active=len(active))
-        return (
-            {k: int(v) for k, v in zip(MOE_COUNTS, counts)},
-            {k: done[k] for k in ("revealed", "committed", "emitted")},
-        )
+        rnd.moe = {k: int(v) for k, v in zip(MOE_COUNTS, counts)}
+        rnd.blocks = {k: done[k] for k in ("revealed", "committed", "emitted")}
+        rnd.delivered = True
+        return rnd.moe, rnd.blocks
 
     def _dispatch_round(self, rid: int, active, tables, lengths, tokens,
                         ahead: int) -> _Round:
@@ -657,13 +760,17 @@ class ServingEngine:
         return rnd.moe
 
     def settle(self) -> None:
-        """Deliver the round in flight, if there is one: fetch its ids and
-        record its tokens.  Whatever reads or moves a slot outside a round
-        calls it first (a preemption, a migration in or out, a prefix
-        handoff; a resume never meets a round in flight, since none is
-        dispatched ahead while a sequence waits to resume); the step that
-        owns the round still retires and counts it."""
-        if self._ahead is not None:
+        """Deliver the round (or diffusion pass) in flight, if there is
+        one: fetch its ids and record its tokens.  Whatever reads or moves
+        a slot outside a round calls it first (a preemption, a migration in
+        or out, a prefix handoff; a resume never meets a round in flight,
+        since none is dispatched ahead while a sequence waits to resume);
+        the step that owns the round still retires and counts it."""
+        if self._ahead is None:
+            return
+        if self.block_len > 1:
+            self._deliver_pass(self._ahead)
+        else:
             self._deliver(self._ahead)
 
     def _decode_round(self, tables, lengths, tokens) -> tuple:
@@ -1354,13 +1461,19 @@ class ServingEngine:
         # round's (S, V) logits here and a prefill's (1, V) below
         b = self.block_len
         if b > 1:
-            logits, self.pools, _ = self._decode(
-                self.params, self.pools, np.zeros((S, P), np.int32), null(S),
-                np.zeros((S, b), np.int32), np.zeros((S,), bool),
-            )
-            jax.block_until_ready(self._unmask(
-                logits, np.zeros((S, b), bool), null(S)
-            ))
+            # a pass and its pick on host arrays, then on the pick's open
+            # blocks as they lie on the device (a pass dispatched ahead)
+            tokens, masked = np.zeros((S, b), np.int32), np.zeros((S, b), bool)
+            for _ in range(2):
+                logits, self.pools, _ = self._decode(
+                    self.params, self.pools, np.zeros((S, P), np.int32),
+                    null(S), tokens, np.zeros((S,), bool),
+                )
+                *_, tokens, masked = self._pass_pick(
+                    logits, masked, null(S), tokens, np.zeros((S,), bool),
+                    mask_id=self.batcher.diffusion.mask_id,
+                )
+            jax.block_until_ready(tokens)
         else:
             logits, *_ = self._decode_round(
                 np.zeros((S, P), np.int32), null(S), null(S)

@@ -4,7 +4,9 @@ fetches its own round's ids, when the host already knows that round
 the device.  The tokens are those of the engine that never does, step by
 step; the path disengages wherever the next round is not known yet; and a
 round in flight is delivered before anything reads or moves a slot, or
-dropped with its replica.
+dropped with its replica.  A diffusion pass goes ahead the same way
+(``ContinuousBatcher.continues_block``), fed the pick's fold of its reveal
+into the next pass's open blocks on the device.
 """
 
 import jax
@@ -306,3 +308,273 @@ def test_a_replica_killed_with_a_round_in_flight_returns_each_request_once(
     # the dead replica delivered its first round only: the one in flight
     # was dropped with it, and the survivor recomputed both requests
     assert engines[1].decode_steps == 1 and engines[1]._ahead is not None
+
+
+# ------------------------------------------------------- the diffusion pass
+
+
+def _sdar(slots=4):
+    from tests.test_sdar_moe import engine as sdar_engine, tiny as sdar_tiny
+
+    return sdar_engine(sdar_tiny(), slots=slots)
+
+
+def _sdar_prompt(n, seed):
+    from tests.test_sdar_moe import MASK
+
+    return _prompt(seed, n, MASK)
+
+
+#: step -> (prompt length, max_new_tokens) submitted before it: whole blocks
+#: and prompt tails of 1 to 3, admissions mid-stream, and (for four slots)
+#: a pass that holds all three phases and one that finishes two requests
+_PASSES = {0: [(8, 12), (10, 8), (9, 4)], 1: [(12, 8)], 2: [(7, 8)],
+           6: [(11, 4), (6, 8)], 9: [(16, 8)]}
+_PASSES_MAX_NEW = [m for step in sorted(_PASSES) for _, m in _PASSES[step]]
+
+
+def _open_blocks(eng):
+    return [None if s is None else (s.length, list(s.generated),
+                                    list(s.block.tokens), list(s.block.masked),
+                                    s.block.phase)
+            for s in eng.batcher.slots]
+
+
+def test_passes_dispatched_ahead_give_the_tokens_of_the_engine_that_waits(
+    monkeypatch
+):
+    """The same requests through two engines of the diffusion block, one
+    never dispatching a pass ahead: every request's tokens and count the
+    same, bit for bit.  (A request that arrives while a pass is in flight
+    joins the pass after it, so the two engines' steps differ there.)
+    After each pass dispatched ahead, the host's own arrays once the pass
+    before it is recorded are the ones it predicted, and its open blocks
+    the pick's fold, commit passes included."""
+    fast, slow = _sdar(), _sdar()
+    monkeypatch.setattr(slow.batcher, "continues_block", lambda slots: False)
+    predicted, phases = [], []
+    dispatch, record = fast._dispatch_pass, fast.batcher.record_block_pass
+
+    def dispatch_spy(rid, active, arrays, prev=None):
+        if prev is not None:
+            predicted.append((arrays, np.asarray(prev.tokens),
+                              np.asarray(prev.masked)))
+        return dispatch(rid, active, arrays, prev)
+
+    def record_spy(slots, ids, revealed, now):
+        phases.append({fast.batcher.slots[i].block.phase for i in slots})
+        return record(slots, ids, revealed, now)
+
+    monkeypatch.setattr(fast, "_dispatch_pass", dispatch_spy)
+    monkeypatch.setattr(fast.batcher, "record_block_pass", record_spy)
+    rid, step, finished = 0, 0, []
+    while step == 0 or not (fast.idle and slow.idle):
+        for t, m in _PASSES.get(step, []):
+            req = Request(rid=rid, prompt=_sdar_prompt(t, rid), max_new_tokens=m)
+            assert fast.submit(req) and slow.submit(req)
+            rid += 1
+        if not slow.idle:
+            slow.step()
+        if fast.idle:
+            step += 1
+            continue
+        before = len(predicted)
+        finished.append(fast.step()["finished"])
+        if len(predicted) > before:
+            host, tokens, masked = predicted[-1]
+            now = fast.batcher.block_arrays()
+            for i in (0, 1, 4, 5, 6):  # tables, lengths, reveal, commit, active
+                np.testing.assert_array_equal(host[i], now[i])
+            np.testing.assert_array_equal(tokens[now[6]], now[2][now[6]])
+            np.testing.assert_array_equal(masked[now[6]], now[3][now[6]])
+        step += 1
+    assert rid == 8
+    assert sorted(fast.completed) == sorted(slow.completed) == list(range(8))
+    for r, done in slow.completed.items():
+        np.testing.assert_array_equal(fast.completed[r].tokens, done.tokens)
+        assert fast.completed[r].n_tokens == done.n_tokens == _PASSES_MAX_NEW[r]
+    # what the traffic was for: a pass of all three phases, a commit pass
+    # folded ahead, two finishers in one round, passes that went ahead
+    assert {0, 1, 2} in phases and max(finished) >= 2
+    assert any(p[0][5].any() for p in predicted)
+    assert _ahead(fast) == len(predicted) > 0 and _ahead(slow) == 0
+
+
+def test_the_picks_fold_is_what_the_host_records_and_its_reveal_is_todays():
+    """The pick a pass runs, against the reveal alone (``unmask_greedy_ids``
+    on three arguments, as it was before the fold), on logits with exact
+    and one-ulp near ties: the same ids and revealed places, bit for bit;
+    and its folded open blocks are those ``record_block_pass`` leaves on
+    the host, pass after pass, commit passes and prompt tails included."""
+    from flextree_tpu.serving.batcher import BlockDiffusion
+    from flextree_tpu.serving.engine import unmask_greedy_ids
+
+    diff = BlockDiffusion(4, 2, 90)
+    batcher = ContinuousBatcher(PCFG, BatcherConfig(slots=4), diff)
+    pick = jax.jit(unmask_greedy_ids, static_argnames="mask_id")
+    reveal_alone = jax.jit(unmask_greedy_ids)
+    rng = np.random.default_rng(7)
+    arrivals = {0: [(8, 12), (10, 8)], 1: [(7, 12)], 2: [(9, 16)]}
+    commits = 0
+    for n in range(14):
+        for t, m in arrivals.get(n, []):
+            assert batcher.submit(Request(rid=100 * n + t, prompt=_prompt(n, t, 90),
+                                          max_new_tokens=m))
+        batcher.try_admit()
+        slots = batcher.active_slots()
+        _, _, tokens, masked, reveal, commit, _ = batcher.block_arrays()
+        logits = rng.normal(size=(4, 4, 96)).astype(np.float32)
+        logits[:, 1] = logits[:, 0]  # places 0 and 1 tie exactly
+        logits[:, 3] = np.nextafter(logits[:, 2], np.inf)  # a hair apart
+        ids, revealed, nxt_tokens, nxt_masked = (np.asarray(a) for a in pick(
+            logits, masked, reveal, tokens, commit, mask_id=90))
+        want_ids, want_revealed = reveal_alone(logits, masked, reveal)
+        np.testing.assert_array_equal(ids, want_ids)
+        np.testing.assert_array_equal(revealed, want_revealed)
+        commits += int(commit.sum())
+        batcher.record_block_pass(slots, ids, revealed, float(n))
+        _, _, tokens, masked, _, _, still = batcher.block_arrays()
+        np.testing.assert_array_equal(nxt_tokens[still], tokens[still])
+        np.testing.assert_array_equal(nxt_masked[still], masked[still])
+        batcher.retire_ready()
+    assert commits >= 4
+
+
+def test_the_next_pass_waits_where_the_host_cannot_know_it():
+    """``continues_block`` is False for a request with stop tokens, a slot
+    whose pass delivers its last block, a queued request with a free slot,
+    and a preempted sequence; True otherwise."""
+    from flextree_tpu.serving.batcher import BlockDiffusion
+
+    def batcher_with(*reqs, slots=3):
+        b = ContinuousBatcher(PCFG, BatcherConfig(slots=slots),
+                              BlockDiffusion(4, 2, 90))
+        for r in reqs:
+            assert b.submit(r)
+        b.try_admit()
+        return b
+
+    def req(rid, m=8, **kw):
+        return Request(rid=rid, prompt=_prompt(rid, 8, 90), max_new_tokens=m, **kw)
+
+    b = batcher_with(req(0), req(1))
+    assert b.continues_block(b.active_slots())
+    assert not b.continues_block(b.active_slots()[:1])  # not the slots there
+    # stop tokens
+    b = batcher_with(req(0), req(1, stop_tokens=(5,)))
+    assert not b.continues_block(b.active_slots())
+    # a pass that delivers a request's last block: a 4-token answer is one
+    # block, delivered by its second denoising pass
+    b = batcher_with(req(0), req(1, m=4))
+    slots = b.active_slots()
+    assert b.continues_block(slots)  # phase 0 delivers nothing
+    b.slots[slots[1]].block.phase = 1
+    assert not b.continues_block(slots)
+    b.slots[slots[1]].request = req(1, m=8)  # one block of two: goes on
+    assert b.continues_block(slots)
+    # a queued request with a free slot, and none free
+    b = batcher_with(req(0), req(1))
+    assert b.submit(req(2))
+    assert b.queue and not b.continues_block(b.active_slots())
+    b = batcher_with(req(0), req(1), slots=2)
+    assert b.submit(req(2))
+    assert b.queue and b.continues_block(b.active_slots())
+    # a preempted sequence waiting to resume
+    b = batcher_with(req(0), req(1))
+    slots = b.active_slots()
+    b.preempt(slots[-1])
+    assert not b.continues_block(slots[:-1])
+    # the one-token check never takes a diffusion pass, nor this one a round
+    assert not batcher_with(req(0)).continues([0])
+    one = ContinuousBatcher(PCFG, BatcherConfig(slots=2))
+    assert not one.continues_block([])
+
+
+def test_a_pass_fed_the_picks_blocks_compiles_nothing_after_the_warmup():
+    """The warm-up runs a pass and its pick on host arrays, then on the
+    pick's open blocks as they lie on the device; the window's passes,
+    synchronous and dispatched ahead, compile nothing."""
+    import jax.monitoring
+
+    eng = _sdar(slots=3)
+    eng.warmup([10], [eng.pcfg.blocks_for(10 + 8)])
+    compiles = []
+
+    def on(name, duration, **_):
+        if name == "/jax/core/compile/backend_compile_duration":
+            compiles.append(duration)
+
+    jax.monitoring.register_event_duration_secs_listener(on)
+    try:
+        for rid in range(4):
+            assert eng.submit(Request(rid=rid, prompt=_sdar_prompt(10, rid),
+                                      max_new_tokens=8))
+        eng.run_until_idle()
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on)
+    assert _ahead(eng) > 0 and compiles == []
+    assert sorted(eng.completed) == [0, 1, 2, 3]
+
+
+def test_settle_delivers_a_pass_in_flight_exactly_once():
+    """A pass dispatched ahead, settled outside a step: its reveal is
+    recorded once (a second settle and the step that owns it record
+    nothing more), the step still counts what it delivered, and every
+    completion is the waiting engine's."""
+    fast, slow = _sdar(slots=2), _sdar(slots=2)
+    slow.batcher.continues_block = lambda slots: False
+    for eng in (fast, slow):
+        for rid in range(2):
+            assert eng.submit(Request(rid=rid, prompt=_sdar_prompt(9, rid),
+                                      max_new_tokens=8))
+    assert fast.step() == slow.step()
+    assert fast._ahead is not None and not fast._ahead.delivered
+    revealed = fast.report()["counters"]["serve.tokens_revealed"]
+    fast.settle()
+    assert fast._ahead.delivered and fast.decode_steps == 2
+    counters = fast.report()["counters"]
+    assert counters["serve.tokens_revealed"] > revealed
+    fast.settle()
+    assert fast.decode_steps == 2
+    assert fast.report()["counters"] == counters
+    assert fast.step() == slow.step()
+    assert fast.decode_steps == slow.decode_steps == 2
+    assert _open_blocks(fast) == _open_blocks(slow)
+    fast.run_until_idle()
+    slow.run_until_idle()
+    for rid in range(2):
+        np.testing.assert_array_equal(fast.completed[rid].tokens,
+                                      slow.completed[rid].tokens)
+
+
+def test_the_spans_and_the_counter_count_the_passes_dispatched_ahead():
+    """Each pass dispatched ahead opens ``ft.engine.decode_dispatch`` with
+    ``ahead`` 1 and the round of the step after the one that dispatched it,
+    its ``ft.engine.decode_fetch`` carries that id once, the step that
+    owns it counts its slots under ``ahead`` on ``ft.engine.bookkeeping``,
+    and ``serve.rounds_ahead`` counts the passes."""
+    from flextree_tpu.obs import flight_recorder
+
+    eng = _sdar(slots=3)
+    for rid in range(4):
+        assert eng.submit(Request(rid=rid, prompt=_sdar_prompt(7 + rid, rid),
+                                  max_new_tokens=8))
+    with flight_recorder(None) as rec:
+        eng.run_until_idle()
+
+    def spans(name):
+        return [e for e in rec.events if e["kind"] == "span" and e["name"] == name]
+
+    dispatched = spans("ft.engine.decode_dispatch")
+    books = spans("ft.engine.bookkeeping")
+    fetched = [e["round"] for e in spans("ft.engine.decode_fetch")]
+    ahead = [e for e in dispatched if e["ahead"] == 1]
+    assert ahead and len(ahead) == _ahead(eng)
+    assert sorted(e["round"] for e in dispatched) == sorted(fetched)
+    assert len(set(fetched)) == len(fetched) == eng.decode_steps
+    by_round = {e["round"]: e for e in books}
+    for e in ahead:
+        # dispatched by the step before its own, for the same slots
+        assert by_round[e["round"] - 1]["ahead"] == e["active"]
+        assert by_round[e["round"]]["decoded"] == e["active"]
+    assert sum(e["ahead"] for e in books) == sum(e["active"] for e in ahead)

@@ -1019,6 +1019,80 @@ def test_the_block_cells_pass_program_walks_in_the_kernel_in_place(
     assert whole < 10.8e9, f"{whole / 1e9:.2f} GB"
 
 
+def test_a_pass_fed_the_picks_open_blocks_on_the_device_is_the_same_executable(
+    v5e, tpu_lowering
+):
+    """A diffusion pass dispatched ahead takes its open blocks from the pick
+    program as they lie on the chip, where any other pass takes them from
+    the host: at the cell's shapes the pick compiles for the v5e with its
+    fold, and the pass program compiled for either ``tokens`` is one
+    executable (the two optimized modules differ only where the abstract
+    argument states its placement, as for the one-token round's ids)."""
+    import hashlib
+    import re
+
+    from jax._src.lib import xla_client
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from flextree_tpu.models import sdar_moe
+    from flextree_tpu.serving.engine import unmask_greedy_ids
+    from flextree_tpu.serving.kv_cache import init_pools, make_paged_decode_fn
+
+    cfg, t, pcfg = _sdar_cell()
+    slots, b = t["slots"], cfg.block_len
+    one = NamedSharding(_mesh(v5e[:1], (1, 1, 1)), P())
+    params = _on(jax.eval_shape(
+        lambda k: sdar_moe.init_params(k, cfg), jax.random.PRNGKey(0)), one)
+    pools = _on(jax.eval_shape(lambda: init_pools(cfg, pcfg)), one)
+
+    def a(*s, dt=jnp.int32, placed=True):
+        return jax.ShapeDtypeStruct(s, dt, sharding=one if placed else None)
+
+    args = (a(slots, b, cfg.vocab_size, dt=jnp.float32),
+            a(slots, b, dt=jnp.bool_), a(slots), a(slots, b),
+            a(slots, dt=jnp.bool_))
+    _, _, tokens, masked = jax.eval_shape(
+        lambda *x: unmask_greedy_ids(*x, mask_id=cfg.mask_id), *args)
+    assert (tokens.shape, tokens.dtype, masked.dtype) == (
+        (slots, b), jnp.int32, jnp.bool_)
+    pick = jax.jit(unmask_greedy_ids, static_argnames="mask_id")
+    decode = make_paged_decode_fn(cfg, donate=True, fused=True)
+    options = xla_client._xla.HloPrintOptions.short_parsable()
+    options.print_metadata = False  # the source lines of the two calls
+
+    def compiled(tokens):
+        exe = decode.trace(
+            params, pools, a(slots, pcfg.blocks_per_seq), a(slots), tokens,
+            a(slots, dt=jnp.bool_)).lower(lowering_platforms=("tpu",)).compile()
+        [module] = exe.runtime_executable().hlo_modules()
+        return module.to_string(options).splitlines()
+
+    # the pass program with placed tokens is the one the pass test above
+    # compiles too: every compile here is off the persistent cache, which
+    # cannot read a program back for a chip that is described, not attached
+    cached = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        picked = pick.trace(*args, mask_id=cfg.mask_id).lower(
+            lowering_platforms=("tpu",)).compile()
+        host, device = compiled(a(slots, b, placed=False)), compiled(a(slots, b))
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cached)
+        compilation_cache.reset_cache()
+    assert picked.memory_analysis().temp_size_in_bytes < 0.2e9
+    placement = re.compile(
+        r"allow_spmd_sharding_propagation_to_parameters=\{[^}]*\}"
+        r"|, sharding=\{replicated\}, "
+        r'frontend_attributes=\{xla\.sdy\.sharding="[^"]*"\}')
+    differ = [i for i, (x, y) in enumerate(zip(host, device)) if x != y]
+    assert len(host) == len(device) > 1000 and len(differ) == 2
+    print_host, print_device = (
+        hashlib.sha256("\n".join(placement.sub("", x) for x in m).encode())
+        .hexdigest() for m in (host, device))
+    assert print_host == print_device
+
+
 def test_the_block_cells_longest_prefill_is_block_causal_flash_beside_what_is_resident(
     v5e, tpu_lowering
 ):
