@@ -60,16 +60,17 @@ pool pressure evicts idle index entries before blocking admission.
 Still a pure host-side state machine: the index stores token ids and
 block ids, never tensors.
 
-**Diffusion over blocks** (``ContinuousBatcher(..., diffusion=
-BlockDiffusion(...))``, for a model whose pass computes B positions a
-slot): a resident sequence's ``length`` counts its COMMITTED positions, a
-multiple of B, and it holds an :class:`OpenBlock` (the prompt's tail, then
-masked places) at its phase; ``block_arrays`` is a pass's view of the
-slots (or of the pass after it, ``ahead=1``, where ``continues_block``
-says that pass is known before this one's reveal is recorded) and
-``record_block_pass`` what a pass did: reveals put in place, a
-fully revealed block's tokens delivered at one stamp, a commit pass that
-advances ``length`` by B and opens the next block.  A request's
+**A round's kind** (``ContinuousBatcher(..., kind)``): one token a slot
+(:class:`OneToken`), or a pass of B positions a slot for a model that
+generates by diffusion over blocks (:class:`BlockDiffusion`).  The kind
+lays out ``batch_arrays`` and says, slot by slot, whether a slot goes on
+in the round after one whose tokens are not recorded yet (``continues``).
+Under diffusion a resident sequence's ``length`` counts its COMMITTED
+positions, a multiple of B, and it holds an :class:`OpenBlock` (the
+prompt's tail, then masked places) at its phase; ``record_block_pass`` is
+what a pass did: reveals put in place, a fully revealed block's tokens
+delivered at one stamp, a commit pass that advances ``length`` by B and
+opens the next block.  A request's
 ``max_new_tokens`` must be a multiple of B, and it must be greedy.
 """
 
@@ -87,6 +88,7 @@ __all__ = [
     "Request",
     "SeqState",
     "OpenBlock",
+    "OneToken",
     "BlockDiffusion",
     "PreemptedSeq",
     "BatcherConfig",
@@ -115,12 +117,51 @@ class Request:
         return int(np.asarray(self.prompt).shape[0])
 
 
+class OneToken:
+    """A round of one token a slot: every block's but a diffusion block's
+    (:class:`BlockDiffusion`)."""
+
+    def arrays(self, slots, blocks_per_seq: int, ahead: int = 0) -> tuple:
+        """(tables (S, P), lengths (S,), tokens (S,), active (S,)) int32 /
+        bool numpy views of ``slots`` — inactive rows are all-NULL_BLOCK
+        tables at length 0 with token 0 (masked no-ops).  ``ahead``: 1 for
+        the round after one whose tokens are not recorded yet; an active
+        row's length counts that round's position, and the tokens are
+        that round's ids, on the device, and are None here."""
+        S, P = len(slots), blocks_per_seq
+        tables = np.full((S, P), NULL_BLOCK, np.int32)
+        lengths = np.zeros((S,), np.int32)
+        tokens = np.zeros((S,), np.int32)
+        active = np.zeros((S,), bool)
+        for i, s in enumerate(slots):
+            if s is None or s.done:
+                continue
+            tables[i, : len(s.block_ids)] = s.block_ids
+            lengths[i] = s.length + ahead
+            tokens[i] = s.pending_token
+            active[i] = True
+        return tables, lengths, None if ahead else tokens, active
+
+    def goes_on(self, s: SeqState, block_size: int) -> bool:
+        """Whether ``s`` runs the round after one whose token is not
+        recorded yet, known now: it picks greedily, does not finish with
+        that token (``max_new_tokens``), and writes one position further
+        in a block it already holds."""
+        req = s.request
+        return (
+            req.temperature <= 0
+            and len(s.generated) + 1 < req.max_new_tokens
+            and (s.length + 1) // block_size < len(s.block_ids)
+        )
+
+
 @dataclasses.dataclass(frozen=True)
 class BlockDiffusion:
     """How a model that generates by diffusion over blocks makes its
     tokens: blocks of ``length`` positions aligned on absolute positions,
     ``steps`` denoising passes a block, then one commit pass; an
-    unrevealed place holds ``mask_id``."""
+    unrevealed place holds ``mask_id``.  The kind of its rounds, each a
+    pass."""
 
     length: int
     steps: int
@@ -133,6 +174,61 @@ class BlockDiffusion:
         ``get_num_transfer_tokens``)."""
         base, rest = divmod(masked, self.steps)
         return tuple(base + (j < rest) for j in range(self.steps))
+
+    def arrays(self, slots, blocks_per_seq: int, ahead: int = 0) -> tuple:
+        """A pass's view of ``slots``: (tables (S, P), lengths (S,)
+        COMMITTED positions, tokens (S, B) the open blocks as they stand,
+        masked (S, B), reveal (S,) places this pass reveals (0 in a commit
+        pass), commit (S,) the slots whose pass is a commit pass, active
+        (S,)); inactive rows are all-NULL_BLOCK tables at length 0 with
+        nothing masked.  ``ahead``: 1 for the pass after one whose reveal
+        is not recorded yet, each slot one phase further (after a commit
+        pass, the first pass of a fresh block ``B`` positions further);
+        its tokens and masked places are that pass's fold, on the device,
+        and are None here."""
+        S, P, b = len(slots), blocks_per_seq, self.length
+        tables = np.full((S, P), NULL_BLOCK, np.int32)
+        lengths = np.zeros((S,), np.int32)
+        tokens = np.zeros((S, b), np.int32)
+        masked = np.zeros((S, b), bool)
+        reveal = np.zeros((S,), np.int32)
+        commit = np.zeros((S,), bool)
+        active = np.zeros((S,), bool)
+        for i, s in enumerate(slots):
+            if s is None or s.done:
+                continue
+            blk = s.block
+            length, phase, reveals = s.length, blk.phase, blk.reveals
+            if ahead and phase == len(reveals):  # a fresh block
+                length, phase = length + b, 0
+                reveals = self.reveals(b)
+            elif ahead:
+                phase += 1
+            tables[i, : len(s.block_ids)] = s.block_ids
+            lengths[i] = length
+            tokens[i] = blk.tokens
+            masked[i] = blk.masked
+            commit[i] = phase == len(reveals)
+            if not commit[i]:
+                reveal[i] = reveals[phase]
+            active[i] = True
+        if ahead:
+            tokens = masked = None
+        return tables, lengths, tokens, masked, reveal, commit, active
+
+    def goes_on(self, s: SeqState, block_size: int) -> bool:
+        """Whether ``s`` runs the pass after one whose reveal is not
+        recorded yet, known now but for its open block: this pass does not
+        deliver its last block, and it holds the blocks the next pass
+        writes (always under ``reserve``).  Its phase follows the static
+        schedule, and a commit pass advances its length by exactly B."""
+        blk, b = s.block, self.length
+        commits = blk.phase == len(blk.reveals)
+        delivers = blk.phase == len(blk.reveals) - 1
+        return not (
+            delivers
+            and len(s.generated) + b - blk.fixed >= s.request.max_new_tokens
+        ) and s.length + b * commits + b <= len(s.block_ids) * block_size
 
 
 @dataclasses.dataclass
@@ -233,15 +329,15 @@ class BatcherConfig:
 
 
 class ContinuousBatcher:
-    """``diffusion``: how a model that generates by diffusion over blocks
-    makes its tokens (:class:`BlockDiffusion`), or None for one token a
-    slot a round."""
+    """``kind``: what a round is, :class:`OneToken` (the default) or a
+    :class:`BlockDiffusion` (then also ``diffusion``)."""
 
     def __init__(self, pcfg: PagedCacheConfig, bcfg: BatcherConfig,
-                 diffusion: BlockDiffusion | None = None):
+                 kind: OneToken | BlockDiffusion | None = None):
         self.pcfg = pcfg
         self.bcfg = bcfg
-        self.diffusion = diffusion
+        self.kind = OneToken() if kind is None else kind
+        self.diffusion = kind if isinstance(kind, BlockDiffusion) else None
         self.allocator = BlockAllocator(pcfg.num_blocks)
         self.prefix_index = (
             PrefixIndex(pcfg.block_size, self.allocator)
@@ -601,53 +697,29 @@ class ContinuousBatcher:
             if s is not None and not s.done
         ]
 
-    def batch_arrays(self, ahead: int = 0):
-        """(tables (S, P), lengths (S,), tokens (S,), active (S,)) int32 /
-        bool numpy views of the current slots — inactive rows are
-        all-NULL_BLOCK tables at length 0 with token 0 (masked no-ops).
-        ``ahead``: rounds dispatched whose tokens are not recorded yet; an
-        active row's length counts their positions (its token is then
-        stale: the round takes the last round's ids, on the device)."""
-        S, P = self.bcfg.slots, self.pcfg.blocks_per_seq
-        tables = np.full((S, P), NULL_BLOCK, np.int32)
-        lengths = np.zeros((S,), np.int32)
-        tokens = np.zeros((S,), np.int32)
-        active = np.zeros((S,), bool)
-        for i, s in enumerate(self.slots):
-            if s is None or s.done:
-                continue
-            tables[i, : len(s.block_ids)] = s.block_ids
-            lengths[i] = s.length + ahead
-            tokens[i] = s.pending_token
-            active[i] = True
-        return tables, lengths, tokens, active
+    def batch_arrays(self, ahead: int = 0) -> tuple:
+        """A round's host view of the slots, as its kind lays it out
+        (:meth:`OneToken.arrays`, :meth:`BlockDiffusion.arrays`)."""
+        return self.kind.arrays(self.slots, self.pcfg.blocks_per_seq, ahead)
 
     def continues(self, slots) -> bool:
-        """Whether the round after one that decodes ``slots``, whose tokens
-        are not recorded yet, is known now: one token a slot (a diffusion
-        pass has :meth:`continues_block`), nothing waiting to resume or to
-        be admitted, and every one of ``slots``, and no other, decodes
-        again, greedily, one position further in a block it already holds —
-        none finishes with this round's token (``max_new_tokens``; a stop
-        token could not be known before the token is)."""
-        if self.diffusion is not None or self.preempted:
+        """Whether the round after one that runs ``slots``, whose tokens
+        are not recorded yet, is known now but for what it takes of that
+        round on the device: nothing waits to resume or to be admitted,
+        every one of ``slots``, and no other, runs again, none has stop
+        tokens (a stop could not be known before its token is), and the
+        kind says each goes on (``goes_on``)."""
+        if self.preempted:
             return False
         if self.queue and None in self.slots:
             return False  # an admission would join the next round
         if self.active_slots() != list(slots):
             return False
-        bs = self.pcfg.block_size
-        for i in slots:
-            s = self.slots[i]
-            req = s.request
-            if (
-                req.temperature > 0
-                or req.stop_tokens
-                or len(s.generated) + 1 >= req.max_new_tokens
-                or (s.length + 1) // bs >= len(s.block_ids)
-            ):
-                return False
-        return True
+        return all(
+            not self.slots[i].request.stop_tokens
+            and self.kind.goes_on(self.slots[i], self.pcfg.block_size)
+            for i in slots
+        )
 
     def record_first_token(self, slot: int, token: int, now_s: float) -> None:
         s = self.slots[slot]
@@ -666,80 +738,6 @@ class ContinuousBatcher:
         s.generated.append(int(token))
         s.token_times.append(now_s)
         self._maybe_finish(s, now_s)
-
-    def block_arrays(self, ahead: int = 0):
-        """A diffusion pass's view of the slots: (tables (S, P), lengths
-        (S,) COMMITTED positions, tokens (S, B) the open blocks as they
-        stand, masked (S, B), reveal (S,) places this pass reveals (0 in a
-        commit pass), commit (S,) the slots whose pass is a commit pass,
-        active (S,)); inactive rows are all-NULL_BLOCK tables at length 0
-        with nothing masked.  ``ahead``: 1 for the pass after one whose
-        reveal is not recorded yet, each slot one phase further (after a
-        commit pass, the first pass of a fresh block ``B`` positions
-        further); its tokens and masked places are that pass's fold, on
-        the device, and are None here."""
-        S, P = self.bcfg.slots, self.pcfg.blocks_per_seq
-        b = self.diffusion.length
-        tables = np.full((S, P), NULL_BLOCK, np.int32)
-        lengths = np.zeros((S,), np.int32)
-        tokens = np.zeros((S, b), np.int32)
-        masked = np.zeros((S, b), bool)
-        reveal = np.zeros((S,), np.int32)
-        commit = np.zeros((S,), bool)
-        active = np.zeros((S,), bool)
-        for i, s in enumerate(self.slots):
-            if s is None or s.done:
-                continue
-            blk = s.block
-            length, phase, reveals = s.length, blk.phase, blk.reveals
-            if ahead and phase == len(reveals):  # a fresh block
-                length, phase = length + b, 0
-                reveals = self.diffusion.reveals(b)
-            elif ahead:
-                phase += 1
-            tables[i, : len(s.block_ids)] = s.block_ids
-            lengths[i] = length
-            tokens[i] = blk.tokens
-            masked[i] = blk.masked
-            commit[i] = phase == len(reveals)
-            if not commit[i]:
-                reveal[i] = reveals[phase]
-            active[i] = True
-        if ahead:
-            tokens = masked = None
-        return tables, lengths, tokens, masked, reveal, commit, active
-
-    def continues_block(self, slots) -> bool:
-        """Whether the diffusion pass after one that runs ``slots``, whose
-        reveal is not recorded yet, is known now but for its tokens and
-        masked places (which the device folds from that reveal): nothing
-        waits to resume or to be admitted, every one of ``slots``, and no
-        other, runs the next pass, no request has stop tokens (a stop could
-        not be known before its token is), none of ``slots`` has its last
-        block delivered by this pass, and each holds the blocks the next
-        pass writes (always under ``reserve``).  Each slot's phase follows
-        the static schedule, its reveals are fixed when its block opens,
-        and a commit pass advances its length by exactly ``B``."""
-        if self.diffusion is None or self.preempted:
-            return False
-        if self.queue and None in self.slots:
-            return False  # an admission would join the next pass
-        if self.active_slots() != list(slots):
-            return False
-        b, bs = self.diffusion.length, self.pcfg.block_size
-        for i in slots:
-            s = self.slots[i]
-            blk, req = s.block, s.request
-            commits = blk.phase == len(blk.reveals)
-            delivers = blk.phase == len(blk.reveals) - 1
-            if (
-                req.stop_tokens
-                or (delivers
-                    and len(s.generated) + b - blk.fixed >= req.max_new_tokens)
-                or s.length + b * commits + b > len(s.block_ids) * bs
-            ):
-                return False
-        return True
 
     def record_block_pass(self, slots, ids, revealed, now_s: float) -> dict:
         """What one pass did to the ``slots`` that ran it: ``ids`` (S, B)

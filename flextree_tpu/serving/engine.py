@@ -32,22 +32,20 @@ against ``generate``.  ``step()`` is one scheduling round:
    free their blocks immediately and land in ``completed``.
 
 **Dispatch ahead.**  A step may dispatch the NEXT round before it fetches
-its own round's ids, when the host already knows that round: the same
-slots (none finishes with this round's token, none has a stop token, every
-one picks greedily, nothing waits to resume or to be admitted), one
-position further (each in a block it already holds), fed this round's
-picked ids as they lie on the device.  The step then fetches, records and
-retires while the device runs the next round, and the next step finds its
-round already dispatched (``ContinuousBatcher.continues``).  A diffusion
-pass goes ahead the same way (``ContinuousBatcher.continues_block``): the
-same slots, none whose last block this pass delivers, no stop tokens,
-nothing waiting to resume or to be admitted; each slot one phase further
-by the static schedule (after a commit pass a fresh block, ``B``
-positions further), its open block this pass's reveal folded in by the
-pick program, on the device.  Whatever reads or moves a slot outside a
-round delivers the round in flight first (:meth:`ServingEngine.settle`);
-one that is dropped with its engine (a replica's drain) delivers
-nothing.
+its own round's ids, when the host already knows that round
+(``ContinuousBatcher.continues``): the same slots, none with a stop
+token, nothing waiting to resume or to be admitted, and each slot going
+on by its round's kind (one token a slot: it picks greedily, does not
+finish with this round's token, and writes one position further in a
+block it holds; a diffusion pass: this pass does not deliver its last
+block, and it holds the blocks the next pass writes).  The next round
+takes what it needs of this one as it lies on the device: the picked ids,
+or the pass's reveal folded into the open blocks by its pick.  The step
+then fetches, records and retires while the device runs the next round,
+and the next step finds its round already dispatched.  Whatever reads or
+moves a slot outside a round delivers the round in flight first
+(:meth:`ServingEngine.settle`); one that is dropped with its engine (a
+replica's drain) delivers nothing.
 
 Sampling is per request, and token ids, not logits, cross to the host:
 the greedy pick is :func:`greedy_ids` on the device (``generate``'s
@@ -64,7 +62,11 @@ own phase (a denoising pass, or the commit pass that keeps a clean block's
 K/V), and the reveal on the device (:func:`unmask_greedy_ids`); a slot
 delivers a whole block of tokens at one stamp or none, and ``step()``
 reports ``emitted``, the tokens delivered (``decoded`` for the other
-blocks).
+blocks).  What differs between a round and a pass is the round's kind
+(``_Tokens``, ``_Blocks``), chosen once when the engine is built: its
+host arrays and its half of ``continues`` (the batcher's), its programs
+and what it records.  One record (``_Round``), one dispatch and one
+delivery serve both.
 
 Timestamps come from the module-level ``_now`` (monotonic), injectable
 for tests the same way ``runtime.supervisor._wall`` is.
@@ -95,6 +97,7 @@ from .batcher import (
     BatcherConfig,
     BlockDiffusion,
     ContinuousBatcher,
+    OneToken,
     Request,
     SeqState,
 )
@@ -214,41 +217,106 @@ class _RevealView:
 
 @dataclasses.dataclass
 class _Round:
-    """A decode round of one token a slot from its dispatch to its
-    delivery: its id (the step that owns it), the slots it decodes, and
-    its ids (with the routers' counts, where the block routes) on the
-    device until it is delivered.  ``logits`` are kept only where a slot
-    samples on the host (``sampled`` of them)."""
+    """A round (a diffusion pass) from its dispatch to its delivery: its id
+    (the step that owns it) and the slots it runs; on the device until it
+    is delivered, what the host fetches (``fetch``: the ids, and a pass's
+    revealed places; ``counts``: the routers', where the block routes) and
+    what the round after it takes there (``feed``: the ids, or a pass's
+    open blocks with its reveal folded in); ``logits`` only where
+    ``sampled`` slots sample on the host.  Once delivered, what the routers
+    counted (``moe``), the kind's counts (``blocks``: a pass's revealed,
+    committed, emitted) and the tokens it delivered (``emitted``)."""
 
     rid: int
     active: list
-    ids: object
+    fetch: tuple
     counts: object
+    feed: tuple
     sampled: int
     logits: object
     moe: dict = dataclasses.field(default_factory=dict)
-    delivered: bool = False
-
-
-@dataclasses.dataclass
-class _Pass:
-    """A diffusion pass from its dispatch to its delivery: its id (the
-    step that owns it), the slots it runs, and on the device until it is
-    delivered its ids, revealed places and the routers' counts, with the
-    next pass's open blocks (``tokens``, ``masked``: the reveal folded in
-    by the pick).  Once delivered, what the routers counted (``moe``) and
-    the pass's counts (``blocks``: revealed, committed, emitted)."""
-
-    rid: int
-    active: list
-    ids: object
-    revealed: object
-    counts: object
-    tokens: object
-    masked: object
-    moe: dict = dataclasses.field(default_factory=dict)
     blocks: dict = dataclasses.field(default_factory=dict)
+    emitted: int = 0
     delivered: bool = False
+
+
+class _Tokens(OneToken):
+    """A round of one token a slot as the engine runs it: the decode
+    program (over the state too, where the block holds one), then
+    :func:`greedy_ids`; the round after it takes those ids, on the
+    device."""
+
+    def span_args(self, eng) -> dict:
+        return {}
+
+    def dispatch(self, eng, arrays, feed) -> tuple:
+        """Dispatch the round's programs on ``arrays`` (``feed``: what the
+        round in flight hands it, where it goes ahead of one).  Returns
+        ``(logits, what the host fetches, the routers' counts or None, what
+        the round after it takes)``."""
+        tables, lengths, tokens, _ = arrays
+        # a model with routed experts hands out a third result, what its
+        # routers did this round
+        logits, *routed = eng._decode_round(
+            tables, lengths, tokens if feed is None else feed[0]
+        )
+        ids = eng._greedy_ids(logits)
+        return logits, (ids,), routed[0]["counts"] if routed else None, (ids,)
+
+    def record(self, eng, rnd, fetched, now) -> tuple:
+        """Record each slot's token: the device's pick, or one sampled on
+        the host from the slot's logits row.  Returns ``(tokens delivered,
+        the kind's counts)``."""
+        (ids,) = fetched
+        for slot in rnd.active:
+            tok = eng._pick(slot, ids, rnd.logits, slot)
+            eng.batcher.record_decode_token(slot, tok, now)
+        return len(rnd.active), {}
+
+
+class _Blocks(BlockDiffusion):
+    """A diffusion pass as the engine runs it: the pass over every slot's
+    open block, each at its own phase (a denoising pass, or the commit pass
+    of a block whose last place the pass before revealed), then
+    :func:`unmask_greedy_ids`, which folds the reveal into the next pass's
+    open blocks on the device: what the pass after it takes."""
+
+    def span_args(self, eng) -> dict:
+        return dict(block_len=self.length,
+                    query_rows=eng.bcfg.slots * self.length,
+                    denoise_steps=self.steps)
+
+    def dispatch(self, eng, arrays, feed) -> tuple:
+        """As :meth:`_Tokens.dispatch`; the host fetches the ids and the
+        revealed places."""
+        tables, lengths, tokens, masked, reveal, commit, act = arrays
+        if feed is not None:
+            tokens, masked = feed
+        logits, eng.pools, routed = eng._decode(
+            eng.params, eng.pools, tables, lengths, tokens, act
+        )
+        ids, revealed, *folded = eng._pass_pick(
+            logits, masked, reveal, tokens, commit, mask_id=self.mask_id
+        )
+        return logits, (ids, revealed), routed["counts"], tuple(folded)
+
+    def record(self, eng, rnd, fetched, now) -> tuple:
+        """Record what the pass did (``record_block_pass``), and count it.
+        Returns ``(tokens delivered, the pass's counts: revealed, committed,
+        emitted)``."""
+        ids, revealed = fetched
+        done = eng.batcher.record_block_pass(rnd.active, ids, revealed, now)
+        m = eng.metrics
+        for slot in done["first"]:
+            m.histogram("serve.ttft_ms").observe(
+                (now - eng.batcher.slots[slot].request.arrival_s) * 1e3
+            )
+        m.counter("serve.tokens_revealed").inc(done["revealed"])
+        m.counter("serve.commit_passes").inc(done["committed"])
+        m.counter("serve.blocks_committed").inc(done["blocks"])
+        return done["emitted"], {
+            k: done[k] for k in ("revealed", "committed", "emitted")
+        }
 
 
 @dataclasses.dataclass(frozen=True)
@@ -324,9 +392,11 @@ class ServingEngine:
         self.metrics.windowed_histogram(
             "serve.ttft_ms", interval_s=self.slo_window_s / 10.0, intervals=10
         )
-        # positions a pass computes a slot: 1, or a diffusion block's B
+        # positions a pass computes a slot: 1, or a diffusion block's B; and
+        # what a round is, the batcher's kind with what the engine runs for
+        # it: one token a slot, or a diffusion block's pass
         self.block_len = block_of(cfg).block_len(cfg)
-        diffusion = None
+        self._kind = _Tokens()
         if self.block_len > 1:
             if pcfg.block_size % self.block_len:
                 raise ValueError(
@@ -339,10 +409,8 @@ class ServingEngine:
                     "block and phase) is not implemented: build the engine "
                     "with BatcherConfig(admission='reserve')"
                 )
-            diffusion = BlockDiffusion(
-                self.block_len, cfg.denoise_steps, cfg.mask_id
-            )
-        self.batcher = ContinuousBatcher(pcfg, self.bcfg, diffusion)
+            self._kind = _Blocks(self.block_len, cfg.denoise_steps, cfg.mask_id)
+        self.batcher = ContinuousBatcher(pcfg, self.bcfg, self._kind)
         if self.batcher.prefix_index is not None and not isinstance(
             cfg, TransformerConfig
         ):
@@ -407,7 +475,7 @@ class ServingEngine:
         # the same program
         self._pass_pick = jax.jit(unmask_greedy_ids, static_argnames="mask_id")
         self._unmask = _RevealView(
-            self._pass_pick, diffusion.mask_id if diffusion else 0
+            self._pass_pick, getattr(self._kind, "mask_id", 0)
         )
         # suffix-only prefill for prefix-cache hits, fused with the block
         # gather into ONE program: one compile per (chain_len, cached_len,
@@ -449,7 +517,7 @@ class ServingEngine:
         self.decode_steps = 0
         # the round (a diffusion pass) the last step dispatched ahead: the
         # next step's own
-        self._ahead: _Round | _Pass | None = None
+        self._ahead: _Round | None = None
         self.metrics.counter("serve.rounds_ahead")
         # windowed prefix hit-rate over the SLO window (admissions only;
         # exported as a gauge so `obs metrics DIR --prom` carries it)
@@ -506,7 +574,6 @@ class ServingEngine:
         docstring); every token of its own round is recorded when it
         returns."""
         t0 = _now()
-        moe_ids: dict = {}  # what the round's routers counted, if any
         with span("ft.engine.round", round=self.steps):
             with span("ft.engine.resume"):
                 resumed = self.batcher.try_resume(t0)
@@ -537,42 +604,27 @@ class ServingEngine:
                 preempted = self._grow_with_preemption()
             own, self._ahead = self._ahead, None
             active = self.batcher.active_slots() if own is None else own.active
-            emitted = ahead = 0
-            block_counts: dict = {}
-            if own is None and active and self.block_len > 1:
+            ahead = 0
+            if own is None and active:
                 with span("ft.batcher.batch_arrays"):
-                    arrays = self.batcher.block_arrays()
-                own = self._dispatch_pass(self.steps, active, arrays)
-            elif own is None and active:
-                with span("ft.batcher.batch_arrays"):
-                    tables, lengths, tokens, _ = self.batcher.batch_arrays()
-                own = self._dispatch_round(
-                    self.steps, active, tables, lengths, tokens, 0
-                )
-            if own is not None and self.block_len > 1:
-                if not own.delivered and self.batcher.continues_block(active):
-                    # the next pass is known but for its open blocks, which
-                    # are this pass's reveal folded in, on the device
-                    self._ahead = self._dispatch_pass(
-                        self.steps + 1, active,
-                        self.batcher.block_arrays(ahead=1), own,
-                    )
-                    self.metrics.counter("serve.rounds_ahead").inc()
-                    ahead = len(active)
-                moe_ids, block_counts = self._deliver_pass(own)
-                emitted = block_counts["emitted"]
-            elif own is not None:
+                    arrays = self.batcher.batch_arrays()
+                own = self._dispatch(self.steps, active, arrays)
+            if own is not None:
                 if not own.delivered and self.batcher.continues(active):
                     # the next round is known before this one's ids reach
-                    # the host: its tokens are those ids, on the device
-                    tables, lengths, _, _ = self.batcher.batch_arrays(ahead=1)
-                    self._ahead = self._dispatch_round(
-                        self.steps + 1, active, tables, lengths, own.ids, 1
+                    # the host, but for what it takes of them: the ids, or
+                    # a pass's reveal folded in, on the device
+                    self._ahead = self._dispatch(
+                        self.steps + 1, active,
+                        self.batcher.batch_arrays(ahead=1), own,
                     )
                     self.metrics.counter("serve.rounds_ahead").inc()
                     ahead = len(active)
-                moe_ids = self._deliver(own)
-                emitted = len(active)
+                self._deliver(own)
+            moe, blocks, emitted = (
+                ({}, {}, 0) if own is None
+                else (own.moe, own.blocks, own.emitted)
+            )
             with span("ft.engine.retire"):
                 finished = self.batcher.retire_ready()
                 for slot, state in finished:
@@ -588,15 +640,13 @@ class ServingEngine:
                 finished=len(finished), blocks_in_use=total - free,
                 blocks_total=total,
                 state_slots_live=self.batcher.num_active if self.state else 0,
-                **moe_ids, **block_counts,
+                **moe, **blocks,
             ):
                 self.steps += 1
                 m = self.metrics
-                if moe_ids:
-                    m.counter("serve.moe_picks").inc(moe_ids["picks"])
-                    m.counter("serve.moe_local_picks").inc(
-                        moe_ids["local_picks"]
-                    )
+                if moe:
+                    m.counter("serve.moe_picks").inc(moe["picks"])
+                    m.counter("serve.moe_local_picks").inc(moe["local_picks"])
                 m.counter("serve.rounds").inc()
                 m.counter("serve.admitted").inc(len(admitted))
                 m.counter("serve.finished").inc(len(finished))
@@ -644,120 +694,54 @@ class ServingEngine:
             "expert_kernel_layers": self.expert_kernel_layers,
         }
 
-    def _dispatch_pass(self, rid: int, active, arrays,
-                       prev: _Pass | None = None) -> _Pass:
-        """Dispatch pass ``rid`` of the diffusion blocks over the
-        ``active`` slots (each at its own phase: a denoising pass of its
-        open block, or the commit pass of a block whose last place the pass
-        before revealed) and the pick of its reveal, and fetch nothing.
-        ``arrays``: ``block_arrays``'s; ``prev``: the pass in flight, whose
-        reveal is not fetched yet, where this pass goes ahead of it (its
-        open blocks are then ``prev``'s fold, on the device)."""
-        tables, lengths, tokens, masked, reveal, commit, act = arrays
-        if prev is not None:
-            tokens, masked = prev.tokens, prev.masked
+    def _dispatch(self, rid: int, active, arrays,
+                  prev: _Round | None = None) -> _Round:
+        """Dispatch round ``rid`` over the ``active`` slots, its decode
+        program and then its pick, and fetch nothing.  ``arrays``:
+        ``batch_arrays``'s; ``prev``: the round in flight, not fetched yet,
+        where this one goes ahead of it (it then takes ``prev.feed``, on
+        the device)."""
         with span(
             "ft.engine.decode_dispatch", round=rid, active=len(active),
             ahead=int(prev is not None), **self._program_counts(),
-            block_len=self.block_len,
-            query_rows=self.bcfg.slots * self.block_len,
-            denoise_steps=self.batcher.diffusion.steps,
+            **self._kind.span_args(self),
         ):
-            logits, self.pools, routed = self._decode(
-                self.params, self.pools, tables, lengths, tokens, act
+            logits, fetch, counts, feed = self._kind.dispatch(
+                self, arrays, None if prev is None else prev.feed
             )
-            picked = self._pass_pick(
-                logits, masked, reveal, tokens, commit,
-                mask_id=self.batcher.diffusion.mask_id,
-            )
-        return _Pass(rid, active, *picked[:2], routed["counts"], *picked[2:])
-
-    def _deliver_pass(self, rnd: _Pass) -> tuple:
-        """Fetch a pass's ids and reveal and record what it did, once.
-        Returns ``(what the routers counted, the pass's counts: revealed,
-        committed, emitted)``."""
-        if rnd.delivered:
-            return rnd.moe, rnd.blocks
-        active = rnd.active
-        with span("ft.engine.decode_fetch", round=rnd.rid):
-            # (S, B) ids and the revealed places, and the routers' counts:
-            # no logits row crosses
-            ids, revealed, counts = jax.device_get(
-                (rnd.ids, rnd.revealed, rnd.counts)
-            )
-        now = _now()
-        with span(
-            "ft.engine.sample", on_device=len(active), rows_fetched=0,
-            active=len(active),
-        ):
-            done = self.batcher.record_block_pass(active, ids, revealed, now)
-        for slot in done["first"]:
-            self.metrics.histogram("serve.ttft_ms").observe(
-                (now - self.batcher.slots[slot].request.arrival_s) * 1e3
-            )
-        self.decode_steps += 1
-        m = self.metrics
-        m.counter("serve.decode_tokens").inc(done["emitted"])
-        m.counter("serve.tokens_revealed").inc(done["revealed"])
-        m.counter("serve.commit_passes").inc(done["committed"])
-        m.counter("serve.blocks_committed").inc(done["blocks"])
-        record_event("serve_decode", n_active=len(active))
-        rnd.moe = {k: int(v) for k, v in zip(MOE_COUNTS, counts)}
-        rnd.blocks = {k: done[k] for k in ("revealed", "committed", "emitted")}
-        rnd.delivered = True
-        return rnd.moe, rnd.blocks
-
-    def _dispatch_round(self, rid: int, active, tables, lengths, tokens,
-                        ahead: int) -> _Round:
-        """Dispatch round ``rid`` over the ``active`` slots and the pick of
-        its ids, and fetch nothing.  ``ahead``: 1 when the previous
-        round's ids are still on the device (``tokens`` are those ids)."""
-        with span(
-            "ft.engine.decode_dispatch", round=rid, active=len(active),
-            ahead=ahead, **self._program_counts(),
-        ):
-            # a model with routed experts hands out a third result, what
-            # its routers did this round
-            logits, *routed = self._decode_round(tables, lengths, tokens)
-            ids = self._greedy_ids(logits)
         # counted while the device decodes
         sampled = sum(
             self.batcher.slots[slot].request.temperature > 0
             for slot in active
         )
-        return _Round(
-            rid, active, ids, routed[0]["counts"] if routed else None,
-            sampled, logits if sampled else None,
-        )
+        return _Round(rid, active, fetch, counts, feed, sampled,
+                      logits if sampled else None)
 
-    def _deliver(self, rnd: _Round) -> dict:
-        """Fetch a round's ids and record its tokens, once.  Returns what
-        its routers counted ({} for a block without experts)."""
+    def _deliver(self, rnd: _Round) -> None:
+        """Fetch a round's ids (and a pass's revealed places) and record
+        what it did, once."""
         if rnd.delivered:
-            return rnd.moe
+            return
         active, sampled = rnd.active, rnd.sampled
         with span("ft.engine.decode_fetch", round=rnd.rid):
             # the logits stay on the device and are dropped with the
             # round.  The routers' counts come back beside the ids, in the
             # same fetch
             if rnd.counts is None:
-                ids = np.asarray(rnd.ids)
+                fetched = [np.asarray(a) for a in rnd.fetch]
             else:
-                ids, counts = jax.device_get((rnd.ids, rnd.counts))
+                *fetched, counts = jax.device_get((*rnd.fetch, rnd.counts))
                 rnd.moe = {k: int(v) for k, v in zip(MOE_COUNTS, counts)}
         now = _now()
         with span(
             "ft.engine.sample", on_device=len(active) - sampled,
             rows_fetched=sampled, active=len(active),
         ):
-            for slot in active:
-                tok = self._pick(slot, ids, rnd.logits, slot)
-                self.batcher.record_decode_token(slot, tok, now)
+            rnd.emitted, rnd.blocks = self._kind.record(self, rnd, fetched, now)
         rnd.delivered, rnd.logits = True, None
         self.decode_steps += 1
-        self.metrics.counter("serve.decode_tokens").inc(len(active))
+        self.metrics.counter("serve.decode_tokens").inc(rnd.emitted)
         record_event("serve_decode", n_active=len(active))
-        return rnd.moe
 
     def settle(self) -> None:
         """Deliver the round (or diffusion pass) in flight, if there is
@@ -766,11 +750,7 @@ class ServingEngine:
         or out, a prefix handoff; a resume never meets a round in flight,
         since none is dispatched ahead while a sequence waits to resume);
         the step that owns the round still retires and counts it."""
-        if self._ahead is None:
-            return
-        if self.block_len > 1:
-            self._deliver_pass(self._ahead)
-        else:
+        if self._ahead is not None:
             self._deliver(self._ahead)
 
     def _decode_round(self, tables, lengths, tokens) -> tuple:
@@ -1457,28 +1437,16 @@ class ServingEngine:
         def null(n):
             return np.zeros((n,), np.int32)
 
-        # the greedy pick is warmed on both of its shapes: the decode
-        # round's (S, V) logits here and a prefill's (1, V) below
+        # the round's programs on the arrays of no live slot, then on what
+        # the first hands the round after it, as it lies on the device (a
+        # round dispatched ahead); the greedy pick is warmed on both of its
+        # shapes, a one-token round's (S, V) logits here and a prefill's
+        # (1, V) below
+        arrays, feed = self._kind.arrays([None] * S, P), None
+        for _ in range(2):
+            *_, feed = self._kind.dispatch(self, arrays, feed)
+        jax.block_until_ready(feed)
         b = self.block_len
-        if b > 1:
-            # a pass and its pick on host arrays, then on the pick's open
-            # blocks as they lie on the device (a pass dispatched ahead)
-            tokens, masked = np.zeros((S, b), np.int32), np.zeros((S, b), bool)
-            for _ in range(2):
-                logits, self.pools, _ = self._decode(
-                    self.params, self.pools, np.zeros((S, P), np.int32),
-                    null(S), tokens, np.zeros((S,), bool),
-                )
-                *_, tokens, masked = self._pass_pick(
-                    logits, masked, null(S), tokens, np.zeros((S,), bool),
-                    mask_id=self.batcher.diffusion.mask_id,
-                )
-            jax.block_until_ready(tokens)
-        else:
-            logits, *_ = self._decode_round(
-                np.zeros((S, P), np.int32), null(S), null(S)
-            )
-            jax.block_until_ready(self._greedy_ids(logits))
         cache = None
         # a diffusion block prefills a prompt's whole blocks only
         for t in sorted(set(int(t) // b * b for t in prompt_lens) - {0}):

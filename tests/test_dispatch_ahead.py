@@ -4,10 +4,12 @@ fetches its own round's ids, when the host already knows that round
 the device.  The tokens are those of the engine that never does, step by
 step; the path disengages wherever the next round is not known yet; and a
 round in flight is delivered before anything reads or moves a slot, or
-dropped with its replica.  A diffusion pass goes ahead the same way
-(``ContinuousBatcher.continues_block``), fed the pick's fold of its reveal
-into the next pass's open blocks on the device.
+dropped with its replica.  A diffusion pass goes ahead by the same path,
+its kind's half of ``continues`` slot by slot, fed the pick's fold of its
+reveal into the next pass's open blocks on the device.
 """
+
+import dataclasses
 
 import jax
 import jax.numpy as jnp
@@ -66,41 +68,126 @@ def _want(params, req, pcfg=PCFG):
         max_new_tokens=req.max_new_tokens, max_len=pcfg.max_len))[0]
 
 
+def _sdar(slots=4):
+    from tests.test_sdar_moe import engine as sdar_engine, tiny as sdar_tiny
+
+    return sdar_engine(sdar_tiny(), slots=slots)
+
+
+def _engine(block, params, slots=3):
+    """An engine of one model: the dense block, one that holds a state a
+    slot (``olmo``), one that routes experts (``laguna``), and the diffusion
+    block (``sdar``, whose rounds are passes)."""
+    if block == "dense":
+        return _dense(params, slots=slots)
+    if block == "sdar":
+        return _sdar(slots=slots)
+    return _block(block)
+
+
+def _vocab(eng):
+    """The ids a prompt may hold: the model's, below a diffusion block's
+    mask id."""
+    diffusion = eng.batcher.diffusion
+    return eng.cfg.vocab_size if diffusion is None else diffusion.mask_id
+
+
+def _slots(eng):
+    """Each slot's length, tokens and open block (a diffusion block's)."""
+    return [None if s is None else (s.length, list(s.generated),
+                                    s.block and dataclasses.astuple(s.block))
+            for s in eng.batcher.slots]
+
+
 # ---------------------------------------------- token for token the same
 
 
-@pytest.mark.parametrize("block", ["dense", "olmo", "laguna"])
+#: step -> (prompt length, max_new_tokens) submitted before it.  One token a
+#: slot: three slots, four requests, all at the start (the fourth waits for
+#: the first to retire).  The diffusion block: whole blocks and prompt tails
+#: of 1 to 3, admissions mid-stream, and (for four slots) a pass that holds
+#: all three phases and one that finishes two requests
+_ROUNDS = {0: [(5, 9), (7, 4), (3, 6), (6, 5)]}
+_PASSES = {0: [(8, 12), (10, 8), (9, 4)], 1: [(12, 8)], 2: [(7, 8)],
+           6: [(11, 4), (6, 8)], 9: [(16, 8)]}
+
+
+@pytest.mark.parametrize("block", ["dense", "olmo", "laguna", "sdar"])
 def test_rounds_dispatched_ahead_give_the_tokens_of_the_engine_that_waits(
     params, block, monkeypatch
 ):
     """The same requests through two engines of one model, one of them
-    never dispatching ahead: after every step the same counts and the same
-    tokens in every slot, and the same completions — for the dense block,
-    a block that holds a state a slot, and one that routes experts."""
-    if block == "dense":
-        fast, slow = _dense(params), _dense(params)
-        vocab = CFG.vocab_size
-    else:
-        fast, slow = _block(block), _block(block)
-        vocab = fast.cfg.vocab_size
+    never dispatching ahead: the same completions, bit for bit — for the
+    dense block, a block that holds a state a slot, one that routes
+    experts, and the diffusion block.  With every request in at the start
+    the two move in lockstep: after every step the same counts and the same
+    tokens in every slot.  (A request that arrives while a round is in
+    flight joins the round after it, so the two engines' steps differ
+    there.)  After each round dispatched ahead, the host's own arrays once
+    the round before it is recorded are the ones it predicted, and what it
+    took on the device (the ids, or a pass's open blocks, the pick's fold,
+    commit passes included) the host's."""
+    traffic = _PASSES if block == "sdar" else _ROUNDS
+    fast = _engine(block, params, slots=4 if block == "sdar" else 3)
+    slow = _engine(block, params, slots=4 if block == "sdar" else 3)
     monkeypatch.setattr(slow.batcher, "continues", lambda slots: False)
-    # three slots, four requests: the fourth waits for the first to retire
-    reqs = [Request(rid=i, prompt=_prompt(i, t, vocab), max_new_tokens=m)
-            for i, (t, m) in enumerate([(5, 9), (7, 4), (3, 6), (6, 5)])]
-    for eng in (fast, slow):
-        for r in reqs:
-            assert eng.submit(r)
-    steps = 0
-    while not slow.idle:
-        assert fast.step() == slow.step()
-        assert _generated(fast) == _generated(slow)
-        steps += 1
-    assert fast.idle and steps > 0
-    assert sorted(fast.completed) == sorted(slow.completed) == [0, 1, 2, 3]
-    for rid, done in slow.completed.items():
-        np.testing.assert_array_equal(fast.completed[rid].tokens, done.tokens)
-    assert _ahead(fast) > 0 and _ahead(slow) == 0
-    assert fast.decode_steps == slow.decode_steps
+    predicted, phases, max_new = [], [], []
+    dispatch, record = fast._dispatch, fast.batcher.record_block_pass
+
+    def dispatch_spy(rid, active, arrays, prev=None):
+        if prev is not None:
+            predicted.append((arrays, prev.feed))
+        return dispatch(rid, active, arrays, prev)
+
+    def record_spy(slots, ids, revealed, now):
+        phases.append({fast.batcher.slots[i].block.phase for i in slots})
+        return record(slots, ids, revealed, now)
+
+    monkeypatch.setattr(fast, "_dispatch", dispatch_spy)
+    monkeypatch.setattr(fast.batcher, "record_block_pass", record_spy)
+    lockstep = list(traffic) == [0]
+    rid, step, finished = 0, 0, []
+    while step == 0 or not (fast.idle and slow.idle):
+        for t, m in traffic.get(step, []):
+            req = Request(rid=rid, prompt=_prompt(rid, t, _vocab(fast)),
+                          max_new_tokens=m)
+            assert fast.submit(req) and slow.submit(req)
+            max_new.append(m)
+            rid += 1
+        waited = None if slow.idle else slow.step()
+        if fast.idle:
+            step += 1
+            continue
+        before = len(predicted)
+        out = fast.step()
+        finished.append(out["finished"])
+        if lockstep:
+            assert out == waited
+            assert _generated(fast) == _generated(slow)
+        if len(predicted) > before:
+            host, feed = predicted[-1]
+            now = fast.batcher.batch_arrays()
+            active, fed = now[-1], iter(feed)
+            for want, got in zip(host, now):
+                if want is None:  # what the round took on the device
+                    np.testing.assert_array_equal(
+                        np.asarray(next(fed))[active], got[active])
+                else:
+                    np.testing.assert_array_equal(want, got)
+        step += 1
+    assert rid == sum(len(reqs) for reqs in traffic.values())
+    assert sorted(fast.completed) == sorted(slow.completed) == list(range(rid))
+    for r, done in slow.completed.items():
+        np.testing.assert_array_equal(fast.completed[r].tokens, done.tokens)
+        assert fast.completed[r].n_tokens == done.n_tokens == max_new[r]
+    assert _ahead(fast) == len(predicted) > 0 and _ahead(slow) == 0
+    if lockstep:
+        assert fast.decode_steps == slow.decode_steps
+    else:
+        # what the traffic was for: a pass of all three phases, a commit
+        # pass folded ahead, two finishers in one round
+        assert {0, 1, 2} in phases and max(finished) >= 2
+        assert any(arrays[5].any() for arrays, _ in predicted)
 
 
 def test_completions_are_generates_with_finishers_in_one_round(params):
@@ -122,17 +209,22 @@ def test_completions_are_generates_with_finishers_in_one_round(params):
     assert _ahead(eng) > 0
 
 
-def test_a_round_fed_the_picks_ids_compiles_nothing_after_the_warmup(params):
-    """The warm-up runs the decode program on host tokens alone; a round
-    dispatched ahead takes the pick's ids as they lie on the device, and
-    reaches the same executable: the rounds after the warm-up compile
-    nothing (what the benchmark counts as ``entry.compiles_in_window``)."""
+@pytest.mark.parametrize("block, n, prompt_len, max_new, seed",
+                         [("dense", 3, 6, 7, 40), ("sdar", 4, 10, 8, 0)])
+def test_a_round_fed_the_picks_ids_compiles_nothing_after_the_warmup(
+    params, block, n, prompt_len, max_new, seed
+):
+    """The warm-up runs the round's programs on host arrays, then on what
+    the pick hands the round after it as it lies on the device (the ids, or
+    a pass's folded open blocks): the rounds after the warm-up, synchronous
+    and dispatched ahead, compile nothing (what the benchmark counts as
+    ``entry.compiles_in_window``)."""
     import jax.monitoring
 
-    eng = _dense(params)
-    reqs = [Request(rid=i, prompt=_prompt(40 + i, 6), max_new_tokens=7)
-            for i in range(3)]
-    eng.warmup([6], [PCFG.blocks_for(6 + 7)])
+    eng = _engine(block, params)
+    reqs = [Request(rid=i, prompt=_prompt(seed + i, prompt_len, _vocab(eng)),
+                    max_new_tokens=max_new) for i in range(n)]
+    eng.warmup([prompt_len], [eng.pcfg.blocks_for(prompt_len + max_new)])
     compiles = []
 
     def on(name, duration, **_):
@@ -147,6 +239,7 @@ def test_a_round_fed_the_picks_ids_compiles_nothing_after_the_warmup(params):
     finally:
         jax.monitoring.unregister_event_duration_listener(on)
     assert _ahead(eng) > 0 and compiles == []
+    assert sorted(eng.completed) == list(range(n))
 
 
 # ------------------------------------------------------- when it disengages
@@ -313,93 +406,6 @@ def test_a_replica_killed_with_a_round_in_flight_returns_each_request_once(
 # ------------------------------------------------------- the diffusion pass
 
 
-def _sdar(slots=4):
-    from tests.test_sdar_moe import engine as sdar_engine, tiny as sdar_tiny
-
-    return sdar_engine(sdar_tiny(), slots=slots)
-
-
-def _sdar_prompt(n, seed):
-    from tests.test_sdar_moe import MASK
-
-    return _prompt(seed, n, MASK)
-
-
-#: step -> (prompt length, max_new_tokens) submitted before it: whole blocks
-#: and prompt tails of 1 to 3, admissions mid-stream, and (for four slots)
-#: a pass that holds all three phases and one that finishes two requests
-_PASSES = {0: [(8, 12), (10, 8), (9, 4)], 1: [(12, 8)], 2: [(7, 8)],
-           6: [(11, 4), (6, 8)], 9: [(16, 8)]}
-_PASSES_MAX_NEW = [m for step in sorted(_PASSES) for _, m in _PASSES[step]]
-
-
-def _open_blocks(eng):
-    return [None if s is None else (s.length, list(s.generated),
-                                    list(s.block.tokens), list(s.block.masked),
-                                    s.block.phase)
-            for s in eng.batcher.slots]
-
-
-def test_passes_dispatched_ahead_give_the_tokens_of_the_engine_that_waits(
-    monkeypatch
-):
-    """The same requests through two engines of the diffusion block, one
-    never dispatching a pass ahead: every request's tokens and count the
-    same, bit for bit.  (A request that arrives while a pass is in flight
-    joins the pass after it, so the two engines' steps differ there.)
-    After each pass dispatched ahead, the host's own arrays once the pass
-    before it is recorded are the ones it predicted, and its open blocks
-    the pick's fold, commit passes included."""
-    fast, slow = _sdar(), _sdar()
-    monkeypatch.setattr(slow.batcher, "continues_block", lambda slots: False)
-    predicted, phases = [], []
-    dispatch, record = fast._dispatch_pass, fast.batcher.record_block_pass
-
-    def dispatch_spy(rid, active, arrays, prev=None):
-        if prev is not None:
-            predicted.append((arrays, np.asarray(prev.tokens),
-                              np.asarray(prev.masked)))
-        return dispatch(rid, active, arrays, prev)
-
-    def record_spy(slots, ids, revealed, now):
-        phases.append({fast.batcher.slots[i].block.phase for i in slots})
-        return record(slots, ids, revealed, now)
-
-    monkeypatch.setattr(fast, "_dispatch_pass", dispatch_spy)
-    monkeypatch.setattr(fast.batcher, "record_block_pass", record_spy)
-    rid, step, finished = 0, 0, []
-    while step == 0 or not (fast.idle and slow.idle):
-        for t, m in _PASSES.get(step, []):
-            req = Request(rid=rid, prompt=_sdar_prompt(t, rid), max_new_tokens=m)
-            assert fast.submit(req) and slow.submit(req)
-            rid += 1
-        if not slow.idle:
-            slow.step()
-        if fast.idle:
-            step += 1
-            continue
-        before = len(predicted)
-        finished.append(fast.step()["finished"])
-        if len(predicted) > before:
-            host, tokens, masked = predicted[-1]
-            now = fast.batcher.block_arrays()
-            for i in (0, 1, 4, 5, 6):  # tables, lengths, reveal, commit, active
-                np.testing.assert_array_equal(host[i], now[i])
-            np.testing.assert_array_equal(tokens[now[6]], now[2][now[6]])
-            np.testing.assert_array_equal(masked[now[6]], now[3][now[6]])
-        step += 1
-    assert rid == 8
-    assert sorted(fast.completed) == sorted(slow.completed) == list(range(8))
-    for r, done in slow.completed.items():
-        np.testing.assert_array_equal(fast.completed[r].tokens, done.tokens)
-        assert fast.completed[r].n_tokens == done.n_tokens == _PASSES_MAX_NEW[r]
-    # what the traffic was for: a pass of all three phases, a commit pass
-    # folded ahead, two finishers in one round, passes that went ahead
-    assert {0, 1, 2} in phases and max(finished) >= 2
-    assert any(p[0][5].any() for p in predicted)
-    assert _ahead(fast) == len(predicted) > 0 and _ahead(slow) == 0
-
-
 def test_the_picks_fold_is_what_the_host_records_and_its_reveal_is_todays():
     """The pick a pass runs, against the reveal alone (``unmask_greedy_ids``
     on three arguments, as it was before the fold), on logits with exact
@@ -422,7 +428,7 @@ def test_the_picks_fold_is_what_the_host_records_and_its_reveal_is_todays():
                                           max_new_tokens=m))
         batcher.try_admit()
         slots = batcher.active_slots()
-        _, _, tokens, masked, reveal, commit, _ = batcher.block_arrays()
+        _, _, tokens, masked, reveal, commit, _ = batcher.batch_arrays()
         logits = rng.normal(size=(4, 4, 96)).astype(np.float32)
         logits[:, 1] = logits[:, 0]  # places 0 and 1 tie exactly
         logits[:, 3] = np.nextafter(logits[:, 2], np.inf)  # a hair apart
@@ -433,7 +439,7 @@ def test_the_picks_fold_is_what_the_host_records_and_its_reveal_is_todays():
         np.testing.assert_array_equal(revealed, want_revealed)
         commits += int(commit.sum())
         batcher.record_block_pass(slots, ids, revealed, float(n))
-        _, _, tokens, masked, _, _, still = batcher.block_arrays()
+        _, _, tokens, masked, _, _, still = batcher.batch_arrays()
         np.testing.assert_array_equal(nxt_tokens[still], tokens[still])
         np.testing.assert_array_equal(nxt_masked[still], masked[still])
         batcher.retire_ready()
@@ -441,7 +447,7 @@ def test_the_picks_fold_is_what_the_host_records_and_its_reveal_is_todays():
 
 
 def test_the_next_pass_waits_where_the_host_cannot_know_it():
-    """``continues_block`` is False for a request with stop tokens, a slot
+    """``continues`` is False for a request with stop tokens, a slot
     whose pass delivers its last block, a queued request with a free slot,
     and a preempted sequence; True otherwise."""
     from flextree_tpu.serving.batcher import BlockDiffusion
@@ -458,88 +464,60 @@ def test_the_next_pass_waits_where_the_host_cannot_know_it():
         return Request(rid=rid, prompt=_prompt(rid, 8, 90), max_new_tokens=m, **kw)
 
     b = batcher_with(req(0), req(1))
-    assert b.continues_block(b.active_slots())
-    assert not b.continues_block(b.active_slots()[:1])  # not the slots there
+    assert b.continues(b.active_slots())
+    assert not b.continues(b.active_slots()[:1])  # not the slots there
     # stop tokens
     b = batcher_with(req(0), req(1, stop_tokens=(5,)))
-    assert not b.continues_block(b.active_slots())
+    assert not b.continues(b.active_slots())
     # a pass that delivers a request's last block: a 4-token answer is one
     # block, delivered by its second denoising pass
     b = batcher_with(req(0), req(1, m=4))
     slots = b.active_slots()
-    assert b.continues_block(slots)  # phase 0 delivers nothing
+    assert b.continues(slots)  # phase 0 delivers nothing
     b.slots[slots[1]].block.phase = 1
-    assert not b.continues_block(slots)
+    assert not b.continues(slots)
     b.slots[slots[1]].request = req(1, m=8)  # one block of two: goes on
-    assert b.continues_block(slots)
+    assert b.continues(slots)
     # a queued request with a free slot, and none free
     b = batcher_with(req(0), req(1))
     assert b.submit(req(2))
-    assert b.queue and not b.continues_block(b.active_slots())
+    assert b.queue and not b.continues(b.active_slots())
     b = batcher_with(req(0), req(1), slots=2)
     assert b.submit(req(2))
-    assert b.queue and b.continues_block(b.active_slots())
+    assert b.queue and b.continues(b.active_slots())
     # a preempted sequence waiting to resume
     b = batcher_with(req(0), req(1))
     slots = b.active_slots()
     b.preempt(slots[-1])
-    assert not b.continues_block(slots[:-1])
-    # the one-token check never takes a diffusion pass, nor this one a round
-    assert not batcher_with(req(0)).continues([0])
-    one = ContinuousBatcher(PCFG, BatcherConfig(slots=2))
-    assert not one.continues_block([])
+    assert not b.continues(slots[:-1])
 
 
-def test_a_pass_fed_the_picks_blocks_compiles_nothing_after_the_warmup():
-    """The warm-up runs a pass and its pick on host arrays, then on the
-    pick's open blocks as they lie on the device; the window's passes,
-    synchronous and dispatched ahead, compile nothing."""
-    import jax.monitoring
-
-    eng = _sdar(slots=3)
-    eng.warmup([10], [eng.pcfg.blocks_for(10 + 8)])
-    compiles = []
-
-    def on(name, duration, **_):
-        if name == "/jax/core/compile/backend_compile_duration":
-            compiles.append(duration)
-
-    jax.monitoring.register_event_duration_secs_listener(on)
-    try:
-        for rid in range(4):
-            assert eng.submit(Request(rid=rid, prompt=_sdar_prompt(10, rid),
-                                      max_new_tokens=8))
-        eng.run_until_idle()
-    finally:
-        jax.monitoring.unregister_event_duration_listener(on)
-    assert _ahead(eng) > 0 and compiles == []
-    assert sorted(eng.completed) == [0, 1, 2, 3]
-
-
-def test_settle_delivers_a_pass_in_flight_exactly_once():
-    """A pass dispatched ahead, settled outside a step: its reveal is
-    recorded once (a second settle and the step that owns it record
-    nothing more), the step still counts what it delivered, and every
-    completion is the waiting engine's."""
-    fast, slow = _sdar(slots=2), _sdar(slots=2)
-    slow.batcher.continues_block = lambda slots: False
+@pytest.mark.parametrize("block", ["dense", "sdar"])
+def test_settle_delivers_a_pass_in_flight_exactly_once(params, block):
+    """A round (a pass) dispatched ahead, settled outside a step: its
+    tokens (a pass's reveal) are recorded once (a second settle and the
+    step that owns it record nothing more), the step still counts what it
+    delivered, and every completion is the waiting engine's."""
+    fast, slow = _engine(block, params, slots=2), _engine(block, params, slots=2)
+    slow.batcher.continues = lambda slots: False
     for eng in (fast, slow):
         for rid in range(2):
-            assert eng.submit(Request(rid=rid, prompt=_sdar_prompt(9, rid),
+            assert eng.submit(Request(rid=rid, prompt=_prompt(rid, 9, _vocab(eng)),
                                       max_new_tokens=8))
     assert fast.step() == slow.step()
     assert fast._ahead is not None and not fast._ahead.delivered
-    revealed = fast.report()["counters"]["serve.tokens_revealed"]
+    counter = "serve.tokens_revealed" if block == "sdar" else "serve.decode_tokens"
+    recorded = fast.report()["counters"][counter]
     fast.settle()
     assert fast._ahead.delivered and fast.decode_steps == 2
     counters = fast.report()["counters"]
-    assert counters["serve.tokens_revealed"] > revealed
+    assert counters[counter] > recorded
     fast.settle()
     assert fast.decode_steps == 2
     assert fast.report()["counters"] == counters
     assert fast.step() == slow.step()
     assert fast.decode_steps == slow.decode_steps == 2
-    assert _open_blocks(fast) == _open_blocks(slow)
+    assert _slots(fast) == _slots(slow)
     fast.run_until_idle()
     slow.run_until_idle()
     for rid in range(2):
@@ -557,7 +535,7 @@ def test_the_spans_and_the_counter_count_the_passes_dispatched_ahead():
 
     eng = _sdar(slots=3)
     for rid in range(4):
-        assert eng.submit(Request(rid=rid, prompt=_sdar_prompt(7 + rid, rid),
+        assert eng.submit(Request(rid=rid, prompt=_prompt(rid, 7 + rid, _vocab(eng)),
                                   max_new_tokens=8))
     with flight_recorder(None) as rec:
         eng.run_until_idle()
